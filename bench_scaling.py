@@ -60,10 +60,12 @@ import os
 import subprocess
 import sys
 
-# Measured single-chip step times (this repo's own TPU v5e measurements;
-# BASELINE.json.published is empty, so these are the only real numbers).
+# Single-chip step times measured on a TPU v5e on an earlier runtime
+# (July-August 2026, not reproduced on today's libtpu; docs/benchmarks.md).
+# BASELINE.json.published is empty, so these are the only chip numbers the
+# projection has until the S1 benchmark re-measures them.
 MEASURED_STEP_SECONDS = {
-    # 2,542 img/s/chip at batch 256 (BENCH_r02.json).
+    # 2,542 img/s/chip at batch 256.
     "rn50": 256 / 2542.27,
     # 354 seq/s/chip at batch 32, seq 128 (docs/benchmarks.md, round 2;
     # reproduced round 5: fp16 354.2 same-process as the fp8 row below).

@@ -31,7 +31,7 @@ _sys.path.insert(0, _dir(_dir(_abs(__file__))))  # repo root importable
 
 import argparse
 
-from _harness import setup_devices, timed_training
+from _harness import require_tpu, setup_devices, timed_training
 
 
 def serve_multi_lora(args):
@@ -131,6 +131,8 @@ def main():
     args = p.parse_args()
 
     setup_devices(args.cpu_devices)
+    if args.full or args.mid:
+        require_tpu("--8b" if args.full else "--1b", args.cpu_devices)
     if args.serve_adapters:
         serve_multi_lora(args)
         return

@@ -16,7 +16,6 @@ Public API (mirrors ``import horovod.torch as hvd`` surface)::
     step = hvd.make_train_step(loss_fn, opt)
 """
 
-from .core import compat as _compat  # noqa: F401  (jax version shims)
 from .core.basics import (  # noqa: F401
     init, shutdown, is_initialized, mesh, reduce_axes,
     size, rank, local_size, local_rank, cross_size, cross_rank,

@@ -1,11 +1,11 @@
-"""Latency-hiding XLA / libtpu flag pack for the backward-overlap exchange.
+"""Latency-hiding libtpu flag pack for the backward-overlap exchange.
 
 The microbatched train step (``training.py``, ``microbatches=k``) emits the
 per-bucket ``reduce-scatter`` of microbatch *i* between the backward segments
 of microbatch *i+1*, but the emitted schedule only turns into *wall-clock*
 overlap when the compiler (a) runs collectives asynchronously and (b) uses
 the latency-hiding scheduler to sink compute between collective-start and
-collective-done.  On TPU those behaviours sit behind XLA/libtpu flags that
+collective-done.  On TPU those behaviours sit behind libtpu flags that
 must be set **before** the backend initialises.
 
 This module assembles the recommended pack and applies it to the process
@@ -15,7 +15,7 @@ applied vs. rejected and why.  Design rules:
 * **No-op on CPU.**  The flags are TPU-only; on the CPU backend (tests,
   laptops) every flag is rejected with reason ``"cpu backend"`` and the
   environment is left untouched.
-* **User flags win.**  A flag the user already set in ``XLA_FLAGS`` /
+* **User flags win.**  A flag the user already set in
   ``LIBTPU_INIT_ARGS`` is never overridden (reason ``"user-set"``).
 * **Too late is an error, not a surprise.**  If the JAX backend is already
   initialised the pack cannot take effect; every flag is rejected with
@@ -34,19 +34,19 @@ import dataclasses
 import os
 from typing import Dict, Mapping, MutableMapping, Optional, Tuple
 
-# The pack.  Keyed by the environment variable each flag belongs to:
-# ``XLA_FLAGS`` feeds the host-side XLA compiler, ``LIBTPU_INIT_ARGS``
-# feeds libtpu at device initialisation.  Values are the full
-# ``--flag=value`` strings appended (space-separated) to the variable.
+# The pack.  Keyed by the environment variable each flag belongs to;
+# values are the full ``--flag=value`` strings appended (space-separated)
+# to the variable.  Every flag here is a libtpu flag and rides
+# ``LIBTPU_INIT_ARGS``: jaxlib 0.9.0 aborts the process on any of them in
+# ``XLA_FLAGS`` ("Unknown flag in XLA_FLAGS"), libtpu 0.0.34 accepts all
+# nine and rejects an unknown name ("Unknown command line flag").
 XLA_FLAG_PACK: Dict[str, Tuple[str, ...]] = {
-    "XLA_FLAGS": (
+    "LIBTPU_INIT_ARGS": (
         # Sink independent compute between collective start/done pairs.
         "--xla_tpu_enable_latency_hiding_scheduler=true",
         # Run all-gathers (the microbatch finalize's single AG) async.
         "--xla_enable_async_all_gather=true",
         "--xla_enable_async_collective_permute=true",
-    ),
-    "LIBTPU_INIT_ARGS": (
         # Fuse the per-bucket reduce-scatters with surrounding compute into
         # async pairs so backward(i+1) runs during exchange(i).
         "--xla_tpu_enable_async_collective_fusion=true",

@@ -3,8 +3,11 @@
 One environment flag, ``HOROVOD_PALLAS`` (``HVD_TPU_PALLAS``), gates
 every Pallas kernel family in the package:
 
-- ``auto`` (default): kernels run on TPU, the XLA reference runs
-  elsewhere;
+- ``auto`` (default): on TPU the families Mosaic has compiled at their
+  callers' full-width shapes run as kernels (``flash``,
+  ``flash_decode``); ``fused_update`` and ``bn_bwd`` resolve to the XLA
+  path (see ``_AUTO_XLA``).  Off TPU every family takes the XLA
+  reference;
 - ``1``: force the kernels everywhere (off-TPU they run in the Pallas
   interpreter -- slow, but numerically the kernel path; this is what the
   CPU parity tests and the CI step audit use);
@@ -82,6 +85,15 @@ _FAMILY_ENV = {
     "bn_bwd": "PALLAS_BN",
 }
 
+# Families whose ``auto`` resolves to the XLA path even on TPU; an
+# explicit ``1`` still runs the kernels.  ``bn_bwd``: XLA's fused
+# backward beat the two-pass kernel's 7N-byte floor at the RN50 sites
+# (docs/benchmarks.md, round 5).  ``fused_update``: Mosaic refuses the
+# kernels at RN50's PowerSGD bucket shapes -- ``(256, c)`` f32 row blocks
+# overflow the 16 MB scoped-VMEM limit at c = 4096, and a near-square
+# dim with no 128-multiple divisor has no legal lane tiling.
+_AUTO_XLA = frozenset({"bn_bwd", "fused_update"})
+
 _warned_legacy = False
 
 
@@ -112,8 +124,8 @@ def pallas_enabled(family: str) -> bool:
 
     Resolution order: the per-family override, then (for ``flash``) the
     legacy ``HVD_TPU_FLASH`` flag, then the global ``HOROVOD_PALLAS``,
-    then ``auto`` (TPU only).  Read per call: tests flip the env between
-    traces.
+    then ``auto`` (TPU only, minus ``_AUTO_XLA``).  Read per call: tests
+    flip the env between traces.
     """
     if family not in KERNEL_CONTRACTS:
         raise ValueError(f"unknown pallas kernel family {family!r}; "
@@ -124,12 +136,20 @@ def pallas_enabled(family: str) -> bool:
     if flag is None:
         flag = _read("PALLAS")
     if flag in (None, "", "auto"):
-        return jax.default_backend() == "tpu"
+        return (jax.default_backend() == "tpu"
+                and family not in _AUTO_XLA)
     return flag != "0"
 
 
 def interpret_mode() -> bool:
-    """Pallas kernels interpret off-TPU (CPU tests, the CI step audit)."""
+    """Pallas kernels interpret off-TPU (CPU tests, the CI step audit).
+
+    Keyed on the process default backend, like ``pallas_enabled``: a
+    kernel forced on (``=1``) and then run on a non-default device fails
+    to lower loudly; it never silently interprets on a TPU process.
+    ``chip_smoke.py`` counts the Mosaic custom calls in each lowered step
+    to prove the compiled path is the one that ran.
+    """
     return jax.default_backend() != "tpu"
 
 

@@ -15,6 +15,10 @@ agent runs the same per-process entry with the coordinator on worker 0.
 Usage::
 
     python -m horovod_tpu.run -np 4 --cpu python train.py --epochs 1
+
+``-np N`` above 1 needs ``--cpu``: a TPU chip belongs to one process, and
+one process drives all of a host's chips (``hvd.size()`` is the chip
+count), so on a TPU host the job is ``python train.py``.
 """
 
 from __future__ import annotations
@@ -202,9 +206,9 @@ def run_command(args: Optional[List[str]] = None) -> int:
         if not all_local(hosts):
             parser.error(
                 "remote hosts in -H/--hostfile: this launcher spawns "
-                "processes locally (on TPU pods each worker VM's agent "
-                "runs `hvdrun` with its local slots; point every VM at "
-                "the same --coordinator and use HOROVOD_RANK offsets). "
+                "processes locally (on TPU pods each worker VM runs ONE "
+                "process -- `hvdrun -np 1` -- pointed at the same "
+                "--coordinator with its own HOROVOD_RANK). "
                 f"Got: {', '.join(h for h, _ in hosts)}")
         if np_ is None:
             np_ = total_slots(hosts)
@@ -222,13 +226,26 @@ def run_command(args: Optional[List[str]] = None) -> int:
                 parser.error(str(e))
             if not all_local(hosts):
                 parser.error(
-                    "LSF allocation spans multiple hosts: run hvdrun on "
-                    "each worker VM with -np <local slots> and a shared "
-                    "--coordinator. Hosts: "
+                    "LSF allocation spans multiple hosts: run `hvdrun -np "
+                    "1` on each worker VM with a shared --coordinator. "
+                    "Hosts: "
                     f"{', '.join(h for h, _ in hosts)}")
             np_ = total_slots(hosts)
     if np_ is None:
         np_ = 1
+    if np_ > 1 and not opts.cpu and not opts.host_discovery_script:
+        # A TPU chip belongs to one process.  N unpinned workers would each
+        # open every local chip: the first wins, the rest fail or hang.
+        parser.error(
+            f"-np {np_} without --cpu would start {np_} processes on this "
+            "host that each open every local TPU chip, and a chip belongs "
+            "to one process. Supported: ONE process drives all local "
+            "chips (`python train.py`, or `hvdrun -np 1 python train.py`; "
+            "hvd.size() is the chip count); across hosts, one process per "
+            "host (hvd.init() bootstraps from TPU_WORKER_HOSTNAMES, or run "
+            "`hvdrun -np 1` on each host with a shared --coordinator and "
+            "HOROVOD_RANK/HOROVOD_SIZE); `--cpu -np N` starts N CPU "
+            "workers for tests.")
     if opts.host_discovery_script:
         from ..core.config import load_config
         from ..elastic.driver import ElasticDriver

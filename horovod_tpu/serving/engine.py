@@ -47,11 +47,12 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
 
 from ..core.config import _env, _env_bool, _env_int
 from ..timeline import spans as _spans
-from .decode import (build_decode_step, build_verify_step, greedy_sample,
-                     prefill_forward)
+from .decode import (build_decode_step, build_verify_step,
+                     decode_param_specs, greedy_sample, prefill_forward)
 from .kvcache import (CacheConfig, PagedKVCache, PrefixCache,
                       cache_sharding)
 from .scheduler import (ContinuousBatchScheduler, Request,
@@ -164,7 +165,20 @@ def _pct(values: List[float], q: float) -> float:
 
 
 class ServingEngine:
-    """Continuous-batching inference over one Llama-family model."""
+    """Continuous-batching inference over one Llama-family model.
+
+    ``mesh``: the ``("tp",)`` mesh the decode step and the KV pool shard
+    over.  The default, ``mesh=None``, is ``jax.devices()[:1]`` -- ONE
+    chip, however many the host has; pass a mesh over the chips you mean
+    to use.
+
+    ``params``: the tree as ``model.init`` / a checkpoint restore returns
+    it (one device, uncommitted).  Prefill is replicated math and runs
+    there; the engine places a second, tp-sharded copy on the mesh for
+    the decode step once, at construction.  A tree already sharded over
+    several chips cannot feed prefill on a TPU: GSPMD does not partition
+    the Mosaic flash kernel.
+    """
 
     def __init__(self, config, params, *, mesh=None, slots: int = 0,
                  page_size: int = 0, max_len: int = 0, dtype=jnp.float32,
@@ -178,9 +192,9 @@ class ServingEngine:
         self.config = config
         self.params = params
         if mesh is None:
-            from jax.sharding import Mesh
             mesh = Mesh(np.asarray(jax.devices()[:1]), ("tp",))
         self.mesh = mesh
+        self._decode_params = self._place_decode_params()
         self.slots = slots or _env_int("SERVING_SLOTS", 8)
         self.page_size = page_size or _env_int("SERVING_PAGE_SIZE", 16)
         self.max_len = max_len or _env_int("SERVING_MAX_LEN",
@@ -273,6 +287,13 @@ class ServingEngine:
         # past).  Slots in here are state "prefill" and excluded from
         # the decode batch until their last chunk lands.
         self._chunking: Dict[int, Dict[str, Any]] = {}
+
+    def _place_decode_params(self):
+        """The decode step's copy of the params, sharded over ``tp`` --
+        placed once, so a dispatch does not re-shard the tree."""
+        return jax.device_put(self.params, jax.tree.map(
+            lambda spec: NamedSharding(self.mesh, spec),
+            decode_param_specs(self.params)))
 
     # -- one-request helpers ----------------------------------------------
     def _begin_prefill(self, st: Dict[str, Any], slot: int, req: Request,
@@ -428,7 +449,7 @@ class ServingEngine:
             cache.reserve(slot, length + 1, writable_from=length)
         active = np.zeros((self.slots,), bool)
         active[slots] = True
-        args = [self.params, cache.k, cache.v,
+        args = [self._decode_params, cache.k, cache.v,
                 jnp.asarray(np.array(st["last_tokens"])),
                 cache.lengths_device(), cache.table_device(),
                 jnp.asarray(active)]
@@ -490,7 +511,8 @@ class ServingEngine:
         tokens_in[:, 1:] = drafts
         active = np.zeros((self.slots,), bool)
         active[slots] = True
-        args = [self.params, cache.k, cache.v, jnp.asarray(tokens_in),
+        args = [self._decode_params, cache.k, cache.v,
+                jnp.asarray(tokens_in),
                 cache.lengths_device(), cache.table_device(),
                 jnp.asarray(active)]
         if self.kv_compress:
@@ -549,6 +571,7 @@ class ServingEngine:
         """
         old_tp = int(self.mesh.devices.size)
         self.mesh = mesh
+        self._decode_params = self._place_decode_params()
         self.cache = PagedKVCache(self.cache_config, cache_sharding(mesh))
         self.scheduler.cache = self.cache
         if self._prefix is not None:

@@ -46,35 +46,13 @@ def set_host_device_flag(xla_flags: str, n: int) -> str:
 
 
 def backend_initialized() -> bool:
-    """Best-effort: has jax already created a live backend in this process?
+    """Has jax already created a live backend in this process?
 
-    Probes a private jax internal; any failure (renamed module/attr after a
-    jax upgrade) is treated as "unknown", reported as uninitialized so
-    callers proceed with the normal pre-init path.
+    jax 0.9 exposes no public probe; ``backends_are_initialized`` is the
+    function jax's own config validators call.
     """
-    try:
-        import jax._src.xla_bridge as xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:
-        return False
-
-
-def multiprocess_cpu_supported() -> bool:
-    """Can this jax run MULTI-PROCESS computations on the CPU backend?
-
-    The ``run -np N --cpu`` localhost mode jits programs over a mesh that
-    spans several processes' CPU devices; jaxlib only implements the
-    cross-host CPU transfers this needs from the 0.5 line on (older
-    runtimes raise ``Multiprocess computations aren't implemented on the
-    CPU backend``).  Single-process virtual-device meshes
-    (``force_host_device_count``) work everywhere and are not gated by
-    this.
-    """
-    try:
-        import jax
-        return tuple(int(p) for p in jax.__version__.split(".")[:2]) >= (0, 5)
-    except Exception:
-        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def force_host_device_count(n: int, cpu: bool = True,
@@ -93,3 +71,27 @@ def force_host_device_count(n: int, cpu: bool = True,
     if cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
+
+
+# <checkout>/.jax_cache: fixed by the package's location, so every run of
+# the same checkout finds what the last one compiled.
+_DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: when it
+    is set, jax has already read it and no directory is set in code.
+    Otherwise the cache lives at ``<checkout>/.jax_cache`` (gitignored).
+    The persistence thresholds are jax's own (compiles of 1.0 s and up,
+    any size).  Call before the process's first compile -- jax latches
+    whether the cache is in use at that point.
+    """
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          _DEFAULT_COMPILE_CACHE)
+    return jax.config.jax_compilation_cache_dir

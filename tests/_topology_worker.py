@@ -1,18 +1,26 @@
 """Deviceless topology-AOT worker (spawned by test_scaling.py).
 
-Compiles a tiny shard_map program (one matmul + one psum + one ppermute)
-against a real TPU topology via ``jax.experimental.topologies`` -- no TPU
-attached -- and prints one JSON line describing the compiled SCHEDULE.
-This is the CI gate for the round-4 evidence mechanism: if the toolchain
-stops emitting scheduled modules, async collective-permute pairs, or
-sync all-reduces, this worker's output changes and the test fails,
-instead of docs/benchmarks.md silently rotting.
+``<topology>``: compiles a tiny shard_map program (one matmul + one psum
++ one ppermute) against a real TPU topology via
+``jax.experimental.topologies`` -- no TPU attached -- and prints one JSON
+line describing the compiled SCHEDULE.  This is the CI gate for the
+round-4 evidence mechanism: if the toolchain stops emitting scheduled
+modules, async collective-permute pairs, or sync all-reduces, this
+worker's output changes and the test fails, instead of
+docs/benchmarks.md silently rotting.
+
+``<topology> kernels``: compiles each Pallas family that ``auto``
+enables on TPU through Mosaic (``interpret=False``) at the shapes
+``chip_smoke.py`` runs, and prints ``{case: mosaic_call_count}``.  A
+kernel Mosaic refuses raises here, in the sandbox, before any chip time
+is spent on it.
 
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
 
 import json
+import os
 import sys
 from os.path import abspath, dirname
 
@@ -59,5 +67,59 @@ def main(topology: str) -> int:
     return 0
 
 
+def kernels(topology: str) -> int:
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops import attention, pallas
+
+    # This process's default backend is the CPU (no chip attached), so
+    # the package would pick the XLA reference and, forced on, the
+    # interpreter.  Force the kernels on through the package's own
+    # switch and pin the interpreter off: what lowers below is what
+    # lowers on the chip.
+    pallas.interpret_mode = lambda: False
+
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    sharding = SingleDeviceSharding(td.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def flash_fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: attention.flash_attention(*a).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def decode(q, k, v, lengths):
+        return attention.decode_attention(q, k, v, lengths=lengths)
+
+    cases = {
+        # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.
+        "flash_bert_large": (
+            flash_fwd_bwd, [spec((32, 16, 128, 64), jnp.bfloat16)] * 3),
+        # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
+        "flash_decode_b8": (decode, [
+            spec((8, 16, 1, 128), jnp.float32),
+            spec((8, 8, 1024, 128), jnp.float32),
+            spec((8, 8, 1024, 128), jnp.float32),
+            spec((8,), jnp.int32)]),
+    }
+    out = {}
+    for name, (fn, args) in cases.items():
+        lowered = jax.jit(fn).lower(*args)
+        lowered.compile()   # Mosaic refusals raise here
+        out[name] = lowered.as_text().count("tpu_custom_call")
+    print(json.dumps(out))
+    return 0
+
+
 if __name__ == "__main__":
-    sys.exit(main(sys.argv[1] if len(sys.argv) > 1 else "v5e:2x4"))
+    topo = sys.argv[1] if len(sys.argv) > 1 else "v5e:2x4"
+    if sys.argv[2:] == ["kernels"]:
+        os.environ["HOROVOD_PALLAS"] = "1"
+        sys.exit(kernels(topo))
+    sys.exit(main(topo))

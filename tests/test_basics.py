@@ -70,6 +70,18 @@ def test_hierarchical_mesh_single_process(n_devices):
     horovod_tpu.shutdown()
 
 
+def test_force_cpu_after_other_backend_raises(monkeypatch):
+    """HOROVOD_FORCE_CPU only takes effect before the first backend
+    exists; asked for too late it must not carry on on the accelerator."""
+    horovod_tpu.shutdown()
+    horovod_tpu.init(config=Config(force_cpu=True))  # CPU is up: fine
+    horovod_tpu.shutdown()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="already initialized"):
+        horovod_tpu.init(config=Config(force_cpu=True))
+    assert not horovod_tpu.is_initialized()
+
+
 def test_allgather_object(hvd, n_devices):
     objs = hvd.allgather_object({"rank_data": [1, 2, 3], "s": "hello"})
     assert len(objs) == n_devices

@@ -5,17 +5,6 @@ import sys
 
 import pytest
 
-from horovod_tpu.utils.platform import multiprocess_cpu_supported
-
-# These tests launch REAL multi-process XLA computations; this jaxlib's
-# CPU backend cannot run them ("Multiprocess computations aren't
-# implemented on the CPU backend"), so they only run on capable jaxlib
-# builds / real accelerators.
-_requires_multiprocess = pytest.mark.skipif(
-    not multiprocess_cpu_supported(),
-    reason="this jaxlib cannot run multiprocess computations on the "
-           "CPU backend")
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -402,7 +391,6 @@ def test_executor_parallel_materialization(tmp_path):
         assert tuple(r_) in all_rows
 
 
-@_requires_multiprocess
 def test_executor_materialization_matches_driver_training(tmp_path):
     """End-to-end fit() through the executor path trains to the same
     quality as the driver-streamed path on the same data."""
@@ -486,7 +474,6 @@ def test_write_shards_validation_stripe(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_jax_estimator_fit_transform(tmp_path):
     from horovod_tpu.spark import JaxEstimator, LocalStore
     x, y = _blobs(n=64)
@@ -511,7 +498,6 @@ class _TorchMLP(__import__("torch").nn.Module):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_torch_estimator_fit_transform(tmp_path):
     from horovod_tpu.spark import LocalStore, TorchEstimator
     x, y = _blobs(n=64)
@@ -525,7 +511,6 @@ def test_torch_estimator_fit_transform(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_keras_estimator_fit_transform(tmp_path):
     import tensorflow as tf
     from horovod_tpu.spark import KerasEstimator, LocalStore
@@ -599,7 +584,6 @@ def test_elastic_ray_executor_requires_source_without_ray():
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_elastic_ray_executor_runs_function(tmp_path):
     from horovod_tpu.ray import ElasticRayExecutor
     hosts = tmp_path / "hosts.txt"
@@ -641,7 +625,6 @@ def test_lightning_estimator_rejects_plain_module():
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_lightning_estimator_fit_transform(tmp_path):
     from horovod_tpu.spark import LightningEstimator, LocalStore
     x, y = _blobs(n=64)

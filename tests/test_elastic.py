@@ -14,17 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.utils.platform import multiprocess_cpu_supported
-
-# These tests launch REAL multi-process XLA computations; this jaxlib's
-# CPU backend cannot run them ("Multiprocess computations aren't
-# implemented on the CPU backend"), so they only run on capable jaxlib
-# builds / real accelerators.
-_requires_multiprocess = pytest.mark.skipif(
-    not multiprocess_cpu_supported(),
-    reason="this jaxlib cannot run multiprocess computations on the "
-           "CPU backend")
-
 import horovod_tpu as hv
 from horovod_tpu import elastic
 from horovod_tpu.elastic.notify import (Notifier, read_assignment,
@@ -193,7 +182,6 @@ def _run_elastic_live(tmp_path, initial, mutated, expect_final, target=40,
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_elastic_scale_down_live(tmp_path):
     """3 workers -> discovery drops one -> survivors re-rendezvous at size
     2 and finish."""
@@ -202,7 +190,6 @@ def test_elastic_scale_down_live(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_elastic_network_rendezvous_live(tmp_path):
     """Same scale-down flow, but membership + heartbeats ride the
     HMAC-signed HTTP KV rendezvous instead of the assignment file."""
@@ -212,7 +199,6 @@ def test_elastic_network_rendezvous_live(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_elastic_scale_up_live(tmp_path):
     """2 workers -> discovery adds a third -> everyone re-rendezvouses at
     size 3 and finishes together (newcomer adopts survivors' progress)."""
@@ -314,7 +300,6 @@ def test_comm_failure_classifier_requires_runtime_type():
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_preemption_sigterm_live(tmp_path):
     """A real SIGTERM to one worker mid-training: it leaves via the
     commit-boundary interrupt (graceful marker printed, state committed),
@@ -348,7 +333,6 @@ def test_discovery_failure_keeps_last_known_hosts(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_elastic_resnet50_variant(tmp_path):
     """BASELINE's elastic-RN50 workload: the flax ResNet-50 behind the
     same commit/restore protocol (static 2-host membership smoke)."""
@@ -428,7 +412,6 @@ def test_gce_poll_stop_idempotent_and_reset_stops_it(monkeypatch):
 
 @pytest.mark.integration
 @pytest.mark.slow
-@_requires_multiprocess
 def test_chaos_kill_rank_live(tmp_path):
     """Deterministic chaos kill: HOROVOD_CHAOS SIGKILLs rank 1 at step
     5; the driver evicts the dead worker and the survivors finish at

@@ -184,10 +184,6 @@ def test_allreduce_benchmark_cpu():
 
 @pytest.mark.integration
 def test_tensorflow2_mnist_two_process():
-    from horovod_tpu.utils.platform import multiprocess_cpu_supported
-    if not multiprocess_cpu_supported():
-        pytest.skip("this jaxlib cannot run multiprocess computations on "
-                    "the CPU backend")
     out = _run(["-m", "horovod_tpu.run", "-np", "2", "--cpu",
                 sys.executable,
                 os.path.join(REPO, "examples", "tensorflow2_mnist.py"),

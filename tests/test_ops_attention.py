@@ -479,6 +479,11 @@ def test_pallas_switch_resolution(monkeypatch):
         monkeypatch.delenv(var, raising=False)
     # auto: follows the backend (CPU here -> off).
     assert not _pallas.pallas_enabled("flash")
+    # auto on TPU: only the families Mosaic compiles at full width; the
+    # refuted BN kernel and the refused PowerSGD kernels stay on XLA.
+    with monkeypatch.context() as m:
+        m.setattr(_pallas.jax, "default_backend", lambda: "tpu")
+        assert _pallas.active_kernels() == ("flash", "flash_decode")
     # global switch gates every family...
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     assert _pallas.active_kernels() == _pallas.registered_kernels()

@@ -1,5 +1,9 @@
 """Unit tests for the shared pre-init platform-forcing helper."""
 
+import os
+import subprocess
+import sys
+
 from horovod_tpu.utils.platform import (backend_initialized,
                                         merge_host_device_flag)
 
@@ -47,26 +51,42 @@ def test_backend_initialized_reports_true_under_conftest():
     assert backend_initialized()
 
 
-def test_package_import_does_not_initialize_backend():
-    """Guard the pre-init contract structurally: the platform helper is
-    reached through ``horovod_tpu.__init__``, so that import graph must
-    never initialize a jax backend -- otherwise every pre-init entry point
-    (conftest, examples, the driver dryrun) silently regresses to the
-    round-1 one-device failure."""
-    import os
-    import subprocess
-    import sys
-    from os.path import abspath, dirname
+# ---------------------------------------------------------------------------
+# Compile cache placement (configure_compile_cache, shared by hvd.init()
+# and chip_smoke.py).
+# ---------------------------------------------------------------------------
 
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_compile_cache_defaults_to_checkout(hvd, monkeypatch):
+    """Unset, the cache is ``<checkout>/.jax_cache`` -- computed from the
+    package location, so two inits (and two runs) give the same path."""
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        paths = []
+        for _ in range(2):
+            hvd.shutdown()
+            hvd.init()
+            paths.append(jax.config.jax_compilation_cache_dir)
+        assert paths == [os.path.join(REPO, ".jax_cache")] * 2
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_env_var_wins(tmp_path):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, no code path sets another
+    directory: jax read the variable at import and hvd.init() leaves it."""
     proc = subprocess.run(
         [sys.executable, "-c",
-         "import horovod_tpu\n"
-         "from horovod_tpu.utils.platform import backend_initialized\n"
-         "assert not backend_initialized(), 'import initialized a backend'\n"
-         "print('IMPORT_CLEAN')"],
-        cwd=dirname(dirname(abspath(__file__))), env=env,
-        capture_output=True, text=True, timeout=300)
+         "import jax, horovod_tpu as hvd\n"
+         "hvd.init(); first = jax.config.jax_compilation_cache_dir\n"
+         "hvd.shutdown(); hvd.init()\n"
+         "print(first); print(jax.config.jax_compilation_cache_dir)"],
+        cwd=REPO, capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
     assert proc.returncode == 0, (proc.stdout[-2000:], proc.stderr[-2000:])
-    assert "IMPORT_CLEAN" in proc.stdout
+    assert proc.stdout.split() == [str(tmp_path)] * 2

@@ -7,17 +7,6 @@ import sys
 
 import pytest
 
-from horovod_tpu.utils.platform import multiprocess_cpu_supported
-
-# These tests launch REAL multi-process XLA computations; this jaxlib's
-# CPU backend cannot run them ("Multiprocess computations aren't
-# implemented on the CPU backend"), so they only run on capable jaxlib
-# builds / real accelerators.
-_requires_multiprocess = pytest.mark.skipif(
-    not multiprocess_cpu_supported(),
-    reason="this jaxlib cannot run multiprocess computations on the "
-           "CPU backend")
-
 from horovod_tpu.run import check_build, free_port, worker_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -53,8 +42,19 @@ def test_cli_requires_command():
         run_command(["-np", "2"])
 
 
+def test_cli_refuses_unpinned_tpu_workers(capsys):
+    """-np N > 1 without --cpu would start N processes that each open
+    every local chip; the launcher exits at once and names the supported
+    mode instead of hanging on the chip."""
+    from horovod_tpu.run import run_command
+    for argv in (["-np", "4"], ["-H", "localhost:4"]):
+        with pytest.raises(SystemExit) as exc:
+            run_command(argv + ["python", "x.py"])
+        assert exc.value.code == 2
+        assert "ONE process drives all local chips" in capsys.readouterr().err
+
+
 @pytest.mark.integration
-@_requires_multiprocess
 def test_two_process_static_run():
     """Spawn a real 2-process job through the CLI (slow: ~30s)."""
     env = dict(os.environ)
@@ -150,7 +150,6 @@ def test_ipv6_host_specs():
 
 @pytest.mark.integration
 @pytest.mark.parametrize("np_", [2, 3])
-@_requires_multiprocess
 def test_join_drains_stragglers(np_):
     """Reference JoinOp behavior: ranks stop after different batch counts;
     survivors' averages cover active ranks only; nobody deadlocks; join
@@ -214,7 +213,6 @@ if __name__ == "__main__":
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_peer_death_error_classification(tmp_path):
     """Pin the elastic classifier against the LIVE error surface of this
     JAX version: kill a peer mid-collective; the survivor's exception
@@ -235,7 +233,6 @@ def test_peer_death_error_classification(tmp_path):
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_launcher_dash_h_derives_np():
     """-H localhost:2 with no -np runs 2 workers end-to-end."""
     env = dict(os.environ)
@@ -608,7 +605,6 @@ print(f"rank {{r}}: tf1 hook OK", flush=True)
 
 
 @pytest.mark.integration
-@_requires_multiprocess
 def test_tf1_hook_broadcasts_across_processes(tmp_path):
     """The TF1 session hook moves rank 0's initial variable values to every
     rank through the mesh broadcast (reference hook semantics)."""

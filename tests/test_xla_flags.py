@@ -35,22 +35,23 @@ def test_tpu_platform_applies_full_pack():
     for var, flags in xla_flags.XLA_FLAG_PACK.items():
         for f in flags:
             assert f in env[var].split()
-    # The scheduler flag specifically must land in XLA_FLAGS.
-    assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        in env["XLA_FLAGS"]
+    # jaxlib aborts on any of these in XLA_FLAGS; the whole pack rides
+    # LIBTPU_INIT_ARGS.
+    assert "XLA_FLAGS" not in env
 
 
 def test_user_set_flag_wins():
     user = "--xla_tpu_enable_latency_hiding_scheduler=false"
-    env = {"XLA_FLAGS": user}
+    env = {"LIBTPU_INIT_ARGS": user}
     report = xla_flags.apply_xla_flags(env=env, platform="tpu")
     assert report.rejected == {
         "--xla_tpu_enable_latency_hiding_scheduler=true": "user-set"}
     # The user's value is preserved verbatim, pack flags appended after.
-    assert env["XLA_FLAGS"].split()[0] == user
+    assert env["LIBTPU_INIT_ARGS"].split()[0] == user
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" \
-        not in env["XLA_FLAGS"].split()
-    assert "--xla_enable_async_all_gather=true" in env["XLA_FLAGS"].split()
+        not in env["LIBTPU_INIT_ARGS"].split()
+    assert "--xla_enable_async_all_gather=true" \
+        in env["LIBTPU_INIT_ARGS"].split()
 
 
 def test_apply_is_idempotent():
@@ -74,12 +75,12 @@ def test_detect_platform_prefers_env_vars():
 
 
 def test_report_summary_lists_applied_and_rejected():
-    env = {"XLA_FLAGS": "--xla_enable_async_all_gather=false"}
+    env = {"LIBTPU_INIT_ARGS": "--xla_enable_async_all_gather=false"}
     report = xla_flags.apply_xla_flags(env=env, platform="tpu")
     text = report.summary()
     assert "platform=tpu" in text
-    assert "+ XLA_FLAGS: --xla_tpu_enable_latency_hiding_scheduler=true" \
-        in text
+    assert ("+ LIBTPU_INIT_ARGS: "
+            "--xla_tpu_enable_latency_hiding_scheduler=true") in text
     assert "- --xla_enable_async_all_gather=true  (user-set)" in text
 
 

@@ -237,10 +237,6 @@ def test_zero_report_accounting():
 @pytest.mark.integration
 @pytest.mark.parametrize("nproc", [2, 4])
 def test_zero1_parity_multiprocess(nproc):
-    from horovod_tpu.utils.platform import multiprocess_cpu_supported
-    if not multiprocess_cpu_supported():
-        pytest.skip("this jaxlib cannot run multiprocess computations on "
-                    "the CPU backend")
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     out = subprocess.run(
