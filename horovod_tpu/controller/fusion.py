@@ -278,9 +278,11 @@ def fused_tree_collective(tree, collective_fn,
     if not leaves:
         return tree
     spec = plan_buckets(leaves, threshold_bytes, extra=extra)
-    buffers = pack(leaves, spec)
+    with jax.named_scope("hvd_exchange/pack"):
+        buffers = pack(leaves, spec)
     reduced = [collective_fn(b) for b in buffers]
-    return jax.tree.unflatten(treedef, unpack(reduced, spec))
+    with jax.named_scope("hvd_exchange/unpack"):
+        return jax.tree.unflatten(treedef, unpack(reduced, spec))
 
 
 # -- explicit leg planning (two-level exchange) ----------------------------

@@ -305,30 +305,32 @@ def _flash_decode(q, k, v, lengths, scale: float, bk: int):
         "kernel", kernel="flash_decode",
         nbytes=k.size * k.dtype.itemsize * 2).legs[0])
     kernel = functools.partial(_decode_kernel, scale=scale, bk=bk, nk=nk)
-    o = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h_kv, nk),
-            in_specs=[
-                pl.BlockSpec((1, 1, rep, d),
-                             lambda bi, hi, j, lens: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, 1, bk, d),
-                             lambda bi, hi, j, lens: (bi, hi, j, 0)),
-                pl.BlockSpec((1, 1, bk, d),
-                             lambda bi, hi, j, lens: (bi, hi, j, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rep, d),
-                                   lambda bi, hi, j, lens: (bi, hi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((rep, _LANES), jnp.float32),
-                pltpu.VMEM((rep, _LANES), jnp.float32),
-                pltpu.VMEM((rep, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, d), q.dtype),
-        interpret=_pallas.interpret_mode(),
-    )(lengths.astype(jnp.int32), q4, k, v)
+    with jax.named_scope("hvd_flash_decode"):
+        o = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, h_kv, nk),
+                in_specs=[
+                    pl.BlockSpec((1, 1, rep, d),
+                                 lambda bi, hi, j, lens: (bi, hi, 0, 0)),
+                    pl.BlockSpec((1, 1, bk, d),
+                                 lambda bi, hi, j, lens: (bi, hi, j, 0)),
+                    pl.BlockSpec((1, 1, bk, d),
+                                 lambda bi, hi, j, lens: (bi, hi, j, 0)),
+                ],
+                out_specs=pl.BlockSpec((1, 1, rep, d),
+                                       lambda bi, hi, j, lens: (bi, hi, 0, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((rep, _LANES), jnp.float32),
+                    pltpu.VMEM((rep, _LANES), jnp.float32),
+                    pltpu.VMEM((rep, d), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, h_kv, rep, d), q.dtype),
+            name="hvd_flash_decode",
+            interpret=_pallas.interpret_mode(),
+        )(lengths.astype(jnp.int32), q4, k, v)
     return o.reshape(b, h, 1, d)
 
 
@@ -467,25 +469,28 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
             pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
         ]
         operands += [qseg[:, None, :], kseg[:, None, :]]
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, _LANES), lambda b, h, i, j: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((batch, heads, tq, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        interpret=_pallas.interpret_mode(),
-    )(*operands)
+    with jax.named_scope("hvd_flash_fwd"):
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, _LANES),
+                             lambda b, h, i, j: (b, h, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((batch, heads, tq, _LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+            name="hvd_flash_fwd",
+            interpret=_pallas.interpret_mode(),
+        )(*operands)
     return o, lse[..., 0]
 
 
@@ -620,16 +625,19 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk):
             pl.BlockSpec((1, 1, bk), lambda b, h, i, j: (b, 0, j)),
         ]
         dq_operands += [qseg[:, None, :], kseg[:, None, :]]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, bq=bq, bk=bk, nk=nk, off=off),
-        grid=(batch, heads, nq, nk),
-        in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_pallas.interpret_mode(),
-    )(*dq_operands)
+    with jax.named_scope("hvd_flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale, causal=causal,
+                              has_seg=has_seg, bq=bq, bk=bk, nk=nk, off=off),
+            grid=(batch, heads, nq, nk),
+            in_specs=dq_in_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, d),
+                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            name="hvd_flash_bwd_dq",
+            interpret=_pallas.interpret_mode(),
+        )(*dq_operands)
 
     # dk/dv at *query*-head granularity in f32 (per-group partials), group-
     # summed outside the kernel; transient only -- forward K/V are never
@@ -653,25 +661,27 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk):
             pl.BlockSpec((1, 1, bk), lambda b, h, j, i: (b, 0, j)),
         ]
         dkv_operands += [qseg[:, None, :], kseg[:, None, :]]
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          has_seg=has_seg, bq=bq, bk=bk, nq=nq, off=off),
-        grid=(batch, heads, nk, nq),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((batch, heads, tk, d), jnp.float32),
-            jax.ShapeDtypeStruct((batch, heads, tk, d), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        interpret=_pallas.interpret_mode(),
-    )(*dkv_operands)
+    with jax.named_scope("hvd_flash_bwd_dkv"):
+        dk_h, dv_h = pl.pallas_call(
+            functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                              has_seg=has_seg, bq=bq, bk=bk, nq=nq, off=off),
+            grid=(batch, heads, nk, nq),
+            in_specs=dkv_in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
+                pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((batch, heads, tk, d), jnp.float32),
+                jax.ShapeDtypeStruct((batch, heads, tk, d), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            name="hvd_flash_bwd_dkv",
+            interpret=_pallas.interpret_mode(),
+        )(*dkv_operands)
     if rep > 1:
         dk_h = dk_h.reshape(batch, h_kv, rep, tk, d).sum(axis=2)
         dv_h = dv_h.reshape(batch, h_kv, rep, tk, d).sum(axis=2)

@@ -104,24 +104,28 @@ def _bn_bwd_kernels(x2, dy2, mean, var, scale, eps):
     row = lambda a: a.astype(jnp.float32).reshape(1, feat)
     blk = pl.BlockSpec((bn_, feat), lambda i: (i, 0))
     row_spec = pl.BlockSpec((1, feat), lambda i: (0, 0))
-    dbeta, dgamma = pl.pallas_call(
-        functools.partial(_reduce_kernel, nblocks=nblocks),
-        grid=(nblocks,),
-        in_specs=[blk, blk, row_spec, row_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((1, feat), jnp.float32)] * 2,
-        scratch_shapes=[pltpu.VMEM((2, feat), jnp.float32)],
-        interpret=interpret_mode(),
-    )(x2, dy2, row(mean), row(inv))
-    dx2 = pl.pallas_call(
-        functools.partial(_dx_kernel, inv_n=1.0 / n),
-        grid=(nblocks,),
-        in_specs=[blk, blk, row_spec, row_spec, row_spec, row_spec,
-                  row_spec],
-        out_specs=blk,
-        out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
-        interpret=interpret_mode(),
-    )(x2, dy2, row(mean), row(inv), row(scale), dbeta, dgamma)
+    with jax.named_scope("hvd_bn_bwd_reduce"):
+        dbeta, dgamma = pl.pallas_call(
+            functools.partial(_reduce_kernel, nblocks=nblocks),
+            grid=(nblocks,),
+            in_specs=[blk, blk, row_spec, row_spec],
+            out_specs=[row_spec, row_spec],
+            out_shape=[jax.ShapeDtypeStruct((1, feat), jnp.float32)] * 2,
+            scratch_shapes=[pltpu.VMEM((2, feat), jnp.float32)],
+            name="hvd_bn_bwd_reduce",
+            interpret=interpret_mode(),
+        )(x2, dy2, row(mean), row(inv))
+    with jax.named_scope("hvd_bn_bwd_dx"):
+        dx2 = pl.pallas_call(
+            functools.partial(_dx_kernel, inv_n=1.0 / n),
+            grid=(nblocks,),
+            in_specs=[blk, blk, row_spec, row_spec, row_spec, row_spec,
+                      row_spec],
+            out_specs=blk,
+            out_shape=jax.ShapeDtypeStruct(x2.shape, x2.dtype),
+            name="hvd_bn_bwd_dx",
+            interpret=interpret_mode(),
+        )(x2, dy2, row(mean), row(inv), row(scale), dbeta, dgamma)
     return dx2, dgamma[0], dbeta[0]
 
 

@@ -96,17 +96,19 @@ def matricize_p(x_mat, res_mat, q0, *, prescale: float = 1.0):
                                    prescale=prescale)
         in_specs = [row_spec, row_spec, q0_spec]
         operands = (x_mat, res_mat, q0)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[row_spec, pl.BlockSpec((bm, r), lambda i: (i, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, c), jnp.float32),
-            jax.ShapeDtypeStruct((m, r), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(*operands)
+    with jax.named_scope("hvd_matricize_p"):
+        return pl.pallas_call(
+            kernel,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[row_spec, pl.BlockSpec((bm, r), lambda i: (i, 0))],
+            out_shape=[
+                jax.ShapeDtypeStruct((m, c), jnp.float32),
+                jax.ShapeDtypeStruct((m, r), jnp.float32),
+            ],
+            name="hvd_matricize_p",
+            interpret=interpret_mode(),
+        )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -149,24 +151,26 @@ def orthonormalize_q(acc_mat, p_mean):
     m, c = acc_mat.shape
     r = p_mean.shape[1]
     bc = _row_block(c)
-    return pl.pallas_call(
-        _orthonormalize_q_kernel,
-        grid=(c // bc,),
-        in_specs=[
-            pl.BlockSpec((m, bc), lambda j: (0, j)),
-            pl.BlockSpec((m, r), lambda j: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((m, r), lambda j: (0, 0)),
-            pl.BlockSpec((bc, r), lambda j: (j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, r), jnp.float32),
-            jax.ShapeDtypeStruct((c, r), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((m, r), jnp.float32)],
-        interpret=interpret_mode(),
-    )(acc_mat, p_mean)
+    with jax.named_scope("hvd_orthonormalize_q"):
+        return pl.pallas_call(
+            _orthonormalize_q_kernel,
+            grid=(c // bc,),
+            in_specs=[
+                pl.BlockSpec((m, bc), lambda j: (0, j)),
+                pl.BlockSpec((m, r), lambda j: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((m, r), lambda j: (0, 0)),
+                pl.BlockSpec((bc, r), lambda j: (j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((m, r), jnp.float32),
+                jax.ShapeDtypeStruct((c, r), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((m, r), jnp.float32)],
+            name="hvd_orthonormalize_q",
+            interpret=interpret_mode(),
+        )(acc_mat, p_mean)
 
 
 # ---------------------------------------------------------------------------
@@ -202,16 +206,18 @@ def reconstruct_residual(acc_mat, p_orth, q_mean, q_local, *,
     fac_spec = pl.BlockSpec((c, r), lambda i: (0, 0))
     kernel = functools.partial(_reconstruct_kernel, n_scale=n_scale,
                                postscale=postscale)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm,),
-        in_specs=[row_spec,
-                  pl.BlockSpec((bm, r), lambda i: (i, 0)),
-                  fac_spec, fac_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, c), jnp.float32),
-            jax.ShapeDtypeStruct((m, c), jnp.float32),
-        ],
-        interpret=interpret_mode(),
-    )(acc_mat, p_orth, q_mean, q_local)
+    with jax.named_scope("hvd_reconstruct_residual"):
+        return pl.pallas_call(
+            kernel,
+            grid=(m // bm,),
+            in_specs=[row_spec,
+                      pl.BlockSpec((bm, r), lambda i: (i, 0)),
+                      fac_spec, fac_spec],
+            out_specs=[row_spec, row_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((m, c), jnp.float32),
+                jax.ShapeDtypeStruct((m, c), jnp.float32),
+            ],
+            name="hvd_reconstruct_residual",
+            interpret=interpret_mode(),
+        )(acc_mat, p_orth, q_mean, q_local)

@@ -162,79 +162,86 @@ def allreduce_gradients(grads,
         return tuple(st.mesh.axis_names) if st.mesh is not None else ()
 
     def collective(buf):
+        # The stages carry hvd_exchange/ scopes into the HLO text and the
+        # trace viewer; an exchange-level codec (EF, fp8, per-leg) is all
+        # "collective".
         ax = resolved_axes()
-        if is_error_feedback(compression):
-            # Exchange-level EF codec WITHOUT residual state: the stateful
-            # path lives in the DistributedOptimizer wrap (it owns the
-            # residual carry); this surface serves tuner samples and
-            # direct calls, where dropping the error is acceptable.
-            if process_set is not None:
-                raise NotImplementedError(
-                    "powersgd/topk do not support process-set reductions "
-                    "(no masked identity for a factored/sparse exchange); "
-                    "use fp16/bf16 there")
-            return _stateless_ef_collective(
-                buf, compression, op, axes, prescale_factor,
-                postscale_factor)
-        if is_fp8(compression):
-            # Exchange-level codec: the collective itself changes (a psum
-            # cannot carry fp8 -- compression.py module docstring).
-            from ..collectives.reduce_op import Adasum
-            if op is Adasum:
-                return _ops.allreduce(
-                    buf, op, axes=axes, process_set=process_set,
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor, wire_codec="fp8")
-            if process_set is not None:
-                raise NotImplementedError(
-                    "Compression.fp8 does not support process-set "
-                    "Sum/Average reductions (no masked identity for a "
-                    "quantized exchange); use fp16/bf16 there")
-            return _ops.fp8_allreduce(
-                buf, op, axes=axes, prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)
-        c, ctx = compression.compress(buf)
-        hier_ok = (process_set is None and len(ax) == 2
-                   and op in (_ops.Sum, Average))
-        if is_hier_legs(compression):
-            # Per-leg codec (ici:...,dcn:...): the exchange itself is the
-            # two-level decomposition with each hop's codec applied on
-            # that hop only.  On a flat mesh the DCN hop degenerates, so
-            # ride the psum-compatible ICI codec on the flat exchange.
-            if hier_ok:
+        with jax.named_scope("hvd_exchange/collective"):
+            if is_error_feedback(compression):
+                # Exchange-level EF codec WITHOUT residual state: the stateful
+                # path lives in the DistributedOptimizer wrap (it owns the
+                # residual carry); this surface serves tuner samples and
+                # direct calls, where dropping the error is acceptable.
+                if process_set is not None:
+                    raise NotImplementedError(
+                        "powersgd/topk do not support process-set reductions "
+                        "(no masked identity for a factored/sparse exchange); "
+                        "use fp16/bf16 there")
+                return _stateless_ef_collective(
+                    buf, compression, op, axes, prescale_factor,
+                    postscale_factor)
+            if is_fp8(compression):
+                # Exchange-level codec: the collective itself changes (a psum
+                # cannot carry fp8 -- compression.py module docstring).
+                from ..collectives.reduce_op import Adasum
+                if op is Adasum:
+                    return _ops.allreduce(
+                        buf, op, axes=axes, process_set=process_set,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor, wire_codec="fp8")
+                if process_set is not None:
+                    raise NotImplementedError(
+                        "Compression.fp8 does not support process-set "
+                        "Sum/Average reductions (no masked identity for a "
+                        "quantized exchange); use fp16/bf16 there")
+                return _ops.fp8_allreduce(
+                    buf, op, axes=axes, prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor)
+        with jax.named_scope("hvd_exchange/compress"):
+            c, ctx = compression.compress(buf)
+        with jax.named_scope("hvd_exchange/collective"):
+            hier_ok = (process_set is None and len(ax) == 2
+                       and op in (_ops.Sum, Average))
+            if is_hier_legs(compression):
+                # Per-leg codec (ici:...,dcn:...): the exchange itself is the
+                # two-level decomposition with each hop's codec applied on
+                # that hop only.  On a flat mesh the DCN hop degenerates, so
+                # ride the psum-compatible ICI codec on the flat exchange.
+                if hier_ok:
+                    r = _ops.hierarchical_allreduce(
+                        c, op, dcn_axis=ax[0], ici_axis=ax[1],
+                        dcn_codec=compression.dcn, ici_codec=compression.ici,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor)
+                    return r
+                _note_flat_leg(c, compression.ici)
+                ci, ictx = compression.ici.compress(c)
+                r = _ops.allreduce(ci, op, axes=axes, process_set=process_set,
+                                   prescale_factor=prescale_factor,
+                                   postscale_factor=postscale_factor)
+                return compression.ici.decompress(r, ictx)
+            if explicit_hier and hier_ok:
                 r = _ops.hierarchical_allreduce(
                     c, op, dcn_axis=ax[0], ici_axis=ax[1],
-                    dcn_codec=compression.dcn, ici_codec=compression.ici,
                     prescale_factor=prescale_factor,
                     postscale_factor=postscale_factor)
-                return r
-            _note_flat_leg(c, compression.ici)
-            ci, ictx = compression.ici.compress(c)
-            r = _ops.allreduce(ci, op, axes=axes, process_set=process_set,
-                               prescale_factor=prescale_factor,
-                               postscale_factor=postscale_factor)
-            return compression.ici.decompress(r, ictx)
-        if explicit_hier and hier_ok:
-            r = _ops.hierarchical_allreduce(
-                c, op, dcn_axis=ax[0], ici_axis=ax[1],
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)
-        elif (chunk_bytes > 0 and process_set is None
-              and op in (_ops.Sum, Average)):
-            # HOROVOD_EXCHANGE_CHUNK_MB (or the tuner's chunk axis):
-            # decompose the bucket into overlap-friendly RS+AG chunks.
-            # Chunking acts on the compressed wire buffer, so it composes
-            # with fp16/bf16 codecs.
-            r = _ops.chunked_allreduce(
-                c, op, chunk_bytes=chunk_bytes, axes=ax,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor)
-        else:
-            _note_flat_leg(buf, compression)
-            r = _ops.allreduce(c, op, axes=axes, process_set=process_set,
-                               prescale_factor=prescale_factor,
-                               postscale_factor=postscale_factor)
-        return compression.decompress(r, ctx)
+            elif (chunk_bytes > 0 and process_set is None
+                  and op in (_ops.Sum, Average)):
+                # HOROVOD_EXCHANGE_CHUNK_MB (or the tuner's chunk axis):
+                # decompose the bucket into overlap-friendly RS+AG chunks.
+                # Chunking acts on the compressed wire buffer, so it composes
+                # with fp16/bf16 codecs.
+                r = _ops.chunked_allreduce(
+                    c, op, chunk_bytes=chunk_bytes, axes=ax,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor)
+            else:
+                _note_flat_leg(buf, compression)
+                r = _ops.allreduce(c, op, axes=axes, process_set=process_set,
+                                   prescale_factor=prescale_factor,
+                                   postscale_factor=postscale_factor)
+        with jax.named_scope("hvd_exchange/decompress"):
+            return compression.decompress(r, ctx)
 
     def _note_flat_leg(buf, comp):
         # Flat fused-bucket exchange: register the plan-IR row at trace

@@ -490,7 +490,7 @@ class ServingControlPlane:
         completed = st["completed"]
         new_tokens = sum(len(r.tokens) for r in completed)
         ttfts = [r.ttft_s for r in completed if r.ttft_s is not None]
-        lats = [l for r in completed for l in r.token_latencies]
+        lats = [g for r in completed for g in r.token_gaps]
         serving = ServingReport(
             num_requests=len(requests), completed=len(completed),
             rejected=rejected,

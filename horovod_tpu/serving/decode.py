@@ -274,7 +274,7 @@ class ServingDecodeStep:
 
     def __call__(self, *args):
         rec = _spans.recorder()
-        with rec.span("dispatch", name="serving", leg=self._leg):
+        with rec.span("dispatch", name="decode.dispatch", leg=self._leg):
             return self._fn(*args)
 
 

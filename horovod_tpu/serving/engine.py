@@ -329,25 +329,29 @@ class ServingEngine:
 
     def _do_prefill(self, slot: int, req: Request, prompt_dev,
                     matched: int = 0, entries: Sequence = ()) -> int:
-        with _spans.recorder().span("dispatch", name="prefill",
-                                    leg="serving_prefill"):
-            if matched:
-                # Prefix hit: only the tail goes through the forward
-                # pass, conditioned on the cached pages as past K/V --
-                # the matched tokens' prefill FLOPs are avoided.
-                past = self.cache.gather_pages(entries)
-                logits, kl, vl = self._prefill_chunked(
-                    self.params, prompt_dev[matched:][None], past)
-                self.cache.write_prefill(slot, kl[:, 0, matched:],
-                                         vl[:, 0, matched:],
-                                         start=matched)
-            else:
-                aid = jnp.int32(req.adapter_id) \
-                    if self.adapters is not None else None
-                logits, kl, vl = self._prefill(
-                    self.params, prompt_dev[None], self.adapters, aid)
-                self.cache.write_prefill(slot, kl[:, 0], vl[:, 0])
-            first = int(greedy_sample(logits[:, -1, :])[0])
+        rec = _spans.recorder()
+        with rec.span("dispatch", name="serve.prefill",
+                      leg="serving_prefill", rid=req.rid, slot=slot,
+                      prompt_len=req.prompt_len):
+            with rec.phase("prefill.dispatch", rid=req.rid):
+                if matched:
+                    # Prefix hit: only the tail goes through the forward
+                    # pass, conditioned on the cached pages as past K/V
+                    # -- the matched tokens' prefill FLOPs are avoided.
+                    past = self.cache.gather_pages(entries)
+                    logits, kl, vl = self._prefill_chunked(
+                        self.params, prompt_dev[matched:][None], past)
+                    kl, vl = kl[:, 0, matched:], vl[:, 0, matched:]
+                else:
+                    aid = jnp.int32(req.adapter_id) \
+                        if self.adapters is not None else None
+                    logits, kl, vl = self._prefill(
+                        self.params, prompt_dev[None], self.adapters, aid)
+                    kl, vl = kl[:, 0], vl[:, 0]
+            with rec.phase("prefill.write_kv", rid=req.rid):
+                self.cache.write_prefill(slot, kl, vl, start=matched)
+            with rec.phase("prefill.sample_fetch", rid=req.rid):
+                first = int(greedy_sample(logits[:, -1, :])[0])
         return first
 
     def _advance_chunks(self, st: Dict[str, Any], now) -> None:
@@ -433,52 +437,72 @@ class ServingEngine:
         st["last_tokens"][slot] = self.re_prefill(slot, req)
 
     # -- one decode round (shared with serving.controlplane) ---------------
+    def _round_span(self, st: Dict[str, Any], slots: List[int]):
+        """The ``decode.round`` span of one round over ``slots``.
+        ``live_tokens`` is what the round's attention reads: each slot's
+        resident context and the token this round writes."""
+        return _spans.recorder().phase(
+            "decode.round", round=int(st["decode_steps"]), slots=len(slots),
+            live_tokens=int(sum(int(self.cache.lengths[s]) + 1
+                                for s in slots)))
+
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """One plain continuous-batching decode step over live slots.
 
         ``st`` is the mutable per-run state dict (``last_tokens``,
         ``adapter_ids``, ``completed``, ``occ_samples``,
         ``decode_steps``); the control plane's drain loop drives this
-        same method so its gauges stay truthful.
+        same method so its gauges stay truthful.  Returns the seconds
+        from dispatch to the last fetch.
         """
         sched = self.scheduler
         cache = self.cache
+        phase = _spans.recorder().phase
         slots = self._decode_slots()
-        for slot in slots:
-            length = int(cache.lengths[slot])
-            cache.reserve(slot, length + 1, writable_from=length)
-        active = np.zeros((self.slots,), bool)
-        active[slots] = True
-        args = [self._decode_params, cache.k, cache.v,
-                jnp.asarray(np.array(st["last_tokens"])),
-                cache.lengths_device(), cache.table_device(),
-                jnp.asarray(active)]
-        if self.kv_compress:
-            args += list(cache.compress_operands())
-        if self.adapters is not None:
-            args += [self.adapters,
-                     jnp.asarray(np.array(st["adapter_ids"]))]
-        t0 = time.monotonic()
-        logits, cache.k, cache.v = self.step(*args)
-        sampled = np.asarray(greedy_sample(logits))  # sync point
-        # Per-slot SDC screen: one reduced scalar per row (sum propagates
-        # any NaN/Inf in the vocab axis), fetched with the sample.
-        finite = np.isfinite(np.asarray(jnp.sum(logits, axis=-1)))
-        step_s = time.monotonic() - t0
-        st["decode_steps"] += 1
-        st["occ_samples"].append(sched.occupancy)
-        for slot in slots:
-            req = sched.active[slot]
-            if not finite[slot]:
-                self._quarantine_logits(st, slot, req)
-                continue
-            tok = int(sampled[slot])
-            req.tokens.append(tok)
-            cache.lengths[slot] += 1
-            st["last_tokens"][slot] = tok
-            sched.note_decode_token(req, step_s)
-            if req.finished or int(cache.lengths[slot]) >= self.max_len:
-                self._release(st, slot, now)
+        with self._round_span(st, slots):
+            with phase("decode.reserve"):
+                for slot in slots:
+                    length = int(cache.lengths[slot])
+                    cache.reserve(slot, length + 1, writable_from=length)
+            with phase("decode.args"):
+                active = np.zeros((self.slots,), bool)
+                active[slots] = True
+                args = [self._decode_params, cache.k, cache.v,
+                        jnp.asarray(np.array(st["last_tokens"])),
+                        cache.lengths_device(), cache.table_device(),
+                        jnp.asarray(active)]
+                if self.kv_compress:
+                    args += list(cache.compress_operands())
+                if self.adapters is not None:
+                    args += [self.adapters,
+                             jnp.asarray(np.array(st["adapter_ids"]))]
+            t0 = time.monotonic()
+            logits, cache.k, cache.v = self.step(*args)
+            with phase("decode.sample_fetch"):
+                sampled = np.asarray(greedy_sample(logits))  # sync point
+            # Per-slot SDC screen: one reduced scalar per row (sum
+            # propagates any NaN/Inf in the vocab axis): a second
+            # program and a second fetch.
+            with phase("decode.finite_fetch"):
+                finite = np.isfinite(np.asarray(jnp.sum(logits, axis=-1)))
+            step_s = time.monotonic() - t0
+            with phase("decode.bookkeep"):
+                st["decode_steps"] += 1
+                st["occ_samples"].append(sched.occupancy)
+                t_tok = now()
+                for slot in slots:
+                    req = sched.active[slot]
+                    if not finite[slot]:
+                        self._quarantine_logits(st, slot, req)
+                        continue
+                    tok = int(sampled[slot])
+                    req.tokens.append(tok)
+                    cache.lengths[slot] += 1
+                    st["last_tokens"][slot] = tok
+                    sched.note_decode_token(req, t_tok)
+                    if req.finished or \
+                            int(cache.lengths[slot]) >= self.max_len:
+                        self._release(st, slot, now)
         return step_s
 
     def spec_round(self, st: Dict[str, Any], now) -> float:
@@ -494,67 +518,78 @@ class ServingEngine:
         """
         sched = self.scheduler
         cache = self.cache
+        phase = _spans.recorder().phase
         k = self.spec_k
         width = k + 1
         slots = self._decode_slots()
         reqs = {s: sched.active[s] for s in slots}
         base = {s: int(cache.lengths[s]) for s in slots}
-        for s in slots:
-            # Room for this round's widest write, capped at the slot's
-            # page allotment (columns past max_len scatter to scratch).
-            cache.reserve(s, min(base[s] + width, self.max_len),
-                          writable_from=base[s])
-        drafts = self.drafter.propose(reqs, k,
-                                      np.array(st["last_tokens"]))
-        tokens_in = np.zeros((self.slots, width), np.int32)
-        tokens_in[:, 0] = st["last_tokens"]
-        tokens_in[:, 1:] = drafts
-        active = np.zeros((self.slots,), bool)
-        active[slots] = True
-        args = [self._decode_params, cache.k, cache.v,
-                jnp.asarray(tokens_in),
-                cache.lengths_device(), cache.table_device(),
-                jnp.asarray(active)]
-        if self.kv_compress:
-            args += list(cache.compress_operands())
-        t0 = time.monotonic()
-        logits, cache.k, cache.v = self.verify_step(*args)
-        sampled = np.asarray(greedy_sample(logits))  # [slots, width]
-        # Per-slot SDC screen across every verify column: a poisoned
-        # column anywhere in the window disqualifies the whole round for
-        # that slot (the agreeing-prefix walk would condition on it).
-        finite = np.isfinite(
-            np.asarray(jnp.sum(logits, axis=(-2, -1))))
-        step_s = time.monotonic() - t0
-        st["decode_steps"] += 1
-        st["spec_rounds"] = st.get("spec_rounds", 0) + 1
-        st["occ_samples"].append(sched.occupancy)
-        for s in slots:
-            req = reqs[s]
-            if not finite[s]:
-                self._quarantine_logits(st, s, req)
-                continue
-            # Longest agreeing prefix: draft j survives iff every
-            # earlier draft did AND it equals the target's argmax for
-            # the position it sits at.
-            m = 0
-            while m < k and drafts[s, m] == sampled[s, m]:
-                m += 1
-            emit = min(m + 1,
-                       req.max_new_tokens - len(req.tokens),
-                       self.max_len - base[s])
-            accepted = max(emit - 1, 0)
-            st["proposed"] = st.get("proposed", 0) + k
-            st["accepted"] = st.get("accepted", 0) + accepted
-            sched.note_spec(k, accepted)
-            for j in range(emit):
-                req.tokens.append(int(sampled[s, j]))
-                sched.note_decode_token(req, step_s / max(emit, 1))
-            cache.lengths[s] = base[s] + emit
-            st["last_tokens"][s] = req.tokens[-1]
-            self.drafter.observe(s, req, accepted)
-            if req.finished or int(cache.lengths[s]) >= self.max_len:
-                self._release(st, s, now)
+        with self._round_span(st, slots):
+            with phase("decode.reserve"):
+                for s in slots:
+                    # Room for this round's widest write, capped at the
+                    # slot's page allotment (columns past max_len scatter
+                    # to scratch).
+                    cache.reserve(s, min(base[s] + width, self.max_len),
+                                  writable_from=base[s])
+            with phase("decode.args"):
+                drafts = self.drafter.propose(reqs, k,
+                                              np.array(st["last_tokens"]))
+                tokens_in = np.zeros((self.slots, width), np.int32)
+                tokens_in[:, 0] = st["last_tokens"]
+                tokens_in[:, 1:] = drafts
+                active = np.zeros((self.slots,), bool)
+                active[slots] = True
+                args = [self._decode_params, cache.k, cache.v,
+                        jnp.asarray(tokens_in),
+                        cache.lengths_device(), cache.table_device(),
+                        jnp.asarray(active)]
+                if self.kv_compress:
+                    args += list(cache.compress_operands())
+            t0 = time.monotonic()
+            logits, cache.k, cache.v = self.verify_step(*args)
+            with phase("decode.sample_fetch"):
+                sampled = np.asarray(greedy_sample(logits))  # [slots, width]
+            # Per-slot SDC screen across every verify column: a poisoned
+            # column anywhere in the window disqualifies the whole round
+            # for that slot (the agreeing-prefix walk would condition on
+            # it).
+            with phase("decode.finite_fetch"):
+                finite = np.isfinite(
+                    np.asarray(jnp.sum(logits, axis=(-2, -1))))
+            step_s = time.monotonic() - t0
+            with phase("decode.bookkeep"):
+                st["decode_steps"] += 1
+                st["spec_rounds"] = st.get("spec_rounds", 0) + 1
+                st["occ_samples"].append(sched.occupancy)
+                t_tok = now()
+                for s in slots:
+                    req = reqs[s]
+                    if not finite[s]:
+                        self._quarantine_logits(st, s, req)
+                        continue
+                    # Longest agreeing prefix: draft j survives iff every
+                    # earlier draft did AND it equals the target's argmax
+                    # for the position it sits at.
+                    m = 0
+                    while m < k and drafts[s, m] == sampled[s, m]:
+                        m += 1
+                    emit = min(m + 1,
+                               req.max_new_tokens - len(req.tokens),
+                               self.max_len - base[s])
+                    accepted = max(emit - 1, 0)
+                    st["proposed"] = st.get("proposed", 0) + k
+                    st["accepted"] = st.get("accepted", 0) + accepted
+                    sched.note_spec(k, accepted)
+                    for j in range(emit):
+                        req.tokens.append(int(sampled[s, j]))
+                        sched.note_decode_token(req, t_tok)
+                    cache.lengths[s] = base[s] + emit
+                    st["last_tokens"][s] = req.tokens[-1]
+                    self.drafter.observe(s, req, accepted)
+                    if req.finished or \
+                            int(cache.lengths[s]) >= self.max_len:
+                        self._release(st, s, now)
         return step_s
 
     # -- elastic resize hooks (driven by serving.controlplane) -------------
@@ -652,7 +687,9 @@ class ServingEngine:
         prompts_dev: Dict[int, Any] = {}
         self._chunking.clear()
 
-        with RequestPrefetcher(admissible, self.prefetch_depth) as feed:
+        phase = _spans.recorder().phase
+        with phase("serve", requests=len(admissible)), \
+                RequestPrefetcher(admissible, self.prefetch_depth) as feed:
             fetched = next(feed, None)
 
             while True:
@@ -662,12 +699,13 @@ class ServingEngine:
                     # expire and page pressure can resolve.
                     self._prefix.tick()
                 # Pull every request whose arrival time has passed.
-                while fetched is not None and \
-                        fetched[0].arrival_s <= now():
-                    req, dev = fetched
-                    prompts_dev[req.rid] = dev
-                    sched.submit(req)
-                    fetched = next(feed, None)
+                with phase("serve.arrivals"):
+                    while fetched is not None and \
+                            fetched[0].arrival_s <= now():
+                        req, dev = fetched
+                        prompts_dev[req.rid] = dev
+                        sched.submit(req)
+                        fetched = next(feed, None)
                 if not sched.has_work():
                     if fetched is None:
                         break
@@ -678,12 +716,15 @@ class ServingEngine:
                         skip += gap
                     continue
 
-                for slot, req in sched.admit(now()):
+                with phase("serve.admit"):
+                    admitted = sched.admit(now())
+                for slot, req in admitted:
                     dev = prompts_dev.pop(req.rid)
                     self._begin_prefill(st, slot, req, dev, now)
 
                 if self._chunking:
-                    self._advance_chunks(st, now)
+                    with phase("serve.chunks"):
+                        self._advance_chunks(st, now)
                 if not self._decode_slots():
                     continue
 
@@ -699,7 +740,7 @@ class ServingEngine:
         new_tokens = sum(len(r.tokens) for r in completed)
         prompt_tokens = sum(r.prompt_len for r in completed)
         ttfts = [r.ttft_s for r in completed if r.ttft_s is not None]
-        lats = [l for r in completed for l in r.token_latencies]
+        lats = [g for r in completed for g in r.token_gaps]
         proposed = int(st["proposed"])
         accepted = int(st["accepted"])
         pq, ph = int(st["prefix_queries"]), int(st["prefix_hits"])

@@ -639,8 +639,9 @@ class PagedKVCache:
         offs = jnp.asarray(pos % c.page_size)
         dt = jnp.dtype(c.dtype)
         # One scatter per pool: [L, t, H, D] lands at (page, off) pairs.
-        self.k = self.k.at[:, pages, offs].set(k_layers.astype(dt))
-        self.v = self.v.at[:, pages, offs].set(v_layers.astype(dt))
+        with jax.named_scope("hvd_kv_write_prefill"):
+            self.k = self.k.at[:, pages, offs].set(k_layers.astype(dt))
+            self.v = self.v.at[:, pages, offs].set(v_layers.astype(dt))
         self.lengths[slot] = start + t
 
     def grow(self, slot: int) -> None:

@@ -33,7 +33,9 @@ Every transition is instrumented through the PR 6
   draft tokens proposed vs accepted (acceptance rate =
   accepted / proposed),
 * ``horovod_serving_ttft_seconds`` / ``horovod_serving_token_latency_seconds``
-  histograms (time-to-first-token, per-output-token latency),
+  histograms (time-to-first-token; the gap between a request's
+  consecutive tokens on the engine's clock, so a prefill that stalls the
+  batch shows in every running request's next gap),
 * per-tenant SLO families (PR 16):
   ``horovod_serving_ttft_by_tenant_seconds{tenant}``,
   ``horovod_serving_tenant_occupancy{tenant}``,
@@ -59,6 +61,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..timeline import spans as _spans
 from ..timeline.metrics import registry as _registry
 
 # Per-token decode latencies sit well under the default step buckets'
@@ -125,7 +128,9 @@ class Request:
     admit_s: Optional[float] = None
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
-    token_latencies: List[float] = dataclasses.field(default_factory=list)
+    # The engine's arrival-faithful clock at each emitted token, the
+    # first included: token_times[0] == first_token_s.
+    token_times: List[float] = dataclasses.field(default_factory=list)
     tenant: str = "default"            # SLO class (TenantClass.name)
     session_id: Optional[int] = None   # multi-turn warm-KV session key
     # Load-generator engine affinity hint (per-engine arrival skew in
@@ -145,6 +150,12 @@ class Request:
         if self.first_token_s is None:
             return None
         return self.first_token_s - self.arrival_s
+
+    @property
+    def token_gaps(self) -> List[float]:
+        """Seconds between consecutive emitted tokens."""
+        t = self.token_times
+        return [b - a for a, b in zip(t, t[1:])]
 
 
 class ContinuousBatchScheduler:
@@ -192,7 +203,8 @@ class ContinuousBatchScheduler:
             buckets=LATENCY_BUCKETS)
         self._m_tok_lat = reg.histogram(
             "horovod_serving_token_latency_seconds",
-            "Per-output-token latency", buckets=LATENCY_BUCKETS)
+            "Gap between a request's consecutive output tokens",
+            buckets=LATENCY_BUCKETS)
         self._m_slot_states = reg.gauge(
             "horovod_serving_slot_states",
             "Decode-batch slots by lifecycle state",
@@ -353,6 +365,7 @@ class ContinuousBatchScheduler:
         sampled -- the request joins the decode batch."""
         req.state = "decode"
         req.first_token_s = now_s
+        req.token_times.append(now_s)
         self._m_tokens.labels(phase="prefill").inc(req.prompt_len)
         self._m_tokens.labels(phase="decode").inc()  # the sampled token
         self._m_ttft.observe(max(now_s - req.arrival_s, 0.0))
@@ -363,10 +376,16 @@ class ContinuousBatchScheduler:
         # not-yet-decodable capacity.
         self._update_gauges()
 
-    def note_decode_token(self, req: Request, latency_s: float) -> None:
+    def note_decode_token(self, req: Request, now_s: float) -> None:
+        """A decode round emitted a token for ``req`` at ``now_s`` on the
+        engine's clock.  The gap to the request's previous token is what
+        ``horovod_serving_token_latency_seconds`` observes: the tokens a
+        speculative round emits share its timestamp, so accepted drafts
+        show as gaps of zero, as the user sees them."""
         self._m_tokens.labels(phase="decode").inc()
-        self._m_tok_lat.observe(max(latency_s, 0.0))
-        req.token_latencies.append(latency_s)
+        if req.token_times:
+            self._m_tok_lat.observe(max(now_s - req.token_times[-1], 0.0))
+        req.token_times.append(now_s)
 
     def note_spec(self, proposed: int, accepted: int) -> None:
         """Account one speculative round: ``proposed`` draft tokens went
@@ -397,6 +416,11 @@ class ContinuousBatchScheduler:
         req.done_s = now_s
         req.slot = -1
         self._release(slot)
+        _spans.recorder().file(
+            "request", under="serve", rid=req.rid,
+            arrival_s=req.arrival_s, admit_s=req.admit_s,
+            first_token_s=req.first_token_s, done_s=now_s,
+            prompt_len=req.prompt_len, token_times=req.token_times)
         self._m_requests.labels(
             event="completed" if completed else "evicted").inc()
         self._update_gauges()

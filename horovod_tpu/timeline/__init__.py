@@ -354,24 +354,3 @@ class OverlapMonitor:
         if not self.windows:
             return 0.0
         return float(sum(self.windows) / len(self.windows))
-
-
-@contextlib.contextmanager
-def device_trace(logdir: str):
-    """Capture a device-side profiler trace alongside the semantic
-    timeline (SURVEY.md 5.1: ``jax.profiler`` owns device timing).
-
-    Produces an XPlane/Perfetto trace under ``logdir`` viewable in
-    TensorBoard or ui.perfetto.dev::
-
-        with horovod_tpu.timeline.device_trace("/tmp/prof"):
-            for _ in range(10):
-                params, opt_state, loss = step(params, opt_state, batch)
-    """
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

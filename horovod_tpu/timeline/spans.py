@@ -27,15 +27,25 @@ Per step, the recorder folds its spans into a compact summary dict::
 which feeds the :class:`~horovod_tpu.timeline.straggler.StragglerMonitor`
 locally and, under ``HOROVOD_TRACE_SYNC=1``, the KV trace plane
 (``timeline/sync.py``) for rank 0 to merge.
+
+One funnel, three sinks.  :meth:`SpanRecorder.span` also KEEPS each span
+-- name, start and end on ``time.perf_counter_ns``, its own id, the id
+of the span that was open on the same thread when it began, and its
+attributes -- in a bounded ring (:meth:`SpanRecorder.records`), and
+enters a ``jax.profiler.TraceAnnotation("hvd." + name, **attrs)`` for
+the same interval: while a profiler trace is on, the span lies in the
+trace's host plane on the clock the device's operations are on, so a
+device gap can be laid against what the host was doing.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
-from collections import OrderedDict
-from typing import Dict, Optional
+from collections import OrderedDict, deque
+from typing import Dict, List, NamedTuple, Optional
 
 #: Span kinds a step decomposes into.  "dispatch" is the jitted-step
 #: dispatch call; "dispatch_gap" the host time between consecutive
@@ -46,8 +56,44 @@ from typing import Dict, Optional
 SPAN_KINDS = ("dispatch", "dispatch_gap", "exchange", "fence", "bucket",
               "negotiate", "compute")
 
+#: A phase of a host loop (the serve loop, a decode round and their
+#: parts).  Phases nest -- a round holds its own dispatch -- so they are
+#: kept in the record ring and the profiler's trace and left out of the
+#: per-step sums, which would count the same time twice.
+PHASE = "phase"
+
 #: Per-step summaries kept in the ring buffer.
 SUMMARY_RING = 64
+
+#: Span records kept in the ring: a 30 s serve run files some 7,000.
+RECORD_RING = 32768
+
+
+class SpanRecord(NamedTuple):
+    """One kept span.  ``parent`` is the id of the span that was open on
+    the same thread when this one began (None for a root).  A record
+    made by :meth:`SpanRecorder.file` is a point: ``start_ns ==
+    end_ns``."""
+
+    name: str
+    start_ns: int            # time.perf_counter_ns
+    end_ns: int
+    id: int
+    parent: Optional[int]
+    attrs: dict
+
+
+_trace_annotation = None
+
+
+def _annotation(name: str, attrs: dict):
+    """``jax.profiler.TraceAnnotation``, imported at the first span: a
+    ``Timeline`` must be able to open before jax is imported at all."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name, **attrs)
 
 
 class SpanRecorder:
@@ -64,6 +110,9 @@ class SpanRecorder:
         # trace-time leg registry: leg -> {"nbytes": n, "buckets": k}
         self.legs: Dict[str, dict] = {}
         self._listeners = []
+        self._ring: "deque[SpanRecord]" = deque(maxlen=RECORD_RING)
+        self._ids = itertools.count(1)
+        self._open = threading.local()   # .stack: [(id, name), ...]
 
     # -- wiring -----------------------------------------------------------
     def configure(self, rank: Optional[int] = None,
@@ -139,30 +188,87 @@ class SpanRecorder:
     @contextlib.contextmanager
     def span(self, kind: str, name: str = "", leg: Optional[str] = None,
              bucket_id: Optional[int] = None,
-             fuse_key: Optional[str] = None):
+             fuse_key: Optional[str] = None, **attrs):
         """Time a host region and tag it ``(rank, step, bucket_id,
-        fuse_key, leg)``.  Mirrors into the Chrome-trace timeline (one
-        ``spans`` track, args carry the tags) when one is attached."""
+        fuse_key, leg)``.  Three sinks: the per-step sum of ``kind``
+        (not for :data:`PHASE`), the Chrome-trace timeline when one is
+        attached (args carry the tags), and the record ring together
+        with a ``TraceAnnotation("hvd." + (name or kind))`` in the
+        profiler's trace.  ``attrs`` (``rid``, ``round``, ``slot``...:
+        numbers or strings) go to the record and the annotation."""
+        label = name or kind
+        if leg is not None:
+            attrs["leg"] = leg
         tl = self.timeline
-        args = None
+        track = "phases" if kind == PHASE else (name or "spans")
+        event = label if kind == PHASE else kind
         if tl is not None:
-            args = {"rank": self.rank, "step": self._step}
-            if leg is not None:
-                args["leg"] = leg
+            args = dict(attrs, rank=self.rank, step=self._step)
             if bucket_id is not None:
                 args["bucket_id"] = int(bucket_id)
             if fuse_key is not None:
                 args["fuse_key"] = str(fuse_key)
-            tl.begin(name or "spans", kind, args=args)
-        t0 = time.perf_counter()
+            tl.begin(track, event, args=args)
+        stack = self._stack()
+        sid = next(self._ids)
+        parent = stack[-1][0] if stack else None
+        stack.append((sid, label))
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with _annotation("hvd." + label, attrs):
+                yield
         finally:
-            dur = time.perf_counter() - t0
+            t1 = time.perf_counter_ns()
+            stack.pop()
             if tl is not None:
-                tl.end(name or "spans", kind)
-            self.add(kind, dur, leg=leg, bucket_id=bucket_id,
-                     fuse_key=fuse_key)
+                tl.end(track, event)
+            with self._lock:
+                self._ring.append(
+                    SpanRecord(label, t0, t1, sid, parent, attrs))
+            if kind != PHASE:
+                self.add(kind, (t1 - t0) / 1e9, leg=leg,
+                         bucket_id=bucket_id, fuse_key=fuse_key)
+
+    def phase(self, name: str, **attrs):
+        """``span(PHASE, name=name, **attrs)``: a phase of a host loop."""
+        return self.span(PHASE, name=name, **attrs)
+
+    # -- the record ring --------------------------------------------------
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def file(self, name: str, under: Optional[str] = None,
+             **attrs) -> SpanRecord:
+        """File a point record (no interval, no annotation): what a
+        layer knows only once something is over, such as a finished
+        request's timestamps.  Its parent is the innermost span named
+        ``under`` that is open on this thread (None where there is
+        none), else the innermost open span."""
+        stack = self._stack()
+        if under is None:
+            parent = stack[-1][0] if stack else None
+        else:
+            parent = next((sid for sid, label in reversed(stack)
+                           if label == under), None)
+        now = time.perf_counter_ns()
+        rec = SpanRecord(name, now, now, next(self._ids), parent, attrs)
+        with self._lock:
+            self._ring.append(rec)
+        return rec
+
+    def records(self, name: Optional[str] = None,
+                since_ns: Optional[int] = None) -> List[SpanRecord]:
+        """The kept records, oldest first (a span is filed when it
+        closes, so a parent follows its children): those named ``name``
+        and begun at or after ``since_ns``, where given."""
+        with self._lock:
+            out = list(self._ring)
+        return [r for r in out
+                if (name is None or r.name == name)
+                and (since_ns is None or r.start_ns >= since_ns)]
 
     # -- trace-time leg registry ------------------------------------------
     def note_leg(self, leg, nbytes: Optional[int] = None,
@@ -232,6 +338,7 @@ class SpanRecorder:
             self._acc.clear()
             self.summaries.clear()
             self.legs.clear()
+            self._ring.clear()
             self._listeners = []
             self.timeline = None
             self.rank = 0

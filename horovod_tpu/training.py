@@ -1198,7 +1198,12 @@ class _InstrumentedStep:
         gap = (t0 - self._last_end) if self._last_end is not None else 0.0
         if gap > 0:
             rec.add("dispatch_gap", gap, emit=True)
-        with rec.span("dispatch", name="step"):
+        # StepTraceAnnotation: the profiler's trace groups the device's
+        # work by step number; the span beside it is the same interval
+        # in the program's own records.
+        with jax.profiler.StepTraceAnnotation("hvd.train_step",
+                                              step_num=step), \
+                rec.span("dispatch", name="step", step=step):
             out = self._fn(params, *rest)
         t1 = _time.perf_counter()
         wall = t1 - t0

@@ -1,0 +1,336 @@
+"""One clock: the spans the program records are kept (name, start, end,
+id, parent, attributes) and lie in the profiler's trace; the serve loop
+and a decode round are spans with children; a request carries a
+timestamp a token; kernels and exchange stages have names in the HLO.
+"""
+
+import glob
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh
+
+import horovod_tpu as hv
+from horovod_tpu.models.transformer import LLAMA_SERVE, LlamaLM
+from horovod_tpu.serving import Request, ServingEngine
+from horovod_tpu.timeline import spans
+
+CFG = LLAMA_SERVE
+ROUND_CHILDREN = {"decode.reserve", "decode.args", "decode.dispatch",
+                  "decode.sample_fetch", "decode.finite_fetch",
+                  "decode.bookkeep"}
+
+
+# -- the record ring --------------------------------------------------------
+
+def _nested(rec):
+    with rec.span(spans.PHASE, name="outer", round=3):
+        with rec.span("dispatch", name="inner", leg="serving_decode"):
+            pass
+        with rec.span(spans.PHASE, name="sibling"):
+            pass
+    by = {r.name: r for r in rec.records()}
+    assert by["inner"].parent == by["outer"].id
+    assert by["sibling"].parent == by["outer"].id
+    assert by["outer"].parent is None
+    assert by["outer"].attrs == {"round": 3}
+    assert by["inner"].attrs == {"leg": "serving_decode"}
+    # Filed as it closes: children before their parent.
+    assert [r.name for r in rec.records()] == ["inner", "sibling", "outer"]
+    assert by["outer"].start_ns <= by["inner"].start_ns \
+        <= by["inner"].end_ns <= by["sibling"].start_ns \
+        <= by["outer"].end_ns
+
+
+def _threads(rec):
+    inside = threading.Event()
+    done = threading.Event()
+
+    def other():
+        with rec.span(spans.PHASE, name="other"):
+            inside.set()
+            assert done.wait(10)
+
+    t = threading.Thread(target=other)
+    t.start()
+    assert inside.wait(10)
+    with rec.span(spans.PHASE, name="mine"):
+        pass
+    done.set()
+    t.join(10)
+    assert not t.is_alive()
+    by = {r.name: r for r in rec.records()}
+    assert by["mine"].parent is None and by["other"].parent is None
+
+
+def _bounded(rec):
+    for i in range(spans.RECORD_RING + 10):
+        with rec.span(spans.PHASE, name="s", i=i):
+            pass
+    got = rec.records()
+    assert len(got) == spans.RECORD_RING
+    assert got[-1].attrs["i"] == spans.RECORD_RING + 9
+    assert got[0].attrs["i"] == 10
+    ids = [r.id for r in got]
+    assert len(set(ids)) == len(ids)
+
+
+def _filed(rec):
+    assert rec.file("request", under="serve", rid=0).parent is None
+    with rec.span(spans.PHASE, name="serve"):
+        with rec.span(spans.PHASE, name="decode.round"):
+            under = rec.file("request", under="serve", rid=1,
+                             token_times=[0.1, 0.2])
+            innermost = rec.file("note")
+            nowhere = rec.file("request", under="absent")
+    by = {r.name: r for r in rec.records() if r.start_ns != r.end_ns}
+    assert under.parent == by["serve"].id
+    assert innermost.parent == by["decode.round"].id
+    assert nowhere.parent is None
+    assert under.start_ns == under.end_ns
+    assert under.attrs == {"rid": 1, "token_times": [0.1, 0.2]}
+
+
+def _filters(rec):
+    with rec.span(spans.PHASE, name="a"):
+        pass
+    with rec.span(spans.PHASE, name="b"):
+        pass
+    b = rec.records(name="b")
+    assert [r.name for r in b] == ["b"]
+    assert [r.name for r in rec.records(since_ns=b[0].start_ns)] == ["b"]
+    rec.reset()
+    assert rec.records() == []
+
+
+def _sums_unchanged(rec):
+    """The per-step sums take the kinds they took; a phase adds none."""
+    rec.set_step(4)
+    with rec.span(spans.PHASE, name="decode.round"):
+        with rec.span("dispatch", name="decode.dispatch",
+                      leg="serving_decode"):
+            pass
+    summary = rec.step_boundary(4, 1.0)
+    assert set(summary["spans"]) == {"dispatch"}
+    assert summary["legs"]["serving_decode"]["count"] == 1
+
+
+@pytest.mark.parametrize("case", [_nested, _threads, _bounded, _filed,
+                                  _filters, _sums_unchanged],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_span_records(case):
+    case(spans.SpanRecorder())
+
+
+def test_spans_lie_in_the_profilers_trace(tmp_path):
+    """While a trace is on, a span is a host event ``hvd.<name>`` with
+    its attributes as the event's stats, nested as the records are."""
+    from jax.profiler import ProfileData
+    rec = spans.SpanRecorder()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with rec.span(spans.PHASE, name="decode.round", round=7, slots=2,
+                      live_tokens=11):
+            with rec.span("dispatch", name="decode.dispatch",
+                          leg="serving_decode"):
+                jnp.ones((4,)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))[0]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("hvd."):
+                    events[e.name] = (e.start_ns, e.start_ns + e.duration_ns,
+                                      dict(e.stats))
+    assert set(events) == {"hvd.decode.round", "hvd.decode.dispatch"}
+    r0, r1, stats = events["hvd.decode.round"]
+    d0, d1, dstats = events["hvd.decode.dispatch"]
+    assert stats == {"round": 7, "slots": 2, "live_tokens": 11}
+    assert dstats == {"leg": "serving_decode"}
+    assert r0 <= d0 <= d1 <= r1
+    # The ring's interval and the trace's are one interval.
+    kept = rec.records(name="decode.round")[0]
+    assert abs((kept.end_ns - kept.start_ns) - (r1 - r0)) < 2e6
+
+
+# -- the serving engine -----------------------------------------------------
+
+def _mesh1():
+    return Mesh(np.asarray(jax.devices()[:1], dtype=object).reshape(1),
+                ("tp",))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return LlamaLM(CFG, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+
+def _requests(lens, outs, arrivals=None, seed=0):
+    rng = np.random.RandomState(seed)
+    return [Request(rid=i, prompt=rng.randint(
+                        0, CFG.vocab_size, size=n).astype(np.int32),
+                    max_new_tokens=o,
+                    arrival_s=0.0 if arrivals is None else arrivals[i])
+            for i, (n, o) in enumerate(zip(lens, outs))]
+
+
+@pytest.mark.parametrize("spec_decode", [False, True],
+                         ids=["plain", "speculative"])
+def test_serve_leaves_a_tree_of_spans(params, spec_decode):
+    eng = ServingEngine(CFG, params, mesh=_mesh1(), slots=3, page_size=8,
+                        max_len=64, spec_decode=spec_decode, spec_k=2)
+    # What the round's attention reads, counted from outside before each
+    # round as the benchmark's wrapper counts it.
+    counted = []
+    round_fn = "spec_round" if spec_decode else "decode_once"
+    inner = getattr(eng, round_fn)
+
+    def counting(st, now):
+        counted.append(sum(int(eng.cache.lengths[s]) + 1
+                           for s in eng._decode_slots()))
+        return inner(st, now)
+
+    setattr(eng, round_fn, counting)
+    rec = spans.recorder()
+    rec.reset()
+    reqs = _requests([4, 9, 6, 5], [5, 3, 6, 1])
+    report = eng.serve(reqs)
+    assert report.completed == 4
+
+    serve = rec.records(name="serve")
+    assert len(serve) == 1
+    serve = serve[0]
+    records = rec.records(since_ns=serve.start_ns)
+    by_id = {r.id: r for r in records}
+
+    def root_of(r):
+        while r.parent is not None:
+            r = by_id[r.parent]
+        return r
+
+    assert all(root_of(r) is serve for r in records)
+    names = {r.name for r in records}
+    assert {"serve.arrivals", "serve.admit", "serve.prefill",
+            "prefill.dispatch", "prefill.write_kv",
+            "prefill.sample_fetch", "decode.round"} | ROUND_CHILDREN \
+        <= names
+
+    rounds = rec.records(name="decode.round")
+    assert len(rounds) == report.decode_steps == len(counted)
+    assert [r.attrs["live_tokens"] for r in rounds] == counted
+    assert [r.attrs["round"] for r in rounds] == list(range(len(rounds)))
+    for rnd in rounds:
+        kids = [r for r in records if r.parent == rnd.id]
+        assert {k.name for k in kids} == ROUND_CHILDREN
+        for k in kids:
+            assert rnd.start_ns <= k.start_ns <= k.end_ns <= rnd.end_ns
+        assert 1 <= rnd.attrs["slots"] <= 3
+
+    prefills = rec.records(name="serve.prefill")
+    assert sorted(p.attrs["rid"] for p in prefills) == [0, 1, 2, 3]
+    assert {p.attrs["prompt_len"] for p in prefills} == {4, 9, 6, 5}
+
+    filed = {r.attrs["rid"]: r for r in rec.records(name="request")}
+    assert sorted(filed) == [0, 1, 2, 3]
+    for req in reqs:
+        a = filed[req.rid].attrs
+        assert filed[req.rid].parent == serve.id
+        assert len(a["token_times"]) == len(req.tokens) \
+            == req.max_new_tokens
+        assert a["token_times"] == sorted(a["token_times"])
+        assert a["token_times"][0] == a["first_token_s"] \
+            == req.first_token_s
+        assert a["arrival_s"] <= a["admit_s"] <= a["first_token_s"] \
+            <= a["token_times"][-1] <= a["done_s"] == req.done_s
+        assert a["prompt_len"] == req.prompt_len
+    if spec_decode:
+        # The tokens one speculative round emits share its timestamp.
+        assert report.accepted_tokens == sum(
+            g == 0.0 for r in reqs for g in r.token_gaps)
+
+
+def test_token_latency_sees_a_prefill_that_stalls_the_batch(params):
+    """One long prompt arrives while short requests decode: its prefill
+    (a shape the engine has not compiled) holds every running request's
+    next token back, and the token latency's tail shows it.  As the time
+    of one dispatch and fetch it could not: that is the decode step's."""
+    eng = ServingEngine(CFG, params, mesh=_mesh1(), slots=4, page_size=8,
+                        max_len=64)
+    eng.serve(_requests([4, 4, 4], [3, 3, 3]))        # warm-up
+    steps = []
+    decode_once = eng.decode_once
+    eng.decode_once = lambda st, now: steps.append(
+        decode_once(st, now)) or steps[-1]
+    reqs = _requests([4, 4, 4, 33], [24, 24, 24, 2],
+                     arrivals=[0.0, 0.0, 0.0, 0.01], seed=1)
+    report = eng.serve(reqs)
+    assert report.completed == 4
+    long_req = reqs[3]
+    stalled = [g for r in reqs[:3]
+               for t0, g in zip(r.token_times, r.token_gaps)
+               if t0 <= long_req.first_token_s <= t0 + g]
+    assert len(stalled) == 3, "the prefill fell between two rounds"
+    assert min(stalled) > max(steps)
+    assert report.token_latency_p99_s > max(steps)
+    assert report.token_latency_p50_s < min(stalled)
+
+
+# -- names in the HLO -------------------------------------------------------
+
+def _train_step_text():
+    hv.shutdown()
+    hv.init(devices=jax.devices()[:2])
+    try:
+        rng = np.random.RandomState(0)
+        p0 = {"w": rng.randn(16, 4).astype(np.float32),
+              "b": np.zeros((4,), np.float32)}
+        opt = hv.DistributedOptimizer(optax.sgd(0.05), compression="fp16")
+        step = hv.make_train_step(
+            lambda p, x: jnp.mean((x @ p["w"] + p["b"]) ** 2), opt)
+        x = hv.shard_batch(np.asarray(rng.randn(4, 16), np.float32))
+        return step.lower(hv.replicate(p0), hv.replicate(opt.init(p0)),
+                          x).as_text(debug_info=True)
+    finally:
+        hv.shutdown()
+
+
+def _decode_step_text():
+    from horovod_tpu.serving import (CacheConfig, PagedKVCache,
+                                     build_decode_step, cache_sharding)
+    mesh = _mesh1()
+    params = LlamaLM(CFG, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+    ccfg = CacheConfig(num_layers=CFG.num_layers,
+                       num_kv_heads=CFG.num_kv_heads,
+                       head_dim=CFG.head_dim, slots=2, page_size=8,
+                       max_len=32)
+    cache = PagedKVCache(ccfg, cache_sharding(mesh))
+    step = build_decode_step(CFG, mesh, slots=2, page_size=8,
+                             pages_per_slot=ccfg.pages_per_slot)
+    # The step builds its jitted program at its first call: trace that.
+    return jax.jit(step._fn).lower(
+        params, cache.k, cache.v, jnp.zeros((2,), jnp.int32),
+        cache.lengths_device(), cache.table_device(),
+        jnp.ones((2,), bool)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("text_of, names", [
+    (_train_step_text, ["hvd_exchange/pack", "hvd_exchange/compress",
+                        "hvd_exchange/collective",
+                        "hvd_exchange/decompress", "hvd_exchange/unpack"]),
+    (_decode_step_text, ["hvd_flash_decode"]),
+], ids=["train_step", "decode_step"])
+def test_lowered_text_carries_the_names(monkeypatch, text_of, names):
+    monkeypatch.setenv("HOROVOD_PALLAS_DECODE", "1")
+    text = text_of()
+    for name in names:
+        assert name in text, name
