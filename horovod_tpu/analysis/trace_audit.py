@@ -482,7 +482,7 @@ def _build_3d_config(config: str):
 
 
 def _build_serving_config(config: str):
-    """``(step, args, None, name)`` for the serving decode audits.
+    """``(step, args, (1, 2), name)`` for the serving decode audits.
 
     ``serving_decode`` builds on the largest valid tp size the device
     pool allows; ``serving_decode_resized`` on the next size down --
@@ -490,9 +490,9 @@ def _build_serving_config(config: str):
     ``resized_from`` provenance in the step meta so the expected model
     notes the transition.  ``serving_verify`` is the width-5 (k=4)
     speculative verify step on the full tp size: the audit must match
-    the widened multiset exactly, no new declines.  No donation: the
-    decode step's pool aliasing is the engine's business, not the
-    trainer's.
+    the widened multiset exactly, no new declines.  ``(1, 2)`` mirrors
+    the step's own donation of ``k_pool``/``v_pool``: each pool leaf
+    must be matched by an output (its in-place successor).
     """
     import numpy as np
     from jax.sharding import Mesh
@@ -532,7 +532,7 @@ def _build_serving_config(config: str):
         step._meta["resized_from"] = resized_from
     args = (params, cache.k, cache.v, tokens, cache.lengths_device(),
             cache.table_device(), jnp.zeros((ccfg.slots,), bool))
-    return step, args, None, f"step:{config}"
+    return step, args, (1, 2), f"step:{config}"
 
 
 def audit_standard_configs(configs: Optional[Sequence[str]] = None

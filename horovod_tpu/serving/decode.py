@@ -302,6 +302,13 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     Idle slots produce zero attention output (dead-row convention) and
     their logits are discarded by the engine.
 
+    The step CONSUMES ``k_pool`` and ``v_pool``: both are donated, the
+    scatters update them in place and the returned pools are their
+    successors.  The arrays passed in are deleted by the call -- rebind
+    from the result (``logits, cache.k, cache.v = step(...)``) and pass
+    ``jnp.copy(pool)`` to keep a snapshot.  Every other operand (params,
+    the fp8 pools, tables) is read only.
+
     ``width > 1`` is the speculative-decoding VERIFY step (built through
     :func:`build_verify_step`): ``tokens`` widens to ``[slots, width]``
     (the last sampled token followed by ``width - 1`` drafts), every
@@ -514,7 +521,10 @@ def build_decode_step(config: LlamaConfig, mesh, *,
         fn = jax.shard_map(spmd, mesh=mesh, in_specs=tuple(in_specs),
                            out_specs=(P(), pool_spec, pool_spec),
                            check_vma=False)
-        return jax.jit(fn)
+        # The pools are updated in place: outputs 1 and 2 alias inputs
+        # 1 and 2 (same shape, dtype and ``pool_spec``), so the round
+        # scatters into the caller's buffers instead of into a copy.
+        return jax.jit(fn, donate_argnums=(1, 2))
 
     # The jitted callable is built lazily on first call so the shard_map
     # in_specs can mirror the actual params tree (LoRA leaves included).

@@ -59,6 +59,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -124,8 +125,27 @@ class CacheConfig:
         }
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _pool_set(pool, values, pages, offs=None):
+    """``pool.at[:, pages(, offs)].set(values)`` in place: the pool is
+    donated, so the scatter writes the rows it names and copies nothing
+    else.  Compiles once per ``values`` shape."""
+    with jax.named_scope("hvd_kv_write_prefill"):
+        if offs is None:
+            return pool.at[:, pages].set(values)
+        return pool.at[:, pages, offs].set(values)
+
+
 class PagedKVCache:
-    """Device page pool + host page table / free list for one model."""
+    """Device page pool + host page table / free list for one model.
+
+    ``k`` and ``v`` have ONE owner at a time: every program that writes
+    a pool (the decode/verify step, :meth:`write_prefill`, the
+    copy-on-write clone, :meth:`adopt_pages`) consumes the array it is
+    given and hands back its successor, which is rebound here.  Read
+    ``cache.k``/``cache.v`` at call time and keep no reference across a
+    write -- the old array is deleted; ``jnp.copy`` it first to keep a
+    snapshot."""
 
     def __init__(self, config: CacheConfig, sharding=None):
         self.config = config
@@ -374,8 +394,8 @@ class PagedKVCache:
                     f"divergence of slot {slot}")
             new = self._free.pop()
             self._refcount[new] = 1
-            self.k = self.k.at[:, new].set(self.k[:, pid])
-            self.v = self.v.at[:, new].set(self.v[:, pid])
+            self.k = _pool_set(self.k, self.k[:, pid], new)
+            self.v = _pool_set(self.v, self.v[:, pid], new)
             self.page_table[slot, i] = new
             self.drop_page_ref(pid)
 
@@ -527,9 +547,9 @@ class PagedKVCache:
             self._refcount[pid] = 1
         dt = jnp.dtype(self.config.dtype)
         dev = jnp.asarray(pids)
-        self.k = self.k.at[:, dev].set(jnp.asarray(k_pages, dt))
-        self.v = self.v.at[:, dev].set(jnp.asarray(np.asarray(v_pages),
-                                                   dt))
+        self.k = _pool_set(self.k, jnp.asarray(k_pages, dt), dev)
+        self.v = _pool_set(self.v, jnp.asarray(np.asarray(v_pages), dt),
+                           dev)
         return [("f", int(p)) for p in pids]
 
     def adopt_compressed_pages(self, kq, vq, kscale, vscale
@@ -639,9 +659,8 @@ class PagedKVCache:
         offs = jnp.asarray(pos % c.page_size)
         dt = jnp.dtype(c.dtype)
         # One scatter per pool: [L, t, H, D] lands at (page, off) pairs.
-        with jax.named_scope("hvd_kv_write_prefill"):
-            self.k = self.k.at[:, pages, offs].set(k_layers.astype(dt))
-            self.v = self.v.at[:, pages, offs].set(v_layers.astype(dt))
+        self.k = _pool_set(self.k, k_layers.astype(dt), pages, offs)
+        self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
         self.lengths[slot] = start + t
 
     def grow(self, slot: int) -> None:

@@ -163,8 +163,10 @@ def test_verify_step_rows_bitwise_match_sequential_decode(base_params):
     base = cache.lengths_device()
     active = jnp.zeros((ccfg.slots,), bool).at[0].set(True)
 
+    # Both steps consume the pools they are given, so the sequential
+    # run decodes on copies and the verify step gets the originals.
     k0, v0 = cache.k, cache.v
-    rows, k, v = [], k0, v0
+    rows, k, v = [], jnp.copy(k0), jnp.copy(v0)
     for i in range(W):
         tok = jnp.zeros((ccfg.slots,), jnp.int32).at[0].set(tokens[0, t0 + i])
         logits, k, v = plain(params, k, v, tok, base + i, table, active)
@@ -298,7 +300,9 @@ def test_fp8_compressed_page_survives_donor_page_poisoning(base_params):
             cache.lengths_device(), cache.table_device(),
             jnp.zeros((ccfg.slots,), bool).at[0].set(True),
             *cache.compress_operands())
-    clean, _, _ = step(params, cache.k, cache.v, *args)
+    # The step consumes its pools: the clean run gets copies so the
+    # cache's own arrays are still there to poison.
+    clean, _, _ = step(params, jnp.copy(cache.k), jnp.copy(cache.v), *args)
 
     # Poison every free f32 page with FINITE garbage, as a recycling
     # slot would (the masking contract zeroes stale pages' attention
