@@ -334,6 +334,212 @@ def _flash_decode(q, k, v, lengths, scale: float, bk: int):
     return o.reshape(b, h, 1, d)
 
 
+# ---------------------------------------------------------------------------
+# Latent (MLA) decode: the absorbed form over a shared latent key.
+# ---------------------------------------------------------------------------
+
+# Pages a grid step of ``hvd_mla_decode`` copies and computes: 64 pages of
+# 16 tokens are 1,024 keys a step, 1.3 MB a buffer at 640 columns.
+MLA_PAGES_PER_BLOCK = 64
+
+
+def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
+                         value_dim: int, scale: float,
+                         force_reference: bool = False):
+    """Single-token decode attention in latent attention's ABSORBED form,
+    read straight out of the page pool.
+
+    ``q``: ``(b, h, w)`` -- each head's query against a cached row: its
+    first ``value_dim`` columns already carried into the latent space
+    (``q_nope @ W_kvb^K``), the rest its rotated part.  ``pool``:
+    ``(layers, pages, page_size, w)``, the cache's one pool: a token's
+    row is its normalised latent (``value_dim`` columns) and, beside it,
+    the one rotated key ALL heads share.  ``page_table``: ``(b,
+    pages_per_slot)`` int32, row ``i``'s pages in order; ``lengths``:
+    ``(b,)`` live tokens of each row.  Scores are ``(q . row) * scale``
+    over the whole row; the values are the row's first ``value_dim``
+    columns, so the result is ``(b, h, value_dim)`` float32 in the latent
+    space (the caller carries it out through ``W_kvb^V``).  A row with
+    ``lengths == 0`` gives exactly zero.
+
+    Dispatch as :func:`decode_attention`: the ``hvd_mla_decode`` kernel
+    where the ``mla_decode`` family is on, ``jax.numpy`` over a gathered
+    view otherwise.  The kernel WALKS THE PAGE TABLE: no view of a slot's
+    pages is ever materialised.  Its grid is the list of live (row, block
+    of ``MLA_PAGES_PER_BLOCK`` pages) items; each step copies its block's
+    live pages from the pool into VMEM by their scalar-prefetched ids
+    while the step before computes (two buffers), takes the ``h`` heads
+    as the MXU tile's rows (all of them share the key, as one GQA group
+    does) and uses the block once as key and, its first columns, as
+    value.  Pages past ``lengths`` are neither copied nor computed."""
+    b, h, w = q.shape
+    if pool.ndim != 4 or pool.shape[3] != w or not 0 < value_dim <= w \
+            or page_table.shape[0] != b:
+        raise ValueError(
+            f"mla_decode_attention: q {q.shape}, pool {pool.shape}, "
+            f"page_table {page_table.shape} and value_dim {value_dim} do "
+            "not fit together")
+    if lengths.shape != (b,):
+        raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
+    if not force_reference and _pallas.pallas_enabled("mla_decode"):
+        return _mla_decode(q, pool, page_table, lengths, int(layer),
+                           value_dim, float(scale), MLA_PAGES_PER_BLOCK)
+    s = page_table.shape[1] * pool.shape[2]
+    kv = pool[layer][page_table].reshape(b, s, w).astype(q.dtype)
+    logits = jnp.einsum("bhw,bsw->bhs", q, kv,
+                        preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(s)[None, None, :] < lengths[:, None, None]
+    logits = jnp.where(live, logits, _NEG_INF)
+    probs = jnp.where(live, jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("bhs,bsr->bhr", probs.astype(kv.dtype),
+                      kv[..., :value_dim],
+                      preferred_element_type=jnp.float32)
+
+
+def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
+                       q_ref, pool_ref, o_ref, buf, sem, m_scr, l_scr,
+                       acc_scr, *, layer, scale, ppb, page, value_dim):
+    """Grid ``(items,)``, sequential: item ``i`` is block ``blk_ref[i]``
+    (``ppb`` pages) of row ``slot_ref[i]``; the first ``n_ref[0]`` items
+    are live, a row's items follow one another, and the online-softmax
+    state lives in VMEM scratch across them, as in ``_decode_kernel``.
+    While item ``i`` computes out of one half of ``buf``, the pages of
+    item ``i + 1`` are on their way into the other.  The operands go to
+    the MXU in their own type (bfloat16 on the chip) with float32
+    accumulation."""
+    i = pl.program_id(0)
+    n_items = n_ref[0]
+    bk = ppb * page
+
+    def live_pages(item):
+        left = len_ref[slot_ref[item]] - blk_ref[item] * bk
+        return jnp.clip((left + page - 1) // page, 0, ppb)
+
+    def start(item, half):
+        first = blk_ref[item] * ppb
+        row = slot_ref[item]
+
+        def one(j, carry):
+            pltpu.make_async_copy(
+                pool_ref.at[layer, table_ref[row, first + j]],
+                buf.at[half, j], sem.at[half]).start()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(item), one, 0)
+
+    def wait(item, half):
+        def one(j, carry):
+            pltpu.make_async_copy(pool_ref.at[layer, 0], buf.at[half, 0],
+                                  sem.at[half]).wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(item), one, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        # Pages a block does not copy keep what the buffer held: masked
+        # below, but never a NaN's bit pattern.
+        buf[...] = jnp.zeros_like(buf)
+        start(0, 0)
+
+    @pl.when(i < n_items)
+    def _item():
+        half = i % 2
+
+        @pl.when(i + 1 < n_items)
+        def _next():
+            start(i + 1, 1 - half)
+
+        wait(i, half)
+        blk = blk_ref[i]
+        length = len_ref[slot_ref[i]]
+
+        @pl.when(blk == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        kv = buf[half].reshape(bk, buf.shape[-1])     # (bk, w)
+        s = jax.lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        cols = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        live = cols < length
+        s = jnp.where(live, s, _NEG_INF)
+        m_prev = m_scr[:, :1]                         # (h, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)  # (h, bk)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        @pl.when((blk + 1) * bk >= length)
+        def _finish():
+            l = l_scr[:, :1]
+            o_ref[0] = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+
+
+def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
+                scale: float, ppb: int):
+    b, h, w = q.shape
+    page = pool.shape[2]
+    pps = page_table.shape[1]
+    ppb = min(ppb, pps)
+    bk = ppb * page
+    per_row = -(-pps // ppb)
+    lengths = lengths.astype(jnp.int32)
+    # The live items: every row has one at least (an idle row's only
+    # item copies nothing and writes its zeros), a live row one a block
+    # that holds a live token.  ``items`` is the static bound.
+    blocks = jnp.maximum((lengths + bk - 1) // bk, 1)
+    ends = jnp.cumsum(blocks)
+    items = b * per_row
+    ids = jnp.arange(items, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, ids, side="right"),
+                       b - 1).astype(jnp.int32)
+    blk = jnp.clip(ids - (ends - blocks)[slot], 0, per_row - 1).astype(
+        jnp.int32)
+    n_items = ends[-1:].astype(jnp.int32)
+    if pps % ppb:
+        # The last block of a full row reads table entries past its end.
+        page_table = jnp.pad(page_table, ((0, 0), (0, per_row * ppb - pps)))
+
+    def row(i, lens, table, slots, blks, n):
+        # Items past the last live one name its row again: nothing moves.
+        return slots[jnp.minimum(i, n[0] - 1)], 0, 0
+
+    kernel = functools.partial(_mla_decode_kernel, layer=layer, scale=scale,
+                               ppb=ppb, page=page, value_dim=value_dim)
+    with jax.named_scope("hvd_mla_decode"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(items,),
+                in_specs=[pl.BlockSpec((1, h, w), row),
+                          pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, h, value_dim), row),
+                scratch_shapes=[
+                    pltpu.VMEM((2, ppb, page, w), pool.dtype),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.VMEM((h, _LANES), jnp.float32),
+                    pltpu.VMEM((h, _LANES), jnp.float32),
+                    pltpu.VMEM((h, value_dim), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            name="hvd_mla_decode",
+            interpret=_pallas.interpret_mode(),
+        )(lengths, page_table.astype(jnp.int32), slot, blk, n_items,
+          q.astype(pool.dtype), pool)
+
+
 def _causal_mask(s, qi, ki, bq, bk, off):
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + off
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

@@ -1,0 +1,258 @@
+"""Routed experts for a served step: a router that drops nothing and a
+grouped matmul over the (token, choice) pairs that tokens chose.
+
+:func:`moe_ffn` is the inference expert layer (``parallel/moe.py`` is the
+Switch-style TRAINING layer, with a capacity that drops tokens, and is
+left alone):
+
+* :func:`route` scores in float32 (sigmoid), chooses ``top_k`` of ALL the
+  experts by ``score + bias`` and weighs by the renormalised, scaled
+  scores -- the bias chooses and never weighs;
+* the ``tokens * top_k`` pairs are sorted by expert and laid out in
+  row tiles of ``tm`` that never straddle two experts (each expert's run
+  is padded up to a tile), so the matmul kernel is a plain tiled product
+  whose weight block is picked by a scalar-prefetched expert id a tile.
+  Work grows with ``tokens * top_k`` (plus under one tile an expert
+  touched), never with ``tokens * experts``; no capacity, nothing
+  dropped;
+* the layer is told which experts it HOLDS (``first``, and the leading
+  dim of the stacked weights): it routes over all of them, computes its
+  own experts' part and adds the shared expert only where
+  ``with_shared`` says so -- the cut expert parallelism asks for, with
+  no exchange on one chip.
+
+The Mosaic call is named ``hvd_moe_gmm`` (the trace's name for it); off
+the TPU the same tiles go through a ``jax.numpy`` loop-free reference
+unless ``HOROVOD_PALLAS=1`` asks for the interpreter.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas as _pallas
+
+_HI = jax.lax.Precision.HIGHEST
+_MIN_TILE = 16        # bf16 sublane tile: the smallest row tile.
+_MAX_TILE = 128       # the v5e MXU's rows.
+_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+class Routing(NamedTuple):
+    experts: jax.Array     # [tokens, top_k] int32, chosen expert ids
+    weights: jax.Array     # [tokens, top_k] float32, what each adds
+
+
+def route(h, w_router, bias, *, top_k: int, scale: float) -> Routing:
+    """Sigmoid scores in float32, ``top_k`` by ``score + bias``, weights
+    ``scale * score / (sum of the chosen scores + 1e-20)``."""
+    s = jax.nn.sigmoid(jnp.matmul(h.astype(jnp.float32),
+                                  w_router.astype(jnp.float32),
+                                  precision=_HI))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    g = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return Routing(idx.astype(jnp.int32), g)
+
+
+def row_tile(pairs: int, experts: int) -> int:
+    """Rows a tile: about the mean run of an expert, as a power of two
+    between the bf16 sublane tile and the MXU's rows."""
+    want = max(pairs // max(experts, 1), 1)
+    tm = _MIN_TILE
+    while tm < min(want, _MAX_TILE):
+        tm *= 2
+    return tm
+
+
+class Layout(NamedTuple):
+    """Where each (token, choice) pair lies among the padded rows."""
+    src: jax.Array          # [rows] token each padded row reads
+    dest: jax.Array         # [tokens, top_k] row of each pair
+    held: jax.Array         # [tokens, top_k] bool: pair's expert is here
+    tile_expert: jax.Array  # [rows // tm] local expert id a tile
+    active: jax.Array       # [1] tiles that hold at least one pair
+    counts: jax.Array       # [experts] pairs routed to each expert (all)
+
+
+def layout(experts_of, num_experts: int, tm: int, *, first: int = 0,
+           held: Optional[int] = None, live=None) -> Layout:
+    """Sort the pairs by expert and pad each held expert's run to a
+    multiple of ``tm``.  ``live`` (``[tokens]`` bool) leaves dead rows'
+    pairs out of every count."""
+    t, k = experts_of.shape
+    held = num_experts if held is None else held
+    flat = experts_of.reshape(-1)
+    if live is not None:
+        flat = jnp.where(jnp.repeat(live, k), flat, num_experts)
+    counts = jnp.zeros((num_experts + 1,), jnp.int32).at[flat].add(1)
+    counts = counts[:num_experts]
+    local = flat - first
+    here = (local >= 0) & (local < held) & (flat < num_experts)
+    key = jnp.where(here, local, held)                 # the rest last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    key_sorted = key[order]
+    mine = jax.lax.dynamic_slice(counts, (first,), (held,))
+    tiles = (mine + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles)
+    starts = jnp.cumsum(mine) - mine                   # unpadded run start
+    pad_starts = (tile_end - tiles) * tm
+    safe = jnp.minimum(key_sorted, held - 1)
+    rank = jnp.arange(t * k, dtype=jnp.int32) - starts[safe]
+    row_sorted = pad_starts[safe] + rank
+    rows = _padded_rows(t * k, held, tm)
+    row_sorted = jnp.where(key_sorted < held, row_sorted, rows)  # dropped
+    src = jnp.zeros((rows,), jnp.int32).at[row_sorted].set(
+        order // k, mode="drop")
+    dest = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.minimum(row_sorted, rows - 1))
+    tile_ids = jnp.arange(rows // tm, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, tile_ids, side="right"),
+        held - 1).astype(jnp.int32)
+    return Layout(src, dest.reshape(t, k), here.reshape(t, k), tile_expert,
+                  tile_end[-1:].astype(jnp.int32), counts)
+
+
+def _padded_rows(pairs: int, held: int, tm: int) -> int:
+    """The most rows the padded layout can take, as whole tiles."""
+    worst = pairs + min(held, pairs) * (tm - 1)
+    return -(-worst // tm) * tm
+
+
+def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool):
+    """One row tile against its expert's weight block.  ``swiglu``:
+    ``silu(x @ w_gate) * (x @ w_up)`` in one pass over ``x``."""
+    del te_ref
+    o_ref = refs[-1]
+
+    @pl.when(pl.program_id(0) < na_ref[0])
+    def _tile():
+        x = x_ref[...]
+        y = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
+        if swiglu:
+            y = jax.nn.silu(y) * jnp.dot(
+                x, refs[1][...], preferred_element_type=jnp.float32)
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _gmm_pallas(x, ws, tile_expert, active, tm: int):
+    rows, kdim = x.shape
+    n = ws[0].shape[2]
+
+    def last(i, na):
+        return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
+
+    # Tiles past the last active one name its blocks again: nothing is
+    # fetched for them and the kernel body is predicated off.
+    w_spec = pl.BlockSpec(
+        (None, kdim, n), lambda i, te, na: (te[last(i, na)], 0, 0))
+    kernel = functools.partial(_gmm_kernel, swiglu=len(ws) == 2)
+    with jax.named_scope("hvd_moe_gmm"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(rows // tm,),
+                in_specs=[pl.BlockSpec(
+                    (tm, kdim), lambda i, te, na: (last(i, na), 0))]
+                + [w_spec] * len(ws),
+                out_specs=pl.BlockSpec(
+                    (tm, n), lambda i, te, na: (last(i, na), 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            name="hvd_moe_gmm",
+            interpret=_pallas.interpret_mode(),
+        )(tile_expert, active, x, *ws)
+
+
+def _gmm_reference(x, ws, tile_expert, active, tm: int):
+    """The same tiles in ``jax.numpy``: each tile against the weights of
+    its expert, gathered a tile (fine at test sizes, and the CPU path of
+    a tiny engine)."""
+    rows, kdim = x.shape
+    xt = x.reshape(rows // tm, tm, kdim)
+
+    def mm(w):
+        return jnp.einsum("tmk,tkn->tmn", xt, w[tile_expert],
+                          preferred_element_type=jnp.float32)
+
+    y = mm(ws[0])
+    if len(ws) == 2:
+        y = jax.nn.silu(y) * mm(ws[1])
+    live = jnp.arange(rows // tm) < active[0]
+    return jnp.where(live[:, None, None], y, 0.0).astype(x.dtype).reshape(
+        rows, -1)
+
+
+def grouped_matmul(x, ws, tile_expert, active, *, tm: int,
+                   force_reference: bool = False):
+    """``x`` ``[rows, k]`` in tiles of ``tm`` rows, tile ``i`` against
+    ``w[tile_expert[i]]`` for each ``w`` ``[experts, k, n]`` of ``ws``:
+    one weight gives ``x @ w``; two give ``silu(x @ w0) * (x @ w1)``.
+    Only the first ``active[0]`` tiles are computed; the rows of the
+    others are undefined."""
+    if x.shape[0] % tm:
+        raise ValueError(f"{x.shape[0]} rows are not whole tiles of {tm}")
+    if len(ws) not in (1, 2):
+        raise ValueError(f"one or two weights, got {len(ws)}")
+    if not force_reference and _pallas.pallas_enabled("moe_gmm"):
+        return _gmm_pallas(x, tuple(ws), tile_expert, active, tm)
+    return _gmm_reference(x, tuple(ws), tile_expert, active, tm)
+
+
+def moe_ffn(h, params, *, top_k: int, scale: float, num_experts: int,
+            first: int = 0, with_shared: bool = True, live=None,
+            h_router=None, force_reference: bool = False):
+    """The routed layer over ``h`` ``[tokens, d]``; ``h_router``: the
+    same rows as the router reads them (float32, before they were rounded
+    to ``h``'s type), ``h`` itself where None.
+
+    ``params``: ``router/kernel`` ``[d, num_experts]``,
+    ``router/e_score_correction_bias`` ``[num_experts]``, ``experts``
+    (``w_gate``, ``w_up`` ``[held, d, f]``, ``w_down`` ``[held, f, d]``:
+    the experts ``first .. first + held - 1``) and ``shared`` (a SwiGLU's
+    three kernels).  Returns ``(y, counts)``: this share's part of the
+    layer's output (every held expert's, and the shared expert's where
+    ``with_shared``) in float32, unrounded, and the ``[num_experts]``
+    count of pairs routed to each expert by the ``live`` rows.
+    """
+    dtype = h.dtype
+    t = h.shape[0]
+    ex = params["experts"]
+    held = ex["w_gate"].shape[0]
+    r = route(h if h_router is None else h_router,
+              params["router"]["kernel"],
+              params["router"]["e_score_correction_bias"],
+              top_k=top_k, scale=scale)
+    tm = row_tile(t * top_k, num_experts)
+    lay = layout(r.experts, num_experts, tm, first=first, held=held,
+                 live=live)
+    xs = h[lay.src]
+    act = grouped_matmul(xs, (ex["w_gate"].astype(dtype),
+                              ex["w_up"].astype(dtype)),
+                         lay.tile_expert, lay.active, tm=tm,
+                         force_reference=force_reference)
+    ys = grouped_matmul(act, (ex["w_down"].astype(dtype),),
+                        lay.tile_expert, lay.active, tm=tm,
+                        force_reference=force_reference)
+    picked = jnp.where(lay.held[..., None], ys[lay.dest].astype(jnp.float32),
+                       0.0)
+    y = jnp.einsum("tkd,tk->td", picked, r.weights)
+    if with_shared:
+        sh = params["shared"]
+        gate = h @ sh["w_gate"]["kernel"].astype(dtype)
+        up = h @ sh["w_up"]["kernel"].astype(dtype)
+        y = y + jnp.dot((jax.nn.silu(gate) * up).astype(dtype),
+                        sh["w_down"]["kernel"].astype(dtype),
+                        preferred_element_type=jnp.float32)
+    return y, lay.counts

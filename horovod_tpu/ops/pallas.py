@@ -5,9 +5,9 @@ every Pallas kernel family in the package:
 
 - ``auto`` (default): on TPU the families Mosaic has compiled at their
   callers' full-width shapes run as kernels (``flash``,
-  ``flash_decode``); ``fused_update`` and ``bn_bwd`` resolve to the XLA
-  path (see ``_AUTO_XLA``).  Off TPU every family takes the XLA
-  reference;
+  ``flash_decode``, ``mla_decode``, ``moe_gmm``); ``fused_update`` and
+  ``bn_bwd`` resolve to the XLA path (see ``_AUTO_XLA``).  Off TPU every
+  family takes the XLA reference;
 - ``1``: force the kernels everywhere (off-TPU they run in the Pallas
   interpreter -- slow, but numerically the kernel path; this is what the
   CPU parity tests and the CI step audit use);
@@ -62,6 +62,20 @@ KERNEL_CONTRACTS = {
         "note": "split-KV decode kernel; the serving step's two "
                 "row-parallel psums per layer stay in XLA",
     },
+    "mla_decode": {
+        "collectives": (),
+        "wire_delta_bytes": 0,
+        "site": "ops.attention.mla_decode_attention",
+        "note": "absorbed latent-attention decode kernel over the latent "
+                "page pool; tp = 1, no exchange",
+    },
+    "moe_gmm": {
+        "collectives": (),
+        "wire_delta_bytes": 0,
+        "site": "ops.moe.grouped_matmul",
+        "note": "grouped matmul over the experts a chip holds; the layer "
+                "runs without its exchange on one chip",
+    },
     "fused_update": {
         "collectives": (),
         "wire_delta_bytes": 0,
@@ -81,6 +95,7 @@ KERNEL_CONTRACTS = {
 _FAMILY_ENV = {
     "flash": "PALLAS_FLASH",
     "flash_decode": "PALLAS_DECODE",
+    "mla_decode": "PALLAS_DECODE",     # the decode kernels share a switch
     "fused_update": "PALLAS_FUSED_UPDATE",
     "bn_bwd": "PALLAS_BN",
 }
@@ -130,7 +145,8 @@ def pallas_enabled(family: str) -> bool:
     if family not in KERNEL_CONTRACTS:
         raise ValueError(f"unknown pallas kernel family {family!r}; "
                          f"known: {sorted(KERNEL_CONTRACTS)}")
-    flag = _read(_FAMILY_ENV[family])
+    # ``moe_gmm`` has no switch of its own: it follows the global one.
+    flag = _read(_FAMILY_ENV[family]) if family in _FAMILY_ENV else None
     if flag is None and family == "flash":
         flag = _legacy_flash_flag()
     if flag is None:

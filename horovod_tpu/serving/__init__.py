@@ -24,8 +24,10 @@ from .kvcache import (CacheConfig, PagedKVCache,  # noqa: F401
                       PrefixCache, cache_sharding)
 from .kvwire import (WirePages, decode_kv, encode_kv,  # noqa: F401
                      import_pages, wire_tier)
+from .layerspec import LayerSpec, layer_spec  # noqa: F401
 from .loadgen import (LoadSpec, fleet_spec, generate,  # noqa: F401
                       long_prompt_spec, prefix_spec)
+from .mla_moe import MlaMoeConfig  # noqa: F401
 from .policy import (Decision, FleetPolicy,  # noqa: F401
                      FleetPolicyConfig, FleetSample, PolicyConfig,
                      ScalePolicy, SLOSample, valid_tp_sizes)

@@ -51,10 +51,9 @@ from jax.sharding import Mesh, NamedSharding
 
 from ..core.config import _env, _env_bool, _env_int
 from ..timeline import spans as _spans
-from .decode import (build_decode_step, build_verify_step,
-                     decode_param_specs, greedy_sample, prefill_forward)
-from .kvcache import (CacheConfig, PagedKVCache, PrefixCache,
-                      cache_sharding)
+from .decode import greedy_sample
+from .kvcache import CacheConfig, PagedKVCache, PrefixCache
+from .layerspec import layer_spec
 from .scheduler import (ContinuousBatchScheduler, Request,
                         parse_tenant_classes)
 from .spec import NgramDrafter
@@ -158,6 +157,14 @@ class ServingReport:
         return dataclasses.asdict(self)
 
 
+@jax.jit
+def _screen_and(logits, told):
+    """The finite screen's per-slot sums with what the step told of its
+    round behind them: one program, one fetch."""
+    return jnp.concatenate([jnp.sum(logits, axis=-1),
+                            told.astype(jnp.float32)])
+
+
 def _pct(values: List[float], q: float) -> float:
     if not values:
         return 0.0
@@ -165,7 +172,13 @@ def _pct(values: List[float], q: float) -> float:
 
 
 class ServingEngine:
-    """Continuous-batching inference over one Llama-family model.
+    """Continuous-batching inference over one model.
+
+    ``config``: a ``LlamaConfig`` or any config with a ``layer_spec()``
+    (``serving.mla_moe.MlaMoeConfig``).  The prefill, the decode step
+    and the cache's layout are built from that one description
+    (``serving.layerspec.LayerSpec``); a feature the model's programs
+    lack raises ``NotImplementedError`` here, by name.
 
     ``mesh``: the ``("tp",)`` mesh the decode step and the KV pool shard
     over.  The default, ``mesh=None``, is ``jax.devices()[:1]`` -- ONE
@@ -190,15 +203,17 @@ class ServingEngine:
                  prefix_cache: Optional[bool] = None,
                  session_ttl_steps: int = 0, tenants=None):
         self.config = config
+        self.spec = spec = layer_spec(config)
         self.params = params
         if mesh is None:
             mesh = Mesh(np.asarray(jax.devices()[:1]), ("tp",))
         self.mesh = mesh
+        spec.require(tp=mesh.devices.size > 1, lora=adapters is not None)
         self._decode_params = self._place_decode_params()
         self.slots = slots or _env_int("SERVING_SLOTS", 8)
         self.page_size = page_size or _env_int("SERVING_PAGE_SIZE", 16)
         self.max_len = max_len or _env_int("SERVING_MAX_LEN",
-                                           config.max_seq_len)
+                                           spec.max_seq_len)
         self.prefetch_depth = prefetch_depth or _env_int(
             "SERVING_PREFETCH", 2)
         self.spec_decode = (_env_bool("SPEC_DECODE")
@@ -214,8 +229,12 @@ class ServingEngine:
         self.session_ttl_steps = session_ttl_steps or _env_int(
             "SESSION_TTL_STEPS", 512)
         if tenants is None:
-            spec = _env("TENANT_CLASSES")
-            tenants = parse_tenant_classes(spec) if spec else None
+            classes = _env("TENANT_CLASSES")
+            tenants = parse_tenant_classes(classes) if classes else None
+        spec.require(spec_decode=self.spec_decode,
+                     kv_compress=self.kv_compress,
+                     prefill_chunk=self.prefill_chunk > 0,
+                     prefix_cache=self.prefix_cache)
         if self.spec_decode and self.spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {self.spec_k}")
         if adapters is not None and self.spec_decode:
@@ -234,13 +253,12 @@ class ServingEngine:
         self.adapters = adapters
         self.lora_alpha = lora_alpha
         self.cache_config = CacheConfig(
-            num_layers=config.num_layers,
-            num_kv_heads=config.num_kv_heads, head_dim=config.head_dim,
-            slots=self.slots, page_size=self.page_size,
-            max_len=self.max_len, dtype=str(jnp.dtype(dtype)),
-            compress=self.kv_compress)
+            num_layers=spec.num_layers, slots=self.slots,
+            page_size=self.page_size, max_len=self.max_len,
+            dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
+            page=spec.page)
         self.cache = PagedKVCache(self.cache_config,
-                                  cache_sharding(mesh))
+                                  spec.pool_sharding(mesh))
         # Admission must price the widest step a slot can take: k drafts
         # + the target's bonus token under speculation, else 1.
         budget = self.spec_k + 1 if self.spec_decode else 1
@@ -255,31 +273,18 @@ class ServingEngine:
         if self.prefix_cache:
             self._prefix = PrefixCache(
                 self.cache, session_ttl_steps=self.session_ttl_steps)
-        self.step = build_decode_step(
-            config, mesh, slots=self.slots, page_size=self.page_size,
-            pages_per_slot=self.cache_config.pages_per_slot, dtype=dtype,
-            with_lora=adapters is not None, lora_alpha=lora_alpha,
-            compress=self.kv_compress)
-        self.verify_step = None
+        self._build_steps()
+        self.drafter = None
         if self.spec_decode:
-            self.verify_step = build_verify_step(
-                config, mesh, slots=self.slots, width=self.spec_k + 1,
-                page_size=self.page_size,
-                pages_per_slot=self.cache_config.pages_per_slot,
-                dtype=dtype, compress=self.kv_compress)
             self.drafter = drafter if drafter is not None \
                 else NgramDrafter()
-        else:
-            self.drafter = None
 
         def _prefill(p, toks, ad, aid):
-            return prefill_forward(p, config, toks, dtype=dtype,
-                                   adapters=ad, adapter_id=aid,
-                                   lora_alpha=lora_alpha)
+            return spec.prefill(p, toks, dtype=dtype, adapters=ad,
+                                adapter_id=aid, lora_alpha=lora_alpha)
 
         def _prefill_chunk(p, toks, past):
-            return prefill_forward(p, config, toks, dtype=dtype,
-                                   past=past)
+            return spec.prefill(p, toks, dtype=dtype, past=past)
 
         self._prefill = jax.jit(_prefill)
         self._prefill_chunked = jax.jit(_prefill_chunk)
@@ -293,7 +298,23 @@ class ServingEngine:
         placed once, so a dispatch does not re-shard the tree."""
         return jax.device_put(self.params, jax.tree.map(
             lambda spec: NamedSharding(self.mesh, spec),
-            decode_param_specs(self.params)))
+            self.spec.param_specs(self.params)))
+
+    def _build_steps(self) -> None:
+        """The decode step (and the verify step under speculation) over
+        ``self.mesh``, with the device state the step carries beside
+        the pools."""
+        common = dict(slots=self.slots, page_size=self.page_size,
+                      pages_per_slot=self.cache_config.pages_per_slot,
+                      dtype=self.dtype, compress=self.kv_compress)
+        self.step = self.spec.build_step(
+            self.mesh, with_lora=self.adapters is not None,
+            lora_alpha=self.lora_alpha, **common)
+        self._step_state = tuple(self.spec.step_state())
+        self.verify_step = None
+        if self.spec_decode:
+            self.verify_step = self.spec.build_step(
+                self.mesh, width=self.spec_k + 1, **common)
 
     # -- one-request helpers ----------------------------------------------
     def _begin_prefill(self, st: Dict[str, Any], slot: int, req: Request,
@@ -347,7 +368,8 @@ class ServingEngine:
                         if self.adapters is not None else None
                     logits, kl, vl = self._prefill(
                         self.params, prompt_dev[None], self.adapters, aid)
-                    kl, vl = kl[:, 0], vl[:, 0]
+                    kl = kl[:, 0]
+                    vl = None if vl is None else vl[:, 0]
             with rec.phase("prefill.write_kv", rid=req.rid):
                 self.cache.write_prefill(slot, kl, vl, start=matched)
             with rec.phase("prefill.sample_fetch", rid=req.rid):
@@ -477,16 +499,29 @@ class ServingEngine:
                     args += [self.adapters,
                              jnp.asarray(np.array(st["adapter_ids"]))]
             t0 = time.monotonic()
-            logits, cache.k, cache.v = self.step(*args)
+            # Beside the pools a step may carry device state of its own
+            # (donated in, handed back) and return a few numbers of the
+            # round after it: those ride on the finite screen's fetch.
+            n_state = len(self._step_state)
+            out = self.step(*args, *self._step_state)
+            logits, cache.k, cache.v = out[:3]
+            self._step_state = out[3:3 + n_state]
             with phase("decode.sample_fetch"):
                 sampled = np.asarray(greedy_sample(logits))  # sync point
             # Per-slot SDC screen: one reduced scalar per row (sum
             # propagates any NaN/Inf in the vocab axis): a second
             # program and a second fetch.
             with phase("decode.finite_fetch"):
-                finite = np.isfinite(np.asarray(jnp.sum(logits, axis=-1)))
+                told = out[3 + n_state:]
+                screen = np.asarray(_screen_and(logits, *told) if told
+                                    else jnp.sum(logits, axis=-1))
+                finite = np.isfinite(screen[:self.slots])
             step_s = time.monotonic() - t0
-            with phase("decode.bookkeep"):
+            # What the step told of its round (``LayerSpec.step_tells``:
+            # a routed model's touched experts) goes on the bookkeep span.
+            told = {name: int(x) for name, x in zip(
+                self.spec.step_tells, screen[self.slots:])}
+            with phase("decode.bookkeep", **told):
                 st["decode_steps"] += 1
                 st["occ_samples"].append(sched.occupancy)
                 t_tok = now()
@@ -607,27 +642,20 @@ class ServingEngine:
         old_tp = int(self.mesh.devices.size)
         self.mesh = mesh
         self._decode_params = self._place_decode_params()
-        self.cache = PagedKVCache(self.cache_config, cache_sharding(mesh))
+        self.spec.require(tp=mesh.devices.size > 1)
+        self.cache = PagedKVCache(self.cache_config,
+                                  self.spec.pool_sharding(mesh))
         self.scheduler.cache = self.cache
         if self._prefix is not None:
             # Cached pages lived in the old pool: start a fresh tree
             # over the new one (suspended requests re-prefill anyway).
             self._prefix = PrefixCache(
                 self.cache, session_ttl_steps=self.session_ttl_steps)
-        self.step = build_decode_step(
-            self.config, mesh, slots=self.slots, page_size=self.page_size,
-            pages_per_slot=self.cache_config.pages_per_slot,
-            dtype=self.dtype, with_lora=self.adapters is not None,
-            lora_alpha=self.lora_alpha, compress=self.kv_compress)
+        self._build_steps()
         # The auditor's serving branch notes resize provenance so the
         # post-shrink gate can assert the exchange contract held.
         self.step._meta["resized_from"] = old_tp
         if self.verify_step is not None:
-            self.verify_step = build_verify_step(
-                self.config, mesh, slots=self.slots,
-                width=self.spec_k + 1, page_size=self.page_size,
-                pages_per_slot=self.cache_config.pages_per_slot,
-                dtype=self.dtype, compress=self.kv_compress)
             self.verify_step._meta["resized_from"] = old_tp
 
     def re_prefill(self, slot: int, req: Request) -> int:
@@ -650,7 +678,8 @@ class ServingEngine:
                 else None
             _, kl, vl = self._prefill(
                 self.params, jnp.asarray(full)[None], self.adapters, aid)
-            self.cache.write_prefill(slot, kl[:, 0], vl[:, 0])
+            self.cache.write_prefill(
+                slot, kl[:, 0], None if vl is None else vl[:, 0])
         if self.drafter is not None:
             self.drafter.re_prefill(slot, req)
         return int(req.tokens[-1])
@@ -737,6 +766,11 @@ class ServingEngine:
                     self.decode_once(st, now)
 
         wall_s = max(time.monotonic() - start, 1e-9)
+        if self._step_state:
+            # What the step accumulated on the device over this call,
+            # fetched once now; the next call starts from zero.
+            self.spec.publish_state(self._step_state)
+            self._step_state = tuple(self.spec.step_state())
         new_tokens = sum(len(r.tokens) for r in completed)
         prompt_tokens = sum(r.prompt_len for r in completed)
         ttfts = [r.ttft_s for r in completed if r.ttft_s is not None]

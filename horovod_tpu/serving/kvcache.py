@@ -4,7 +4,13 @@ Physical layout is a fixed page pool per layer::
 
     k, v: [num_layers, num_pages, page_size, num_kv_heads, head_dim]
 
-sharded over the ``tp`` mesh axis on the kv-head dim (the same split the
+(or, where ``CacheConfig.page`` says what one token holds in each pool,
+``[num_layers, num_pages, page_size, *page[0]]`` and ``*page[1]``; a
+``page[1]`` of None means there is no second pool and ``v`` is None: a
+latent-attention model keeps its normalised latent and its one rotated
+key side by side in ONE row, with no head dim at all -- pages,
+refcounts, the table and every write path are the same), sharded over
+the ``tp`` mesh axis on the kv-head dim (the same split the
 tensor-parallel decode step gives the attention projections, so a rank's
 cache shard pairs exactly with its ``wk``/``wv`` kernel shards and no
 cross-rank traffic ever touches the cache).  The LOGICAL view -- which
@@ -75,22 +81,54 @@ class CacheConfig:
     """Static shape of the pool (identical on every rank and mesh size)."""
 
     num_layers: int
-    num_kv_heads: int
-    head_dim: int
+    # K and V of ``[num_kv_heads, head_dim]`` a token -- or ``page``, not
+    # both: the pools' shapes have ONE source.
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    _: dataclasses.KW_ONLY
     slots: int
     page_size: int
     max_len: int
     dtype: str = "float32"
     compress: bool = False         # fp8 cold-page compression on/off
     hot_pages: int = 1             # full pages behind the head kept f32
+    # Trailing dims of ONE token's entry in the first and second pool
+    # (the second None: one pool only), in place of ``num_kv_heads`` and
+    # ``head_dim`` (which are then filled in where both pools hold the
+    # same two dims, and stay None elsewhere).
+    page: Optional[Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]] = None
 
     def __post_init__(self):
+        heads = (self.num_kv_heads, self.head_dim)
+        if (self.page is None) == (None in heads):
+            raise ValueError(
+                "give num_kv_heads and head_dim, or page, and not both: "
+                f"{heads}, {self.page}")
+        if self.page is None:
+            object.__setattr__(self, "page", (heads, heads))
+        else:
+            page = tuple(None if e is None else tuple(int(n) for n in e)
+                         for e in self.page)
+            object.__setattr__(self, "page", page)
+            if page[0] == page[1] and len(page[0]) == 2:
+                object.__setattr__(self, "num_kv_heads", page[0][0])
+                object.__setattr__(self, "head_dim", page[0][1])
+        if self.compress and self.num_kv_heads is None:
+            raise NotImplementedError(
+                "the fp8 cold pool holds [kv_heads, head_dim] entries "
+                f"only, not {self.page}")
         if self.max_len % self.page_size:
             raise ValueError(
                 f"max_len {self.max_len} not a multiple of page_size "
                 f"{self.page_size}")
         if self.hot_pages < 0:
             raise ValueError(f"hot_pages must be >= 0: {self.hot_pages}")
+
+    @property
+    def entries(self) -> tuple:
+        """What one token holds in the first and in the second pool
+        (None: there is no second pool)."""
+        return self.page
 
     @property
     def pages_per_slot(self) -> int:
@@ -115,7 +153,7 @@ class CacheConfig:
         tests/test_serving.py across 1- and 8-device meshes)."""
         return {
             "kv_shape": [self.num_layers, self.num_pages + 1,
-                         self.page_size, self.num_kv_heads, self.head_dim],
+                         self.page_size, *self.entries[0]],
             "page_table_shape": [self.slots, self.pages_per_slot],
             "page_size": self.page_size,
             "pages_per_slot": self.pages_per_slot,
@@ -133,6 +171,14 @@ def _pool_set(pool, values, pages, offs=None):
     with jax.named_scope("hvd_kv_write_prefill"):
         if offs is None:
             return pool.at[:, pages].set(values)
+        if pool.ndim == 4:
+            # Rows with no head dim: under a leading slice XLA re-lays
+            # the WHOLE pool out to scatter ``[layers, t, w]`` rows (two
+            # pool-sized copies, seen in a deviceless compile); with the
+            # layers indexed like the pages it scatters in place.
+            layers = jnp.arange(pool.shape[0])[:, None]
+            return pool.at[layers, pages[None, :], offs[None, :]].set(
+                values)
         return pool.at[:, pages, offs].set(values)
 
 
@@ -151,13 +197,14 @@ class PagedKVCache:
         self.config = config
         c = config
         # +1: trailing scratch page, the write sink for idle slots.
-        shape = (c.num_layers, c.num_pages + 1, c.page_size,
-                 c.num_kv_heads, c.head_dim)
-        k = jnp.zeros(shape, jnp.dtype(c.dtype))
-        v = jnp.zeros(shape, jnp.dtype(c.dtype))
+        lead = (c.num_layers, c.num_pages + 1, c.page_size)
+        shape = lead + (c.num_kv_heads, c.head_dim)      # the fp8 pools'
+        k = jnp.zeros(lead + c.entries[0], jnp.dtype(c.dtype))
+        v = None if c.entries[1] is None else jnp.zeros(
+            lead + c.entries[1], jnp.dtype(c.dtype))
         if sharding is not None:
             k = jax.device_put(k, sharding)
-            v = jax.device_put(v, sharding)
+            v = None if v is None else jax.device_put(v, sharding)
         self.sharding = sharding
         self.k = k
         self.v = v
@@ -265,10 +312,11 @@ class PagedKVCache:
         per-row f32 scale (the number ``can_admit`` effectively budgets
         against)."""
         c = self.config
-        row = c.num_kv_heads * c.head_dim
-        page_f32 = c.num_layers * c.page_size * row * 2 \
+        row = sum(int(np.prod(e)) for e in c.entries     # both pools
+                  if e is not None)
+        page_f32 = c.num_layers * c.page_size * row \
             * jnp.dtype(c.dtype).itemsize
-        page_fp8 = c.num_layers * c.page_size * (row + 4) * 2
+        page_fp8 = c.num_layers * c.page_size * (row + 8)
         return (self.allocated_pages * page_f32
                 + self.compressed_pages * page_fp8)
 
@@ -395,7 +443,8 @@ class PagedKVCache:
             new = self._free.pop()
             self._refcount[new] = 1
             self.k = _pool_set(self.k, self.k[:, pid], new)
-            self.v = _pool_set(self.v, self.v[:, pid], new)
+            if self.v is not None:
+                self.v = _pool_set(self.v, self.v[:, pid], new)
             self.page_table[slot, i] = new
             self.drop_page_ref(pid)
 
@@ -614,8 +663,8 @@ class PagedKVCache:
                 view = jnp.where(
                     jnp.asarray(cmask)[None, :, None, None, None],
                     deq, view)
-            l, n, ps, hh, dd = view.shape
-            out.append(view.reshape(l, n * ps, hh, dd)[:, None])
+            l, n, ps = view.shape[:3]
+            out.append(view.reshape(l, n * ps, *view.shape[3:])[:, None])
         return tuple(out)
 
     def demote_page(self, pid: int) -> int:
@@ -645,7 +694,9 @@ class PagedKVCache:
         """Scatter a prefilled prompt's K/V into the slot's pages.
 
         ``k_layers``/``v_layers``: ``[num_layers, t, num_kv_heads,
-        head_dim]`` (post-RoPE, as the decode step expects).  Reserves
+        head_dim]`` (post-RoPE, as the decode step expects; ``[num_layers,
+        t, *entry]`` of each pool where ``CacheConfig.page`` is set, and
+        ``v_layers`` None where there is one pool).  Reserves
         pages for ``start + t`` tokens and sets ``lengths[slot] =
         start + t``.  ``start`` is the prefix-cache seam: a matched
         prefix's pages are already attached and immutable, only the
@@ -660,7 +711,8 @@ class PagedKVCache:
         dt = jnp.dtype(c.dtype)
         # One scatter per pool: [L, t, H, D] lands at (page, off) pairs.
         self.k = _pool_set(self.k, k_layers.astype(dt), pages, offs)
-        self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
+        if self.v is not None:
+            self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
         self.lengths[slot] = start + t
 
     def grow(self, slot: int) -> None:
@@ -986,10 +1038,16 @@ def _quantize_pages(pool, pids):
     return q.reshape(l, n, pg, hh, dd), s.reshape(l, n, pg)
 
 
-def cache_sharding(mesh, tp_axis: str = "tp"):
-    """NamedSharding splitting the kv-head dim over ``tp`` (dims:
-    layers, pages, page_size, kv_heads, head_dim)."""
+def cache_sharding(mesh, tp_axis: str = "tp", *, entry_rank: int = 2,
+                   split: Optional[int] = 0):
+    """NamedSharding of a pool ``[layers, pages, page_size, *entry]``:
+    the ``tp`` axis splits dim ``split`` of a token's entry (the kv-head
+    dim of a ``[kv_heads, head_dim]`` entry by default; None: the pool is
+    whole on every chip)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     if mesh is None:
         return None
-    return NamedSharding(mesh, P(None, None, None, tp_axis, None))
+    dims = [None] * (3 + entry_rank)
+    if split is not None:
+        dims[3 + split] = tp_axis
+    return NamedSharding(mesh, P(*dims))

@@ -1,0 +1,124 @@
+"""One description of a served model's layers.
+
+``ServingEngine`` builds its prefill, its decode step and its
+``CacheConfig`` from a :class:`LayerSpec`, never from a model's own
+config fields: what kind of attention a layer has, what ONE token holds
+in a page of each of the cache's two pools, what kind of feed-forward
+each layer has, whether the head is the embedding transposed, which of
+the engine's features the model's programs do not have, and the
+functions that build those programs.
+
+A config describes itself through a ``layer_spec()`` method
+(:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`); a
+``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
+described here, by the functions of ``serving/decode.py``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Mapping, Optional, Tuple
+
+# The engine's optional features, as ``LayerSpec.unsupported`` names them.
+FEATURES = ("tp", "lora", "spec_decode", "kv_compress", "prefill_chunk",
+            "prefix_cache")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    attention: str                    # "gqa" | "mla"
+    # Trailing dims of ONE token's entry in the first and the second pool
+    # (``[layers, pages, page_size, *dims]``), and what each holds; the
+    # second None: the model keeps one pool.
+    page: Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]
+    page_holds: Tuple[str, Optional[str]]
+    ffn: Tuple[str, ...]              # a layer: "dense" | "moe"
+    tied_head: bool
+    max_seq_len: int
+    # Which trailing dim of a page entry the ``tp`` axis splits (None:
+    # the pools are whole on every chip).
+    tp_page_dim: Optional[int]
+    # ``prefill(params, tokens, *, dtype, adapters, adapter_id,
+    # lora_alpha, past) -> (logits, first_layers, second_layers)``, each
+    # ``[layers, batch, t, *page entry]`` (None for a pool not kept).
+    prefill: Callable[..., Any]
+    # ``build_step(mesh, *, slots, page_size, pages_per_slot, dtype,
+    # width, with_lora, lora_alpha, compress) -> ServingDecodeStep``.
+    build_step: Callable[..., Any]
+    param_specs: Callable[[Any], Any]
+    # Feature (one of FEATURES) -> why this model's programs lack it.
+    unsupported: Mapping[str, str] = dataclasses.field(default_factory=dict)
+    # Device state the decode step carries from round to round beside
+    # the pools (donated in, handed back), and where it goes when
+    # ``serve`` returns.
+    step_state: Callable[[], tuple] = lambda: ()
+    publish_state: Callable[[tuple], None] = lambda state: None
+    # Names of the whole numbers the step returns last, in one int32
+    # vector, about the round it ran: attributes of ``decode.bookkeep``.
+    step_tells: Tuple[str, ...] = ()
+
+    def __post_init__(self):
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"attention kind {self.attention!r}")
+        if set(self.ffn) - {"dense", "moe"}:
+            raise ValueError(f"feed-forward kinds {sorted(set(self.ffn))}")
+        if [e is None for e in self.page] != [
+                h is None for h in self.page_holds] or self.page[0] is None:
+            raise ValueError(
+                f"pools {self.page} and what they hold {self.page_holds}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.ffn)
+
+    def require(self, **wanted: bool) -> None:
+        """Raise ``NotImplementedError``, by name, for each feature that
+        is wanted and that this model's programs do not have."""
+        for name, on in wanted.items():
+            if name not in FEATURES:
+                raise KeyError(name)
+            if on and name in self.unsupported:
+                raise NotImplementedError(
+                    f"{name}: {self.unsupported[name]}")
+
+    def pool_sharding(self, mesh, tp_axis: str = "tp"):
+        """The pools' sharding: the ``tp`` split on ``tp_page_dim``."""
+        from .kvcache import cache_sharding
+        return cache_sharding(mesh, tp_axis, entry_rank=len(self.page[0]),
+                              split=self.tp_page_dim)
+
+
+def layer_spec(config) -> LayerSpec:
+    """The description of ``config``'s layers: its own, or a
+    ``LlamaConfig``'s."""
+    describe = getattr(config, "layer_spec", None)
+    if describe is not None:
+        return describe()
+    from ..models.transformer import LlamaConfig
+    if isinstance(config, LlamaConfig):
+        return _llama_spec(config)
+    raise TypeError(
+        f"{type(config).__name__} has no layer_spec() and is not a "
+        "LlamaConfig: ServingEngine cannot build its programs from it")
+
+
+def _llama_spec(config) -> LayerSpec:
+    from . import decode
+
+    def prefill(params, tokens, **kw):
+        return decode.prefill_forward(params, config, tokens, **kw)
+
+    def build_step(mesh, *, width: int = 1, **kw):
+        if width > 1:
+            kw.pop("with_lora", None)
+            kw.pop("lora_alpha", None)
+            return decode.build_verify_step(config, mesh, width=width, **kw)
+        return decode.build_decode_step(config, mesh, **kw)
+
+    entry = (config.num_kv_heads, config.head_dim)
+    return LayerSpec(
+        attention="gqa", page=(entry, entry),
+        page_holds=("rotated keys", "values"),
+        ffn=("dense",) * config.num_layers, tied_head=True,
+        max_seq_len=config.max_seq_len, tp_page_dim=0, prefill=prefill,
+        build_step=build_step, param_specs=decode.decode_param_specs)

@@ -73,7 +73,7 @@ def kernels(topology: str) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops import attention, pallas
+    from horovod_tpu.ops import attention, moe, pallas
 
     # This process's default backend is the CPU (no chip attached), so
     # the package would pick the XLA reference and, forced on, the
@@ -97,7 +97,44 @@ def kernels(topology: str) -> int:
     def decode(q, k, v, lengths):
         return attention.decode_attention(q, k, v, lengths=lengths)
 
+    def mla_decode(q, pool, page_table, lengths):
+        return attention.mla_decode_attention(
+            q, pool, page_table, layer=3, lengths=lengths, value_dim=512,
+            scale=192 ** -0.5)
+
+    def mla_prefill(q, k, v):
+        return attention.flash_attention(q, k, v, causal=True)
+
+    def gmm(tm):
+        def run(x, w_gate, w_up, w_down, tile_expert, active):
+            act = moe.grouped_matmul(x, (w_gate, w_up), tile_expert,
+                                     active, tm=tm)
+            return moe.grouped_matmul(act, (w_down,), tile_expert, active,
+                                      tm=tm)
+        return run
+
+    def gmm_args(rows, tm):
+        bf = jnp.bfloat16
+        return [spec((rows, 2048), bf), spec((256, 2048, 768), bf),
+                spec((256, 2048, 768), bf), spec((256, 768, 2048), bf),
+                spec((rows // tm,), jnp.int32), spec((1,), jnp.int32)]
+
     cases = {
+        # The latent-attention decode of 64 slots out of the page pool
+        # (5 layers of 34,817 pages of 16 rows): 32 heads, a cached row
+        # of latent 512 + rotated key 64 in 640 columns (bfloat16), 544
+        # pages a slot.
+        "mla_decode_b64": (mla_decode, [
+            spec((64, 32, 640), jnp.bfloat16),
+            spec((5, 34817, 16, 640), jnp.bfloat16),
+            spec((64, 544), jnp.int32), spec((64,), jnp.int32)]),
+        # Its expanded prefill: keys 192 wide, values padded to 192.
+        "flash_mla_prefill_8k": (
+            mla_prefill, [spec((1, 32, 8192, 192), jnp.bfloat16)] * 3),
+        # 256 experts of 2048 x 768: a decode round's 64 x 8 pairs in
+        # tiles of 16 rows, an 8,192-token prefill's in tiles of 128.
+        "moe_gmm_decode": (gmm(16), gmm_args(4352, 16)),
+        "moe_gmm_prefill_8k": (gmm(128), gmm_args(98048, 128)),
         # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.
         "flash_bert_large": (
             flash_fwd_bwd, [spec((32, 16, 128, 64), jnp.bfloat16)] * 3),

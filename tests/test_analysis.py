@@ -380,7 +380,8 @@ def test_expected_exchange_kernel_aware(hvd, monkeypatch):
     report = audit_step(step, *args, donate_argnums=donate, name=name)
     assert report.ok(), report.render()
     assert report.expected.kernels == ("bn_bwd", "flash", "flash_decode",
-                                       "fused_update")
+                                       "fused_update", "mla_decode",
+                                       "moe_gmm")
     assert not report.expected.notes
     assert report.summary["unaccounted_ops"] == 0
 

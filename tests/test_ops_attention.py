@@ -483,14 +483,17 @@ def test_pallas_switch_resolution(monkeypatch):
     # refuted BN kernel and the refused PowerSGD kernels stay on XLA.
     with monkeypatch.context() as m:
         m.setattr(_pallas.jax, "default_backend", lambda: "tpu")
-        assert _pallas.active_kernels() == ("flash", "flash_decode")
+        assert _pallas.active_kernels() == ("flash", "flash_decode",
+                                            "mla_decode", "moe_gmm")
     # global switch gates every family...
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     assert _pallas.active_kernels() == _pallas.registered_kernels()
     # ...and the per-family override wins over it.
     monkeypatch.setenv("HOROVOD_PALLAS_DECODE", "0")
     assert not _pallas.pallas_enabled("flash_decode")
+    assert not _pallas.pallas_enabled("mla_decode")   # the same switch
     assert _pallas.pallas_enabled("flash")
+    assert _pallas.pallas_enabled("moe_gmm")          # the global one only
     with pytest.raises(ValueError, match="unknown pallas kernel family"):
         _pallas.pallas_enabled("nope")
 
