@@ -231,8 +231,10 @@ def _build_case(model: str, n: int, per_chip_batch: int = 0):
                  jax.ShapeDtypeStruct(y.shape, y.dtype, sharding=bat)))
         stats_leaves = len(jax.tree.leaves(stats))
         grad_leaves = jax.tree.leaves(params)
-        # Emitted all-reduces: one per gradient fusion bucket, one per
-        # mutated BN-stat leaf, one for the loss mean.  The -chunked
+        # Emitted all-reduces: one per gradient leaf (the elementwise
+        # exchange builds no fusion bucket; XLA's combiner groups the
+        # psums when it compiles), one per mutated
+        # BN-stat leaf, one for the loss mean.  The -chunked
         # variant (HOROVOD_EXCHANGE_CHUNK_MB, set by run_worker) replaces
         # every bucket all-reduce with reduce-scatter+all-gather chunks,
         # so only the BN-stat and loss all-reduces remain -- and each
@@ -256,7 +258,7 @@ def _build_case(model: str, n: int, per_chip_batch: int = 0):
             # BN-stat and loss all-reduces remain.
             expected_emitted = stats_leaves + 1
         else:
-            expected_emitted = buckets + stats_leaves + 1
+            expected_emitted = len(grad_leaves) + stats_leaves + 1
         grad_bytes = sum(l.size * l.dtype.itemsize for l in grad_leaves)
         if fp8:
             grad_bytes //= 4  # e4m3 wire (+ one f32 scale per bucket)
@@ -506,7 +508,7 @@ def _build_case(model: str, n: int, per_chip_batch: int = 0):
                 abstract(frozen, rep))
         grad_leaves = jax.tree.leaves(trainable)
         buckets = len(plan_buckets(grad_leaves).buffers)
-        expected_emitted = buckets + 1  # adapter buckets + loss mean
+        expected_emitted = len(grad_leaves) + 1  # adapter leaves + loss
         # f32 adapters on the wire; the frozen tree must contribute 0.
         payload = sum(l.size * l.dtype.itemsize for l in grad_leaves) + 4
     else:

@@ -11,8 +11,10 @@ itself diverges from its plan.
 
 Width references:
 
-- cast codecs: one ``psum`` per bucket, full bucket elements at the wire
-  dtype (f32 buckets cast down, narrow/int buckets ride as-is);
+- cast codecs: one ``psum`` per LEAF at the wire dtype (f32 leaves cast
+  down, narrow/int leaves ride as-is): the elementwise exchange builds no
+  bucket (``fusion.exchange_needs_vector``); where the flat exchange
+  packs (per-leg codec on a flat mesh), one ``psum`` of the bucket;
 - powersgd(r): two f32 ``psum`` legs per floating bucket of
   ``powersgd_factor_widths(size, r)`` elements -- the P/Q factor widths
   ``joinop._replay`` replays bitwise;
@@ -183,7 +185,8 @@ def expected_exchange(params, meta: dict) -> ExpectedExchange:
 
 
 def _expected_exchange(params, meta: dict) -> ExpectedExchange:
-    from ..controller.fusion import exchange_chunk_bytes, explain_plan
+    from ..controller.fusion import (exchange_chunk_bytes,
+                                     exchange_needs_vector, explain_plan)
     from ..core.state import global_state
     from ..optim import distributed as _dist
     from ..optim import zero as _zero
@@ -270,6 +273,11 @@ def _expected_exchange(params, meta: dict) -> ExpectedExchange:
                                 plan_rows=rows,
                                 notes=(f"chunked exchange ({chunk}B chunks "
                                        "of the wire buffer)",))
+    if not exchange_needs_vector(comp, op):
+        return ExpectedExchange(
+            ops=_flat_leaf_ops(leaves, comp), plan_rows=rows, notes=(
+                "elementwise exchange: one psum a leaf, no fusion buffer "
+                "(XLA's combiner groups them; the rows are accounting)",))
     return ExpectedExchange(ops=_flat_bucket_ops(rows, comp),
                             plan_rows=rows)
 
@@ -285,6 +293,21 @@ def _flat_bucket_ops(rows: List[dict], comp) -> List[ExpectedOp]:
             compression=comp)
         ops += _plan_ops(plan.legs,
                          tag=f"bucket{r['bucket']}({r['dtype']})")
+    return ops
+
+
+def _flat_leaf_ops(leaves, comp) -> List[ExpectedOp]:
+    """The elementwise exchange: one flat psum per leaf at the codec's
+    wire dtype, from the same ``plan_exchange("flat", ...)`` row the step
+    notes for the leaf."""
+    from ..controller import fusion as _fusion
+    ops = []
+    for i, leaf in enumerate(leaves):
+        plan = _fusion.plan_exchange(
+            "flat", size=int(leaf.size), dtype=str(jnp.dtype(leaf.dtype)),
+            compression=comp)
+        ops += _plan_ops(plan.legs,
+                         tag=f"leaf{i}({jnp.dtype(leaf.dtype)})")
     return ops
 
 
@@ -589,7 +612,8 @@ def _expected_3d(params, meta: dict) -> ExpectedExchange:
     """
     from ..collectives.compression import is_hier_legs
     from ..collectives.reduce_op import Average, Sum
-    from ..controller.fusion import (exchange_chunk_bytes, explain_plan,
+    from ..controller.fusion import (exchange_chunk_bytes,
+                                     exchange_needs_vector, explain_plan,
                                      hier_requested)
 
     tp = int(meta.get("tp", 1) or 1)
@@ -657,9 +681,13 @@ def _expected_3d(params, meta: dict) -> ExpectedExchange:
                 return _unsupported((
                     "per-leg codec without the (dcn, data) pair: the "
                     "runtime raises",))
-            else:
+            elif exchange_needs_vector(comp, op):
                 base = ExpectedExchange(ops=_flat_bucket_ops(rows, comp),
                                         plan_rows=rows)
+            else:
+                base = ExpectedExchange(
+                    ops=_flat_leaf_ops(jax.tree.leaves(local), comp),
+                    plan_rows=rows)
     if not base.supported:
         return base
 

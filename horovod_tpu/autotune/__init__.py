@@ -8,7 +8,11 @@ architecture here:
 
 * the tunable surface is the gradient bucket size (``fusion_threshold``)
   and -- when the native cycle scheduler is active (torch shim) -- the
-  cycle time;
+  cycle time.  The bucket size shapes a step only where buckets are
+  built (``fusion.exchange_needs_vector``, ZeRO-1, the microbatched
+  step); on the leaf-wise exchange it is inert, and a sample of such a
+  step scores every candidate that differs in the threshold alone
+  (``record_step(threshold_inert=True)``);
 * scoring is observed bytes/sec over ``steps_per_sample`` steps;
 * the search is expected-improvement Bayesian optimization over a
   discrete grid (:mod:`horovod_tpu.autotune.gp`), seeded with a strided
@@ -389,8 +393,15 @@ class Autotuner:
         return self._best is not None
 
     # -- sampling loop ----------------------------------------------------
-    def record_step(self, seconds: float, nbytes: int) -> None:
-        """Report one training step's wall time and gradient bytes."""
+    def record_step(self, seconds: float, nbytes: int,
+                    threshold_inert: bool = False) -> None:
+        """Report one training step's wall time and gradient bytes.
+
+        ``threshold_inert``: the sampled step builds no fusion bucket (the
+        leaf-wise exchange of ``allreduce_gradients``: XLA's combiner
+        groups its all-reduces), so the candidates that differ from this
+        sample in the fusion threshold alone are the same compiled step.
+        They take this sample's score and are never run."""
         if self._best is not None:
             return
         if self._skip_next:
@@ -404,6 +415,11 @@ class Autotuner:
         score = self._accum_bytes / max(self._accum_s, 1e-9)  # bytes/s
         self._opt.observe(self._idx, score)
         self._samples.append(self.grid[self._idx] + (score,))
+        if threshold_inert:
+            here = self.grid[self._idx]
+            for j, point in enumerate(self.grid):
+                if point[1:] == here[1:] and not self._opt.observed(j):
+                    self._opt.observe(j, score)
         from ..timeline import metrics as _metrics
         reg = _metrics.registry()
         reg.counter("horovod_autotune_samples_total",

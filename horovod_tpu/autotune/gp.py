@@ -95,6 +95,10 @@ class BayesianOptimizer:
         self._X.append(index)
         self._y.append(float(score))
 
+    def observed(self, index: int) -> bool:
+        """Whether grid point ``index`` already has a score."""
+        return index in self._X
+
     def suggest(self) -> Optional[int]:
         """Next grid index to try; None when the grid is exhausted."""
         remaining = [i for i in range(len(self.grid)) if i not in self._X]

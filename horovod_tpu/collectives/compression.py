@@ -116,6 +116,15 @@ class BF16Compressor(_CastCompressor):
     wire_dtype = jnp.bfloat16
 
 
+def is_elementwise(compression) -> bool:
+    """True for the codecs that act on every element alone (none and the
+    casts): compressing a bucket's leaves one by one gives the same bits
+    as compressing the bucket, so the exchange needs no flat buffer."""
+    return compression is None or compression is NoneCompressor or (
+        isinstance(compression, type)
+        and issubclass(compression, _CastCompressor))
+
+
 class FP8Compressor(Compressor):
     """e4m3 wire with per-bucket scales -- an EXCHANGE-level codec.
 

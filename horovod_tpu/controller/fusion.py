@@ -2,16 +2,28 @@
 
 The reference's ``fusion_buffer_manager.cc`` keeps a persistent 64 MiB
 device buffer; the background thread memcpys ready gradients in (batched
-D2D CUDA kernels), runs ONE collective, and memcpys out.  Under XLA the
+D2D CUDA kernels), runs ONE collective, and memcpys out: the buffer is its
+answer to the launch latency of one NCCL call a tensor.  Under XLA the
 same idea is expressed functionally at trace time: leaves are raveled and
 concatenated into flat per-dtype buffers no larger than the fusion
-threshold, one ``psum`` is emitted per buffer, and the results are sliced
-back out.  XLA fuses the pack/unpack with neighbouring elementwise work, so
-no copy kernels are written by hand, and donation keeps the buffers from
-doubling HBM footprint.
+threshold (:func:`plan_buckets`, :func:`pack`), one collective is emitted
+per buffer, and the results are sliced back out (:func:`unpack`).
+
+Only an exchange that needs a bucket as ONE contiguous vector pays for
+that (:func:`exchange_needs_vector`: error-feedback residuals, a scale a
+bucket, a reduce-scatter that splits the vector, Adasum's dot products).
+On a TPU the ravel of a tiled matrix is a relayout copy, so the buffer
+moves every gradient byte through HBM several times.  The elementwise
+exchange (``Sum``/``Average`` under none/fp16/bf16) builds none:
+``allreduce_gradients`` emits one psum A LEAF and XLA's all-reduce
+combiner groups them into many-operand all-reduces, each operand in its
+own tiled layout.  There the combiner answers the launch latency, at its
+own threshold, and the plan below is not part of the compiled step.
 
 ``HOROVOD_FUSION_THRESHOLD`` (default 64 MiB) controls bucket size, exactly
-as in the reference (SURVEY.md section 5.6).
+as in the reference (SURVEY.md section 5.6), wherever buckets are built:
+the packed exchanges, ZeRO-1's arenas, the microbatched step and the eager
+grouped collectives.  It does not reach the leaf-wise exchange.
 """
 
 from __future__ import annotations
@@ -266,12 +278,33 @@ def unfuse_flat(buffers: Sequence[jax.Array], spec: FusionSpec
     return unpack(buffers, spec)
 
 
+def exchange_needs_vector(compression, op, *, two_level: bool = False,
+                          chunked: bool = False) -> bool:
+    """Whether a gradient exchange needs each fusion bucket as ONE
+    contiguous vector (so it packs), or is elementwise and reduces leaf by
+    leaf with no buffer.  Read from what the exchange is, never from a
+    knob: only ``Sum``/``Average`` under a codec that acts on every
+    element alone (none and the casts) on the flat exchange is
+    elementwise.  The error-feedback codecs keep a residual a bucket, fp8
+    a scale a bucket, the two-level (``two_level``) and the chunked
+    (``chunked``) exchanges split the vector, Adasum takes dot products
+    over it.  ``allreduce_gradients`` routes on this; the auditor's
+    contract, ``explain_plan``'s ``packed`` column and the step report's
+    ``packed_bytes`` ask here."""
+    from ..collectives.compression import is_elementwise
+    from ..collectives.reduce_op import Average, Sum
+    return (op not in (Sum, Average) or not is_elementwise(compression)
+            or two_level or chunked)
+
+
 def fused_tree_collective(tree, collective_fn,
                           threshold_bytes: Optional[int] = None,
                           extra: Tuple = ()):
     """Apply ``collective_fn(flat_buffer) -> flat_buffer`` to a whole pytree
-    through the fusion buffers.  This is the gradient hot path used by
-    :class:`horovod_tpu.optim.DistributedOptimizer`.  ``extra`` is caller
+    through the fusion buffers.  This is the gradient path of every
+    exchange that needs a bucket as one vector
+    (:func:`exchange_needs_vector`; the others reduce leaf by leaf and
+    never come here).  ``extra`` is caller
     context for the plan memo key (see :func:`plan_buckets`).
     """
     leaves, treedef = jax.tree.flatten(tree)
@@ -425,12 +458,19 @@ def explain_plan(params, threshold_bytes: Optional[int] = None,
     One dict per bucket: ``bucket`` index, ``dtype``, ``leaves`` count,
     ``elements``, raw ``bytes``, ``wire_bytes`` under ``compression``
     (a spec string or codec class; None = uncompressed), the ``codec``
-    name, the eager ``fence`` policy, and the ``fuse_key`` the plan
-    memoizes under.  The rows come from the SAME :func:`plan_buckets`
-    call the exchange makes -- error-feedback codecs fold the
-    ``("ef", codec)`` context exactly like ``ef_bucket_plan`` -- so
-    bucket count and per-bucket bytes match the emitted exchange by
-    construction (asserted in tests/test_metrics.py).
+    name, ``packed``, the eager ``fence`` policy, and the ``fuse_key``
+    the plan memoizes under.  Like ``wire_bytes``, ``packed`` prices the
+    ``Sum``/``Average`` exchange over the whole mesh
+    (:func:`exchange_needs_vector`, with the two-level and chunked
+    settings in force): ``True`` rows are buffers the step builds, one
+    collective each, from the SAME :func:`plan_buckets` call the exchange
+    makes -- error-feedback codecs fold the ``("ef", codec)`` context
+    exactly like ``ef_bucket_plan`` -- so bucket count and per-bucket
+    bytes match the emitted exchange by construction (asserted in
+    tests/test_metrics.py).  ``False`` rows are accounting only: the
+    leaf-wise exchange emits one psum a leaf, XLA's combiner draws the
+    all-reduces, and the threshold changes the rows but not the step
+    (the bytes add up the same).
 
     ``register=True`` also publishes the rows as ``horovod_plan_*``
     gauges so ``/metrics`` exposes the current plan.  Printable via
@@ -461,6 +501,12 @@ def explain_plan(params, threshold_bytes: Optional[int] = None,
     codec = comp.__name__ if comp is not None else "none"
     fence = _fence_policy()
     hier_shape = hier_mesh_shape() if hier_requested(comp) else None
+    from ..collectives.reduce_op import Average
+    # The reverse (bucket-ready) order is the microbatched step's plan:
+    # its reduce-scatters split a vector, so it packs whatever the codec.
+    packed = reverse or exchange_needs_vector(
+        comp, Average, two_level=hier_shape is not None,
+        chunked=exchange_chunk_bytes() > 0)
     rows = []
     for i, (dt, lspecs) in enumerate(spec.buffers):
         dtype = str(jnp.dtype(dt))
@@ -483,7 +529,8 @@ def explain_plan(params, threshold_bytes: Optional[int] = None,
         rows.append({
             "bucket": i, "dtype": dtype, "leaves": len(lspecs),
             "elements": int(size), "bytes": int(raw),
-            "wire_bytes": int(wire), "codec": codec, "fence": fence,
+            "wire_bytes": int(wire), "codec": codec, "packed": packed,
+            "fence": fence,
             "fuse_key": "|".join(
                 [dtype, f"thr={int(threshold_bytes)}", codec]
                 + (["rev"] if reverse else [])),
@@ -504,7 +551,7 @@ def explain_plan(params, threshold_bytes: Optional[int] = None,
             "bucket": len(rows), "dtype": pair[0].wire_dtype,
             "leaves": 0, "elements": int(elements), "bytes": int(raw),
             "wire_bytes": int(sum(l.nbytes for l in moe_legs)),
-            "codec": pair[0].codec, "fence": fence,
+            "codec": pair[0].codec, "packed": False, "fence": fence,
             "fuse_key": "|".join(
                 ["moe", f"E={int(moe['n_experts'])}",
                  f"C={int(moe['capacity'])}", f"d={int(moe['d_model'])}",
@@ -542,7 +589,7 @@ def render_plan(rows: List[dict]) -> str:
     if not rows:
         return "(empty plan: no leaves)"
     cols = ("bucket", "dtype", "leaves", "elements", "bytes",
-            "wire_bytes", "codec", "fence", "fuse_key")
+            "wire_bytes", "codec", "packed", "fence", "fuse_key")
     table = [cols] + [tuple(str(r[c]) for c in cols) for r in rows]
     widths = [max(len(row[i]) for row in table) for i in range(len(cols))]
     lines = ["  ".join(cell.ljust(w) for cell, w in zip(row, widths)).rstrip()

@@ -102,31 +102,22 @@ def _stateless_ef_collective(buf, compression, op, axes,
     return out
 
 
-def allreduce_gradients(grads,
-                        op: ReduceOp = Average,
-                        *,
-                        compression=Compression.none,
-                        fusion_threshold: Optional[int] = None,
-                        axes=None,
-                        process_set=None,
-                        prescale_factor: float = 1.0,
-                        postscale_factor: float = 1.0):
-    """Fused in-step allreduce of a gradient pytree (the hot path).
+class _Route(NamedTuple):
+    """What :func:`allreduce_gradients` dispatches on, resolved once."""
+    compression: Any      # the codec in force (the tuner may override)
+    explicit_hier: bool   # the two-level exchange is asked for
+    chunk_bytes: int      # > 0: the chunked RS+AG decomposition
+    axes: tuple           # the reduce axes' names
+    packed: bool          # fusion.exchange_needs_vector: buckets are built
 
-    Two further knobs resolve at TRACE time (the reference's
-    ParameterManager tunes both; ours does too under
-    ``HOROVOD_AUTOTUNE=1``): the hierarchical-allreduce algorithm choice
-    on (dcn, ici) meshes (``HOROVOD_HIERARCHICAL_ALLREDUCE`` /
-    autotuned) and -- opt-in, it changes wire numerics
-    (``HOROVOD_AUTOTUNE_COMPRESSION=1``) -- the compression codec.
-    """
+
+def _exchange_route(compression, op, axes, process_set) -> _Route:
     from ..collectives.compression import is_fp8
     from ..collectives.reduce_op import Adasum as _Adasum
-    from ..controller.fusion import exchange_chunk_bytes
+    from ..controller.fusion import (exchange_chunk_bytes,
+                                     exchange_needs_vector)
     from ..core.state import global_state
-    compression = parse_compression(compression)
     st = global_state()
-    chunk_bytes = exchange_chunk_bytes()
     tuner = st.autotuner
     if tuner is not None:
         override = tuner.compression_override(compression)
@@ -155,17 +146,64 @@ def allreduce_gradients(grads,
                 explicit_hier = parse_topology_spec(st.config.hierarchical)[0]
             except ValueError:
                 pass
+    if axes is not None:
+        ax = tuple((axes,) if isinstance(axes, str) else axes)
+    else:
+        ax = tuple(st.mesh.axis_names) if st.mesh is not None else ()
+    chunk_bytes = exchange_chunk_bytes()
+    whole = process_set is None     # a subset rides the flat exchange
+    packed = exchange_needs_vector(
+        compression, op, two_level=whole and explicit_hier and len(ax) == 2,
+        chunked=whole and chunk_bytes > 0)
+    return _Route(compression, explicit_hier, chunk_bytes, ax, packed)
 
-    def resolved_axes():
-        if axes is not None:
-            return tuple((axes,) if isinstance(axes, str) else axes)
-        return tuple(st.mesh.axis_names) if st.mesh is not None else ()
+
+def exchange_packs(compression, op: ReduceOp = Average, *, axes=None,
+                   process_set=None) -> bool:
+    """Whether :func:`allreduce_gradients` would build fusion buffers for
+    this exchange at a world above one, under the tuner's and the
+    configuration's settings in force: its own route
+    (``fusion.exchange_needs_vector`` is the rule)."""
+    return _exchange_route(parse_compression(compression), op, axes,
+                           process_set).packed
+
+
+def allreduce_gradients(grads,
+                        op: ReduceOp = Average,
+                        *,
+                        compression=Compression.none,
+                        fusion_threshold: Optional[int] = None,
+                        axes=None,
+                        process_set=None,
+                        prescale_factor: float = 1.0,
+                        postscale_factor: float = 1.0):
+    """Fused in-step allreduce of a gradient pytree (the hot path).
+
+    Two further knobs resolve at TRACE time (the reference's
+    ParameterManager tunes both; ours does too under
+    ``HOROVOD_AUTOTUNE=1``): the hierarchical-allreduce algorithm choice
+    on (dcn, ici) meshes (``HOROVOD_HIERARCHICAL_ALLREDUCE`` /
+    autotuned) and -- opt-in, it changes wire numerics
+    (``HOROVOD_AUTOTUNE_COMPRESSION=1``) -- the compression codec.
+
+    ``Sum``/``Average`` under ``none``/``fp16``/``bf16`` on the flat
+    exchange is elementwise, so it builds no fusion buffer: every leaf is
+    cast, reduced by a psum of its own and cast back in the layout it has,
+    and XLA's all-reduce combiner groups the psums into many-operand
+    all-reduces at its own threshold (``fusion_threshold`` does not reach
+    this path).  Every exchange that needs a bucket as one contiguous
+    vector (``fusion.exchange_needs_vector``) goes through the planner's
+    buckets as before.
+    """
+    from ..collectives.compression import is_fp8
+    route = _exchange_route(parse_compression(compression), op, axes,
+                            process_set)
+    compression, explicit_hier, chunk_bytes, ax, packed = route
 
     def collective(buf):
         # The stages carry hvd_exchange/ scopes into the HLO text and the
         # trace viewer; an exchange-level codec (EF, fp8, per-leg) is all
         # "collective".
-        ax = resolved_axes()
         with jax.named_scope("hvd_exchange/collective"):
             if is_error_feedback(compression):
                 # Exchange-level EF codec WITHOUT residual state: the stateful
@@ -244,9 +282,10 @@ def allreduce_gradients(grads,
             return compression.decompress(r, ctx)
 
     def _note_flat_leg(buf, comp):
-        # Flat fused-bucket exchange: register the plan-IR row at trace
-        # time (the hier/chunked/fp8/EF paths note inside their ops; a
-        # world-1 "reduction" is the identity and moves no bytes).
+        # Flat exchange of one buffer (a packed bucket, or a leaf on the
+        # leaf-wise path): register the plan-IR row at trace time (the
+        # hier/chunked/fp8/EF paths note inside their ops; a world-1
+        # "reduction" is the identity and moves no bytes).
         if world == 1:
             return
         from ..controller import fusion as _fusion
@@ -261,12 +300,14 @@ def allreduce_gradients(grads,
     # deletes the size-1 psum and fuses the scale/compression casts into
     # the surrounding update.  The reference pays its fusion-buffer memcpys
     # even at np=1; knowing the world size at trace time is exactly what
-    # lets the TPU build not to.
+    # lets the TPU build not to.  An elementwise exchange goes the same
+    # way at any world: the casts fuse into the backward and the update,
+    # and the combiner makes the many-operand all-reduces.
     try:
         world = _ops.axis_size(axes)
     except Exception:  # outside a traced mesh context: keep the fused path
         world = None
-    if world == 1:
+    if world == 1 or not packed:
         return jax.tree.map(collective, grads)
 
     # The codec name rides the plan memo key: an EF-codec plan pins the

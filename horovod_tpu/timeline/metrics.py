@@ -461,7 +461,12 @@ class StepReport:
     compressed exchange they match ``bench.py``'s
     ``wire_payload_bytes``-over-``ef_bucket_plan`` accounting.  The
     microbatch overlap factor is intentionally NOT folded in: the figure
-    is the equivalent single-exchange payload."""
+    is the equivalent single-exchange payload.  ``packed_bytes`` are the
+    gradient bytes a step copies into flat fusion buffers before its
+    collectives (and slices back out after them): 0 on the leaf-wise
+    exchange, the raw gradient bytes where the exchange needs contiguous
+    vectors.  Derived from the exchange's route
+    (``training._step_builds_buckets``), not observed in the program."""
 
     step: int
     wall_time_s: float
@@ -471,6 +476,7 @@ class StepReport:
     codec: str = "none"
     exchanged_bytes: int = 0
     uncompressed_bytes: int = 0
+    packed_bytes: int = 0
 
 
 def last_step_report() -> Optional[StepReport]:
@@ -500,6 +506,10 @@ def record_step_report(report: StepReport) -> None:
     reg.gauge("horovod_uncompressed_bytes_per_step",
               "Equivalent uncompressed exchange bytes per optimizer step"
               ).set(report.uncompressed_bytes)
+    reg.gauge("horovod_packed_bytes_per_step",
+              "Gradient bytes copied into flat fusion buffers per "
+              "optimizer step (0: the leaf-wise exchange builds none)"
+              ).set(report.packed_bytes)
     if report.exchanged_bytes > 0 and report.uncompressed_bytes > 0:
         reg.gauge("horovod_compression_ratio",
                   "uncompressed / wire bytes of the gradient exchange"

@@ -1089,9 +1089,10 @@ def _maybe_tuned(shard, donate_argnums, loss_index: int, steps: int = 1,
     ParameterManager score loop.
 
     The fusion threshold is read at trace time, so each candidate needs
-    its own trace -- one compiled step per trace key, observed step time
-    fed back to the tuner (the reference's score loop, minus the
-    background thread).  The timing fence is a value fetch of the loss;
+    its own trace (where the step builds buckets at all:
+    :func:`_step_builds_buckets`) -- one compiled step per trace key,
+    observed step time fed back to the tuner (the reference's score loop,
+    minus the background thread).  The timing fence is a value fetch of the loss;
     it adds a constant per-step latency that cancels in the per-config
     ranking.
 
@@ -1116,6 +1117,11 @@ def _maybe_tuned(shard, donate_argnums, loss_index: int, steps: int = 1,
 
         def tuned_step(params, *rest):
             key = tuner.trace_key()  # every trace-time knob of this sample
+            # A step that builds no fusion bucket is the same program at
+            # every threshold: one compile, one sample for all of them.
+            inert = meta is not None and not _step_builds_buckets(meta)
+            if inert:
+                key = key[1:]
             fn = compiled.get(key)
             if fn is None:
                 fn = jax.jit(shard, donate_argnums=donate_argnums)
@@ -1130,7 +1136,7 @@ def _maybe_tuned(shard, donate_argnums, loss_index: int, steps: int = 1,
             out = fn(params, *rest)
             float(jnp.asarray(out[loss_index]).ravel()[0])  # honest fence
             tuner.record_step(_time.perf_counter() - t0,
-                              grad_nbytes[0] * steps)
+                              grad_nbytes[0] * steps, threshold_inert=inert)
             return out
 
         fn = tuned_step
@@ -1163,7 +1169,7 @@ class _InstrumentedStep:
         self._fn = fn
         self._steps = max(int(steps), 1)
         self._meta = meta
-        self._accounting: Optional[Tuple[str, int, int]] = None
+        self._accounting: Optional[Tuple[str, int, int, int]] = None
         self._step_count = 0
         # perf_counter at the previous call's return: the time until the
         # next call is the host dispatch gap (input pipeline, Python
@@ -1173,13 +1179,13 @@ class _InstrumentedStep:
     def __getattr__(self, name):
         return getattr(self._fn, name)
 
-    def _account(self, params) -> Tuple[str, int, int]:
+    def _account(self, params) -> Tuple[str, int, int, int]:
         if self._accounting is None:
             try:
                 self._accounting = _step_exchange_accounting(
                     params, self._meta)
             except Exception:
-                self._accounting = ("unknown", 0, 0)
+                self._accounting = ("unknown", 0, 0, 0)
         return self._accounting
 
     def __call__(self, params, *rest):
@@ -1189,7 +1195,7 @@ class _InstrumentedStep:
         reg = _metrics.registry()
         if not reg.enabled:
             return self._fn(params, *rest)
-        codec, wire, raw = self._account(params)
+        codec, wire, raw, packed = self._account(params)
         rec = _spans.recorder()
         step = self._step_count + self._steps
         rec.set_step(step)
@@ -1218,7 +1224,8 @@ class _InstrumentedStep:
                 zero_stage=int(self._meta.get("zero_stage", 0)),
                 codec=codec,
                 exchanged_bytes=wire,
-                uncompressed_bytes=raw))
+                uncompressed_bytes=raw,
+                packed_bytes=packed))
         except Exception:
             pass
         try:
@@ -1262,10 +1269,34 @@ class _GuardedStep:
         return out[:-1]
 
 
-def _step_exchange_accounting(params, meta) -> Tuple[str, int, int]:
-    """``(codec, wire_bytes_per_step, uncompressed_bytes_per_step)`` for
-    the exchange a step built with ``meta`` emits, per chip per optimizer
-    step.
+def _step_builds_buckets(meta) -> bool:
+    """Whether a step built with ``meta`` copies its gradients into flat
+    fusion buffers, DERIVED from the route its exchange takes under the
+    settings in force (not read off the lowered program).  ZeRO-1's
+    arenas do; the stateful error-feedback exchange is the wrap's own and
+    keeps a residual a bucket at any world.  Otherwise world 1 maps the
+    collective over the leaves whatever the exchange, the microbatched
+    step reduce-scatters vectors whatever the codec, and the wrap's
+    allreduce says for itself
+    (:func:`~horovod_tpu.optim.distributed.exchange_packs`)."""
+    if meta.get("zero_stage"):
+        return True
+    exchange = getattr(getattr(meta.get("optimizer"), "update", None),
+                       "_hvd_exchange", None)
+    if exchange is None:
+        return False
+    from .collectives.compression import is_error_feedback
+    comp = exchange["compression"]
+    return is_error_feedback(comp) or int(meta.get("world", 1)) > 1 and (
+        int(meta.get("microbatches", 1)) > 1 or _dist.exchange_packs(
+            comp, exchange["op"], axes=exchange["axes"],
+            process_set=exchange["process_set"]))
+
+
+def _step_exchange_accounting(params, meta) -> Tuple[str, int, int, int]:
+    """``(codec, wire_bytes_per_step, uncompressed_bytes_per_step,
+    packed_bytes_per_step)`` for the exchange a step built with ``meta``
+    emits, per chip per optimizer step.
 
     ZeRO-1: ``zero_report``'s ``zero1_exchanged_bytes_per_chip`` against
     its ``replicated_allreduce_bytes_per_chip`` equivalent (so the
@@ -1276,9 +1307,18 @@ def _step_exchange_accounting(params, meta) -> Tuple[str, int, int]:
     Bare optimizer: no collective, wire 0.  The microbatch overlap factor
     is NOT folded in -- the figure is the equivalent single-exchange
     payload (see :class:`~horovod_tpu.timeline.metrics.StepReport`).
+
+    Packed bytes are the gradient bytes the step copies into flat fusion
+    buffers, DERIVED from the route the exchange takes (not read off the
+    lowered program): every one where the exchange needs a contiguous
+    vector (ZeRO-1's arenas, the error-feedback wrap's residual
+    buckets, the microbatched reduce-scatters, and whatever
+    :func:`~horovod_tpu.optim.distributed.exchange_packs` says of the
+    wrap's allreduce above world 1), none on the leaf-wise exchange.
     """
     leaves = jax.tree.leaves(params)
     raw = sum(int(x.size) * jnp.dtype(x.dtype).itemsize for x in leaves)
+    packed = raw if _step_builds_buckets(meta) else 0
     optimizer = meta.get("optimizer")
     if meta.get("zero_stage"):
         rep = _zero.zero_report(optimizer, params,
@@ -1288,11 +1328,11 @@ def _step_exchange_accounting(params, meta) -> Tuple[str, int, int]:
         codec = getattr(comp, "__name__", None) or \
             (str(comp) if comp else "none")
         return (codec, int(rep["zero1_exchanged_bytes_per_chip"]),
-                int(rep["replicated_allreduce_bytes_per_chip"]))
+                int(rep["replicated_allreduce_bytes_per_chip"]), packed)
     exchange = getattr(getattr(optimizer, "update", None),
                        "_hvd_exchange", None)
     if exchange is None:
-        return ("none", 0, raw)
+        return ("none", 0, raw, packed)
     from .collectives.compression import (is_error_feedback,
                                           wire_payload_bytes)
     comp = exchange["compression"]
@@ -1306,7 +1346,8 @@ def _step_exchange_accounting(params, meta) -> Tuple[str, int, int]:
     for dt, lspecs in spec.buffers:
         size = sum(s.size for s in lspecs)
         wire += wire_payload_bytes(comp, size, jnp.dtype(dt).itemsize)
-    return (getattr(comp, "__name__", type(comp).__name__), int(wire), raw)
+    return (getattr(comp, "__name__", type(comp).__name__), int(wire), raw,
+            packed)
 
 
 def make_flax_train_step(

@@ -15,6 +15,15 @@ enables on TPU through Mosaic (``interpret=False``) at the shapes
 kernel Mosaic refuses raises here, in the sandbox, before any chip time
 is spent on it.
 
+``<topology> exchange``: compiles ``hvd.allreduce_gradients`` (fp16 wire,
+``Average``) over the 32 gradient leaves of two BERT-Large layers and
+prints what the exchange became: how many all-reduces, how many operands
+the widest takes, and how many ``concatenate`` / ``dynamic-update-slice``
+/ ``copy`` ops the module holds.  The exchange emits one psum a leaf;
+that XLA's all-reduce combiner makes ONE many-operand all-reduce of them,
+each operand in its own tiled layout, and puts no buffer back, is the
+compiler's doing and is held here.
+
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
@@ -154,8 +163,56 @@ def kernels(topology: str) -> int:
     return 0
 
 
+def exchange(topology: str) -> int:
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    mesh = Mesh(np.asarray(td.devices), ("d",))
+    d, f = 1024, 4096
+    layer = [(d, d), (d,)] * 4 + [(d, f), (f,), (f, d), (d,)] \
+        + [(d,)] * 4
+    shapes = layer * 2
+
+    def local(*grads):
+        # A producer and a consumer, so that the casts have fusions to
+        # join as they do between the backward and the optimizer.
+        grads = [g * 2.0 for g in grads]
+        out = hvd.allreduce_gradients(
+            grads, hvd.Average, compression=hvd.Compression.fp16,
+            axes=("d",))
+        return tuple(o + 1.0 for o in out)
+
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False))
+    rep = NamedSharding(mesh, P())
+    text = fn.lower(*[jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+                      for s in shapes]).compile().as_text()
+    ars = [line.split(" all-reduce")[0] for line in text.splitlines()
+           if re.search(r"= .* all-reduce(-start)?\(", line)]
+    print(json.dumps({
+        "leaves": len(shapes),
+        "all_reduces": len(ars),
+        "operands": max(len(re.findall(r"\[[\d,]*\]", a)) for a in ars),
+        "tiled_operands": sum("T(4,128)" in a for a in ars),
+        **{op: len(re.findall(r"= \S+ %s\(" % op, text))
+           for op in ("concatenate", "dynamic-update-slice", "copy")},
+    }))
+    return 0
+
+
 if __name__ == "__main__":
     topo = sys.argv[1] if len(sys.argv) > 1 else "v5e:2x4"
+    if sys.argv[2:] == ["exchange"]:
+        sys.exit(exchange(topo))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

@@ -78,14 +78,16 @@ def test_rank_masked_data_into_psum_is_not_flagged(hvd):
 
 
 def test_plan_emitted_width_mismatch_is_flagged(hvd):
-    """Auditing the two-bucket fp16 step against a one-bucket plan (a
-    doubled threshold) must produce BOTH mismatch rules: the planned
-    448-element leg is never emitted, and the real 256/192 psums are
-    unaccounted."""
+    """Auditing the fp16 step against the plan of a bf16 wire must produce
+    BOTH mismatch rules: the planned bfloat16 legs are never emitted, and
+    the real float16 psums are unaccounted.  (The elementwise exchange
+    builds no bucket, one psum a leaf, so where the threshold draws the
+    plan's buckets does not show in the emitted collectives; the codec
+    does.)"""
     step, args, donate, _ = build_standard_config("plain")
     from horovod_tpu.collectives.compression import Compression
     wrong = _dist.DistributedOptimizer(
-        optax.sgd(0.01), compression=Compression.fp16,
+        optax.sgd(0.01), compression=Compression.bf16,
         fusion_threshold=4096)
     meta = dict(step._meta, optimizer=wrong)
     report = audit_step(step, *args, meta=meta, donate_argnums=donate,
@@ -95,7 +97,8 @@ def test_plan_emitted_width_mismatch_is_flagged(hvd):
     assert "audit-plan-unaccounted" in _rules(report.findings)
     missing = [f for f in report.findings
                if f.rule == "audit-plan-missing"]
-    assert "448" in missing[0].message
+    assert len(missing) == 3
+    assert "bfloat16[256]" in missing[0].message
 
 
 def test_donated_leaf_without_output_is_flagged(hvd):
@@ -157,20 +160,24 @@ def test_standard_configs_audit_green(hvd):
 
 
 def test_standard_config_expected_leg_counts(hvd):
-    """The audit matches the documented exchange shapes: 1 psum/bucket
-    (plain), RS+AG per arena (zero1), 2 psums/bucket (powersgd),
-    k RS + 1 AG per bucket (microbatch2)."""
+    """The audit matches the documented exchange shapes: 1 psum a leaf
+    (plain: the elementwise exchange), RS+AG per arena (zero1), 2
+    psums/bucket (powersgd), k RS + 1 AG per bucket (microbatch2)."""
     reports = audit_standard_configs()
-    assert reports["plain"].summary["expected_ops"] == 2        # 2 buckets
+    assert reports["plain"].summary["expected_ops"] == 3        # 3 leaves
     assert reports["zero1"].summary["expected_ops"] == 2        # RS + AG
     assert reports["powersgd_ef"].summary["expected_ops"] == 4  # P+Q x 2
     assert reports["microbatch2"].summary["expected_ops"] == 6  # (2RS+AG) x 2
     plain = reports["plain"]
-    # fp16 wire: the emitted psums carry float16 buckets of exactly the
-    # planned element counts.
+    # fp16 wire: the emitted psums carry the three float16 leaves, each
+    # in its own shape; the plan's two rows (256 | 128 + 64) are
+    # accounting and say so.
     sigs = sorted(r.sig() for r in plain.collectives
                   if r.sig() in {op.sig() for op in plain.expected.ops})
-    assert sigs == [("psum", "float16", 192), ("psum", "float16", 256)]
+    assert sigs == [("psum", "float16", 64), ("psum", "float16", 128),
+                    ("psum", "float16", 256)]
+    assert [r["elements"] for r in plain.expected.plan_rows] == [256, 192]
+    assert [r["packed"] for r in plain.expected.plan_rows] == [False, False]
 
 
 def test_train_loop_scan_carry_audits_green(hvd):
@@ -193,7 +200,7 @@ def test_train_loop_scan_carry_audits_green(hvd):
     report = audit_step(loop, params, opt.init(params), batches,
                         donate_argnums=(0, 1), name="step:loop")
     assert report.ok(), report.render()
-    assert report.summary["matched_ops"] == 2
+    assert report.summary["matched_ops"] == 3     # one psum a leaf
     assert all(r.in_loop for r in report.collectives)
 
 

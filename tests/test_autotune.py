@@ -44,6 +44,28 @@ def test_bayesian_optimizer_finds_peak_on_grid():
     assert abs(opt.best_index - 7) <= 1
 
 
+def test_threshold_inert_sample_scores_its_siblings():
+    """A sample of a step that builds no fusion bucket scores every
+    candidate that differs from it in the threshold alone (they are the
+    same compiled step): with two cycle times the tuner runs two samples,
+    not one a threshold, and logs only what it ran."""
+    t = Autotuner(Config(autotune=True), steps_per_sample=1,
+                  cycle_candidates=[1.0, 5.0])
+    thresholds = {g[0] for g in t.grid}
+    assert len(thresholds) >= 5 and len(t.grid) == 2 * len(thresholds)
+    guard = 0
+    while not t.done and guard < 40:
+        # The first step of a sample is the compile and is not scored.
+        t.record_step(0.01 * t.cycle_time_ms(), nbytes=1 << 20,
+                      threshold_inert=True)
+        guard += 1
+    assert t.done
+    assert len(t._samples) == 2
+    assert {s[1] for s in t._samples} == {1.0, 5.0}
+    assert t.cycle_time_ms() == 1.0
+    assert t._opt.n_observed == len(t.grid)
+
+
 def test_autotuner_converges_to_best_throughput(tmp_path):
     """Feed synthetic step times where 32 MiB @ 1ms is fastest; the tuner
     must lock in at (or adjacent to) the peak and log every sample."""
@@ -397,7 +419,16 @@ def test_autotune_value_demo_artifact_committed():
         "hierarchical": 0, "codec": "none"}
 
 
-def test_autotune_e2e_flax_step(hvd):
+@pytest.mark.parametrize("compression, min_samples", [
+    # The default exchange is leaf-wise (PR 27): it builds no fusion
+    # bucket, every threshold candidate is the same compiled step, so one
+    # sample scores them all and the tuner locks.
+    ("none", 1),
+    # fp8 keeps a scale a bucket: the threshold shapes the step, and the
+    # tuner explores it as before.
+    ("fp8", 4),
+], ids=["leafwise", "packed"])
+def test_autotune_e2e_flax_step(hvd, compression, min_samples):
     """Round-5: the tuned wrapper also drives make_flax_train_step (the
     RN50/CNN path used by the on-chip autotune demo) -- the tuner
     consumes steps, explores, and locks; training still converges."""
@@ -423,7 +454,8 @@ def test_autotune_e2e_flax_step(hvd):
         y = jnp.zeros((16,), jnp.int32)
         params = hv_mod.replicate(
             model.init(jax.random.PRNGKey(0), x[:2])["params"])
-        opt = hv_mod.DistributedOptimizer(optax.sgd(0.1))
+        opt = hv_mod.DistributedOptimizer(optax.sgd(0.1),
+                                          compression=compression)
         opt_state = hv_mod.replicate(opt.init(params))
         step = make_flax_train_step(
             lambda v, xx, train: model.apply(v, xx), opt)
@@ -436,7 +468,11 @@ def test_autotune_e2e_flax_step(hvd):
             losses.append(float(loss))
             guard += 1
         assert st.autotuner.done
-        assert len(st.autotuner._samples) >= 4
+        samples = st.autotuner._samples
+        assert len(samples) >= min_samples
+        if compression == "none":
+            # No two samples differ in the threshold alone.
+            assert len({s[1:-1] for s in samples}) == len(samples)
         assert losses[-1] < losses[0]
     finally:
         st.autotuner = None

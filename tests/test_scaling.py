@@ -182,6 +182,22 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
                    "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2}
 
 
+def test_topology_aot_exchange_is_one_many_operand_all_reduce():
+    """The leaf-wise gradient exchange over 32 leaves (50 MB of fp16),
+    compiled for the v5e: the step holds one all-reduce a leaf and XLA's
+    combiner must make ONE all-reduce of them with every leaf an operand
+    in its own tiled layout -- and put no flat buffer back (no
+    concatenate, no dynamic-update-slice, no relayout copy).  If a
+    toolchain stops doing that, ``allreduce_gradients`` pays a collective
+    a leaf and this fails before any chip time is spent."""
+    out = _topology_worker("v5e:2x2", "exchange")
+    assert out["leaves"] == 32
+    assert out["all_reduces"] == 1
+    assert out["operands"] == 32 and out["tiled_operands"] == 1
+    assert (out["concatenate"], out["dynamic-update-slice"],
+            out["copy"]) == (0, 0, 0)
+
+
 def test_optimized_stats_counts_and_bytes():
     st = scaling.optimized_collective_stats(_HLO_SAMPLE)
     assert st.counts == {"all-reduce": 2, "all-gather": 1,
@@ -239,10 +255,12 @@ def test_train_step_wire_accounting_in_process(hvd, n_devices):
 
     lowered = step.lower(params, opt_state, batch)
     emitted = scaling.emitted_collective_stats(lowered.as_text())
-    # One psum per dtype bucket (f32 + bf16 = 2) + the loss mean.
+    # Two dtype buckets in the plan (f32 + bf16), but the elementwise
+    # exchange builds none: one psum a leaf (3) + the loss mean.
     buckets = len(plan_buckets(jax.tree.leaves(params)).buffers)
     assert buckets == 2
-    assert emitted.counts.get("all-reduce") == buckets + 1
+    assert emitted.counts.get("all-reduce") == \
+        len(jax.tree.leaves(params)) + 1
 
     # Emitted payload preserves wire dtypes exactly (bf16 stays bf16).
     param_bytes = sum(x.size * x.dtype.itemsize

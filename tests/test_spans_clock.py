@@ -323,14 +323,27 @@ def _decode_step_text():
         jnp.ones((2,), bool)).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("text_of, names", [
-    (_train_step_text, ["hvd_exchange/pack", "hvd_exchange/compress",
-                        "hvd_exchange/collective",
-                        "hvd_exchange/decompress", "hvd_exchange/unpack"]),
-    (_decode_step_text, ["hvd_flash_decode"]),
-], ids=["train_step", "decode_step"])
-def test_lowered_text_carries_the_names(monkeypatch, text_of, names):
-    monkeypatch.setenv("HOROVOD_PALLAS_DECODE", "1")
+_STAGES = ["hvd_exchange/compress", "hvd_exchange/collective",
+           "hvd_exchange/decompress"]
+
+
+@pytest.mark.parametrize("text_of, env, names, absent", [
+    # fp16 around the flat psum is elementwise: the bucket's leaves ride
+    # as a group, nothing is packed.
+    (_train_step_text, {}, _STAGES,
+     ["hvd_exchange/pack", "hvd_exchange/unpack"]),
+    # The chunked exchange splits a vector: the same step packs.
+    (_train_step_text, {"HOROVOD_EXCHANGE_CHUNK_MB": "1"},
+     ["hvd_exchange/pack"] + _STAGES + ["hvd_exchange/unpack"], []),
+    (_decode_step_text, {"HOROVOD_PALLAS_DECODE": "1"},
+     ["hvd_flash_decode"], []),
+], ids=["train_step", "train_step_packed", "decode_step"])
+def test_lowered_text_carries_the_names(monkeypatch, text_of, env, names,
+                                        absent):
+    for key, value in env.items():
+        monkeypatch.setenv(key, value)
     text = text_of()
     for name in names:
         assert name in text, name
+    for name in absent:
+        assert name not in text, name
