@@ -149,7 +149,7 @@ def _check_spread(params) -> dict:
 
 def phase_rn50(model, image_shape, num_classes: int, batch_per_chip: int,
                steps: int, mosaic_calls: int = 0) -> dict:
-    """Trainer A: the path ``bench.py``'s default mode drives."""
+    """Trainer A: ``DistributedOptimizer`` + ``make_flax_train_step``."""
     n = hvd.size()
     key = jax.random.PRNGKey(0)
     x = jax.random.normal(key, (batch_per_chip * n, *image_shape),
@@ -248,8 +248,8 @@ def _greedy_parity(model, params, reqs) -> dict:
 def phase_server(config, slots: int, page_size: int, max_len: int,
                  prompt_lens, output_lens, num_requests: int,
                  mosaic_calls: int) -> dict:
-    """Server: the path ``bench.py``'s serving drill drives, on a
-    ``("tp",)`` mesh over every local chip."""
+    """Server: ``ServingEngine.serve`` on a ``("tp",)`` mesh over every
+    local chip."""
     devices = jax.devices()
     mesh = Mesh(np.asarray(devices), ("tp",))
     model = LlamaLM(config, dtype=jnp.float32)

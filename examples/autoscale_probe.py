@@ -16,7 +16,7 @@ with zero leaked KV pages.
 Run::
 
     python examples/autoscale_probe.py [--requests 32] [--rate 40]
-    python examples/autoscale_probe.py --bench-json /tmp/BENCH_rXX.json
+    python examples/autoscale_probe.py --bench-json /tmp/autoscale.json
 """
 
 import sys as _sys
@@ -26,7 +26,6 @@ _sys.path.insert(0, _dir(_dir(_abs(__file__))))  # repo root importable
 import argparse
 import json
 import os
-import re
 import urllib.request
 
 CTL_FAMILIES = (
@@ -63,8 +62,8 @@ def main():
                    help="kill@/slow@ spec fired virtually against the "
                         "fleet (chaos.py grammar)")
     p.add_argument("--bench-json", default=None,
-                   help="also write a BENCH-style entry with the "
-                        "autoscale block here")
+                   help="also write the drill's counts (the autoscale "
+                        "block) as JSON to this path")
     args = p.parse_args()
 
     # The endpoint port must be configured before init; 0 = ephemeral.
@@ -170,9 +169,8 @@ def main():
             "requests": rep.serving.num_requests,
             "completed": rep.serving.completed,
             "rejected": rep.serving.rejected}
-        m = re.search(r"BENCH_r(\d+)", os.path.basename(args.bench_json))
         entry = {
-            "n": int(m.group(1)) if m else world,
+            "n": world,
             "cmd": ("JAX_PLATFORMS=cpu python examples/autoscale_probe.py"
                     f" --requests {args.requests} --rate {args.rate}"
                     f" --slots {args.slots}"),
@@ -192,7 +190,7 @@ def main():
                 "autoscale": block}}
         with open(args.bench_json, "w") as f:
             json.dump(entry, f, indent=1)
-        print(f"wrote bench entry -> {args.bench_json}")
+        print(f"wrote autoscale entry -> {args.bench_json}")
 
     hvd.shutdown()
     print(f"\nautoscale probe OK (mesh {rep.mesh_size_initial} -> "

@@ -35,8 +35,9 @@ Two scenarios bracket the decision:
 The cold-start tuner (no warm-start log) samples the 5-config grid
 (flat, plus hier x 4 DCN codecs -- the grid prunes DCN codecs without
 the hierarchical schedule) exhaustively and locks the modeled winner in
-each scenario.  ``python examples/autotune_value_demo.py`` writes the
-selections + the full modeled cost table to ``AUTOTUNE_DEMO.json``;
+each scenario.  ``python examples/autotune_value_demo.py`` prints the
+selections and, where ``AUTOTUNE_DEMO_OUT`` names a path, writes them
+with the full modeled cost table there as JSON;
 ``tests/test_autotune.py`` asserts the selections.
 """
 
@@ -170,15 +171,14 @@ def main():
     mesh = build_mesh(jax.devices()[:8], hierarchical=True, dcn_size=2)
     hvd.init(mesh=mesh)
     results = [run_scenario(name) for name in SCENARIOS]
-    out_path = os.environ.get(
-        "AUTOTUNE_DEMO_OUT",
-        os.path.join(_dir(_dir(_abs(__file__))), "AUTOTUNE_DEMO.json"))
-    doc = {"demo": "autotune_value_demo",
-           "mesh": f"virtual ({DCN_GROUPS}, {ICI_GROUP}) two-level",
-           "results": results}
-    with open(out_path, "w") as f:
-        json.dump(doc, f, indent=2)
-        f.write("\n")
+    out_path = os.environ.get("AUTOTUNE_DEMO_OUT")
+    if out_path:
+        doc = {"demo": "autotune_value_demo",
+               "mesh": f"virtual ({DCN_GROUPS}, {ICI_GROUP}) two-level",
+               "results": results}
+        with open(out_path, "w") as f:
+            json.dump(doc, f, indent=2)
+            f.write("\n")
     for r in results:
         print(f"{r['scenario']}: selected {r['selected']} "
               f"(expected {r['expected']}) -- "

@@ -1,8 +1,9 @@
 """BN(+relu+residual) BACKWARD glue: measured XLA cost vs the HBM floor.
 
-Round-3's per-op account (docs/benchmarks.md) attributed ~45 ms of the
-60.7 ms ResNet-50 backward to HBM-bound BN/relu/residual backward chains
-and left one lever untried: a fused Pallas kernel reading each
+An earlier runtime's per-op account (``rn50_bwd_roofline.py``; not
+reproduced) attributed ~45 ms of the 60.7 ms ResNet-50 backward to
+HBM-bound BN/relu/residual backward chains and left one lever untried:
+a fused Pallas kernel reading each
 activation + cotangent once per pass.  Before writing that kernel, this
 probe establishes whether there is anything left to win: for each hot
 BN site it differential-times (``_harness.differential_bench``) the

@@ -1,7 +1,7 @@
 """NHWC-vs-NCHW probe for ResNet-50's backward convolutions, on the chip.
 
-The per-op roofline (``rn50_op_roofline.py``, docs/benchmarks.md "The
-per-op account") measured the backward pass at 3.0x the forward's wall
+The per-op roofline (``rn50_op_roofline.py``, on an earlier runtime)
+measured the backward pass at 3.0x the forward's wall
 time with only 2x its FLOPs; round 2 INFERRED the dgrad/wgrad convs ran
 ~1.5x slower per FLOP (this probe and ``rn50_bwd_roofline.py`` later
 showed the kernels are in fact near peak and the gap is HBM-bound glue).

@@ -16,8 +16,8 @@ This probe produces that table MEASURED on the chip:
   forward, so the non-conv share (BN/relu/pad fusion overhead) is a
   measured residual, not a guess.
 
-Usage (defaults match bench.py's config: batch 256, 224x224, bf16,
-space-to-depth stem)::
+Usage (defaults are ``chip_smoke.py``'s ResNet-50 phase: batch 256,
+224x224, bf16, space-to-depth stem)::
 
     python examples/rn50_op_roofline.py [--batch 256] [--iters 12]
         [--precision default|highest] [--markdown] [--kernel]

@@ -15,14 +15,13 @@ collectives (``serving_decode/layer*/{attn_wo,mlp_down}``).
 weighted, :func:`horovod_tpu.serving.loadgen.long_prompt_spec`) with
 chunked flash prefill (``--prefill-chunk`` tokens per slice interleaved
 with decode steps), and additionally asserts the
-``serving_prefill_chunk`` span leg fired -- the workload the BENCH_r15
-TTFT-p99 gate measures.
+``serving_prefill_chunk`` span leg fired.
 
 Run::
 
     python examples/serving_probe.py [--requests 16] [--rate 50]
     python examples/serving_probe.py --long-prompts [--prefill-chunk 512]
-    python examples/serving_probe.py --bench-json /tmp/BENCH_rXX.json
+    python examples/serving_probe.py --bench-json /tmp/serving.json
 """
 
 import sys as _sys
@@ -32,7 +31,6 @@ _sys.path.insert(0, _dir(_dir(_abs(__file__))))  # repo root importable
 import argparse
 import json
 import os
-import re
 import urllib.request
 
 SERVING_FAMILIES = (
@@ -69,8 +67,8 @@ def main():
                    help="chunk length for --long-prompts (0 = whole "
                         "prompt at once)")
     p.add_argument("--bench-json", default=None,
-                   help="also write a BENCH-style entry with the "
-                        "serving block here")
+                   help="also write the run's report (the serving "
+                        "block) as JSON to this path")
     args = p.parse_args()
 
     # The endpoint port must be configured before init; 0 = ephemeral.
@@ -197,9 +195,8 @@ def main():
             "token_latency_p99_ms":
                 round(report.token_latency_p99_s * 1e3, 3),
             "batch_occupancy": round(report.mean_occupancy, 4)}
-        m = re.search(r"BENCH_r(\d+)", os.path.basename(args.bench_json))
         entry = {
-            "n": int(m.group(1)) if m else world,
+            "n": world,
             "cmd": ("JAX_PLATFORMS=cpu python examples/serving_probe.py"
                     f" --requests {args.requests} --rate {args.rate}"
                     f" --slots {args.slots}"),
@@ -217,7 +214,7 @@ def main():
                 "serving": block}}
         with open(args.bench_json, "w") as f:
             json.dump(entry, f, indent=1)
-        print(f"wrote bench entry -> {args.bench_json}")
+        print(f"wrote serving entry -> {args.bench_json}")
 
     hvd.shutdown()
     print(f"\nserving probe OK ({report.tokens_per_s:.1f} tokens/s, "

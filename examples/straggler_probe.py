@@ -14,7 +14,7 @@ injected delay to the right rank with a ``dispatch_gap``-dominated step.
 Run::
 
     python examples/straggler_probe.py [--steps 12] [--slow-rank 5]
-    python examples/straggler_probe.py --bench-json /tmp/BENCH_rXX.json
+    python examples/straggler_probe.py --bench-json /tmp/straggler.json
 """
 
 import sys as _sys
@@ -38,8 +38,8 @@ def main():
     p.add_argument("--trace-dir", default=None,
                    help="where per-rank timelines land (default: tmp)")
     p.add_argument("--bench-json", default=None,
-                   help="also write a BENCH-style entry with the "
-                        "straggler block here")
+                   help="also write the attribution (the straggler "
+                        "block) as JSON to this path")
     args = p.parse_args()
     world = args.cpu_devices
     assert 0 <= args.slow_rank < world
@@ -149,13 +149,8 @@ def main():
             "skew_s": round(live["skew_s"], 6),
             "merged_ranks": rep["ranks"],
             "merged_events": rep["events"]}
-        # "n" is the bench ROUND, not the world size: recover it from a
-        # BENCH_r<N>.json target name so the trajectory table stays
-        # duplicate-free.
-        import re
-        m = re.search(r"BENCH_r(\d+)", os.path.basename(args.bench_json))
         entry = {
-            "n": int(m.group(1)) if m else world,
+            "n": world,
             "cmd": ("JAX_PLATFORMS=cpu python examples/straggler_probe.py"
                     f" --steps {args.steps} --slow-rank {args.slow_rank}"
                     f" --slow-step {args.slow_step}"
@@ -172,7 +167,7 @@ def main():
                 "straggler": block}}
         with open(args.bench_json, "w") as f:
             json.dump(entry, f, indent=1)
-        print(f"\nwrote bench entry -> {args.bench_json}")
+        print(f"\nwrote straggler entry -> {args.bench_json}")
 
     hvd.shutdown()
     print("\nstraggler probe OK")

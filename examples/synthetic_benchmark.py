@@ -110,7 +110,7 @@ def main() -> int:
         params, batch_stats, opt_state, loss = step(params, batch_stats,
                                                     opt_state, batch)
     if loss is not None:
-        float(loss)  # device->host fetch: the only reliable fence (bench.py)
+        float(loss)  # device->host fetch: fences the warm-up
 
     t0 = time.perf_counter()
     for _ in range(args.num_iters):

@@ -249,7 +249,7 @@ class Autotuner:
         # exchange (collectives/ops.py::hierarchical_allreduce's
         # ``dcn_codec``).  The ICI legs keep the sample's plain codec --
         # contended DCN with fast ICI is exactly where per-leg compression
-        # pays (the bench's contended_dcn scenario).
+        # pays.
         self.tunes_hier_codec = bool(_env_bool("AUTOTUNE_HIER")
                                      and _mesh_is_two_level())
         hcodecs = [HIER_DCN_NONE, HIER_DCN_BF16, HIER_DCN_FP16,

@@ -389,7 +389,7 @@ def wire_payload_bytes(compression, size: int,
                        itemsize: int = 4, world: int = 1) -> int:
     """Estimated allreduce-equivalent on-wire payload for one exchange of a
     ``size``-element bucket (used by the ``compression_ratio`` timeline
-    counter and the bench wire accounting; link-bytes scaling by
+    counter and the step report's wire accounting; link-bytes scaling by
     ``(n-1)/n`` cancels in ratios so it is left out).
 
     - dtype codecs: the full bucket at the wire itemsize;

@@ -395,8 +395,8 @@ def poll(handle: int) -> bool:
 # Reference analogue: EnqueueTensorAllreduce puts the request on the
 # background loop's queue and RunLoopOnce negotiates EVERYTHING pending in
 # one controller round per cycle.  Here the control-plane cost is the join
-# presence round (~ms on localhost Gloo, measured in docs/benchmarks.md
-# "Eager control plane"), and the grouped/fused entry points already
+# presence round (``examples/eager_latency_probe.py`` times it on a
+# localhost Gloo mesh), and the grouped/fused entry points already
 # amortize it via joinop.flush -- but a loop of ungrouped ``*_async`` ops
 # paid one round each.  Deferring the dispatch until a flush point
 # (synchronize/poll, any sync collective, hvd.join, or the capacity cap)

@@ -290,8 +290,8 @@ def sync(ps) -> Optional[np.ndarray]:
     if _env_bool("JOIN_DISABLE"):
         # Opt-out for workloads that never call hvd.join(): skips the
         # per-dispatch presence collective + its fence on the eager
-        # multi-process hot path (measured: see docs/benchmarks.md
-        # "Eager control plane").  join() raises under this flag.
+        # multi-process hot path (``examples/eager_latency_probe.py``
+        # times both settings).  join() raises under this flag.
         return None
     if client() is None:
         return None

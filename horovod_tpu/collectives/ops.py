@@ -255,8 +255,8 @@ def hierarchical_allreduce(x,
 
     The flat bucket is zero-padded to a multiple of
     ``microbatch_pad_quantum(n_ici)`` so the per-leg wire payload is
-    mesh-invariant across every ``n_ici`` dividing 256 (what the scaling
-    bench gates on).  When the DCN axis has extent 1 (single slice) the
+    mesh-invariant across every ``n_ici`` dividing 256 (held by
+    ``tests/test_hierarchical.py``).  When the DCN axis has extent 1 (single slice) the
     two-level decomposition would only add reduction-order noise, so the
     op statically falls back to the flat ``psum`` over both axes --
     bitwise identical to :func:`allreduce` on the same mesh.
@@ -445,11 +445,10 @@ def microbatch_pad_quantum(n: int, base: int = 256) -> int:
 
     Buckets are zero-padded to a multiple of this before the per-microbatch
     reduce-scatter.  Padding to a multiple of ``n`` alone would make the
-    padded byte count (and hence the wire payload the scaling bench gates
-    on) depend on the mesh size; padding to ``lcm(n, base)`` keeps it
-    mesh-invariant across every ``n`` dividing ``base`` (256 covers the
-    v5e/v5p pod sizes the bench sweeps), so payload == planner holds at
-    the same 3e-7 spread as the zero1/chunked cases.
+    padded byte count (and hence the wire payload) depend on the mesh
+    size; padding to ``lcm(n, base)`` keeps it mesh-invariant across
+    every ``n`` dividing ``base`` (256 covers the v5e/v5p pod sizes), so
+    the planner's bytes are the payload's at every such ``n``.
     """
     return base * n // math.gcd(base, n)
 

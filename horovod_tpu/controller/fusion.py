@@ -110,8 +110,8 @@ def plan_cache_enabled() -> bool:
     """Whether plan memoization is on (``HOROVOD_PLAN_CACHE``, default 1).
 
     ``0`` / ``false`` / ``off`` disables the shared plan cache: every
-    planner call rebuilds from scratch.  Diagnostic knob -- replan counts
-    in the bench and the consistency tests assume the cache is on.
+    planner call rebuilds from scratch.  Diagnostic knob -- the replan counts
+    the consistency tests assert assume the cache is on.
     """
     return os.environ.get("HOROVOD_PLAN_CACHE", "1").strip().lower() \
         not in ("0", "false", "off")
@@ -324,8 +324,8 @@ def fused_tree_collective(tree, collective_fn,
 class ExchangeLeg:
     """One typed row of the exchange-plan IR: which mesh axis the leg
     moves over, which collective it emits, the codec riding that hop,
-    and the closed-form operand/wire accounting the spans, auditor and
-    bench all gate on.
+    and the closed-form operand/wire accounting the spans and the
+    auditor both read.
 
     ``elements`` is the collective's first-operand element count (what
     the jaxpr auditor records); ``nbytes`` the wire payload bytes the
@@ -399,8 +399,8 @@ def plan_hier_legs(size: int, dtype, *, n_dcn: int, n_ici: int,
     Thin wrapper over ``plan_exchange("hier", ...)`` -- the memoized IR
     planner mirrors ``ops.hierarchical_allreduce`` exactly (padding
     quantum, per-leg wire dtypes, ``note_leg`` byte accounting), so the
-    bench's payload gate, the auditor's ``stepmodel`` and the op itself
-    all consume the SAME plan object.  ``compression`` may be ``None``,
+    auditor's ``stepmodel`` and the op itself consume the SAME plan
+    object.  ``compression`` may be ``None``,
     a cast codec (the bucket is cast before the exchange: every leg
     rides the wire dtype), or a per-leg ``ici:...,dcn:...`` codec;
     alternatively pass resolved ``ici_codec``/``dcn_codec`` classes
@@ -621,7 +621,7 @@ def render_plan(rows: List[dict]) -> str:
 # expected-collective multiset from the SAME memoized plan (the ``audit``
 # rows), so expectation and emission can only diverge if an executor
 # diverges from its own plan.  Adding a new leg kind = register a kind +
-# a family here, consume the legs in ONE executor; spans/auditor/bench
+# a family here, consume the legs in ONE executor; spans and auditor
 # pick it up with zero new code (the ROADMAP success test; exercised in
 # tests/test_plan_ir.py).
 
@@ -1348,8 +1348,8 @@ def simulate_issue(legs: Sequence[ExchangeLeg], chip=None) -> dict:
     AND its bucket's previous leg finished (the RS->hop->AG chain).
     Returns the modeled makespan, per-class busy seconds, and the
     dispatch-gap fraction: how much of the makespan the critical link
-    sits idle waiting on dispatch order.  Purely a host-side model (the
-    bench's A/B metric) -- it never touches the wire."""
+    sits idle waiting on dispatch order.  Purely a host-side model -- it
+    never touches the wire."""
     free = {"dcn": 0.0, "ici": 0.0}
     busy = {"dcn": 0.0, "ici": 0.0}
     done: Dict[int, float] = {}

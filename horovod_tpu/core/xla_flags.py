@@ -173,7 +173,7 @@ _last_report: Optional[FlagReport] = None
 def apply(env: Optional[MutableMapping[str, str]] = None,
           platform: Optional[str] = None) -> FlagReport:
     """Convenience wrapper that records the report for later inspection
-    via :func:`last_report` (e.g. from ``bench.py``'s config dump)."""
+    via :func:`last_report`."""
     global _last_report
     _last_report = apply_xla_flags(env=env, platform=platform)
     return _last_report

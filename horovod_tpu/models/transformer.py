@@ -232,7 +232,7 @@ class LlamaConfig:
 LLAMA3_8B = LlamaConfig()
 # ~0.9B single-chip variant; same shape family, used for the
 # comfortable single-chip LoRA benchmark.  (The full 8B also runs on a
-# 16 GB chip via base_dtype="int8" -- see docs/benchmarks.md.)
+# 16 GB chip via base_dtype="int8": examples/llama_lora.py --8b.)
 LLAMA_1B = LlamaConfig(vocab_size=32000, num_layers=16, num_heads=16,
                        num_kv_heads=8, head_dim=128, d_model=2048,
                        ffn_hidden=5632, max_seq_len=4096)

@@ -103,7 +103,8 @@ _FAMILY_ENV = {
 # Families whose ``auto`` resolves to the XLA path even on TPU; an
 # explicit ``1`` still runs the kernels.  ``bn_bwd``: XLA's fused
 # backward beat the two-pass kernel's 7N-byte floor at the RN50 sites
-# (docs/benchmarks.md, round 5).  ``fused_update``: Mosaic refuses the
+# (an earlier runtime's reading; ``examples/bn_bwd_probe.py`` repeats
+# the comparison).  ``fused_update``: Mosaic refuses the
 # kernels at RN50's PowerSGD bucket shapes -- ``(256, c)`` f32 row blocks
 # overflow the 16 MB scoped-VMEM limit at c = 4096, and a near-square
 # dim with no 128-multiple divisor has no legal lane tiling.

@@ -461,7 +461,7 @@ def shard_zero_state(state, mesh: Optional[Mesh] = None):
 
 
 def zero_report(optimizer, params, world: int, compression=None) -> dict:
-    """Static wire/HBM accounting for the zero1 config (bench surface).
+    """Static wire/HBM accounting for the zero1 config.
 
     Returns per-chip link bytes per step for the gradient reduce-scatter
     and the (possibly compressed) param allgather, the replicated

@@ -148,8 +148,7 @@ class ServingReport:
     prefix_hit_rate: float = 0.0
     prefill_tokens_cached: int = 0
     # Fraction of prompt tokens whose per-token prefill forward was
-    # skipped outright (matched pages attached instead of computed) --
-    # the "prefill FLOPs avoided" headline of BENCH_r17.
+    # skipped outright (matched pages attached instead of computed).
     prefill_flops_avoided: float = 0.0
     session_resumes: int = 0
 

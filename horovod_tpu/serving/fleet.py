@@ -197,7 +197,7 @@ class DecodeWorker:
 
 @dataclasses.dataclass
 class FleetReport:
-    """One fleet run's outcome (the BENCH_r20 drill's raw material)."""
+    """One fleet run's outcome."""
 
     num_requests: int
     completed: int

@@ -273,7 +273,7 @@ class PagedKVCache:
     def refcounts_balanced(self) -> bool:
         """True when every page is either on a free list (refcount 0)
         or held (refcount > 0) with the free lists consistent -- the
-        drain-time leak check the BENCH_r17 drill asserts."""
+        drain-time leak check."""
         ok = len(self._free) + self.live_pages == self.config.num_pages
         ok = ok and not any(self._refcount[p] for p in self._free)
         if self.compress:

@@ -8,7 +8,7 @@ queueing delay from the TTFT distribution).
 
 Everything is driven by one seeded ``numpy.random.RandomState``:
 identical :class:`LoadSpec` -> identical request stream, byte for byte
-(asserted in tests), so bench rounds are reproducible.  The PR 16
+(asserted in tests), so runs are reproducible.  The PR 16
 traffic shapes draw from the SAME stream in a fixed order, so turning
 them off reproduces the pre-PR-16 streams exactly:
 
@@ -128,8 +128,7 @@ def _norm(weights: Optional[Sequence[float]], n: int):
 
 def long_prompt_spec(**overrides) -> LoadSpec:
     """The kilotoken-prompt mixture the chunked-prefill TTFT gate runs:
-    512/2048/4096-token prompts weighted toward the long tail (the 4k
-    bucket is what the BENCH_r15 TTFT p99 is measured on)."""
+    512/2048/4096-token prompts weighted toward the long tail."""
     base = dict(num_requests=16, rate_rps=2.0,
                 prompt_lens=(512, 2048, 4096),
                 prompt_weights=(0.5, 0.25, 0.25),
@@ -139,7 +138,7 @@ def long_prompt_spec(**overrides) -> LoadSpec:
 
 
 def prefix_spec(**overrides) -> LoadSpec:
-    """The BENCH_r17 prefix-shared mixture: >= 50% of requests share
+    """The prefix-shared mixture: >= 50% of requests share
     one of a handful of fixed 64-token system prefixes, a quarter open
     two-turn sessions, and arrivals split across a gold/bronze tenant
     mix -- the workload where the radix prefix cache's avoided-prefill
@@ -154,7 +153,7 @@ def prefix_spec(**overrides) -> LoadSpec:
 
 
 def fleet_spec(**overrides) -> LoadSpec:
-    """The BENCH_r20 fleet chaos mixture: prefix-shared traffic that
+    """The fleet chaos mixture: prefix-shared traffic that
     DOUBLES its arrival rate partway through the run while an external
     LB skews arrivals 3:1 toward engine 0 -- the surge + imbalance the
     fleet router's spill path and the scaler's grow-under-traffic path

@@ -41,8 +41,8 @@ Every transition is instrumented through the PR 6
   ``horovod_serving_tenant_occupancy{tenant}``,
   ``horovod_serving_tenant_queue_depth{tenant}``
 
--- the same families the bench serving block and ``serving_probe``
-scrape back out of ``/metrics``.
+-- the same families ``examples/serving_probe.py`` scrapes back out of
+``/metrics``.
 
 Multi-tenancy (PR 16): :class:`TenantClass` declares per-class weight,
 TTFT SLO budget and slot-share cap; admission becomes stride scheduling
@@ -79,7 +79,7 @@ class TenantClass:
     contention); ``max_share`` caps the fraction of decode slots the
     tenant may hold while OTHER tenants are queued (an adversarial
     flood cannot starve the batch); ``ttft_slo_s`` is the class's TTFT
-    p99 budget -- the fairness gate the BENCH_r17 drill asserts."""
+    p99 budget."""
 
     name: str
     weight: float = 1.0

@@ -232,8 +232,8 @@ class DispatchGapMonitor:
     the host.  A k-step scan loop drives it toward zero because one
     dispatch covers k steps.
 
-    Feeds ``bench.py``'s ``scanloop`` config and, when a
-    :class:`Timeline` is active, a ``host_dispatch_gap`` counter track.
+    When a :class:`Timeline` is active it feeds a ``host_dispatch_gap``
+    counter track.
     """
 
     def __init__(self, timeline: Optional[Timeline] = None):
@@ -305,9 +305,8 @@ class OverlapMonitor:
     monolithic post-backward exchange).  ``comm_s <= 0`` (single chip, no
     exchange) records 0.0 by convention: there is nothing to hide.
 
-    Feeds ``bench.py``'s ``overlap`` config and, when a
-    :class:`Timeline` is active, an ``exchange_overlap`` counter track --
-    the overlap analogue of :class:`DispatchGapMonitor`.
+    When a :class:`Timeline` is active it feeds an ``exchange_overlap``
+    counter track -- the overlap analogue of :class:`DispatchGapMonitor`.
     """
 
     def __init__(self, compute_s: float, comm_s: float,

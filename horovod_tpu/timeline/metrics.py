@@ -20,8 +20,7 @@ in the runtime feeds --
 
 Rendered two ways: Prometheus text exposition (served by
 ``run/metrics_server.py`` on ``HOROVOD_METRICS_PORT``) and a plain dict
-via :func:`metrics_snapshot` (recorded into ``BENCH_*.json`` by
-``bench.py``).
+via :func:`metrics_snapshot`.
 
 Zero-overhead when disabled (``HOROVOD_METRICS=0``): every family
 accessor returns a shared null object whose ``inc``/``set``/``observe``
@@ -40,7 +39,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 __all__ = [
     "MetricsRegistry", "StepReport", "registry", "reset_metrics",
     "metrics_snapshot", "render_prometheus", "last_step_report",
-    "record_step_report", "install_default_metrics", "bench_block",
+    "record_step_report", "install_default_metrics",
 ]
 
 # Step wall-time histogram upper bounds (seconds).  Spans sub-ms eager
@@ -458,7 +457,7 @@ class StepReport:
     wire accounting: for ZeRO-1 they match
     ``zero_report()['zero1_exchanged_bytes_per_chip']`` /
     ``['replicated_allreduce_bytes_per_chip']`` byte-for-byte; for a
-    compressed exchange they match ``bench.py``'s
+    compressed exchange they match the
     ``wire_payload_bytes``-over-``ef_bucket_plan`` accounting.  The
     microbatch overlap factor is intentionally NOT folded in: the figure
     is the equivalent single-exchange payload.  ``packed_bytes`` are the
@@ -691,36 +690,3 @@ def histogram_quantile(snap: dict, q: float) -> Optional[float]:
         if bound != float("inf"):
             prev_bound = bound
     return None
-
-
-# -- bench integration -----------------------------------------------------
-
-def bench_block(snap: Optional[dict] = None) -> dict:
-    """Compact snapshot block recorded into each ``BENCH_*.json``.
-
-    Shape is validated by ``tests/test_bench_guard.py``'s
-    ``scan_metrics_snapshot_entries``: counters non-negative, and when a
-    ``compression`` entry is present with matching wire bytes, the
-    gauge-implied ratio must agree with it."""
-    if snap is None:
-        snap = metrics_snapshot()
-
-    def val(name: str, default: float = 0.0) -> float:
-        fam = snap.get(name) or {}
-        return float(fam.get("value", default))
-
-    hist = snap.get("horovod_step_time_seconds") or {}
-    ratio = val("horovod_compression_ratio")
-    return {
-        "families": len(snap),
-        "step_total": int(val("horovod_step_total")),
-        "step_time_count": int(hist.get("count", 0)),
-        "step_time_sum_s": round(float(hist.get("sum", 0.0)), 6),
-        "wire_bytes_total": int(val("horovod_wire_bytes_total")),
-        "wire_bytes_per_step": int(val("horovod_wire_bytes_per_step")),
-        "uncompressed_bytes_per_step": int(
-            val("horovod_uncompressed_bytes_per_step")),
-        "compression_ratio": round(ratio, 4) if ratio > 0 else None,
-        "plan_cache_hits": int(val("horovod_plan_cache_hits_total")),
-        "plan_cache_misses": int(val("horovod_plan_cache_misses_total")),
-    }

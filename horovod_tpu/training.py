@@ -669,8 +669,7 @@ def _microbatch_grad_pipe(exchange, axes, k=1):
     Wire accounting: k reduce-scatters + 1 allgather of the
     ``lcm(n, 256)``-padded bucket move an equivalent-allreduce payload of
     ``(k+1)/2`` buckets -- the overlap costs extra bytes but each piece
-    rides under compute (``bench_scaling.py`` rn50-overlap gates the exact
-    number).  Numerics: the cross-rank reduce runs in the wire dtype like
+    rides under compute.  Numerics: the cross-rank reduce runs in the wire dtype like
     the single-shot path, but the cross-MICROBATCH sum runs in f32 and the
     Average divide happens once at the end, so k>1 matches single-shot to
     accumulation-order tolerance, not bitwise (see ``make_train_step``).
@@ -1160,8 +1159,7 @@ class _InstrumentedStep:
     (shape/dtype reads only -- before the donated buffers are consumed)
     and must match the existing bookkeeping byte-for-byte: the ZeRO-1
     path reuses ``zero_report`` and the compressed path reuses
-    ``wire_payload_bytes`` over the exchange's own bucket plan, exactly
-    as ``bench.py`` prices them.  A failure in the accounting degrades to
+    ``wire_payload_bytes`` over the exchange's own bucket plan.  A failure in the accounting degrades to
     zeros -- it must never break training.
     """
 
@@ -1299,8 +1297,7 @@ def _step_exchange_accounting(params, meta) -> Tuple[str, int, int, int]:
     emits, per chip per optimizer step.
 
     ZeRO-1: ``zero_report``'s ``zero1_exchanged_bytes_per_chip`` against
-    its ``replicated_allreduce_bytes_per_chip`` equivalent (so the
-    implied ratio matches bench.py's zero compression entry).
+    its ``replicated_allreduce_bytes_per_chip`` equivalent.
     DistributedOptimizer wrap: ``wire_payload_bytes`` summed over the
     exchange's own bucket plan (``ef_bucket_plan`` for error-feedback
     codecs, ``plan_buckets`` otherwise) against the raw gradient bytes.

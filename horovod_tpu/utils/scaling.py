@@ -387,8 +387,8 @@ def schedule_overlap_report(
         hbm_bytes_per_s: float = 0.8 * 819e9) -> ScheduleReport:
     """Parse a SCHEDULED TPU module for collective overlap evidence.
 
-    Defaults model a v5e: MXU at the 70% of peak the per-op roofline
-    measured for this workload class (docs/benchmarks.md), HBM at 80% of
+    Defaults model a v5e: MXU at 70% of peak (an earlier runtime's per-op
+    reading for convolutional steps, not reproduced), HBM at 80% of
     the 819 GB/s spec.  The estimates only weight schedule POSITIONS --
     the sync/async split itself is exact (it is read off the text).
     """

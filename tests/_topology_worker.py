@@ -3,11 +3,10 @@
 ``<topology>``: compiles a tiny shard_map program (one matmul + one psum
 + one ppermute) against a real TPU topology via
 ``jax.experimental.topologies`` -- no TPU attached -- and prints one JSON
-line describing the compiled SCHEDULE.  This is the CI gate for the
-round-4 evidence mechanism: if the toolchain stops emitting scheduled
-modules, async collective-permute pairs, or sync all-reduces, this
-worker's output changes and the test fails, instead of
-docs/benchmarks.md silently rotting.
+line describing the compiled SCHEDULE.  This is the CI gate for what
+``utils.scaling.schedule_overlap_report`` reads: if the toolchain stops
+emitting scheduled modules, async collective-permute pairs, or sync
+all-reduces, this worker's output changes and the test fails.
 
 ``<topology> kernels``: compiles each Pallas family that ``auto``
 enables on TPU through Mosaic (``interpret=False``) at the shapes

@@ -18,7 +18,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_phase_rn50_tiny(hvd, n_devices):
-    # The one-stage ResNet bench.py uses for BENCH_TINY.
+    # A one-stage ResNet: the phase's code path at a size the CPU runs.
     model = ResNet(stage_sizes=[1], block_cls=BasicBlock, num_filters=8,
                    num_classes=100, dtype=jnp.bfloat16)
     out = chip_smoke.phase_rn50(model, (32, 32, 3), 100, batch_per_chip=4,
@@ -54,10 +54,10 @@ def test_phase_server_tiny(n_devices):
 
 
 def test_imports_touch_no_backend_and_main_refuses_cpu():
-    """One subprocess, two contracts.  (1) The launcher parent, bench.py's
-    eager drill and bench_scaling's worker spawns import the package
-    before starting children, and a parent that has touched jax's backend
-    holds the chip its child needs: importing ``horovod_tpu``,
+    """One subprocess, two contracts.  (1) The launcher parent and every
+    script that spawns workers import the package before starting
+    children, and a parent that has touched jax's backend holds the chip
+    its child needs: importing ``horovod_tpu``,
     ``horovod_tpu.serving`` and ``horovod_tpu.run.launch`` (and
     ``chip_smoke`` itself) must initialize no backend -- which is also what
     lets conftest, the examples and the driver dryrun force a device count

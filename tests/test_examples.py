@@ -1,6 +1,9 @@
 """Smoke-run every example workload on the CPU mesh (reference CI runs
 its examples per framework; BASELINE.json names these five configs)."""
 
+import ast
+import importlib
+import inspect
 import json
 import os
 import subprocess
@@ -84,8 +87,8 @@ def test_straggler_probe_example_cpu(tmp_path):
     """8-rank virtual-mesh drill: the chaos `slow` fault stalls one
     rank, the straggler monitor and the merged-trace report must both
     name it with a dispatch_gap-dominated step (the probe asserts this
-    internally; the bench entry is validated here)."""
-    bench = tmp_path / "BENCH_r99.json"
+    internally; the entry it writes is checked here)."""
+    bench = tmp_path / "straggler.json"
     out = _run([os.path.join(REPO, "examples", "straggler_probe.py"),
                 "--steps", "10", "--slow-rank", "3", "--slow-step", "4",
                 "--slow-secs", "0.3", "--bench-json", str(bench)])
@@ -96,9 +99,8 @@ def test_straggler_probe_example_cpu(tmp_path):
     doc = json.loads(bench.read_text())
     st = doc["parsed"]["straggler"]
     assert st["detected_rank"] == 3 and st["injected_rank"] == 3
-    assert st["merged_ranks"] == 8
-    from test_bench_guard import scan_straggler_entries
-    assert scan_straggler_entries(str(tmp_path)) == []
+    assert st["merged_ranks"] == st["world"] == 8
+    assert "slow@step=4" in st["spec"] and st["dominant_span"]
 
 
 @pytest.mark.integration
@@ -116,17 +118,17 @@ def test_llama_lora_multi_adapter_serving_cpu():
 def test_serving_probe_example_cpu(tmp_path):
     """8-device virtual-mesh serving drill: the probe scrapes its own
     /metrics endpoint and asserts the request-lifecycle families and
-    span attribution (internally); the bench entry is validated here."""
-    bench = tmp_path / "BENCH_r98.json"
+    span attribution (internally); the entry it writes is checked here."""
+    bench = tmp_path / "serving.json"
     out = _run([os.path.join(REPO, "examples", "serving_probe.py"),
                 "--requests", "12", "--bench-json", str(bench)])
     assert "serving probe OK" in out
     assert "tokens/s" in out
     doc = json.loads(bench.read_text())
     sv = doc["parsed"]["serving"]
-    assert sv["world"] == 8 and sv["completed"] == sv["requests"]
-    from test_bench_guard import scan_serving_entries
-    assert scan_serving_entries(str(tmp_path)) == []
+    assert sv["world"] == 8 and sv["slots"] >= 1
+    assert sv["completed"] == sv["requests"] - sv["rejected"] == 12
+    assert 0 < sv["batch_occupancy"] <= 1
 
 
 @pytest.mark.integration
@@ -145,8 +147,8 @@ def test_autoscale_probe_example_cpu(tmp_path):
     """Closed-loop chaos drill: kill@ forces a drain + shrink, slow@
     gets the rank auto-evicted, zero requests lost; the probe asserts
     the horovod_ctl_* families against its own /metrics endpoint
-    (internally) and the bench entry is validated here."""
-    bench = tmp_path / "BENCH_r99.json"
+    (internally) and the entry it writes is checked here."""
+    bench = tmp_path / "autoscale.json"
     out = _run([os.path.join(REPO, "examples", "autoscale_probe.py"),
                 "--requests", "32", "--bench-json", str(bench)])
     assert "autoscale probe OK" in out
@@ -154,9 +156,10 @@ def test_autoscale_probe_example_cpu(tmp_path):
     doc = json.loads(bench.read_text())
     a = doc["parsed"]["autoscale"]
     assert a["lost_requests"] == 0 and a["drain_leaked_pages"] == 0
-    assert a["final_tp"] < a["initial_tp"]
-    from test_bench_guard import scan_autoscale_entries
-    assert scan_autoscale_entries(str(tmp_path)) == []
+    assert 1 <= a["final_tp"] < a["initial_tp"]
+    assert a["completed"] == a["requests"] - a["rejected"]
+    assert a["decisions"]["shrink"] >= 1 and a["decisions"]["evict"] >= 1
+    assert a["dead_ranks"] and a["evicted_ranks"]
 
 
 @pytest.mark.integration
@@ -189,3 +192,69 @@ def test_tensorflow2_mnist_two_process():
                 os.path.join(REPO, "examples", "tensorflow2_mnist.py"),
                 "--steps", "12"])
     assert "avg final loss" in out
+
+
+# -- every example reaches only for names the package has -------------------
+
+def _package_names_reached(tree):
+    """``(lineno, "horovod_tpu.a.b")`` for every name of the package a
+    file reaches for: what it imports from it, and every attribute chain
+    rooted at an alias of it (``hvd.x.y``)."""
+    aliases, reached = {}, []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            for a in node.names:
+                if a.name.split(".")[0] == "horovod_tpu":
+                    reached.append((node.lineno, a.name))
+                    # without `as`, `import horovod_tpu.x` binds the top
+                    aliases[a.asname or "horovod_tpu"] = \
+                        a.name if a.asname else "horovod_tpu"
+        elif isinstance(node, ast.ImportFrom) and node.level == 0 \
+                and (node.module or "").split(".")[0] == "horovod_tpu":
+            for a in node.names:
+                reached.append((node.lineno, f"{node.module}.{a.name}"))
+                aliases[a.asname or a.name] = f"{node.module}.{a.name}"
+    for node in ast.walk(tree):
+        chain, cur = [], node
+        while isinstance(cur, ast.Attribute):
+            chain.append(cur.attr)
+            cur = cur.value
+        if chain and isinstance(cur, ast.Name) and cur.id in aliases:
+            reached.append((node.lineno, ".".join(
+                [aliases[cur.id]] + chain[::-1])))
+    return reached
+
+
+def _first_missing(dotted):
+    """The shortest prefix of ``dotted`` the package does not have, or
+    None.  A module or a class must have the next name (a submodule may
+    still need importing); below any other object (an instance, a
+    function) the walk stops: those are not the package's to promise."""
+    parts = dotted.split(".")
+    obj = importlib.import_module(parts[0])
+    for i, part in enumerate(parts[1:], 2):
+        if not (inspect.ismodule(obj) or inspect.isclass(obj)):
+            return None
+        if not hasattr(obj, part):
+            try:
+                importlib.import_module(".".join(parts[:i]))
+            except ImportError:
+                return ".".join(parts[:i])
+        obj = getattr(obj, part)
+    return None
+
+
+EXAMPLES = sorted(f for f in os.listdir(os.path.join(REPO, "examples"))
+                  if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("example", EXAMPLES)
+def test_example_reaches_only_for_names_the_package_has(example):
+    path = os.path.join(REPO, "examples", example)
+    with open(path) as f:
+        tree = ast.parse(f.read(), filename=path)
+    missing = sorted({(line, name) for line, name in
+                      _package_names_reached(tree)
+                      if _first_missing(name) is not None})
+    assert not missing, f"{example} reaches for names horovod_tpu does " \
+                        f"not have: {missing}"

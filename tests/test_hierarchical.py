@@ -228,3 +228,41 @@ def test_elastic_resize_changing_ici_extent_zeroes_counted(hier):
     # New layout: 340 pads to 512 (quantum lcm(256, 2)), shard 512/2.
     assert [tuple(r.shape) for r in new_res] == [(4, 2, 256)]
     assert all(float(jnp.abs(r).max()) == 0.0 for r in new_res)
+
+
+# ---------------------------------------------------------------------------
+# The leg plan's closed form.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("compression, dcn_bytes_per_element", [
+    (None, 4.0), ("fp16", 2.0), ("ici:none,dcn:topk:0.01", 0.04)])
+def test_hier_plan_dcn_leg_undercuts_flat_and_is_mesh_invariant(
+        compression, dcn_bytes_per_element):
+    """What the two-level decomposition is for, from the plan alone: the
+    DCN hop carries 1/n_ici of the bucket (times its codec's ratio), well
+    under the flat all-reduce's bytes; the per-leg bytes do not depend on
+    how many slices there are, and the padded bucket is the same for
+    every ICI extent that divides 256."""
+    from horovod_tpu.controller.fusion import plan_hier_legs
+    size = 1_000_003                    # odd on purpose: the pad shows
+    flat_bytes = size * 4
+
+    def legs(n_dcn, n_ici):
+        return {l.tag: l for l in plan_hier_legs(
+            size, "float32", n_dcn=n_dcn, n_ici=n_ici,
+            compression=compression)}
+
+    base = legs(2, 8)
+    assert set(base) == {"hier/ici_rs", "hier/dcn_ar", "hier/ici_ag"}
+    padded = base["hier/ici_rs"].elements
+    assert size <= padded < size + 256 and padded % 256 == 0
+    dcn = base["hier/dcn_ar"]
+    assert dcn.elements == padded // 8
+    assert dcn.nbytes == pytest.approx(
+        dcn.elements * dcn_bytes_per_element, rel=1e-3)
+    assert 0 < dcn.nbytes < flat_bytes / 8 + 256 * 4
+    for n_dcn in (4, 32):               # more slices: the same legs
+        other = legs(n_dcn, 8)
+        assert {t: (l.nbytes, l.elements) for t, l in other.items()} == \
+            {t: (l.nbytes, l.elements) for t, l in base.items()}
+    assert legs(2, 4)["hier/ici_rs"].elements == padded

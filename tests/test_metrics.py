@@ -9,7 +9,7 @@ HTTP endpoint end-to-end during a real CPU train loop, and
 Byte-for-byte contracts: the StepReport wire accounting must equal
 ``zero_report``'s figures on the ZeRO-1 path and
 ``wire_payload_bytes``-over-``ef_bucket_plan`` on the error-feedback
-path -- the same pricing ``bench.py`` records.
+path.
 """
 
 import json
@@ -177,20 +177,6 @@ def test_record_step_report_feeds_families():
     hist = reg.histogram("horovod_step_time_seconds").snapshot()
     assert hist["count"] == 1  # one dispatch covers 4 steps
     np.testing.assert_allclose(hist["sum"], 0.02)
-
-
-def test_bench_block_shape():
-    M.record_step_report(M.StepReport(
-        step=1, wall_time_s=0.01, exchanged_bytes=250,
-        uncompressed_bytes=1000))
-    block = M.bench_block()
-    assert block["step_total"] == 1
-    assert block["wire_bytes_total"] == 250
-    assert block["wire_bytes_per_step"] == 250
-    assert block["uncompressed_bytes_per_step"] == 1000
-    assert block["compression_ratio"] == 4.0
-    for key in ("families", "plan_cache_hits", "plan_cache_misses"):
-        assert block[key] >= 0
 
 
 # -- step report <-> exchange accounting -----------------------------------

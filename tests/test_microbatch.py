@@ -221,3 +221,13 @@ def test_reverse_bucket_plan_orders_last_leaves_first(hvd):
     # Same leaves covered overall, just different bucket order.
     cover = sorted(s.index for _, ls in rev.buffers for s in ls)
     assert cover == [0, 1, 2]
+
+
+@pytest.mark.parametrize("n", (1, 2, 8, 64, 256))
+def test_pad_quantum_is_mesh_size_invariant(n):
+    """Every mesh size that divides 256 pads a bucket to the same length,
+    so the planner's bytes are the payload's at each of them; a size that
+    does not divide it pads to its own multiple."""
+    from horovod_tpu.collectives.ops import microbatch_pad_quantum
+    assert microbatch_pad_quantum(n) == 256
+    assert microbatch_pad_quantum(3 * n) == 768

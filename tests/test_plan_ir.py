@@ -235,6 +235,9 @@ def test_schedule_legs_orders_bandwidth_and_respects_chains(hvd):
     dcn = next(l for l in legs if _fusion.leg_bandwidth(l) == "dcn")
     assert pos[id(dcn)] <= 1 + list(legs).index(dcn)
     assert _fusion.schedule_legs(program, mode="program") == program
+    # Either order carries the same legs: a byte-identical wire payload.
+    assert sorted(map(id, ordered)) == sorted(map(id, program))
+    assert sum(l.nbytes for l in ordered) == sum(l.nbytes for l in program)
     sim_sched = _fusion.simulate_issue(ordered)
     sim_prog = _fusion.simulate_issue(program)
     assert sim_sched["makespan_s"] <= sim_prog["makespan_s"] + 1e-12

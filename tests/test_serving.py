@@ -789,6 +789,8 @@ def test_engine_prefix_cache_end_to_end(base_params):
     assert 0.0 < report.prefix_hit_rate <= 1.0
     assert report.prefill_tokens_cached > 0
     assert 0.0 < report.prefill_flops_avoided < 1.0
+    assert report.prefix_hit_rate == pytest.approx(
+        report.prefix_hits / report.prefix_queries)
     # Drain-time leak proof: slots released during serve, the tree is
     # the only remaining holder; dropping it must empty the pool.
     eng._prefix.drop_all()
