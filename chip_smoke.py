@@ -349,9 +349,10 @@ def main() -> int:
           f"agree_within_5pct={abs(bur / fetch - 1) <= 0.05}")
     _check(all(b > 0 for b in a["peak_bytes"]),
            "a device reports zero peak memory after training")
-    # Flash fwd + dq + dkv per layer.
+    # One block holds T = 128: the head-group flash forward and its one
+    # backward (dq, dk, dv together) per layer.
     b = phase_bert(BERT_LARGE, jnp.bfloat16, batch_per_chip=32, seq=128,
-                   steps=10, mosaic_calls=3 * BERT_LARGE.num_layers)
+                   steps=10, mosaic_calls=2 * BERT_LARGE.num_layers)
     print("smoke B bert-large batch=32/chip seq=128 " + _fmt(b), flush=True)
     c = phase_server(LLAMA_1B, slots=8, page_size=16, max_len=1024,
                      prompt_lens=(32, 64, 128), output_lens=(16, 32),

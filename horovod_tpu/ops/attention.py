@@ -7,7 +7,15 @@ of that dependency is a Pallas flash-attention kernel pair (forward +
 backward, FlashAttention-2 schedule) tiled for the MXU, with an XLA
 reference implementation for CPU tests and as numerical ground truth.
 
-Design notes (see /opt/skills/guides/pallas_guide.md):
+Design notes (see /opt/skills/guides/pallas_guide.md).  ``flash_attention``
+has two kernel sets and picks one from the shapes it is given
+(``_flash_path``): the BLOCKED kernels (``hvd_flash_fwd``,
+``hvd_flash_bwd_dq``, ``hvd_flash_bwd_dkv``) whenever the queries or the
+keys take more than one block or segment ids ride along, the HEAD-GROUP
+kernels (``hvd_flash_hg_fwd``, ``hvd_flash_hg_bwd``) when one block holds
+the sequence.  The names are what the ops line of a device trace shows.
+
+Blocked kernels:
 
 * Grid ``(batch, heads, q_blocks, kv_blocks)`` -- the last grid dimension
   is sequential on TPU, so VMEM scratch (running max ``m``, normaliser
@@ -19,19 +27,52 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
   so only transient kernel I/O pays the lane broadcast.
 * Backward is the standard two-kernel FA2 split: ``dq`` accumulates over
   kv blocks, ``dk/dv`` accumulate over q blocks; ``delta = rowsum(dO*O)``
-  is precomputed by XLA (a trivially fused elementwise reduce).
+  is precomputed by XLA (a trivially fused elementwise reduce).  dk/dv
+  leave at query-head granularity in float32 and are group-summed and
+  cast outside.
+
+Head-group kernels (T = 128 pays 512 grid steps a call and 320 KB of
+broadcast statistics a head in the blocked set; PERF.md, PR 29):
+
+* Grid ``(batch, heads / G)``: a grid step takes G query heads of one
+  batch row with their kv heads -- whole GQA groups, the largest G whose
+  tiles fit ``_HEAD_GROUP_VMEM_BUDGET`` (``_head_group``, a function of
+  the shapes alone) -- and walks them in an unrolled loop.  One block
+  holds all keys, so there is no online-softmax state: a plain softmax.
+* The score tile is computed TRANSPOSED, keys on the sublanes and queries
+  on the lanes, so a head's statistics are one ``(1, T)`` row: ``lse``
+  crosses the boundary as ``(b, h, 1, T)`` float32, 512 bytes a head at
+  T = 128, and nothing is broadcast.  The VJP residual is the same
+  ``(b, h, t)`` logsumexp.
+* ONE backward kernel gives dq, dk and dv from one pass over the score
+  tiles.  ``delta`` is ``sum_k P * dP``, softmax's own backward over the
+  whole row (equal to ``rowsum(dO*O)``), so O is not read; a kv head's
+  query heads are summed in the kernel, so dk/dv leave in the operands'
+  type.
+
+Both sets:
+
+* Matmul operands: q.k^T and dO.v^T hand the MXU the operands in the type
+  they arrive in (head-group) or upcast to float32 (blocked); p and ds
+  are float32 values handed to ``dot`` at Mosaic's default precision,
+  which on the v5e is ONE bfloat16 pass whatever the operand type
+  (measured, PERF.md PR 29) -- so both sets compute the same products.
 * Causal masking is bottom-right aligned (query ``i`` sits at absolute
   position ``tk - tq + i``, the KV-cache/decode convention, matching
-  ``attention_reference``); whole blocks above the diagonal are predicated
-  off with ``@pl.when``.
-* Grouped-query attention broadcasts kv heads through the BlockSpec
-  ``index_map`` (query head ``h`` reads kv head ``h // rep``) instead of
-  materializing repeated K/V in HBM.
+  ``attention_reference``); in the blocked set whole blocks above the
+  diagonal are predicated off with ``@pl.when``.
+* Grouped-query attention never materializes repeated K/V in HBM: the
+  blocked set broadcasts kv heads through the BlockSpec ``index_map``
+  (query head ``h`` reads kv head ``h // rep``), the head-group set loads
+  a group's kv heads once a grid step.
+* Segment ids (and their DEAD rows: zero output, zero gradients) are the
+  blocked set's; without them every query sees a key and no row is dead.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -45,6 +86,8 @@ _LANES = 128          # TPU lane count: last-dim tile granularity.
 _MIN_BLOCK = 8        # f32 sublane tile; smallest sane seq block.
 _NEG_INF = -1e30      # Softmax mask value (finite: avoids NaN on empty rows).
 
+logger = logging.getLogger("horovod_tpu.ops")
+
 # Swept on a v5e on an earlier runtime (July-August 2026, not reproduced;
 # B1 H8 S8192 D128 causal bf16 fwd+bwd, within-run comparisons of
 # differential scan-chains): kv=512 beats kv=256 by ~19% at S=2048 and
@@ -52,7 +95,9 @@ _NEG_INF = -1e30      # Softmax mask value (finite: avoids NaN on empty rows).
 # swaps per q block and feeds the MXU longer runs; q=512 beats q=256 by
 # ~16% at S=8192 (5.18 -> 4.33 ms kernel time) and directionally at
 # S=2048 -- the bigger q tile amortizes the backward's dq/dk/dv re-reads.
-# Shorter sequences clamp the block to the sequence automatically.
+# Shorter sequences clamp the block to the sequence automatically; a
+# sequence that ONE block holds (T <= 512 at these defaults) is no longer
+# governed by this sweep: it runs the head-group kernels (PR 29).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_KV = 512
 
@@ -655,6 +700,9 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
     off = tk - tq
     grid = (batch, heads, nq, nk)
     has_seg = qseg is not None
+    path, group = _flash_path(q, k, has_seg=has_seg, bq=bq, bk=bk)
+    if path == "head_group":
+        return _hg_fwd(q, k, v, scale=scale, causal=causal, group=group)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                has_seg=has_seg, bq=bq, bk=bk, nk=nk,
                                off=off)
@@ -806,6 +854,10 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk):
     nq, nk = tq // bq, tk // bk
     off = tk - tq
     has_seg = qseg is not None
+    path, group = _flash_path(q, k, has_seg=has_seg, bq=bq, bk=bk)
+    if path == "head_group":
+        return _hg_bwd(q, k, v, lse, g, scale=scale, causal=causal,
+                       group=group)
 
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse_t = jnp.broadcast_to(lse[..., None], (*lse.shape, _LANES))
@@ -892,6 +944,190 @@ def _flash_bwd(res, g, *, scale, causal, bq, bk):
         dk_h = dk_h.reshape(batch, h_kv, rep, tk, d).sum(axis=2)
         dv_h = dv_h.reshape(batch, h_kv, rep, tk, d).sum(axis=2)
     return dq, dk_h.astype(k.dtype), dv_h.astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Head-group kernels: a sequence that one block holds.
+# ---------------------------------------------------------------------------
+
+# What a grid step of the head-group kernels may hold in VMEM, counted by
+# ``_head_group_bytes``: 10 of the 16 MiB a Mosaic kernel gets on a v5e,
+# the rest is the compiler's for what it spills.
+_HEAD_GROUP_VMEM_BUDGET = 10 * 1024 * 1024
+
+
+def _head_group_bytes(g_kv: int, rep: int, tq: int, tk: int, d: int,
+                      itemsize: int) -> int:
+    """VMEM bytes a grid step over ``g_kv`` kv heads (``g_kv * rep`` query
+    heads) holds in the backward kernel, the larger of the two: q, do, dq
+    and k, v, dk, dv tiles, each twice (the pipeline's double buffer) and
+    with the head dim padded to the 128 lanes; a statistics row a query
+    head; and the score-shaped float32 tiles of the one head being
+    computed (s/p, dp, ds and the transposed copy the dq matmul takes)."""
+    lanes = -(-d // _LANES) * _LANES
+    tq_lanes = -(-tq // _LANES) * _LANES
+    tiles = g_kv * (3 * rep * tq + 4 * tk) * lanes * itemsize
+    stats = g_kv * rep * _MIN_BLOCK * tq_lanes * 4
+    scores = 4 * tk * tq_lanes * 4
+    return 2 * (tiles + stats) + scores
+
+
+def _head_group(heads: int, kv_heads: int, tq: int, tk: int, d: int,
+                itemsize: int) -> int:
+    """Query heads a grid step of the head-group kernels takes: whole
+    kv-head groups, the largest divisor of ``kv_heads`` whose working set
+    is within ``_HEAD_GROUP_VMEM_BUDGET``; 0 when one kv head's group
+    alone is over it (the blocked kernels run).  A function of the
+    shapes alone."""
+    rep = heads // kv_heads
+    for g_kv in range(kv_heads, 0, -1):
+        if kv_heads % g_kv == 0 and _head_group_bytes(
+                g_kv, rep, tq, tk, d, itemsize) <= _HEAD_GROUP_VMEM_BUDGET:
+            return g_kv * rep
+    return 0
+
+
+def _flash_path(q, k, *, has_seg: bool, bq: int, bk: int):
+    """``("head_group", G)`` when one block holds the query sequence and
+    one the keys, no segment ids ride along and a group fits VMEM;
+    ``("blocked", 0)`` (the online-softmax kernels) otherwise.  Decided
+    at trace time from shapes; logged once a lowering."""
+    tq, tk = q.shape[2], k.shape[2]
+    group = 0
+    if not has_seg and _block(tq, bq) == tq and _block(tk, bk) == tk:
+        group = _head_group(q.shape[1], k.shape[1], tq, tk, q.shape[3],
+                            q.dtype.itemsize)
+    path = ("head_group", group) if group else ("blocked", 0)
+    logger.debug("flash attention q%s k%s blocks (%d, %d): %s kernels, "
+                 "%d heads a grid step", q.shape, k.shape, bq, bk, *path)
+    return path
+
+
+def _scores_t(q, k, *, scale, causal, off):
+    """The score tile TRANSPOSED, ``(tk, tq)``: keys on the sublanes and
+    queries on the lanes, so a query's statistics are one lane of a
+    ``(1, tq)`` row.  q and k go to the MXU in the type they arrive in
+    (a bfloat16 product is exact in float32)."""
+    s_t = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32) * scale
+    if causal:
+        keys = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+        queries = jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1) + off
+        s_t = jnp.where(queries >= keys, s_t, _NEG_INF)
+    return s_t
+
+
+def _hg_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
+                   rep, off):
+    """Grid ``(batch, head groups)``: a plain softmax a head over the one
+    block, no state carried.  Every query sees a key (causal is
+    bottom-right aligned and ``tq <= tk``), so no row is dead."""
+    for j in range(k_ref.shape[1]):
+        k, v = k_ref[0, j], v_ref[0, j].astype(jnp.float32)
+        for g in range(j * rep, (j + 1) * rep):
+            s_t = _scores_t(q_ref[0, g], k, scale=scale, causal=causal,
+                            off=off)
+            m = jnp.max(s_t, axis=0, keepdims=True)           # (1, tq)
+            p_t = jnp.exp(s_t - m)                            # (tk, tq)
+            l = jnp.sum(p_t, axis=0, keepdims=True)
+            o = jax.lax.dot_general(
+                p_t * (1.0 / l), v, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_ref[0, g] = o.astype(o_ref.dtype)
+            lse_ref[0, g] = m + jnp.log(l)
+
+
+def _hg_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   dq_ref, dk_ref, dv_ref, *, scale, causal, rep, off):
+    """dq, dk and dv of a head group in one pass over its score tiles.
+    With the whole row of keys in one tile, softmax's backward is the
+    textbook one: ``delta = sum_k P * dP``, a sublane reduction of two
+    float32 tiles the kernel holds anyway (equal to the blocked kernels'
+    ``rowsum(dO * O)``, which needs O and a pass over all kv blocks).  A
+    kv head's ``rep`` query heads sum into its dk/dv here, so all three
+    leave in the operands' type."""
+    f32 = jnp.float32
+    for j in range(k_ref.shape[1]):
+        k, v = k_ref[0, j], v_ref[0, j]
+        dk = dv = None
+        for g in range(j * rep, (j + 1) * rep):
+            q, do = q_ref[0, g], do_ref[0, g]
+            s_t = _scores_t(q, k, scale=scale, causal=causal, off=off)
+            p_t = jnp.exp(s_t - lse_ref[0, g])                # (tk, tq)
+            dp_t = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                       preferred_element_type=f32)
+            delta = jnp.sum(p_t * dp_t, axis=0, keepdims=True)  # (1, tq)
+            ds_t = p_t * (dp_t - delta) * scale
+            dv_g = jnp.dot(p_t, do.astype(f32), preferred_element_type=f32)
+            dk_g = jnp.dot(ds_t, q.astype(f32), preferred_element_type=f32)
+            dv = dv_g if dv is None else dv + dv_g
+            dk = dk_g if dk is None else dk + dk_g
+            dq = jax.lax.dot_general(ds_t, k.astype(f32),
+                                     (((0,), (0,)), ((), ())),
+                                     preferred_element_type=f32)
+            dq_ref[0, g] = dq.astype(dq_ref.dtype)
+        dk_ref[0, j] = dk.astype(dk_ref.dtype)
+        dv_ref[0, j] = dv.astype(dv_ref.dtype)
+
+
+def _hg_specs(group, rep, tq, tk, d):
+    q_spec = pl.BlockSpec((1, group, tq, d), lambda b, g: (b, g, 0, 0))
+    kv_spec = pl.BlockSpec((1, group // rep, tk, d),
+                           lambda b, g: (b, g, 0, 0))
+    # One (1, tq) row a head, the queries on the lanes.
+    lse_spec = pl.BlockSpec((1, group, 1, tq), lambda b, g: (b, g, 0, 0))
+    return q_spec, kv_spec, lse_spec
+
+
+# The head loop is unrolled (a loop inside the kernel costs 2-3x on the
+# chip: nothing overlaps across its iterations), so a kernel body is G
+# heads long.  Under ``jax.jit`` a model's layers share ONE trace and one
+# lowered function of it; XLA inlines the calls.
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "group"))
+def _hg_fwd(q, k, v, *, scale, causal, group):
+    batch, heads, tq, d = q.shape
+    tk = k.shape[2]
+    rep = heads // k.shape[1]
+    q_spec, kv_spec, lse_spec = _hg_specs(group, rep, tq, tk, d)
+    with jax.named_scope("hvd_flash_hg_fwd"):
+        o, lse = pl.pallas_call(
+            functools.partial(_hg_fwd_kernel, scale=scale, causal=causal,
+                              rep=rep, off=tk - tq),
+            grid=(batch, heads // group),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, lse_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((batch, heads, 1, tq), jnp.float32),
+            ],
+            name="hvd_flash_hg_fwd",
+            interpret=_pallas.interpret_mode(),
+        )(q, k, v)
+    return o, lse[:, :, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "causal", "group"))
+def _hg_bwd(q, k, v, lse, g, *, scale, causal, group):
+    batch, heads, tq, d = q.shape
+    tk = k.shape[2]
+    rep = heads // k.shape[1]
+    q_spec, kv_spec, lse_spec = _hg_specs(group, rep, tq, tk, d)
+    with jax.named_scope("hvd_flash_hg_bwd"):
+        return pl.pallas_call(
+            functools.partial(_hg_bwd_kernel, scale=scale, causal=causal,
+                              rep=rep, off=tk - tq),
+            grid=(batch, heads // group),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, lse_spec],
+            out_specs=[q_spec, kv_spec, kv_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            name="hvd_flash_hg_bwd",
+            interpret=_pallas.interpret_mode(),
+        )(q, k, v, g, lse[:, :, None, :])
+
 
 
 # ---------------------------------------------------------------------------

@@ -53,7 +53,11 @@ KERNEL_CONTRACTS = {
         "collectives": (),
         "wire_delta_bytes": 0,
         "site": "ops.attention.flash_attention",
-        "note": "flash fwd/bwd kernels; exchange untouched",
+        "note": "flash fwd/bwd kernels, two sets chosen by shape at "
+                "trace time (ops.attention._flash_path): blocked "
+                "(hvd_flash_fwd / _bwd_dq / _bwd_dkv) and, where one "
+                "block holds the sequence, head-group (hvd_flash_hg_fwd "
+                "/ _hg_bwd); exchange untouched",
     },
     "flash_decode": {
         "collectives": (),

@@ -10,7 +10,9 @@ all-reduces, this worker's output changes and the test fails.
 
 ``<topology> kernels``: compiles each Pallas family that ``auto``
 enables on TPU through Mosaic (``interpret=False``) at the shapes
-``chip_smoke.py`` runs, and prints ``{case: mosaic_call_count}``.  A
+``chip_smoke.py`` and the benchmark's cells run, and prints ``{case:
+mosaic_call_count}`` and, under ``head_group``, the cases that took
+flash attention's head-group kernels.  A
 kernel Mosaic refuses raises here, in the sandbox, before any chip time
 is spent on it.
 
@@ -110,7 +112,7 @@ def kernels(topology: str) -> int:
             q, pool, page_table, layer=3, lengths=lengths, value_dim=512,
             scale=192 ** -0.5)
 
-    def mla_prefill(q, k, v):
+    def prefill(q, k, v):
         return attention.flash_attention(q, k, v, causal=True)
 
     def gmm(tm):
@@ -138,14 +140,27 @@ def kernels(topology: str) -> int:
             spec((64, 544), jnp.int32), spec((64,), jnp.int32)]),
         # Its expanded prefill: keys 192 wide, values padded to 192.
         "flash_mla_prefill_8k": (
-            mla_prefill, [spec((1, 32, 8192, 192), jnp.bfloat16)] * 3),
+            prefill, [spec((1, 32, 8192, 192), jnp.bfloat16)] * 3),
         # 256 experts of 2048 x 768: a decode round's 64 x 8 pairs in
         # tiles of 16 rows, an 8,192-token prefill's in tiles of 128.
         "moe_gmm_decode": (gmm(16), gmm_args(4352, 16)),
         "moe_gmm_prefill_8k": (gmm(128), gmm_args(98048, 128)),
-        # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.
+        # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.  One block
+        # holds the sequence: the head-group kernels, forward and one
+        # backward, all 16 heads a grid step.
         "flash_bert_large": (
             flash_fwd_bwd, [spec((32, 16, 128, 64), jnp.bfloat16)] * 3),
+        # Mistral-7B's prefill of a 512-token prompt (GQA 32/8, d 128,
+        # causal): the widest head-group step a served cell takes; its
+        # 1,024-token prompt is two blocks and keeps the blocked kernel.
+        "flash_mistral_prefill_512": (prefill, [
+            spec((1, 32, 512, 128), jnp.bfloat16),
+            spec((1, 8, 512, 128), jnp.bfloat16),
+            spec((1, 8, 512, 128), jnp.bfloat16)]),
+        "flash_mistral_prefill_1024": (prefill, [
+            spec((1, 32, 1024, 128), jnp.bfloat16),
+            spec((1, 8, 1024, 128), jnp.bfloat16),
+            spec((1, 8, 1024, 128), jnp.bfloat16)]),
         # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
         "flash_decode_b8": (decode, [
             spec((8, 16, 1, 128), jnp.float32),
@@ -153,11 +168,15 @@ def kernels(topology: str) -> int:
             spec((8, 8, 1024, 128), jnp.float32),
             spec((8,), jnp.int32)]),
     }
-    out = {}
+    out, head_group = {}, []
     for name, (fn, args) in cases.items():
         lowered = jax.jit(fn).lower(*args)
         lowered.compile()   # Mosaic refusals raise here
-        out[name] = lowered.as_text().count("tpu_custom_call")
+        text = lowered.as_text()
+        out[name] = text.count("tpu_custom_call")
+        if "hvd_flash_hg_fwd" in text:
+            head_group.append(name)
+    out["head_group"] = head_group
     print(json.dumps(out))
     return 0
 

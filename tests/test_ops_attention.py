@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import attention as _attn
 from horovod_tpu.ops import attention_reference, flash_attention
 from horovod_tpu.ops.attention import _flash
 
@@ -526,3 +527,240 @@ def test_pallas_kernel_contracts_are_collective_free():
         assert contract["collectives"] == ()
         assert contract["wire_delta_bytes"] == 0
         assert contract["site"]
+
+
+# ---------------------------------------------------------------------------
+# Head-group kernels: a sequence that one block holds (PR 29).
+# ---------------------------------------------------------------------------
+
+# (T, d, heads, kv_heads, causal): the BERT-Large cell's shape, a short
+# one, Mistral's class (GQA 4:1, d = 128, causal) and two tiles of keys.
+_HG_SHAPES = [(128, 64, 16, 16, False), (64, 64, 4, 4, False),
+              (128, 128, 8, 2, True), (256, 64, 4, 4, False)]
+_HG_IDS = ["bert_large", "t64", "mistral_class", "t256"]
+_HG_TOL = {jnp.float32: dict(atol=5e-5, rtol=5e-5),
+           jnp.bfloat16: dict(atol=3e-2, rtol=3e-2)}
+
+
+def _hg_case(shape, dtype, batch=1):
+    t, d, h, h_kv, causal = shape
+    keys = jax.random.split(jax.random.PRNGKey(t + d + h), 4)
+    q = _rand((batch, h, t, d), keys[0], dtype)
+    k = _rand((batch, h_kv, t, d), keys[1], dtype)
+    v = _rand((batch, h_kv, t, d), keys[2], dtype)
+    w = _rand((batch, h, t, d), keys[3])
+    assert _attn._flash_path(q, k, has_seg=False, bq=512, bk=512)[0] \
+        == "head_group"
+
+    def flash(q, k, v):
+        return _flash(q, k, v, d ** -0.5, causal, 512, 512)
+
+    def ref(q, k, v):
+        rep = h // h_kv
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        return attention_reference(
+            f32[0], jnp.repeat(f32[1], rep, axis=1),
+            jnp.repeat(f32[2], rep, axis=1), causal=causal)
+
+    return (q, k, v), w, flash, ref
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", _HG_SHAPES, ids=_HG_IDS)
+def test_head_group_forward_matches_reference(shape, dtype):
+    args, _, flash, ref = _hg_case(shape, dtype, batch=2)
+    got = flash(*args)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref(*args)), **_HG_TOL[dtype])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", _HG_SHAPES, ids=_HG_IDS)
+def test_head_group_grads_match_reference(shape, dtype):
+    """dq, dk and dv, each in its operand's type (a shared kv head's
+    query heads are summed inside the kernel)."""
+    args, w, flash, ref = _hg_case(shape, dtype)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w)
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(*args)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(*args)
+    for g, r, x in zip(got, want, args):
+        assert g.shape == x.shape and g.dtype == dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(r, np.float32),
+                                   **_HG_TOL[dtype])
+
+
+def test_head_group_cross_length_causal():
+    """tq < tk in one block each: bottom-right aligned, as the blocked
+    kernels and the reference."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = _rand((2, 4, 16, 32), keys[0])
+    k = _rand((2, 2, 64, 32), keys[1])
+    v = _rand((2, 2, 64, 32), keys[2])
+    assert _attn._flash_path(q, k, has_seg=False, bq=512, bk=512) \
+        == ("head_group", 4)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))  # noqa: E731
+    flash = lambda q, k, v: _flash(q, k, v, 0.2, True, 512, 512)  # noqa
+    ref = lambda q, k, v: attention_reference(  # noqa: E731
+        q, jnp.repeat(k, 2, axis=1), jnp.repeat(v, 2, axis=1),
+        causal=True, scale=0.2)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def _shapes(q, k, dtype=jnp.bfloat16):
+    return (jax.ShapeDtypeStruct(q, dtype), jax.ShapeDtypeStruct(k, dtype))
+
+
+@pytest.mark.parametrize("q,k,blocks,has_seg,want", [
+    # One block holds the sequence: the head-group kernels.
+    ((32, 16, 128, 64), (32, 16, 128, 64), (512, 512), False, "head_group"),
+    ((1, 32, 128, 128), (1, 8, 128, 128), (512, 512), False, "head_group"),
+    ((1, 32, 512, 128), (1, 8, 512, 128), (512, 512), False, "head_group"),
+    ((2, 4, 16, 32), (2, 2, 64, 32), (512, 512), False, "head_group"),
+    # More than one block, at the served widths (Mistral's 1,024 prompt,
+    # JoyAI's 1,024 and 8,192 with keys 192 wide): the blocked kernels.
+    ((1, 32, 1024, 128), (1, 8, 1024, 128), (512, 512), False, "blocked"),
+    ((1, 32, 1024, 192), (1, 32, 1024, 192), (512, 512), False, "blocked"),
+    ((1, 32, 8192, 192), (1, 32, 8192, 192), (512, 512), False, "blocked"),
+    # Blocks asked smaller than the sequence; one side in two blocks.
+    ((2, 4, 64, 32), (2, 4, 64, 32), (32, 32), False, "blocked"),
+    ((2, 4, 64, 32), (2, 4, 1024, 32), (512, 512), False, "blocked"),
+    # Segment ids are declined.
+    ((32, 16, 128, 64), (32, 16, 128, 64), (512, 512), True, "blocked"),
+    # One kv head's group alone is over the budget (float32, T = 512).
+    ((1, 32, 512, 128), (1, 8, 512, 128), (512, 512), None, "blocked"),
+])
+def test_flash_path_is_chosen_by_shape(q, k, blocks, has_seg, want):
+    dtype = jnp.float32 if has_seg is None else jnp.bfloat16
+    path, group = _attn._flash_path(*_shapes(q, k, dtype),
+                                    has_seg=bool(has_seg),
+                                    bq=blocks[0], bk=blocks[1])
+    assert path == want
+    assert (group > 0) == (want == "head_group")
+
+
+def test_flash_paths_carry_their_kernel_names():
+    """The names the ops line of a device trace shows say which path a
+    shape took: ``hvd_flash_hg_*`` for one block, ``hvd_flash_*`` above."""
+    import re
+
+    def names(t, block):
+        x = jax.ShapeDtypeStruct((1, 2, t, 16), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: _flash(q, k, v, 0.25, True, block,
+                                   block).sum(), argnums=(0, 1, 2)))(x, x, x)
+        return sorted(set(re.findall(r"name=(hvd_\w+)", str(jaxpr))))
+
+    assert names(64, 512) == ["hvd_flash_hg_bwd", "hvd_flash_hg_fwd"]
+    assert names(64, 32) == ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq",
+                             "hvd_flash_fwd"]
+
+
+@pytest.mark.parametrize("heads,kv_heads,tq,tk,d,itemsize", [
+    (16, 16, 128, 128, 64, 2),      # BERT-Large's cell: all 16 heads
+    (16, 16, 128, 128, 64, 4),
+    (32, 8, 128, 128, 128, 2),      # Mistral's prompts of 128 ... 512
+    (32, 8, 256, 256, 128, 2),
+    (32, 8, 512, 512, 128, 2),
+    (16, 8, 384, 384, 128, 2),
+    (16, 16, 512, 512, 64, 2),
+    (32, 4, 256, 256, 128, 2),
+    (8, 8, 8, 512, 256, 4),
+])
+def test_head_group_is_a_function_of_shapes_within_the_budget(
+        heads, kv_heads, tq, tk, d, itemsize):
+    rep = heads // kv_heads
+    group = _attn._head_group(heads, kv_heads, tq, tk, d, itemsize)
+    assert group == _attn._head_group(heads, kv_heads, tq, tk, d, itemsize)
+    assert group > 0 and group % rep == 0 and heads % group == 0
+    g_kv = group // rep
+    assert _attn._head_group_bytes(g_kv, rep, tq, tk, d, itemsize) \
+        <= _attn._HEAD_GROUP_VMEM_BUDGET < 16 * 2 ** 20
+    # The largest: the next divisor of the kv heads is over the budget.
+    bigger = [g for g in range(g_kv + 1, kv_heads + 1) if kv_heads % g == 0]
+    if bigger:
+        assert _attn._head_group_bytes(bigger[0], rep, tq, tk, d,
+                                       itemsize) \
+            > _attn._HEAD_GROUP_VMEM_BUDGET
+    # The batch is not an argument, and the group does not grow with the
+    # sequence or the head dim.
+    assert _attn._head_group(heads, kv_heads, 2 * tq, 2 * tk, d,
+                             itemsize) <= group
+    assert _attn._head_group(heads, kv_heads, tq, tk, 2 * d,
+                             itemsize) <= group
+
+
+def test_head_group_cell_shape_takes_all_sixteen_heads():
+    assert _attn._head_group(16, 16, 128, 128, 64, 2) == 16
+    assert _attn._head_group(32, 8, 512, 512, 128, 2) == 4
+    assert _attn._head_group(32, 8, 512, 512, 128, 4) == 0
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_one_block_with_segment_ids_runs_blocked_kernels(causal):
+    """Segment ids are declined by the head-group path: a packed batch
+    that one block holds still goes through the online-softmax kernels
+    and matches the reference, gradients and dead rows included."""
+    t = 128
+    keys = jax.random.split(jax.random.PRNGKey(12), 4)
+    q = _rand((2, 2, t, 16), keys[0])
+    k = _rand((2, 2, t, 16), keys[1])
+    v = _rand((2, 2, t, 16), keys[2])
+    seg = _packed_segments(keys[3], 2, t)
+    assert _attn._flash_path(q, k, has_seg=True, bq=t, bk=t) \
+        == ("blocked", 0)
+
+    def flash(q, k, v):
+        return _attn._flash_seg(q, k, v, seg, seg, 0.25, causal, t, t)
+
+    def ref(q, k, v):
+        return attention_reference(q, k, v, causal=causal, scale=0.25,
+                                   segment_ids=seg)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))  # noqa: E731
+    for a, b in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def test_public_api_reaches_head_group_kernels(monkeypatch):
+    """``flash_attention`` itself, kernels forced on: BERT's call (no
+    mask, 16 heads of 64) takes the head-group path and logs its choice
+    once a kernel at debug level."""
+    import logging
+    monkeypatch.setenv("HOROVOD_PALLAS_FLASH", "1")
+    keys = jax.random.split(jax.random.PRNGKey(13), 3)
+    q, k, v = (_rand((1, 16, 128, 64), kk, jnp.bfloat16) for kk in keys)
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    logger = logging.getLogger("horovod_tpu.ops")
+    logger.addHandler(handler)
+    old = logger.level
+    logger.setLevel(logging.DEBUG)
+    try:
+        out = flash_attention(q, k, v)
+    finally:
+        logger.setLevel(old)
+        logger.removeHandler(handler)
+    ref = attention_reference(*(x.astype(jnp.float32) for x in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=3e-2, rtol=3e-2)
+    said = [r.getMessage() for r in records]
+    assert len(said) == 1 and "head_group kernels, 16 heads" in said[0]

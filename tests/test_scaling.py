@@ -172,12 +172,18 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     assert set(pallas.registered_kernels()) - pallas._AUTO_XLA == {
         "flash", "flash_decode", "mla_decode", "moe_gmm"}
     out = _topology_worker("v5e:2x2", "kernels")
-    # fwd + dq + dkv; one split-KV call; the latent-attention decode out
-    # of a 64-slot page pool and its expanded 8k prefill (keys 192 wide);
-    # the grouped expert matmul (gate and up fused, then down) at a
-    # decode round's and at an 8k prefill's row tiles.
-    assert out == {"flash_bert_large": 3, "flash_decode_b8": 1,
-                   "mla_decode_b64": 1, "flash_mla_prefill_8k": 1,
+    # BERT-Large at T = 128 is one block: the head-group forward and ONE
+    # backward (dq, dk, dv together), as is Mistral's 512-token prefill;
+    # its 1,024-token prefill and the 8k prefill (keys 192 wide) keep the
+    # blocked forward.  One split-KV call; the latent-attention decode
+    # out of a 64-slot page pool; the grouped expert matmul (gate and up
+    # fused, then down) at a decode round's and at an 8k prefill's row
+    # tiles.
+    assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
+                   "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
+                   "head_group": ["flash_bert_large",
+                                  "flash_mistral_prefill_512"],
+                   "flash_decode_b8": 1, "mla_decode_b64": 1,
                    "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2}
 
 
