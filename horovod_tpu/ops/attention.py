@@ -443,7 +443,8 @@ def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
 
 def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
                        q_ref, pool_ref, o_ref, buf, sem, m_scr, l_scr,
-                       acc_scr, *, layer, scale, ppb, page, value_dim):
+                       acc_scr, *, layer, scale, ppb, page, value_dim,
+                       kv_heads=1, value_off=0):
     """Grid ``(items,)``, sequential: item ``i`` is block ``blk_ref[i]``
     (``ppb`` pages) of row ``slot_ref[i]``; the first ``n_ref[0]`` items
     are live, a row's items follow one another, and the online-softmax
@@ -451,10 +452,32 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
     While item ``i`` computes out of one half of ``buf``, the pages of
     item ``i + 1`` are on their way into the other.  The operands go to
     the MXU in their own type (bfloat16 on the chip) with float32
-    accumulation."""
+    accumulation.
+
+    ``kv_heads == 1``: every query head reads the whole row as its key
+    and the row's first ``value_dim`` columns as its value (the latent).
+    ``kv_heads > 1``: the row is ``kv_heads`` keys of the queries' width
+    side by side and, from column ``value_off``, as many values of
+    ``value_dim``; query head ``i`` belongs to key/value head ``i //
+    (heads / kv_heads)``.  EVERY query head is multiplied against each
+    key/value head's tile-aligned columns and a row mask keeps its own:
+    the MXU's cost is the key tiles it is loaded with, not the 8 rows
+    that stream past them, and no array is ever cut below a tile."""
     i = pl.program_id(0)
     n_items = n_ref[0]
     bk = ppb * page
+    heads, dk = q_ref.shape[1], q_ref.shape[2]
+    rep = heads // kv_heads
+
+    def own(parts):
+        """Row ``i`` of part ``i // rep``."""
+        if kv_heads == 1:
+            return parts[0]
+        head = jax.lax.broadcasted_iota(jnp.int32, parts[0].shape, 0)
+        out = parts[-1]
+        for j in range(kv_heads - 2, -1, -1):
+            out = jnp.where(head < (j + 1) * rep, parts[j], out)
+        return out
 
     def live_pages(item):
         left = len_ref[slot_ref[item]] - blk_ref[item] * bk
@@ -506,8 +529,11 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
         kv = buf[half].reshape(bk, buf.shape[-1])     # (bk, w)
-        s = jax.lax.dot_general(q_ref[0], kv, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = own([jax.lax.dot_general(
+            q_ref[0], kv if kv_heads == 1 else kv[:, j * dk:(j + 1) * dk],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) for j in range(kv_heads)]
+        ) * scale
         cols = blk * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         live = cols < length
         s = jnp.where(live, s, _NEG_INF)
@@ -516,9 +542,11 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
         p = jnp.where(live, jnp.exp(s - m_new), 0.0)  # (h, bk)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :value_dim],
+        acc_scr[:] = acc_scr[:] * alpha + own([jax.lax.dot_general(
+            p.astype(kv.dtype),
+            kv[:, value_off + j * value_dim:value_off + (j + 1) * value_dim],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            for j in range(kv_heads)])
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -529,8 +557,10 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
 
 
 def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
-                scale: float, ppb: int):
-    b, h, w = q.shape
+                scale: float, ppb: int, *, kv_heads: int = 1,
+                value_off: int = 0, name: str = "hvd_mla_decode"):
+    b, h, dk = q.shape
+    w = pool.shape[3]
     page = pool.shape[2]
     pps = page_table.shape[1]
     ppb = min(ppb, pps)
@@ -559,13 +589,16 @@ def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
 
     kernel = functools.partial(_mla_decode_kernel, layer=layer, scale=scale,
                                ppb=ppb, page=page, value_dim=value_dim)
-    with jax.named_scope("hvd_mla_decode"):
+    if kv_heads > 1:
+        kernel = functools.partial(kernel, kv_heads=kv_heads,
+                                   value_off=value_off)
+    with jax.named_scope(name):
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(items,),
-                in_specs=[pl.BlockSpec((1, h, w), row),
+                in_specs=[pl.BlockSpec((1, h, dk), row),
                           pl.BlockSpec(memory_space=pl.ANY)],
                 out_specs=pl.BlockSpec((1, h, value_dim), row),
                 scratch_shapes=[
@@ -579,10 +612,56 @@ def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
             out_shape=jax.ShapeDtypeStruct((b, h, value_dim), jnp.float32),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
-            name="hvd_mla_decode",
+            name=name,
             interpret=_pallas.interpret_mode(),
         )(lengths, page_table.astype(jnp.int32), slot, blk, n_items,
           q.astype(pool.dtype), pool)
+
+
+def cca_decode_attention(q, pool, page_table, *, layer: int, lengths,
+                         kv_heads: int, scale: float,
+                         force_reference: bool = False):
+    """Single-token grouped-query decode attention over rows that hold
+    every key/value head side by side, read straight out of the page
+    pool.
+
+    ``q``: ``(b, h, d)``; ``pool``: ``(layers, pages, page_size, 2 *
+    kv_heads * d)``, a token's row ``[k_0 .. k_{kv-1} | v_0 .. v_{kv-1}]``
+    with no head dim (a ``(kv_heads, d)`` entry of two heads would be
+    padded fourfold by the (8, 128) tiling); ``page_table``, ``lengths``
+    as :func:`mla_decode_attention`.  Query head ``i`` attends to
+    key/value head ``i // (h / kv_heads)``; the result is ``(b, h, d)``
+    float32, exactly zero for a row with ``lengths == 0``.
+
+    The kernel is ``hvd_mla_decode``'s walk of the page table under the
+    name ``hvd_cca_decode`` (same family switch): one copy of a block's
+    live pages serves every head, keys and values alike."""
+    b, h, d = q.shape
+    if pool.ndim != 4 or pool.shape[3] != 2 * kv_heads * d \
+            or h % kv_heads or page_table.shape[0] != b:
+        raise ValueError(
+            f"cca_decode_attention: q {q.shape}, pool {pool.shape}, "
+            f"page_table {page_table.shape} and kv_heads {kv_heads} do not "
+            "fit together")
+    if lengths.shape != (b,):
+        raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
+    if not force_reference and _pallas.pallas_enabled("mla_decode"):
+        return _mla_decode(q, pool, page_table, lengths, int(layer), d,
+                           float(scale), MLA_PAGES_PER_BLOCK,
+                           kv_heads=kv_heads, value_off=kv_heads * d,
+                           name="hvd_cca_decode")
+    s = page_table.shape[1] * pool.shape[2]
+    kv = pool[layer][page_table].reshape(b, s, 2, kv_heads, d).astype(
+        q.dtype)
+    qg = q.reshape(b, kv_heads, h // kv_heads, d)
+    logits = jnp.einsum("bgrd,bsgd->bgrs", qg, kv[:, :, 0],
+                        preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(s)[None, None, None, :] < lengths[:, None, None, None]
+    logits = jnp.where(live, logits, _NEG_INF)
+    probs = jnp.where(live, jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("bgrs,bsgd->bgrd", probs.astype(kv.dtype),
+                      kv[:, :, 1],
+                      preferred_element_type=jnp.float32).reshape(b, h, d)
 
 
 def _causal_mask(s, qi, ki, bq, bk, off):

@@ -1,20 +1,28 @@
-"""Routed experts for a served step: a router that drops nothing and a
+"""Routed experts for a served step: a layer that drops nothing and a
 grouped matmul over the (token, choice) pairs that tokens chose.
 
 :func:`moe_ffn` is the inference expert layer (``parallel/moe.py`` is the
 Switch-style TRAINING layer, with a capacity that drops tokens, and is
 left alone):
 
-* :func:`route` scores in float32 (sigmoid), chooses ``top_k`` of ALL the
-  experts by ``score + bias`` and weighs by the renormalised, scaled
-  scores -- the bias chooses and never weighs;
+* the ROUTER IS THE CALLER'S: ``moe_ffn`` takes a :class:`Routing` (each
+  token's chosen experts and what each adds) and never scores anything.
+  Two routers call it: :func:`route` (one matrix, sigmoid scores in
+  float32, ``top_k`` of ALL the experts by ``score + bias``, weights
+  renormalised and scaled) and :func:`route_top1` (scores the caller's
+  own network made, softmax, the one expert ``score + bias`` puts first,
+  weighed by its score) -- in both the bias chooses and never weighs;
 * the ``tokens * top_k`` pairs are sorted by expert and laid out in
   row tiles of ``tm`` that never straddle two experts (each expert's run
   is padded up to a tile), so the matmul kernel is a plain tiled product
   whose weight block is picked by a scalar-prefetched expert id a tile.
   Work grows with ``tokens * top_k`` (plus under one tile an expert
   touched), never with ``tokens * experts``; no capacity, nothing
-  dropped;
+  dropped.  Where an expert's whole ``[k, n]`` block double-buffered
+  would not leave VMEM room to pipeline (``_column_block``, from shapes
+  alone), the kernel takes the block in column slices: a second grid
+  axis, outermost, so that an expert's tiles still share one fetch of
+  each slice;
 * the layer is told which experts it HOLDS (``first``, and the leading
   dim of the stacked weights): it routes over all of them, computes its
   own experts' part and adds the shared expert only where
@@ -42,6 +50,10 @@ _HI = jax.lax.Precision.HIGHEST
 _MIN_TILE = 16        # bf16 sublane tile: the smallest row tile.
 _MAX_TILE = 128       # the v5e MXU's rows.
 _VMEM_LIMIT = 48 * 1024 * 1024
+# What a grid step's weight blocks may take, both buffers of every
+# weight: a third of the limit, so that the fetch of the next block has
+# room beside the one in use, the row tiles and Mosaic's own scratch.
+_WEIGHT_BLOCK_BUDGET = 16 * 1024 * 1024
 
 
 class Routing(NamedTuple):
@@ -61,9 +73,22 @@ def route(h, w_router, bias, *, top_k: int, scale: float) -> Routing:
     return Routing(idx.astype(jnp.int32), g)
 
 
+def route_top1(logits, bias) -> Routing:
+    """Softmax in float32 over scores the caller's own network made
+    (``[tokens, experts]``), the ONE expert ``score + bias`` puts first,
+    weighed by its score."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    idx = jnp.argmax(s + bias.astype(jnp.float32), axis=-1)[:, None]
+    return Routing(idx.astype(jnp.int32),
+                   jnp.take_along_axis(s, idx, axis=-1))
+
+
 def row_tile(pairs: int, experts: int) -> int:
     """Rows a tile: about the mean run of an expert, as a power of two
-    between the bf16 sublane tile and the MXU's rows."""
+    between the bf16 sublane tile and the MXU's rows.  At 16 experts: a
+    decode round of 96 slots choosing one expert each (6 rows an expert)
+    gets 16, the smallest; so does a 256-token prompt (16 rows an
+    expert); 512 tokens get 32 and 2,048 or more get 128."""
     want = max(pairs // max(experts, 1), 1)
     tm = _MIN_TILE
     while tm < min(want, _MAX_TILE):
@@ -126,13 +151,14 @@ def _padded_rows(pairs: int, held: int, tm: int) -> int:
     return -(-worst // tm) * tm
 
 
-def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool):
-    """One row tile against its expert's weight block.  ``swiglu``:
-    ``silu(x @ w_gate) * (x @ w_up)`` in one pass over ``x``."""
+def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool, tile_axis: int):
+    """One row tile against its expert's weight block (or a column slice
+    of it).  ``swiglu``: ``silu(x @ w_gate) * (x @ w_up)`` in one pass
+    over ``x``."""
     del te_ref
     o_ref = refs[-1]
 
-    @pl.when(pl.program_id(0) < na_ref[0])
+    @pl.when(pl.program_id(tile_axis) < na_ref[0])
     def _tile():
         x = x_ref[...]
         y = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
@@ -142,33 +168,66 @@ def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool):
         o_ref[...] = y.astype(o_ref.dtype)
 
 
+def _column_block(kdim: int, n: int, weights: int, itemsize: int) -> int:
+    """Columns of an expert's ``[kdim, n]`` block a grid step takes: all
+    ``n`` where every weight's block, double-buffered, fits
+    ``_WEIGHT_BLOCK_BUDGET`` (256 experts of ``[2048, 768]``: 12.6 MB for
+    gate and up); else the widest whole-lane-tile divisor of ``n`` that
+    does (16 experts of ``[2048, 2048]``: 32 MB whole, so 1,024)."""
+    def fits(bn):
+        return 2 * weights * kdim * bn * itemsize <= _WEIGHT_BLOCK_BUDGET
+
+    if fits(n) or n % 128:
+        return n
+    bn = n
+    while bn > 128 and not (n % bn == 0 and bn % 128 == 0 and fits(bn)):
+        bn -= 128
+    return bn
+
+
 def _gmm_pallas(x, ws, tile_expert, active, tm: int):
     rows, kdim = x.shape
     n = ws[0].shape[2]
+    bn = _column_block(kdim, n, len(ws), x.dtype.itemsize)
 
     def last(i, na):
         return jnp.minimum(i, jnp.maximum(na[0] - 1, 0))
 
     # Tiles past the last active one name its blocks again: nothing is
     # fetched for them and the kernel body is predicated off.
-    w_spec = pl.BlockSpec(
-        (None, kdim, n), lambda i, te, na: (te[last(i, na)], 0, 0))
-    kernel = functools.partial(_gmm_kernel, swiglu=len(ws) == 2)
+    if bn == n:
+        grid = (rows // tm,)
+        w_spec = pl.BlockSpec(
+            (None, kdim, n), lambda i, te, na: (te[last(i, na)], 0, 0))
+        x_spec = pl.BlockSpec(
+            (tm, kdim), lambda i, te, na: (last(i, na), 0))
+        o_spec = pl.BlockSpec((tm, n), lambda i, te, na: (last(i, na), 0))
+    else:
+        # Column slices outermost: the tiles of one expert follow one
+        # another under one slice, which is fetched once for them all;
+        # the row tiles (small) are read once a slice.
+        grid = (n // bn, rows // tm)
+        w_spec = pl.BlockSpec(
+            (None, kdim, bn),
+            lambda j, i, te, na: (te[last(i, na)], 0, j))
+        x_spec = pl.BlockSpec(
+            (tm, kdim), lambda j, i, te, na: (last(i, na), 0))
+        o_spec = pl.BlockSpec(
+            (tm, bn), lambda j, i, te, na: (last(i, na), j))
+    kernel = functools.partial(_gmm_kernel, swiglu=len(ws) == 2,
+                               tile_axis=len(grid) - 1)
     with jax.named_scope("hvd_moe_gmm"):
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(rows // tm,),
-                in_specs=[pl.BlockSpec(
-                    (tm, kdim), lambda i, te, na: (last(i, na), 0))]
-                + [w_spec] * len(ws),
-                out_specs=pl.BlockSpec(
-                    (tm, n), lambda i, te, na: (last(i, na), 0)),
+                grid=grid,
+                in_specs=[x_spec] + [w_spec] * len(ws),
+                out_specs=o_spec,
             ),
             out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
+                dimension_semantics=("arbitrary",) * len(grid),
                 vmem_limit_bytes=_VMEM_LIMIT),
             name="hvd_moe_gmm",
             interpret=_pallas.interpret_mode(),
@@ -210,32 +269,27 @@ def grouped_matmul(x, ws, tile_expert, active, *, tm: int,
     return _gmm_reference(x, tuple(ws), tile_expert, active, tm)
 
 
-def moe_ffn(h, params, *, top_k: int, scale: float, num_experts: int,
+def moe_ffn(h, params, routing: Routing, *, num_experts: int,
             first: int = 0, with_shared: bool = True, live=None,
-            h_router=None, force_reference: bool = False):
-    """The routed layer over ``h`` ``[tokens, d]``; ``h_router``: the
-    same rows as the router reads them (float32, before they were rounded
-    to ``h``'s type), ``h`` itself where None.
+            force_reference: bool = False):
+    """The routed layer over ``h`` ``[tokens, d]`` under the caller's
+    ``routing`` (``[tokens, top_k]`` experts of ``num_experts`` and
+    weights: :func:`route`, :func:`route_top1`, or any other router).
 
-    ``params``: ``router/kernel`` ``[d, num_experts]``,
-    ``router/e_score_correction_bias`` ``[num_experts]``, ``experts``
-    (``w_gate``, ``w_up`` ``[held, d, f]``, ``w_down`` ``[held, f, d]``:
-    the experts ``first .. first + held - 1``) and ``shared`` (a SwiGLU's
-    three kernels).  Returns ``(y, counts)``: this share's part of the
-    layer's output (every held expert's, and the shared expert's where
+    ``params``: ``experts`` (``w_gate``, ``w_up`` ``[held, d, f]``,
+    ``w_down`` ``[held, f, d]``: the experts ``first .. first + held -
+    1``) and, where ``with_shared``, ``shared`` (a SwiGLU's three
+    kernels).  Returns ``(y, counts)``: this share's part of the layer's
+    output (every held expert's, and the shared expert's where
     ``with_shared``) in float32, unrounded, and the ``[num_experts]``
     count of pairs routed to each expert by the ``live`` rows.
     """
     dtype = h.dtype
-    t = h.shape[0]
+    t, top_k = routing.experts.shape
     ex = params["experts"]
     held = ex["w_gate"].shape[0]
-    r = route(h if h_router is None else h_router,
-              params["router"]["kernel"],
-              params["router"]["e_score_correction_bias"],
-              top_k=top_k, scale=scale)
     tm = row_tile(t * top_k, num_experts)
-    lay = layout(r.experts, num_experts, tm, first=first, held=held,
+    lay = layout(routing.experts, num_experts, tm, first=first, held=held,
                  live=live)
     xs = h[lay.src]
     act = grouped_matmul(xs, (ex["w_gate"].astype(dtype),
@@ -247,7 +301,7 @@ def moe_ffn(h, params, *, top_k: int, scale: float, num_experts: int,
                         force_reference=force_reference)
     picked = jnp.where(lay.held[..., None], ys[lay.dest].astype(jnp.float32),
                        0.0)
-    y = jnp.einsum("tkd,tk->td", picked, r.weights)
+    y = jnp.einsum("tkd,tk->td", picked, routing.weights)
     if with_shared:
         sh = params["shared"]
         gate = h @ sh["w_gate"]["kernel"].astype(dtype)

@@ -70,8 +70,11 @@ KERNEL_CONTRACTS = {
         "collectives": (),
         "wire_delta_bytes": 0,
         "site": "ops.attention.mla_decode_attention",
-        "note": "absorbed latent-attention decode kernel over the latent "
-                "page pool; tp = 1, no exchange",
+        "note": "decode kernel that walks the page table of a pool with no "
+                "head dim: absorbed latent attention (hvd_mla_decode) and, "
+                "general over key/value heads, ops.attention."
+                "cca_decode_attention (hvd_cca_decode); tp = 1, no "
+                "exchange",
     },
     "moe_gmm": {
         "collectives": (),

@@ -255,7 +255,7 @@ class ServingEngine:
             num_layers=spec.num_layers, slots=self.slots,
             page_size=self.page_size, max_len=self.max_len,
             dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
-            page=spec.page)
+            page=spec.page, slot_state=spec.slot_state)
         self.cache = PagedKVCache(self.cache_config,
                                   spec.pool_sharding(mesh))
         # Admission must price the widest step a slot can take: k drafts
@@ -365,12 +365,21 @@ class ServingEngine:
                 else:
                     aid = jnp.int32(req.adapter_id) \
                         if self.adapters is not None else None
-                    logits, kl, vl = self._prefill(
+                    logits, kl, vl, *state = self._prefill(
                         self.params, prompt_dev[None], self.adapters, aid)
                     kl = kl[:, 0]
                     vl = None if vl is None else vl[:, 0]
             with rec.phase("prefill.write_kv", rid=req.rid):
                 self.cache.write_prefill(slot, kl, vl, start=matched)
+            if self.spec.slot_state is not None:
+                # What the slot keeps beside its pages: the prompt's
+                # trailing rows (a hit or a chunk would need them of the
+                # prefix's last token, and is refused by the spec).
+                rows = state[0][:, 0]
+                with rec.phase("prefill.write_state", rid=req.rid,
+                               state_bytes=rows.size
+                               * self.cache.state.dtype.itemsize):
+                    self.cache.write_state(slot, rows)
             with rec.phase("prefill.sample_fetch", rid=req.rid):
                 first = int(greedy_sample(logits[:, -1, :])[0])
         return first
@@ -501,10 +510,15 @@ class ServingEngine:
             # Beside the pools a step may carry device state of its own
             # (donated in, handed back) and return a few numbers of the
             # round after it: those ride on the finite screen's fetch.
-            n_state = len(self._step_state)
-            out = self.step(*args, *self._step_state)
+            # The cache's slot state (where the model has one) leads
+            # them: the step advances the rows of its live slots.
+            own = () if cache.state is None else (cache.state,)
+            n_state = len(own) + len(self._step_state)
+            out = self.step(*args, *own, *self._step_state)
             logits, cache.k, cache.v = out[:3]
-            self._step_state = out[3:3 + n_state]
+            if own:
+                cache.state = out[3]
+            self._step_state = out[3 + len(own):3 + n_state]
             with phase("decode.sample_fetch"):
                 sampled = np.asarray(greedy_sample(logits))  # sync point
             # Per-slot SDC screen: one reduced scalar per row (sum
@@ -675,10 +689,11 @@ class ServingEngine:
                                     leg="serving_reprefill"):
             aid = jnp.int32(req.adapter_id) if self.adapters is not None \
                 else None
-            _, kl, vl = self._prefill(
+            _, kl, vl, *state = self._prefill(
                 self.params, jnp.asarray(full)[None], self.adapters, aid)
             self.cache.write_prefill(
-                slot, kl[:, 0], None if vl is None else vl[:, 0])
+                slot, kl[:, 0], None if vl is None else vl[:, 0],
+                state=state[0][:, 0] if state else None)
         if self.drafter is not None:
             self.drafter.re_prefill(slot, req)
         return int(req.tokens[-1])

@@ -140,6 +140,11 @@ class DecodeWorker:
         self.engine = engine
         self.kv = kv
         self.busy_s = 0.0
+        if engine.cache.state is not None:
+            raise NotImplementedError(
+                "handoff: the KV plane moves pages only, and this model's "
+                "slots keep state beside their pages "
+                f"({engine.spec.slot_state_holds})")
         # The auditor's serving configs read step metadata; tag the
         # role so a fleet trace distinguishes decode meshes from the
         # colocated baseline.

@@ -97,6 +97,9 @@ class CacheConfig:
     # ``head_dim`` (which are then filled in where both pools hold the
     # same two dims, and stay None elsewhere).
     page: Optional[Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]] = None
+    # Values a slot keeps a layer beside its pages (``LayerSpec.
+    # slot_state``); None: no such state.
+    slot_state: Optional[int] = None
 
     def __post_init__(self):
         heads = (self.num_kv_heads, self.head_dim)
@@ -182,8 +185,27 @@ def _pool_set(pool, values, pages, offs=None):
         return pool.at[:, pages, offs].set(values)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _state_set(state, rows, slot):
+    """``state.at[:, slot].set(rows)`` in place (``slot`` traced: one
+    program for every slot)."""
+    with jax.named_scope("hvd_slot_state_write"):
+        return jax.lax.dynamic_update_slice(
+            state, rows[:, None].astype(state.dtype), (0, slot, 0))
+
+
 class PagedKVCache:
     """Device page pool + host page table / free list for one model.
+
+    Two kinds of per-sequence state live here.  PAGES: what each token
+    holds, ``[layers, pages, page_size, *entry]`` a pool, mapped through
+    the page table.  SLOT STATE (``CacheConfig.slot_state``; None for a
+    model whose pages are all it has): what the sequence itself holds
+    beside them, ``state`` ``[layers, slots, width]``, one row a slot --
+    written by :meth:`write_state` from the prefill's trailing rows,
+    advanced by the decode step, cleared by :meth:`free_slot`.  It is
+    owned like a pool: every program that writes it is handed the array,
+    donated, and returns its successor.
 
     ``k`` and ``v`` have ONE owner at a time: every program that writes
     a pool (the decode/verify step, :meth:`write_prefill`, the
@@ -208,6 +230,16 @@ class PagedKVCache:
         self.sharding = sharding
         self.k = k
         self.v = v
+        self.state = None
+        if c.slot_state is not None:
+            self.state = jnp.zeros(
+                (c.num_layers, c.slots, c.slot_state), jnp.dtype(c.dtype))
+            if sharding is not None:
+                # Whole on every chip, and committed like the pools: a
+                # step compiles once, whoever wrote the array last.
+                self.state = jax.device_put(
+                    self.state, jax.sharding.NamedSharding(
+                        sharding.mesh, jax.sharding.PartitionSpec()))
         # Host-side logical view.  Unallocated table entries point at
         # page 0 -- harmless, reads beyond ``lengths`` are masked.
         self.page_table = np.zeros((c.slots, c.pages_per_slot), np.int32)
@@ -514,6 +546,12 @@ class PagedKVCache:
         self._allocated[slot] = 0
         if self.compress:
             self._cheld[slot] = 0
+        if self.state is not None and self.lengths[slot]:
+            # Unlike a page's contents the slot's row is not behind a
+            # length mask: the next sequence here starts from zeros.
+            c = self.config
+            self.write_state(slot, jnp.zeros(
+                (c.num_layers, c.slot_state), jnp.dtype(c.dtype)))
         self.lengths[slot] = 0
 
     def release_all(self) -> int:
@@ -689,8 +727,14 @@ class PagedKVCache:
         return cpid
 
     # -- device writes -----------------------------------------------------
+    def write_state(self, slot: int, rows) -> None:
+        """The slot's row of the slot state, ``[num_layers, width]``:
+        what the sequence holds beside its pages once its last
+        prefilled token is in."""
+        self.state = _state_set(self.state, rows, jnp.int32(slot))
+
     def write_prefill(self, slot: int, k_layers, v_layers,
-                      start: int = 0) -> None:
+                      start: int = 0, state=None) -> None:
         """Scatter a prefilled prompt's K/V into the slot's pages.
 
         ``k_layers``/``v_layers``: ``[num_layers, t, num_kv_heads,
@@ -701,7 +745,9 @@ class PagedKVCache:
         start + t``.  ``start`` is the prefix-cache seam: a matched
         prefix's pages are already attached and immutable, only the
         tail ``[start:]`` is scattered (through the copy-on-write
-        guard, so a partial shared page is cloned first)."""
+        guard, so a partial shared page is cloned first).  ``state``:
+        the prefill's trailing rows for :meth:`write_state`, where the
+        caller does not write them itself."""
         c = self.config
         t = int(k_layers.shape[1])
         self.reserve(slot, start + t, writable_from=start)
@@ -713,6 +759,8 @@ class PagedKVCache:
         self.k = _pool_set(self.k, k_layers.astype(dt), pages, offs)
         if self.v is not None:
             self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
+        if state is not None:
+            self.write_state(slot, state)
         self.lengths[slot] = start + t
 
     def grow(self, slot: int) -> None:

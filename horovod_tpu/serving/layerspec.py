@@ -4,12 +4,14 @@
 ``CacheConfig`` from a :class:`LayerSpec`, never from a model's own
 config fields: what kind of attention a layer has, what ONE token holds
 in a page of each of the cache's two pools, what kind of feed-forward
-each layer has, whether the head is the embedding transposed, which of
-the engine's features the model's programs do not have, and the
-functions that build those programs.
+each layer has, what a SLOT keeps beside its pages (state of the
+sequence that no token's page entry holds), whether the head is the
+embedding transposed, which of the engine's features the model's
+programs do not have, and the functions that build those programs.
 
 A config describes itself through a ``layer_spec()`` method
-(:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`); a
+(:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`,
+:class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`); a
 ``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
 described here, by the functions of ``serving/decode.py``.
 """
@@ -26,7 +28,7 @@ FEATURES = ("tp", "lora", "spec_decode", "kv_compress", "prefill_chunk",
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    attention: str                    # "gqa" | "mla"
+    attention: str                    # "gqa" | "mla" | "cca"
     # Trailing dims of ONE token's entry in the first and the second pool
     # (``[layers, pages, page_size, *dims]``), and what each holds; the
     # second None: the model keeps one pool.
@@ -40,7 +42,9 @@ class LayerSpec:
     tp_page_dim: Optional[int]
     # ``prefill(params, tokens, *, dtype, adapters, adapter_id,
     # lora_alpha, past) -> (logits, first_layers, second_layers)``, each
-    # ``[layers, batch, t, *page entry]`` (None for a pool not kept).
+    # ``[layers, batch, t, *page entry]`` (None for a pool not kept);
+    # with ``slot_state`` a fourth: ``[layers, batch, slot_state]``, what
+    # the slot keeps once the prompt's last token is in.
     prefill: Callable[..., Any]
     # ``build_step(mesh, *, slots, page_size, pages_per_slot, dtype,
     # width, with_lora, lora_alpha, compress) -> ServingDecodeStep``.
@@ -56,9 +60,17 @@ class LayerSpec:
     # Names of the whole numbers the step returns last, in one int32
     # vector, about the round it ran: attributes of ``decode.bookkeep``.
     step_tells: Tuple[str, ...] = ()
+    # Values a slot keeps a layer BESIDE its pages (None: pages are all
+    # a sequence has) and what they are.  ``PagedKVCache`` holds them as
+    # ``[layers, slots, slot_state]``: the prefill hands back the row of
+    # the prompt's last token, the decode step (which takes the array
+    # after ``active`` and returns it after the pools, donated) rewrites
+    # the rows of its live slots, release clears a row.
+    slot_state: Optional[int] = None
+    slot_state_holds: Optional[str] = None
 
     def __post_init__(self):
-        if self.attention not in ("gqa", "mla"):
+        if self.attention not in ("gqa", "mla", "cca"):
             raise ValueError(f"attention kind {self.attention!r}")
         if set(self.ffn) - {"dense", "moe"}:
             raise ValueError(f"feed-forward kinds {sorted(set(self.ffn))}")
@@ -66,6 +78,11 @@ class LayerSpec:
                 h is None for h in self.page_holds] or self.page[0] is None:
             raise ValueError(
                 f"pools {self.page} and what they hold {self.page_holds}")
+        if (self.slot_state is None) != (self.slot_state_holds is None) \
+                or (self.slot_state is not None and self.slot_state < 1):
+            raise ValueError(
+                f"slot state {self.slot_state} and what it holds "
+                f"{self.slot_state_holds!r}")
 
     @property
     def num_layers(self) -> int:

@@ -23,7 +23,8 @@ residual stream, RMSNorm everywhere, no biases):
   space, ``hvd_mla_decode`` over the latents, the result carried out
   through ``W_kvb``'s value half) -- the same mathematics.
 * feed-forward: SwiGLU in the leading dense layers, then
-  :func:`horovod_tpu.ops.moe.moe_ffn` (sigmoid router, ``top_k`` of all
+  :func:`horovod_tpu.ops.moe.moe_ffn` under
+  :func:`~horovod_tpu.ops.moe.route` (sigmoid router, ``top_k`` of all
   the experts by score + bias, nothing dropped, a shared expert).
 * an untied head; the prefill reads out its last row only.
 * the residual stream is float32: every matmul takes operands in the
@@ -47,9 +48,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import flash_attention, mla_decode_attention
-from ..ops.moe import moe_ffn
+from ..ops import moe as _moe
+from . import stepparts
 from .decode import ServingDecodeStep, _dense, _rmsnorm
 from .layerspec import LayerSpec
+from .stepparts import dense_out as _dense_out, lane_pad as _lane_pad
 
 @dataclasses.dataclass(frozen=True)
 class MlaMoeConfig:
@@ -126,24 +129,8 @@ class MlaMoeConfig:
                                 + why_page},
             step_state=lambda: (jnp.zeros(
                 (cfg.moe_layers, cfg.num_experts), jnp.int32),),
-            publish_state=_publish_routed,
+            publish_state=lambda state: stepparts.publish_routed(state[0]),
             step_tells=("experts_touched",))
-
-
-def _publish_routed(state) -> None:
-    """The device's ``[moe layers, experts]`` histogram of routed
-    (token, choice) pairs into the registry, once a ``serve``."""
-    import numpy as np
-
-    from ..timeline import metrics as _metrics
-    counter = _metrics.registry().counter(
-        "moe.tokens_routed",
-        "(token, choice) pairs a decode round routed to each expert",
-        labelnames=("layer", "expert"))
-    hist = np.asarray(state[0])
-    for layer, expert in zip(*np.nonzero(hist)):
-        counter.labels(layer=int(layer), expert=int(expert)).inc(
-            int(hist[layer, expert]))
 
 
 # ---------------------------------------------------------------------------
@@ -244,18 +231,6 @@ def _rope_interleaved(x, positions, theta: float):
                            axis=-1).astype(x.dtype)
 
 
-def _lane_pad(x, width: int):
-    return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
-                   + ((0, width - x.shape[-1]),))
-
-
-def _dense_out(x, node, dtype):
-    """A branch's closing projection: operands in ``dtype``, the result
-    float32, unrounded, for the residual stream."""
-    return jnp.dot(x.astype(dtype), node["kernel"].astype(dtype),
-                   preferred_element_type=jnp.float32)
-
-
 def _swiglu(h, node, dtype):
     gate = _dense(h, node["w_gate"], dtype)
     up = _dense(h, node["w_up"], dtype)
@@ -293,15 +268,16 @@ def _ffn(x, blk, cfg, li, dtype, *, live=None, first_expert=0,
     h = h32.astype(dtype)
     if not cfg.is_moe(li):
         return _swiglu(h, blk["mlp"], dtype), None
-    return moe_ffn(h, blk["moe"], h_router=h32,
-                   top_k=cfg.experts_per_token, scale=cfg.routed_scale,
-                   num_experts=cfg.num_experts, first=first_expert,
-                   with_shared=with_shared, live=live)
-
-
-def _readout(x, p, cfg, dtype):
-    x = _rmsnorm(x, p["final_norm"]["scale"], dtype, cfg.rms_eps)
-    return _dense_out(x, p["lm_head"], dtype)
+    router = blk["moe"]["router"]
+    # The router is looked up where it lives at call time: the benchmark's
+    # tests put a faulty one in its place there.
+    routing = _moe.route(h32, router["kernel"],
+                         router["e_score_correction_bias"],
+                         top_k=cfg.experts_per_token,
+                         scale=cfg.routed_scale)
+    return _moe.moe_ffn(h, blk["moe"], routing,
+                        num_experts=cfg.num_experts, first=first_expert,
+                        with_shared=with_shared, live=live)
 
 
 # ---------------------------------------------------------------------------
@@ -362,7 +338,8 @@ def prefill_forward(params, config: MlaMoeConfig, tokens, positions=None,
         x = x + y.reshape(b, t, -1)
     if last_only:
         x = x[:, -1:]
-    return _readout(x, p, cfg, dtype), jnp.stack(rows), None
+    return (stepparts.readout(x, p, cfg.rms_eps, dtype, tied=False),
+            jnp.stack(rows), None)
 
 
 # ---------------------------------------------------------------------------
@@ -393,65 +370,40 @@ def build_decode_step(config: MlaMoeConfig, mesh, *, slots: int,
     """
     del lora_alpha
     cfg = config
-    if mesh is not None and mesh.devices.size > 1:
-        raise NotImplementedError(
-            f"latent-attention decode is tp = 1 only, got a mesh of "
-            f"{mesh.devices.size}")
-    if width != 1 or with_lora or compress:
-        raise NotImplementedError(
-            "latent-attention decode has no verify step, no adapter banks "
-            "and no fp8 pool")
+    stepparts.refuse_beyond_one_chip(
+        "latent-attention", mesh, width=width, with_lora=with_lora,
+        compress=compress)
     dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     heads = cfg.num_heads
-    scratch = slots * pages_per_slot
 
-    def mla_moe_step(params, pool, no_pool, tokens, positions, page_table,
-                     active, routed):
-        p = params["params"] if "params" in params else params
-        s = tokens.shape[0]
-        x = p["tok_embed"][tokens].astype(jnp.float32)           # [S, d]
-        # Every slot writes (fixed batch shape); idle slots write the
-        # pool's trailing scratch page.
-        page = jnp.where(
-            active, page_table[jnp.arange(s), positions // page_size],
-            scratch)
-        off = positions % page_size
-        lengths = jnp.where(active, positions + 1, 0)
-        touched = jnp.zeros((), jnp.int32)
-        for li in range(cfg.num_layers):
-            blk = p[f"layer_{li}"]
-            attn = blk["attn"]
-            h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
-            q = _queries(h, attn, cfg, dtype)                    # [S, H, .]
-            row = _latents(h, attn, cfg, positions, dtype)
-            pool = pool.at[li, page, off].set(row.astype(pool.dtype))
-            q_pe = _rope_interleaved(q[..., dn:], positions[:, None],
-                                     cfg.rope_theta)
-            w_kvb = attn["kv_b"]["kernel"].astype(dtype).reshape(
-                r, heads, dn + dv)
-            q_lat = jnp.einsum("shn,rhn->shr", q[..., :dn],
-                               w_kvb[..., :dn]).astype(dtype)
-            o_lat = mla_decode_attention(
-                _lane_pad(jnp.concatenate([q_lat, q_pe], axis=-1),
-                          cfg.page_width), pool, page_table,
-                layer=li, lengths=lengths, value_dim=r,
-                scale=cfg.softmax_scale)
-            o = jnp.einsum("shr,rhv->shv", o_lat.astype(dtype),
-                           w_kvb[..., dn:]).astype(dtype)
-            x = x + _dense_out(o.reshape(s, heads * dv), attn["wo"],
-                               dtype)
-            y, counts = _ffn(x, blk, cfg, li, dtype, live=active)
-            x = x + y
-            if counts is not None:
-                mi = li - cfg.first_dense_layers
-                routed = routed.at[mi].add(counts)
-                touched = touched + jnp.sum(counts > 0, dtype=jnp.int32)
-        return (_readout(x, p, cfg, dtype), pool, no_pool, routed,
-                touched[None])
+    def layer(li, blk, x, pool, carried, local, rnd):
+        s = x.shape[0]
+        attn = blk["attn"]
+        h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
+        q = _queries(h, attn, cfg, dtype)                        # [S, H, .]
+        row = _latents(h, attn, cfg, rnd.positions, dtype)
+        pool = pool.at[li, rnd.page, rnd.off].set(row.astype(pool.dtype))
+        q_pe = _rope_interleaved(q[..., dn:], rnd.positions[:, None],
+                                 cfg.rope_theta)
+        w_kvb = attn["kv_b"]["kernel"].astype(dtype).reshape(
+            r, heads, dn + dv)
+        q_lat = jnp.einsum("shn,rhn->shr", q[..., :dn],
+                           w_kvb[..., :dn]).astype(dtype)
+        o_lat = mla_decode_attention(
+            _lane_pad(jnp.concatenate([q_lat, q_pe], axis=-1),
+                      cfg.page_width), pool, rnd.page_table,
+            layer=li, lengths=rnd.lengths, value_dim=r,
+            scale=cfg.softmax_scale)
+        o = jnp.einsum("shr,rhv->shv", o_lat.astype(dtype),
+                       w_kvb[..., dn:]).astype(dtype)
+        x = x + _dense_out(o.reshape(s, heads * dv), attn["wo"], dtype)
+        y, counts = _ffn(x, blk, cfg, li, dtype, live=rnd.active)
+        return (x + y, pool, carried, local, li - cfg.first_dense_layers,
+                counts)
 
-    fn = jax.jit(mla_moe_step, donate_argnums=(1, 7))
-    meta = {"kind": "serving_decode", "arch": "mla_moe", "world": 1,
-            "tp": 1, "num_layers": cfg.num_layers, "d_model": cfg.d_model,
-            "slots": int(slots), "dtype": str(jnp.dtype(dtype)),
-            "lora": False, "compress": False}
-    return ServingDecodeStep(fn, meta)
+    return stepparts.build_one_chip_step(
+        "mla_moe_step", layer, num_layers=cfg.num_layers, eps=cfg.rms_eps,
+        tied=False, page_size=page_size, scratch=slots * pages_per_slot,
+        dtype=dtype, tells=("experts_touched",), carried=0,
+        meta={"arch": "mla_moe", "d_model": cfg.d_model,
+              "slots": int(slots)})

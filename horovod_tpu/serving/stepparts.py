@@ -1,0 +1,159 @@
+"""What the one-chip decode steps share, written once.
+
+``mla_moe.py`` and ``cca_moe.py`` build their decode programs from the
+same parts: the embedding read into a float32 residual stream, where a
+round's token lands in the page pool, a loop over the layers that hands
+each one the pool (and whatever else the model carries), the readout
+over a tied or an untied head, the running ``[routed layers, experts]``
+histogram with what the step tells of its round, and the jit that
+donates every operand the step rewrites.  ``decode.py``'s step is a
+``shard_map`` over the ``tp`` axis with adapter banks and an fp8 pool and
+keeps its own loop; it shares ``_dense`` and ``_rmsnorm``, which live
+there.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, NamedTuple, Sequence
+
+import jax
+import jax.numpy as jnp
+
+from .decode import ServingDecodeStep, _rmsnorm
+
+
+class Round(NamedTuple):
+    """Where one decode round reads and writes, a slot."""
+    positions: jax.Array    # [slots] the token's position
+    page: jax.Array         # [slots] the page its row goes to
+    off: jax.Array          # [slots] the row within that page
+    lengths: jax.Array      # [slots] live tokens once it is written
+    page_table: jax.Array   # [slots, pages_per_slot]
+    active: jax.Array       # [slots] bool
+
+
+def lane_pad(x, width: int):
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1)
+                   + ((0, width - x.shape[-1]),))
+
+
+def dense_out(x, node, dtype):
+    """A branch's closing projection: operands in ``dtype``, the result
+    float32, unrounded, for the residual stream."""
+    return jnp.dot(x.astype(dtype), node["kernel"].astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def embed(p, tokens):
+    """The residual stream's first value: float32."""
+    return p["tok_embed"][tokens].astype(jnp.float32)
+
+
+def readout(x, p, eps: float, dtype, *, tied: bool):
+    """Float32 logits over the final norm: against ``lm_head`` or, tied,
+    against the embedding's own rows."""
+    x = _rmsnorm(x, p["final_norm"]["scale"], dtype, eps)
+    if not tied:
+        return dense_out(x, p["lm_head"], dtype)
+    return jax.lax.dot_general(
+        x, p["tok_embed"].astype(dtype), (((x.ndim - 1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def round_of(positions, page_table, active, *, page_size: int,
+             scratch: int) -> Round:
+    """Every slot writes (fixed batch shape); idle slots write the pool's
+    trailing scratch page."""
+    s = positions.shape[0]
+    page = jnp.where(
+        active, page_table[jnp.arange(s), positions // page_size], scratch)
+    return Round(positions, page, positions % page_size,
+                 jnp.where(active, positions + 1, 0), page_table, active)
+
+
+TELLS = {
+    # Experts, summed over the routed layers, that a live slot chose.
+    "experts_touched": lambda counts: jnp.sum(counts > 0, dtype=jnp.int32),
+    # The most rows one expert took, the largest over the routed layers.
+    "peak_expert_rows": lambda counts: jnp.max(counts).astype(jnp.int32),
+}
+_JOIN = {"experts_touched": jnp.add, "peak_expert_rows": jnp.maximum}
+
+
+def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
+                        eps: float, tied: bool, page_size: int,
+                        scratch: int, dtype, tells: Sequence[str],
+                        carried: int, meta: dict,
+                        local: Callable = lambda x: None
+                        ) -> ServingDecodeStep:
+    """The jitted step ``name``::
+
+        logits, pool, None, *carried, routed, told = step(
+            params, pool, None, tokens, positions, page_table, active,
+            *carried, routed)
+
+    ``layer(li, blk, x, pool, carried, local, rnd) -> (x, pool, carried,
+    local, routed index or None, counts or None)`` computes layer ``li``
+    for every slot: it writes the round's row into ``pool``, may rewrite
+    the ``carried`` arrays (a model's own state beside the pool: per-slot
+    rows, say) and ``local`` (what crosses the layers within the round
+    and no further; ``local(x)`` before the first), and for a routed
+    layer returns its row of the histogram and the ``[experts]`` counts
+    of its live slots.  ``routed`` is the
+    running ``[routed layers, experts]`` int32 histogram; ``told`` holds
+    the ``tells`` of the round, one int32 each.  The pool, the carried
+    arrays and ``routed`` are donated and their successors returned.
+    """
+    def step(params, pool, no_pool, tokens, positions, page_table, active,
+             *state):
+        carry, routed = tuple(state[:carried]), state[carried]
+        p = params["params"] if "params" in params else params
+        x = embed(p, tokens)                                     # [S, d]
+        rnd = round_of(positions, page_table, active,
+                       page_size=page_size, scratch=scratch)
+        told = [jnp.zeros((), jnp.int32) for _ in tells]
+        within = local(x)
+        for li in range(num_layers):
+            x, pool, carry, within, mi, counts = layer(
+                li, p[f"layer_{li}"], x, pool, carry, within, rnd)
+            if counts is not None:
+                routed = routed.at[mi].add(counts)
+                told = [_JOIN[t](was, TELLS[t](counts))
+                        for t, was in zip(tells, told)]
+        return (readout(x, p, eps, dtype, tied=tied), pool, no_pool,
+                *carry, routed, jnp.stack(told))
+
+    step.__name__ = step.__qualname__ = name
+    fn = jax.jit(step, donate_argnums=(1,) + tuple(
+        range(7, 8 + carried)))
+    return ServingDecodeStep(fn, dict(
+        meta, kind="serving_decode", world=1, tp=1, num_layers=num_layers,
+        dtype=str(jnp.dtype(dtype)), lora=False, compress=False))
+
+
+def refuse_beyond_one_chip(what: str, mesh, *, width: int, with_lora: bool,
+                           compress: bool) -> None:
+    if mesh is not None and mesh.devices.size > 1:
+        raise NotImplementedError(
+            f"{what} decode is tp = 1 only, got a mesh of "
+            f"{mesh.devices.size}")
+    if width != 1 or with_lora or compress:
+        raise NotImplementedError(
+            f"{what} decode has no verify step, no adapter banks and no "
+            "fp8 pool")
+
+
+def publish_routed(hist) -> None:
+    """The device's ``[routed layers, experts]`` histogram of routed
+    (token, choice) pairs into the registry, once a ``serve``."""
+    import numpy as np
+
+    from ..timeline import metrics as _metrics
+    counter = _metrics.registry().counter(
+        "moe.tokens_routed",
+        "(token, choice) pairs a decode round routed to each expert",
+        labelnames=("layer", "expert"))
+    hist = np.asarray(hist)
+    for layer, expert in zip(*np.nonzero(hist)):
+        counter.labels(layer=int(layer), expert=int(expert)).inc(
+            int(hist[layer, expert]))

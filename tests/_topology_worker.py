@@ -112,6 +112,11 @@ def kernels(topology: str) -> int:
             q, pool, page_table, layer=3, lengths=lengths, value_dim=512,
             scale=192 ** -0.5)
 
+    def cca_decode(q, pool, page_table, lengths):
+        return attention.cca_decode_attention(
+            q, pool, page_table, layer=3, lengths=lengths, kv_heads=2,
+            scale=128 ** -0.5)
+
     def prefill(q, k, v):
         return attention.flash_attention(q, k, v, causal=True)
 
@@ -123,10 +128,11 @@ def kernels(topology: str) -> int:
                                       tm=tm)
         return run
 
-    def gmm_args(rows, tm):
+    def gmm_args(rows, tm, experts=256, width=768):
         bf = jnp.bfloat16
-        return [spec((rows, 2048), bf), spec((256, 2048, 768), bf),
-                spec((256, 2048, 768), bf), spec((256, 768, 2048), bf),
+        return [spec((rows, 2048), bf), spec((experts, 2048, width), bf),
+                spec((experts, 2048, width), bf),
+                spec((experts, width, 2048), bf),
                 spec((rows // tm,), jnp.int32), spec((1,), jnp.int32)]
 
     cases = {
@@ -145,6 +151,24 @@ def kernels(topology: str) -> int:
         # tiles of 16 rows, an 8,192-token prefill's in tiles of 128.
         "moe_gmm_decode": (gmm(16), gmm_args(4352, 16)),
         "moe_gmm_prefill_8k": (gmm(128), gmm_args(98048, 128)),
+        # 16 experts of 2048 x 2048, top 1: gate and up in column slices
+        # of 1,024 (32 MB whole, double-buffered), down whole; a decode
+        # round's 96 rows in tiles of 16, a 512-token prompt's in 32.
+        "moe_gmm_wide_decode": (gmm(16), gmm_args(336, 16, 16, 2048)),
+        "moe_gmm_wide_prefill_512": (gmm(32), gmm_args(1024, 32, 16, 2048)),
+        # The grouped-query decode of 96 slots out of rows that hold two
+        # key and two value heads of 128 side by side (24 layers of
+        # 9,217 pages of 16 rows, 96 pages a slot), and its 512-token
+        # prefill (8 query heads over 2): one block, the head-group
+        # forward.
+        "cca_decode_b96": (cca_decode, [
+            spec((96, 8, 128), jnp.bfloat16),
+            spec((24, 9217, 16, 512), jnp.bfloat16),
+            spec((96, 96), jnp.int32), spec((96,), jnp.int32)]),
+        "flash_cca_prefill_512": (prefill, [
+            spec((1, 8, 512, 128), jnp.bfloat16),
+            spec((1, 2, 512, 128), jnp.bfloat16),
+            spec((1, 2, 512, 128), jnp.bfloat16)]),
         # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.  One block
         # holds the sequence: the head-group kernels, forward and one
         # backward, all 16 heads a grid step.

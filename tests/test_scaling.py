@@ -178,13 +178,18 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # blocked forward.  One split-KV call; the latent-attention decode
     # out of a 64-slot page pool; the grouped expert matmul (gate and up
     # fused, then down) at a decode round's and at an 8k prefill's row
-    # tiles.
+    # tiles, and at 16 experts of 2048 x 2048 (gate and up in column
+    # slices); the page walk over rows of two key and two value heads (96
+    # slots) and that model's 512-token prefill, 8 query heads over 2.
     assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
                    "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
-                   "head_group": ["flash_bert_large",
+                   "head_group": ["flash_cca_prefill_512",
+                                  "flash_bert_large",
                                   "flash_mistral_prefill_512"],
                    "flash_decode_b8": 1, "mla_decode_b64": 1,
-                   "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2}
+                   "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2,
+                   "moe_gmm_wide_decode": 2, "moe_gmm_wide_prefill_512": 2,
+                   "cca_decode_b96": 1, "flash_cca_prefill_512": 1}
 
 
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():
