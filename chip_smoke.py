@@ -263,7 +263,7 @@ def phase_server(config, slots: int, page_size: int, max_len: int,
     mosaic = _mosaic_calls(jax.jit(eng.step._fn).lower(
         eng._decode_params, cache.k, cache.v,
         jnp.zeros((slots,), jnp.int32), cache.lengths_device(),
-        cache.table_device(), jnp.zeros((slots,), bool)))
+        cache.table_device(), jnp.zeros((slots,), bool), eng._told))
     _check(mosaic == mosaic_calls,
            f"decode step has {mosaic} Mosaic calls, expected "
            f"{mosaic_calls} (one split-KV call per layer)")

@@ -500,6 +500,7 @@ def _build_serving_config(config: str):
     from ..models.transformer import LLAMA_SERVE, LlamaLM
     from ..serving import (CacheConfig, PagedKVCache, build_decode_step,
                            build_verify_step, cache_sharding)
+    from ..serving.decode import no_round
     from ..serving.policy import valid_tp_sizes
 
     cfg = LLAMA_SERVE
@@ -532,6 +533,8 @@ def _build_serving_config(config: str):
         step._meta["resized_from"] = resized_from
     args = (params, cache.k, cache.v, tokens, cache.lengths_device(),
             cache.table_device(), jnp.zeros((ccfg.slots,), bool))
+    if config != "serving_verify":
+        args += (no_round(ccfg.slots),)
     return step, args, (1, 2), f"step:{config}"
 
 

@@ -439,13 +439,13 @@ def build_decode_step(config: CcaMoeConfig, mesh, *, slots: int,
 
         logits, pool, None, slot_state, routed, told = step(
             params, pool, None, tokens, positions, page_table, active,
-            slot_state, routed)
+            slot_state, routed, prev)
 
     as ``mla_moe.build_decode_step``'s with one more operand before
     ``routed``: ``slot_state`` ``[layers, slots, slot_state_width]``, of
     which the step reads every live slot's row (the token before) and
     writes this token's in its place; an idle slot's row is left as it
-    is.  ``told``: ``[experts_touched, peak_expert_rows]`` int32.  The
+    is.  ``told`` ends in ``[experts_touched, peak_expert_rows]``.  The
     step CONSUMES ``pool``, ``slot_state`` and ``routed``.
     """
     del lora_alpha

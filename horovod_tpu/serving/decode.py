@@ -288,11 +288,13 @@ def build_decode_step(config: LlamaConfig, mesh, *,
 
     Signature of the returned step (``width == 1``)::
 
-        logits, k_pool, v_pool = step(params, k_pool, v_pool, tokens,
-                                      positions, page_table, active
-                                      [, kq, vq, kscale, vscale,
-                                         ctable, cmask]
-                                      [, adapters, adapter_ids])
+        logits, k_pool, v_pool, told = step(params, k_pool, v_pool,
+                                            tokens, positions,
+                                            page_table, active
+                                            [, kq, vq, kscale, vscale,
+                                               ctable, cmask]
+                                            [, adapters, adapter_ids],
+                                            prev)
 
     ``tokens``/``positions``/``active``: ``[slots]`` (current token, its
     absolute position == live length before this step, slot liveness).
@@ -302,6 +304,19 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     Idle slots produce zero attention output (dead-row convention) and
     their logits are discarded by the engine.
 
+    The step tells its round itself.  ``told``, its last output, is one
+    int32 vector ``[tokens | finite | tells]`` (:func:`tell_round`):
+    every slot's greedy token over the float32 logits, every slot's
+    finite flag (the engine's screen: 1 where the sum of the slot's
+    logits is finite), then the model's own ``LayerSpec.step_tells``
+    (none here).  ``prev``, its last operand, is the ``told`` of the
+    round before (:func:`no_round` where there is none): where the host
+    gives a slot the token ``-1`` the step reads the slot's token from
+    there (:func:`round_inputs`), so a round can be dispatched before
+    the host has read the round before it.  ``prev`` is read only; the
+    logits are still returned, and cost nothing where nobody fetches
+    them.
+
     The step CONSUMES ``k_pool`` and ``v_pool``: both are donated, the
     scatters update them in place and the returned pools are their
     successors.  The arrays passed in are deleted by the call -- rebind
@@ -310,7 +325,9 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     the fp8 pools, tables) is read only.
 
     ``width > 1`` is the speculative-decoding VERIFY step (built through
-    :func:`build_verify_step`): ``tokens`` widens to ``[slots, width]``
+    :func:`build_verify_step`; no ``prev`` and no ``told``: the host
+    walks the drafts between rounds): ``tokens`` widens to
+    ``[slots, width]``
     (the last sampled token followed by ``width - 1`` drafts), every
     column's K/V is scattered to its own (page, offset) in-step, and
     attention runs :func:`~horovod_tpu.ops.attention.verify_attention`
@@ -368,6 +385,9 @@ def build_decode_step(config: LlamaConfig, mesh, *,
 
     def spmd(params, k_pool, v_pool, tokens, positions, page_table,
              active, *extra):
+        if width == 1:
+            *extra, prev = extra
+            tokens, active = round_inputs(tokens, active, prev)
         if compress:
             kq_pool, vq_pool, kscale, vscale, ctable, cmask = extra[:6]
             extra = extra[6:]
@@ -501,11 +521,14 @@ def build_decode_step(config: LlamaConfig, mesh, *,
 
         x = _rmsnorm(x, p["final_norm"]["scale"], dtype)
         logits = x.astype(jnp.float32) @ emb.astype(jnp.float32).T
-        if width == 1:
-            logits = logits[:, 0, :]                       # [S, vocab]
-        return logits, k_pool, v_pool
+        if width > 1:
+            return logits, k_pool, v_pool
+        logits = logits[:, 0, :]                           # [S, vocab]
+        return logits, k_pool, v_pool, tell_round(logits)
 
     n_base = 7 + (6 if compress else 0)
+    # What follows the adapter banks, where there are any: ``prev``.
+    n_last = 1 if width == 1 else 0
 
     def _build(params_example, adapters_example=None):
         pool_spec = P(None, None, None, tp_axis, None)
@@ -518,8 +541,10 @@ def build_decode_step(config: LlamaConfig, mesh, *,
         if adapters_example is not None:
             in_specs += [jax.tree.map(lambda _: P(), adapters_example),
                          P()]
+        in_specs += [P()] * n_last
         fn = jax.shard_map(spmd, mesh=mesh, in_specs=tuple(in_specs),
-                           out_specs=(P(), pool_spec, pool_spec),
+                           out_specs=(P(), pool_spec, pool_spec)
+                           + (P(),) * n_last,
                            check_vma=False)
         # The pools are updated in place: outputs 1 and 2 alias inputs
         # 1 and 2 (same shape, dtype and ``pool_spec``), so the round
@@ -537,8 +562,9 @@ def build_decode_step(config: LlamaConfig, mesh, *,
         # compiled program also depends on.
         fn = _fusion.plan_executable(
             splan,
-            lambda: _build(args[0],
-                           args[n_base] if len(args) > n_base else None),
+            lambda: _build(
+                args[0],
+                args[n_base] if len(args) > n_base + n_last else None),
             extra=(len(args), bool(compress), int(page_size),
                    int(pages_per_slot), mesh))
         return fn(*args)
@@ -596,6 +622,46 @@ def _dense_lora_only(x, lora_select, dtype, lora_alpha):
 def greedy_sample(logits) -> jnp.ndarray:
     """Deterministic next token per slot: argmax over the vocab."""
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def tell_round(logits, tells=()) -> jnp.ndarray:
+    """What a decode step tells of its round, in one int32 vector (its
+    last output): ``[tokens | finite | tells]``.  ``tokens``: every
+    slot's greedy token over the ``[slots, vocab]`` float32 logits.
+    ``finite``: 1 where the sum of the slot's logits is finite (any NaN
+    or Inf in the row reaches the sum): the engine's screen.  ``tells``:
+    the model's own whole numbers about the round
+    (``LayerSpec.step_tells``)."""
+    return jnp.concatenate([
+        greedy_sample(logits),
+        jnp.isfinite(jnp.sum(logits, axis=-1)).astype(jnp.int32),
+        jnp.array(list(tells), jnp.int32)])
+
+
+def round_inputs(tokens, active, prev):
+    """``(tokens, active)`` of this round.  A slot's token is the host's
+    or, where the host gives ``-1``, the one the round before sampled
+    (``prev``: its ``told``).  Such a slot sits the round out where the
+    round before screened it as not finite: the host will drop what
+    this round computes for it and re-prefill it, and until then
+    nothing is written to its pages or beside them."""
+    slots = tokens.shape[0]
+    ahead = tokens < 0
+    return (jnp.where(ahead, prev[:slots], tokens),
+            active & ~(ahead & (prev[slots:2 * slots] == 0)))
+
+
+def no_round(slots: int, tells: int = 0) -> jnp.ndarray:
+    """The ``prev`` operand of a step before which no round ran (the
+    host then gives every live slot its token)."""
+    return jnp.zeros((2 * slots + tells,), jnp.int32)
+
+
+def read_told(told, slots: int):
+    """``(tokens, finite, tells)`` of a fetched ``told`` vector."""
+    told = np.asarray(told)
+    return (told[:slots], told[slots:2 * slots].astype(bool),
+            told[2 * slots:])
 
 
 # ---------------------------------------------------------------------------

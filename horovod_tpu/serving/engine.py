@@ -47,11 +47,11 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..core.config import _env, _env_bool, _env_int
 from ..timeline import spans as _spans
-from .decode import greedy_sample
+from .decode import greedy_sample, no_round, read_told
 from .kvcache import CacheConfig, PagedKVCache, PrefixCache
 from .layerspec import layer_spec
 from .scheduler import (ContinuousBatchScheduler, Request,
@@ -137,6 +137,10 @@ class ServingReport:
     token_latency_p50_s: float
     token_latency_p99_s: float
     mean_occupancy: float
+    # Of ``decode_steps``, the rounds dispatched while the round before
+    # was still in flight (``serve``'s look-ahead; ``decode.round``'s
+    # ``ahead``).
+    rounds_ahead: int = 0
     # Speculative decoding (zero when HOROVOD_SPEC_DECODE is off).
     spec_rounds: int = 0
     proposed_tokens: int = 0
@@ -156,12 +160,26 @@ class ServingReport:
         return dataclasses.asdict(self)
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A decode round the chip has been handed and the host has not read
+    yet: its live slots, the step's ``told`` vector (on the device) and
+    when it was dispatched."""
+
+    slots: List[int]
+    told: Any
+    t0: float
+
+
 @jax.jit
-def _screen_and(logits, told):
-    """The finite screen's per-slot sums with what the step told of its
-    round behind them: one program, one fetch."""
-    return jnp.concatenate([jnp.sum(logits, axis=-1),
-                            told.astype(jnp.float32)])
+def _verify_told(logits):
+    """A verify round in one program and one fetch: the ``[slots,
+    width]`` greedy tokens with the slot's finite flag behind them (a
+    poisoned column anywhere in the window disqualifies the slot's
+    whole round: the agreeing-prefix walk would condition on it)."""
+    finite = jnp.isfinite(jnp.sum(logits, axis=(-2, -1)))
+    return jnp.concatenate(
+        [greedy_sample(logits), finite[:, None].astype(jnp.int32)], axis=1)
 
 
 def _pct(values: List[float], q: float) -> float:
@@ -309,11 +327,24 @@ class ServingEngine:
         self.step = self.spec.build_step(
             self.mesh, with_lora=self.adapters is not None,
             lora_alpha=self.lora_alpha, **common)
-        self._step_state = tuple(self.spec.step_state())
+        self._step_state = self._fresh_step_state()
+        # The step's last output and its last operand: what the round
+        # before told.
+        self._told = self._whole(
+            no_round(self.slots, len(self.spec.step_tells)))
         self.verify_step = None
         if self.spec_decode:
             self.verify_step = self.spec.build_step(
                 self.mesh, width=self.spec_k + 1, **common)
+
+    def _whole(self, x):
+        """``x`` whole on every chip of the mesh, committed as the step
+        hands its outputs back: the first dispatch compiles the program
+        that every later one runs."""
+        return jax.device_put(x, NamedSharding(self.mesh, PartitionSpec()))
+
+    def _fresh_step_state(self) -> tuple:
+        return tuple(self._whole(x) for x in self.spec.step_state())
 
     # -- one-request helpers ----------------------------------------------
     def _begin_prefill(self, st: Dict[str, Any], slot: int, req: Request,
@@ -440,11 +471,17 @@ class ServingEngine:
         st["completed"].append(self.scheduler.release(slot, now()))
 
     def _decode_slots(self) -> List[int]:
-        """Slots actually in the decode batch: live requests minus
+        """Slots the next decode round takes: live requests minus
         still-chunking prefills and pages-in-flight handoffs (neither
-        has resident context yet)."""
+        has resident context yet), and minus a slot whose last token is
+        in flight.  That is known by COUNT, before any token of the
+        round in flight is read: tokens emitted plus tokens dispatched
+        against ``max_new_tokens``, and the slot's length (which
+        advances at dispatch) against ``max_len``."""
         return [s for s, r in self.scheduler.active.items()
-                if r.state not in ("prefill", "handoff")]
+                if r.state not in ("prefill", "handoff")
+                and len(r.tokens) + r.in_flight < r.max_new_tokens
+                and int(self.cache.lengths[s]) < self.max_len]
 
     def _quarantine_logits(self, st: Dict[str, Any], slot: int,
                            req: Request) -> None:
@@ -467,29 +504,47 @@ class ServingEngine:
         st["last_tokens"][slot] = self.re_prefill(slot, req)
 
     # -- one decode round (shared with serving.controlplane) ---------------
-    def _round_span(self, st: Dict[str, Any], slots: List[int]):
+    def _round_span(self, st: Dict[str, Any], slots: List[int],
+                    ahead: bool = False):
         """The ``decode.round`` span of one round over ``slots``.
         ``live_tokens`` is what the round's attention reads: each slot's
-        resident context and the token this round writes."""
+        resident context and the token this round writes.  ``ahead``: 1
+        where the round is dispatched while the one before is still in
+        flight."""
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=int(sum(int(self.cache.lengths[s]) + 1
-                                for s in slots)))
+                                for s in slots)), ahead=int(ahead))
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
-        """One plain continuous-batching decode step over live slots.
+        """Dispatch one plain continuous-batching decode round over the
+        live slots, and read and book a round.
 
         ``st`` is the mutable per-run state dict (``last_tokens``,
         ``adapter_ids``, ``completed``, ``occ_samples``,
-        ``decode_steps``); the control plane's drain loop drives this
-        same method so its gauges stay truthful.  Returns the seconds
-        from dispatch to the last fetch.
+        ``decode_steps``).  Where it has the key ``in_flight`` (the
+        ``st`` that :meth:`serve` builds) the loop runs ONE ROUND AHEAD:
+        this call reserves pages for, builds the operands of and
+        dispatches the round for the slots that are live by count
+        (:meth:`_decode_slots`), leaves it in ``st["in_flight"]``, and
+        only then fetches and books the round that was in flight
+        (:meth:`_retire`), so the chip has the next round queued while
+        the host reads the last one.  A continuing slot's token never
+        leaves the chip: the host gives the step ``-1`` for it and the
+        step reads it from the ``told`` vector of the round before.
+        Where ``st`` lacks the key (the control plane's drain loop and
+        the fleet's decode worker, which rewrite slots and meshes
+        between rounds) the round is dispatched AND retired by this
+        call.  On entry ``_decode_slots()`` and ``cache.lengths``
+        describe the round this call dispatches: lengths advance at
+        dispatch.  Returns the seconds from the retired round's dispatch
+        to its fetch (0.0 where nothing was retired).
         """
-        sched = self.scheduler
         cache = self.cache
         phase = _spans.recorder().phase
         slots = self._decode_slots()
-        with self._round_span(st, slots):
+        flying: Optional[_Flight] = st.get("in_flight")
+        with self._round_span(st, slots, ahead=flying is not None):
             with phase("decode.reserve"):
                 for slot in slots:
                     length = int(cache.lengths[slot])
@@ -497,8 +552,14 @@ class ServingEngine:
             with phase("decode.args"):
                 active = np.zeros((self.slots,), bool)
                 active[slots] = True
+                tokens = np.array(st["last_tokens"])
+                if flying is not None:
+                    # Their tokens are on the chip, in the round before's
+                    # ``told``; every other live slot joined since (from
+                    # a prefill or a re-prefill) and the host has its.
+                    tokens[flying.slots] = -1
                 args = [self._decode_params, cache.k, cache.v,
-                        jnp.asarray(np.array(st["last_tokens"])),
+                        jnp.asarray(tokens),
                         cache.lengths_device(), cache.table_device(),
                         jnp.asarray(active)]
                 if self.kv_compress:
@@ -508,50 +569,85 @@ class ServingEngine:
                              jnp.asarray(np.array(st["adapter_ids"]))]
             t0 = time.monotonic()
             # Beside the pools a step may carry device state of its own
-            # (donated in, handed back) and return a few numbers of the
-            # round after it: those ride on the finite screen's fetch.
-            # The cache's slot state (where the model has one) leads
-            # them: the step advances the rows of its live slots.
+            # (donated in, handed back).  The cache's slot state (where
+            # the model has one) leads it: the step advances the rows of
+            # its live slots.  Last comes what the round tells of
+            # itself, the one thing the host fetches.
             own = () if cache.state is None else (cache.state,)
             n_state = len(own) + len(self._step_state)
-            out = self.step(*args, *own, *self._step_state)
-            logits, cache.k, cache.v = out[:3]
+            out = self.step(*args, *own, *self._step_state, self._told)
+            _, cache.k, cache.v = out[:3]
             if own:
                 cache.state = out[3]
             self._step_state = out[3 + len(own):3 + n_state]
-            with phase("decode.sample_fetch"):
-                sampled = np.asarray(greedy_sample(logits))  # sync point
-            # Per-slot SDC screen: one reduced scalar per row (sum
-            # propagates any NaN/Inf in the vocab axis): a second
-            # program and a second fetch.
-            with phase("decode.finite_fetch"):
-                told = out[3 + n_state:]
-                screen = np.asarray(_screen_and(logits, *told) if told
-                                    else jnp.sum(logits, axis=-1))
-                finite = np.isfinite(screen[:self.slots])
-            step_s = time.monotonic() - t0
-            # What the step told of its round (``LayerSpec.step_tells``:
-            # a routed model's touched experts) goes on the bookkeep span.
-            told = {name: int(x) for name, x in zip(
-                self.spec.step_tells, screen[self.slots:])}
-            with phase("decode.bookkeep", **told):
-                st["decode_steps"] += 1
-                st["occ_samples"].append(sched.occupancy)
-                t_tok = now()
-                for slot in slots:
-                    req = sched.active[slot]
-                    if not finite[slot]:
-                        self._quarantine_logits(st, slot, req)
-                        continue
-                    tok = int(sampled[slot])
-                    req.tokens.append(tok)
-                    cache.lengths[slot] += 1
-                    st["last_tokens"][slot] = tok
-                    sched.note_decode_token(req, t_tok)
-                    if req.finished or \
-                            int(cache.lengths[slot]) >= self.max_len:
-                        self._release(st, slot, now)
+            self._told = out[-1]
+            # Sent to the host as soon as the round ends, whenever the
+            # host comes to read it.
+            self._told.copy_to_host_async()
+            for slot in slots:
+                cache.lengths[slot] += 1
+                self.scheduler.active[slot].in_flight += 1
+            st["decode_steps"] += 1
+            st["occ_samples"].append(self.scheduler.occupancy)
+            this = _Flight(slots, self._told, t0)
+            if "in_flight" not in st:
+                return self._retire(st, this, now)
+            st["in_flight"] = this
+            st["rounds_ahead"] += flying is not None
+            return 0.0 if flying is None else self._retire(st, flying, now)
+
+    def _retire(self, st: Dict[str, Any], flight: _Flight, now,
+                dropped: Sequence[int] = ()) -> float:
+        """Fetch a dispatched round's ``told`` vector (the round's one
+        sync point) and book it: tokens appended and time-stamped NOW,
+        when the host has them; finished requests released; a slot with
+        nonfinite logits quarantined.  ``dropped``: slots whose result
+        is thrown away (the round ran on a token that was quarantined
+        after its dispatch)."""
+        sched = self.scheduler
+        phase = _spans.recorder().phase
+        with phase("decode.sample_fetch"):
+            sampled, finite, tells = read_told(flight.told, self.slots)
+        step_s = time.monotonic() - flight.t0
+        poisoned = []
+        # What the step told of its round (``LayerSpec.step_tells``:
+        # a routed model's touched experts) goes on the bookkeep span.
+        told = {name: int(x) for name, x in zip(self.spec.step_tells,
+                                                tells)}
+        with phase("decode.bookkeep", **told):
+            t_tok = now()
+            for slot in flight.slots:
+                req = sched.active[slot]
+                req.in_flight -= 1
+                if slot in dropped:
+                    continue
+                if not finite[slot]:
+                    poisoned.append(slot)
+                    continue
+                tok = int(sampled[slot])
+                req.tokens.append(tok)
+                st["last_tokens"][slot] = tok
+                sched.note_decode_token(req, t_tok)
+                if req.finished or (not req.in_flight and int(
+                        self.cache.lengths[slot]) >= self.max_len):
+                    self._release(st, slot, now)
+        if poisoned:
+            # The round behind this one took these slots' tokens from
+            # the poisoned round: read it first, without them.
+            self._catch_up(st, now, dropped=poisoned)
+            for slot in poisoned:
+                self._quarantine_logits(st, slot, sched.active[slot])
         return step_s
+
+    def _catch_up(self, st: Dict[str, Any], now,
+                  dropped: Sequence[int] = ()) -> None:
+        """Retire the round in flight, if there is one: when the loop
+        has nothing to dispatch, and before anything that rewrites slot
+        or mesh state."""
+        flight = st.get("in_flight")
+        if flight is not None:
+            st["in_flight"] = None
+            self._retire(st, flight, now, dropped)
 
     def spec_round(self, st: Dict[str, Any], now) -> float:
         """One speculative round: draft k, verify k+1 wide, accept the
@@ -569,6 +665,7 @@ class ServingEngine:
         phase = _spans.recorder().phase
         k = self.spec_k
         width = k + 1
+        self._catch_up(st, now)
         slots = self._decode_slots()
         reqs = {s: sched.active[s] for s in slots}
         base = {s: int(cache.lengths[s]) for s in slots}
@@ -597,14 +694,8 @@ class ServingEngine:
             t0 = time.monotonic()
             logits, cache.k, cache.v = self.verify_step(*args)
             with phase("decode.sample_fetch"):
-                sampled = np.asarray(greedy_sample(logits))  # [slots, width]
-            # Per-slot SDC screen across every verify column: a poisoned
-            # column anywhere in the window disqualifies the whole round
-            # for that slot (the agreeing-prefix walk would condition on
-            # it).
-            with phase("decode.finite_fetch"):
-                finite = np.isfinite(
-                    np.asarray(jnp.sum(logits, axis=(-2, -1))))
+                told = np.asarray(_verify_told(logits))      # sync point
+                sampled, finite = told[:, :width], told[:, width] != 0
             step_s = time.monotonic() - t0
             with phase("decode.bookkeep"):
                 st["decode_steps"] += 1
@@ -725,7 +816,10 @@ class ServingEngine:
             "prefill_cached": 0, "prefill_computed": 0,
             "session_resumes": 0,
             "last_tokens": np.zeros((self.slots,), np.int32),
-            "adapter_ids": np.zeros((self.slots,), np.int32)}
+            "adapter_ids": np.zeros((self.slots,), np.int32),
+            # This loop runs one round ahead (``decode_once``): the
+            # round the chip has and the host has not read.
+            "in_flight": None, "rounds_ahead": 0}
         completed: List[Request] = st["completed"]
         prompts_dev: Dict[int, Any] = {}
         self._chunking.clear()
@@ -769,6 +863,9 @@ class ServingEngine:
                     with phase("serve.chunks"):
                         self._advance_chunks(st, now)
                 if not self._decode_slots():
+                    # Nothing to dispatch: what is live has its last
+                    # token in flight, or is still being prefilled.
+                    self._catch_up(st, now)
                     continue
 
                 # One continuous-batching round over the decode batch:
@@ -784,7 +881,7 @@ class ServingEngine:
             # What the step accumulated on the device over this call,
             # fetched once now; the next call starts from zero.
             self.spec.publish_state(self._step_state)
-            self._step_state = tuple(self.spec.step_state())
+            self._step_state = self._fresh_step_state()
         new_tokens = sum(len(r.tokens) for r in completed)
         prompt_tokens = sum(r.prompt_len for r in completed)
         ttfts = [r.ttft_s for r in completed if r.ttft_s is not None]
@@ -799,6 +896,7 @@ class ServingEngine:
             rejected=rejected, prompt_tokens=prompt_tokens,
             new_tokens=new_tokens, wall_s=wall_s,
             decode_steps=int(st["decode_steps"]),
+            rounds_ahead=int(st["rounds_ahead"]),
             tokens_per_s=new_tokens / wall_s,
             ttft_p50_s=_pct(ttfts, 50), ttft_p99_s=_pct(ttfts, 99),
             token_latency_p50_s=_pct(lats, 50),

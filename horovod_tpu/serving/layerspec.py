@@ -57,8 +57,10 @@ class LayerSpec:
     # ``serve`` returns.
     step_state: Callable[[], tuple] = lambda: ()
     publish_state: Callable[[tuple], None] = lambda state: None
-    # Names of the whole numbers the step returns last, in one int32
-    # vector, about the round it ran: attributes of ``decode.bookkeep``.
+    # Names of the model's own whole numbers about the round it ran:
+    # the tail of the one int32 vector the step returns last, ``[tokens
+    # | finite | tells]`` (``decode.tell_round``), behind every slot's
+    # greedy token and finite flag; attributes of ``decode.bookkeep``.
     step_tells: Tuple[str, ...] = ()
     # Values a slot keeps a layer BESIDE its pages (None: pages are all
     # a sequence has) and what they are.  ``PagedKVCache`` holds them as

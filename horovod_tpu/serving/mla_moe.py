@@ -356,17 +356,18 @@ def build_decode_step(config: MlaMoeConfig, mesh, *, slots: int,
 
     Signature of the returned step::
 
-        logits, pool, None, routed, touched = step(
+        logits, pool, None, routed, told = step(
             params, pool, None, tokens, positions, page_table, active,
-            routed)
+            routed, prev)
 
     as ``decode.build_decode_step``'s (the second pool's place is None:
-    this model keeps one), with two more operands: ``routed``
-    (``[moe layers, experts]`` int32, the running histogram of (token,
-    choice) pairs) and ``touched`` (``[1]`` int32: experts, summed over
-    the routed layers, that at least one live slot chose this round).
-    The step CONSUMES ``pool`` and ``routed``: both are donated and
-    their successors returned.
+    this model keeps one), with one more operand before ``prev``:
+    ``routed`` (``[moe layers, experts]`` int32, the running histogram
+    of (token, choice) pairs).  ``told`` (``[tokens | finite | tells]``,
+    ``stepparts.build_one_chip_step``) ends in ``experts_touched``:
+    experts, summed over the routed layers, that at least one live slot
+    chose this round.  The step CONSUMES ``pool`` and ``routed``: both
+    are donated and their successors returned.
     """
     del lora_alpha
     cfg = config

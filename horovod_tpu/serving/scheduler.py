@@ -136,6 +136,9 @@ class Request:
     # Load-generator engine affinity hint (per-engine arrival skew in
     # fleet traffic shapes); None = the router decides freely.
     engine_hint: Optional[int] = None
+    # Tokens of this request the chip has been handed and the host has
+    # not read yet (the engine's look-ahead counts with them).
+    in_flight: int = 0
 
     @property
     def prompt_len(self) -> int:

@@ -38,7 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .decode import build_decode_step, greedy_sample, prefill_forward
+from .decode import (build_decode_step, no_round, prefill_forward,
+                     read_told)
 from .kvcache import CacheConfig, PagedKVCache
 from .scheduler import Request
 
@@ -134,6 +135,8 @@ class ModelDrafter:
             pages_per_slot=self.cache_config.pages_per_slot, dtype=dtype)
         self.slots = slots
         self.max_len = max_len
+        # The host walks the drafts: every token is its own.
+        self._no_round = no_round(slots)
 
         def _prefill(p, toks):
             return prefill_forward(p, config, toks, dtype=dtype)
@@ -192,11 +195,12 @@ class ModelDrafter:
         table = cache.table_device()
         act_dev = jnp.asarray(active)
         for i in range(k):
-            logits, cache.k, cache.v = self.step(
+            _, cache.k, cache.v, told = self.step(
                 self.params, cache.k, cache.v,
-                jnp.asarray(cur), jnp.asarray(base + i), table, act_dev)
-            cur = np.asarray(greedy_sample(logits))
-            drafts[:, i] = np.where(active, cur, 0)
+                jnp.asarray(cur), jnp.asarray(base + i), table, act_dev,
+                self._no_round)
+            drafts[:, i] = np.where(active, read_told(told, self.slots)[0],
+                                    0)
             cur = drafts[:, i].copy()
         return drafts
 
@@ -227,9 +231,9 @@ class ModelDrafter:
                 cache.reserve(slot, int(cache.lengths[slot]) + 1)
                 toks[slot] = tok
                 active[slot] = True
-            _, cache.k, cache.v = self.step(
+            _, cache.k, cache.v, _ = self.step(
                 self.params, cache.k, cache.v, jnp.asarray(toks),
                 cache.lengths_device(), cache.table_device(),
-                jnp.asarray(active))
+                jnp.asarray(active), self._no_round)
             for slot in feed:
                 cache.lengths[slot] += 1

@@ -19,7 +19,8 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from .decode import ServingDecodeStep, _rmsnorm
+from .decode import (ServingDecodeStep, _rmsnorm, round_inputs,
+                     tell_round)
 
 
 class Round(NamedTuple):
@@ -90,7 +91,7 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
 
         logits, pool, None, *carried, routed, told = step(
             params, pool, None, tokens, positions, page_table, active,
-            *carried, routed)
+            *carried, routed, prev)
 
     ``layer(li, blk, x, pool, carried, local, rnd) -> (x, pool, carried,
     local, routed index or None, counts or None)`` computes layer ``li``
@@ -100,14 +101,22 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     and no further; ``local(x)`` before the first), and for a routed
     layer returns its row of the histogram and the ``[experts]`` counts
     of its live slots.  ``routed`` is the
-    running ``[routed layers, experts]`` int32 histogram; ``told`` holds
-    the ``tells`` of the round, one int32 each.  The pool, the carried
-    arrays and ``routed`` are donated and their successors returned.
+    running ``[routed layers, experts]`` int32 histogram.  ``told``, the
+    step's last output, is what it tells of its round, one int32 vector
+    ``[tokens | finite | tells]`` (``decode.tell_round``): every slot's
+    greedy token over the float32 logits, every slot's finite flag, and
+    the ``tells`` of the round, one int32 each.  ``prev``, its last
+    operand, is the ``told`` of the round before: a slot to which the
+    host gives the token ``-1`` takes its token from there
+    (``decode.round_inputs``).  The pool,
+    the carried arrays and ``routed`` are donated and their successors
+    returned; ``prev`` is read only.
     """
     def step(params, pool, no_pool, tokens, positions, page_table, active,
              *state):
-        carry, routed = tuple(state[:carried]), state[carried]
+        *carry, routed, prev = state
         p = params["params"] if "params" in params else params
+        tokens, active = round_inputs(tokens, active, prev)
         x = embed(p, tokens)                                     # [S, d]
         rnd = round_of(positions, page_table, active,
                        page_size=page_size, scratch=scratch)
@@ -120,8 +129,9 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                 routed = routed.at[mi].add(counts)
                 told = [_JOIN[t](was, TELLS[t](counts))
                         for t, was in zip(tells, told)]
-        return (readout(x, p, eps, dtype, tied=tied), pool, no_pool,
-                *carry, routed, jnp.stack(told))
+        logits = readout(x, p, eps, dtype, tied=tied)
+        return (logits, pool, no_pool, *carry, routed,
+                tell_round(logits, told))
 
     step.__name__ = step.__qualname__ = name
     fn = jax.jit(step, donate_argnums=(1,) + tuple(

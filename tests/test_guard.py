@@ -419,12 +419,17 @@ def test_serving_nonfinite_logits_reprefill_no_page_leak(hvd):
     real_step = eng.step
     calls = {"n": 0}
 
-    def poisoned_step(*args):
-        logits, k, v = real_step(*args)
+    def poisoned_step(params, k, v, tokens, positions, table, active,
+                      *rest):
         calls["n"] += 1
         if calls["n"] in (3, 4):  # two poisoned decode rounds
-            logits = logits.at[:, 0].set(jnp.nan)
-        return logits, k, v
+            # A resident row of every live slot goes bad: the logits
+            # the step computes, samples from and screens are NaN.
+            pages = np.asarray(table)[np.asarray(active), 0]
+            k = k.at[:, pages, 0].set(jnp.nan)
+            v = v.at[:, pages, 0].set(jnp.nan)
+        return real_step(params, k, v, tokens, positions, table, active,
+                         *rest)
 
     eng.step = poisoned_step
     reprefills0 = tm.registry().counter(
@@ -438,6 +443,7 @@ def test_serving_nonfinite_logits_reprefill_no_page_leak(hvd):
     assert report.completed == 6 and report.rejected == 0
     assert tm.registry().counter(
         "horovod_guard_serving_reprefills_total").value - reprefills0 >= 1
+    assert calls["n"] == report.decode_steps > 4
     # No page leak: every reserved page returned to the free pool.
     assert eng.cache.free_pages == total_pages
     assert all(int(x) == 0 for x in eng.cache.lengths)

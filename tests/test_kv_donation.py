@@ -25,6 +25,7 @@ from horovod_tpu.serving import (CacheConfig, LoadSpec, ModelDrafter,
                                  PagedKVCache, ServingEngine,
                                  build_decode_step, build_verify_step,
                                  cache_sharding, generate, prefix_spec)
+from horovod_tpu.serving.decode import no_round
 
 CFG = LLAMA_SERVE
 WIDTH = 3
@@ -76,8 +77,9 @@ def _step_args(kind, params, cache):
     slots = cache.config.slots
     tokens = jnp.ones((slots,) if width == 1 else (slots, width), jnp.int32)
     active = jnp.zeros((slots,), bool).at[0].set(True)
+    prev = () if kind == "verify" else (no_round(slots),)
     return (params, cache.k, cache.v, tokens, cache.lengths_device(),
-            cache.table_device(), active)
+            cache.table_device(), active, *prev)
 
 
 # Each writer sets its scene, then returns the pools it handed to the ONE
@@ -86,7 +88,7 @@ def _step_args(kind, params, cache):
 
 def _run_step(kind, params, mesh, ccfg, cache):
     args = _step_args(kind, params, cache)
-    _, cache.k, cache.v = _build_step(kind, mesh, ccfg)(*args)
+    _, cache.k, cache.v, *_ = _build_step(kind, mesh, ccfg)(*args)
     return args[1], args[2]
 
 
@@ -166,7 +168,7 @@ def test_step_donates_and_aliases_both_pools(params, monkeypatch, kind,
     monkeypatch.setattr(fusion, "plan_executable", capture)
     step = _build_step(kind, mesh, ccfg)
     args = _step_args(kind, params, cache)
-    _, cache.k, cache.v = step(*args)
+    _, cache.k, cache.v, *_ = step(*args)
     args = (params, cache.k, cache.v) + args[3:]
 
     lowered = built[-1].lower(*args)
@@ -174,7 +176,9 @@ def test_step_donates_and_aliases_both_pools(params, monkeypatch, kind,
                for a in lowered.args_info[0]]
     donated[0] = any(i.donated for i in jax.tree.leaves(
         lowered.args_info[0][0]))
-    assert donated == [False, True, True, False, False, False, False]
+    # The pools alone are consumed: ``prev``, the decode step's last
+    # operand, is still to be fetched when the next round has it.
+    assert donated == [False, True, True] + [False] * (len(args) - 3)
     text = lowered.compile().as_text()
     assert "input_output_alias" in text
     local = list(cache.k.sharding.shard_shape(cache.k.shape))

@@ -24,6 +24,7 @@ from horovod_tpu.serving import (CacheConfig, ContinuousBatchScheduler,
                                  TenantClass, build_decode_step,
                                  cache_sharding, generate, prefill_forward,
                                  prefix_spec, stack_adapters)
+from horovod_tpu.serving.decode import no_round
 from horovod_tpu.timeline import spans
 from horovod_tpu.timeline.metrics import render_prometheus
 
@@ -59,9 +60,9 @@ def _decode_sequence(params, step, cache, tokens, t0, T, slot=0):
         cache.reserve(slot, i + 1)
         tok = jnp.zeros((slots,), jnp.int32).at[slot].set(tokens[0, i])
         active = jnp.zeros((slots,), bool).at[slot].set(True)
-        logits, cache.k, cache.v = step(
+        logits, cache.k, cache.v, _ = step(
             params, cache.k, cache.v, tok, cache.lengths_device(),
-            cache.table_device(), active)
+            cache.table_device(), active, no_round(slots))
         cache.lengths[slot] += 1
         out.append(np.asarray(logits[slot]))
     return np.stack(out)
@@ -330,11 +331,11 @@ def test_request_prefetcher_order_and_error():
 # ---------------------------------------------------------------------------
 
 
-def _audit_args(cache):
+def _audit_args(cache, *banks):
     slots = cache.config.slots
     return (cache.k, cache.v, jnp.zeros((slots,), jnp.int32),
             cache.lengths_device(), cache.table_device(),
-            jnp.zeros((slots,), bool))
+            jnp.zeros((slots,), bool), *banks, no_round(slots))
 
 
 @pytest.mark.parametrize("ndev", [1, 8])
@@ -371,9 +372,9 @@ def test_audit_declines_lora_banks(base_params):
                              with_lora=True)
     expected = expected_exchange(params, meta_from_step(step))
     assert not expected.supported
-    report = audit_step(step, params, *_audit_args(cache),
-                        {"params": banks},
-                        jnp.zeros((ccfg.slots,), jnp.int32),
+    report = audit_step(step, params, *_audit_args(
+                            cache, {"params": banks},
+                            jnp.zeros((ccfg.slots,), jnp.int32)),
                         name="serving-decode-lora")
     assert report.ok()
     assert any(f.rule == "audit-plan-unsupported" for f in report.findings)
@@ -465,9 +466,10 @@ def test_multi_lora_adapters_share_base_model():
         tok = tok.at[0].set(tokens[0, i]).at[1].set(tokens[1, i])
         active = jnp.zeros((ccfg.slots,), bool).at[0].set(True).at[1].set(
             True)
-        logits, cache.k, cache.v = step(
+        logits, cache.k, cache.v, _ = step(
             params, cache.k, cache.v, tok, cache.lengths_device(),
-            cache.table_device(), active, {"params": banks}, adapter_ids)
+            cache.table_device(), active, {"params": banks}, adapter_ids,
+            no_round(ccfg.slots))
         for slot in (0, 1):
             cache.lengths[slot] += 1
             got[slot].append(np.asarray(logits[slot]))

@@ -15,6 +15,7 @@ import pytest
 from benchmarks.families import zaya_cca_moe as family
 from horovod_tpu import serving
 from horovod_tpu.serving import cca_moe
+from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.serving.layerspec import LayerSpec, layer_spec
 from horovod_tpu.timeline import metrics, spans
 
@@ -113,14 +114,17 @@ def _decode(params, cache, step, state, feeds):
         logits, cache.k, cache.v, cache.state, *rest = step(
             params, cache.k, cache.v, jnp.asarray(tokens),
             cache.lengths_device(), cache.table_device(),
-            jnp.asarray(active), cache.state, *state)
-        state, told = tuple(rest[:1]), np.asarray(rest[1])
+            jnp.asarray(active), cache.state, *state, no_round(slots, 2))
+        state = tuple(rest[:1])
+        sampled, finite, told = read_told(rest[1], slots)
         # Top 1: each live slot touches one expert a layer, three layers.
         assert 1 <= told[0] <= 3 * len(feeds)
         assert 1 <= told[1] <= len(feeds)
         for s in feeds:
             cache.lengths[s] += 1
             out[s].append(np.asarray(logits[s]))
+            # The step samples and screens its own logits.
+            assert sampled[s] == np.argmax(out[s][-1]) and finite[s]
     return {s: np.stack(v) for s, v in out.items()}, state
 
 
@@ -231,12 +235,13 @@ def test_the_programs_consume_the_pool_and_the_state_they_write(params):
     args = (params, cache.k, None, jnp.ones((2,), jnp.int32),
             cache.lengths_device(), cache.table_device(),
             jnp.asarray([True, False]))
-    _, cache.k, _, cache.state, hist2, told = step(*args, cache.state, hist)
+    _, cache.k, _, cache.state, hist2, told = step(*args, cache.state, hist,
+                                                   no_round(2, 2))
     assert pool.is_deleted() and rows.is_deleted() and hist.is_deleted()
     assert cache.state.shape == rows.shape and hist2.shape == (3, 8)
-    assert told.shape == (2,)
+    assert told.shape == (2 + 2 + 2,) and not told.is_deleted()
     text = step._fn.lower(params, cache.k, None, *args[3:], cache.state,
-                          hist2).as_text()
+                          hist2, told).as_text()
     assert text.count("tf.aliasing_output") == 3
 
 

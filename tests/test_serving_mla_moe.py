@@ -13,6 +13,7 @@ import pytest
 from benchmarks.families import joyai_mla_moe as family
 from horovod_tpu import serving
 from horovod_tpu.serving import mla_moe
+from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.serving.layerspec import layer_spec
 from horovod_tpu.timeline import metrics, spans
 
@@ -87,10 +88,10 @@ def _prefill_then_decode(params, prompt, steps, dtype=jnp.float32,
         active = jnp.zeros((3,), bool).at[1].set(True)
         logits, cache.k, cache.v, *rest = step(
             params, cache.k, cache.v, tokens, cache.lengths_device(),
-            cache.table_device(), active, *state)
-        state, touched = tuple(rest[:1]), rest[1]
+            cache.table_device(), active, *state, no_round(3, 1))
+        state, told = tuple(rest[:1]), rest[1]
         # One live slot, top 4, two routed layers: 8 experts touched.
-        assert int(touched[0]) == 8
+        assert int(read_told(told, 3)[2][0]) == 8
         cache.lengths[1] += 1
         out.append(np.asarray(logits[1]))
     assert int(np.asarray(state[0]).sum()) == steps * 4 * 2
@@ -220,11 +221,11 @@ def test_the_new_programs_consume_the_pool_they_write(params):
     _, cache.k, _, hist2, _ = step(
         params, cache.k, None, jnp.ones((2,), jnp.int32),
         cache.lengths_device(), cache.table_device(),
-        jnp.asarray([True, False]), hist)
+        jnp.asarray([True, False]), hist, no_round(2, 1))
     assert given.is_deleted() and hist.is_deleted()
     assert cache.k.shape == given.shape and hist2.shape == (2, 16)
     text = step._fn.lower(
         params, cache.k, None, jnp.ones((2,), jnp.int32),
         cache.lengths_device(), cache.table_device(),
-        jnp.asarray([True, False]), hist2).as_text()
+        jnp.asarray([True, False]), hist2, no_round(2, 1)).as_text()
     assert text.count("tf.aliasing_output") == 2

@@ -24,6 +24,7 @@ from horovod_tpu.serving import (CacheConfig, ContinuousBatchScheduler,
                                  PagedKVCache, Request, ServingEngine,
                                  build_decode_step, build_verify_step,
                                  cache_sharding, generate, prefill_forward)
+from horovod_tpu.serving.decode import no_round
 from horovod_tpu.timeline.metrics import render_prometheus
 
 CFG = LLAMA_SERVE
@@ -169,7 +170,8 @@ def test_verify_step_rows_bitwise_match_sequential_decode(base_params):
     rows, k, v = [], jnp.copy(k0), jnp.copy(v0)
     for i in range(W):
         tok = jnp.zeros((ccfg.slots,), jnp.int32).at[0].set(tokens[0, t0 + i])
-        logits, k, v = plain(params, k, v, tok, base + i, table, active)
+        logits, k, v, _ = plain(params, k, v, tok, base + i, table, active,
+                                no_round(ccfg.slots))
         rows.append(np.asarray(logits[0]))
 
     tok2 = jnp.zeros((ccfg.slots, W), jnp.int32).at[0].set(tokens[0, t0:])
@@ -299,10 +301,10 @@ def test_fp8_compressed_page_survives_donor_page_poisoning(base_params):
     args = (jnp.zeros((ccfg.slots,), jnp.int32).at[0].set(prompt[0, -1]),
             cache.lengths_device(), cache.table_device(),
             jnp.zeros((ccfg.slots,), bool).at[0].set(True),
-            *cache.compress_operands())
+            *cache.compress_operands(), no_round(ccfg.slots))
     # The step consumes its pools: the clean run gets copies so the
     # cache's own arrays are still there to poison.
-    clean, _, _ = step(params, jnp.copy(cache.k), jnp.copy(cache.v), *args)
+    clean, *_ = step(params, jnp.copy(cache.k), jnp.copy(cache.v), *args)
 
     # Poison every free f32 page with FINITE garbage, as a recycling
     # slot would (the masking contract zeroes stale pages' attention
@@ -310,7 +312,7 @@ def test_fp8_compressed_page_survives_donor_page_poisoning(base_params):
     bad = jnp.asarray(list(cache._free), jnp.int32)
     poisoned_k = cache.k.at[:, bad].set(1e9)
     poisoned_v = cache.v.at[:, bad].set(1e9)
-    dirty, _, _ = step(params, poisoned_k, poisoned_v, *args)
+    dirty, *_ = step(params, poisoned_k, poisoned_v, *args)
     np.testing.assert_array_equal(np.asarray(dirty[0]),
                                   np.asarray(clean[0]))
 

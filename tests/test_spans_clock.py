@@ -21,8 +21,7 @@ from horovod_tpu.timeline import spans
 
 CFG = LLAMA_SERVE
 ROUND_CHILDREN = {"decode.reserve", "decode.args", "decode.dispatch",
-                  "decode.sample_fetch", "decode.finite_fetch",
-                  "decode.bookkeep"}
+                  "decode.sample_fetch", "decode.bookkeep"}
 
 
 # -- the record ring --------------------------------------------------------
@@ -230,10 +229,23 @@ def test_serve_leaves_a_tree_of_spans(params, spec_decode):
     assert [r.attrs["round"] for r in rounds] == list(range(len(rounds)))
     for rnd in rounds:
         kids = [r for r in records if r.parent == rnd.id]
-        assert {k.name for k in kids} == ROUND_CHILDREN
+        # The plain loop runs one round ahead: a round reads the round
+        # before it, and one with none in flight has nothing to read yet
+        # (the loop reads the last round when it has none to dispatch).
+        want = ROUND_CHILDREN if spec_decode or rnd.attrs["ahead"] \
+            else ROUND_CHILDREN - {"decode.sample_fetch", "decode.bookkeep"}
+        assert sorted(k.name for k in kids) == sorted(want)
         for k in kids:
             assert rnd.start_ns <= k.start_ns <= k.end_ns <= rnd.end_ns
         assert 1 <= rnd.attrs["slots"] <= 3
+    # However the loop ran, every round was dispatched once, read once
+    # and booked once, and no second program screens it.
+    for name in ("decode.dispatch", "decode.sample_fetch",
+                 "decode.bookkeep"):
+        assert sum(r.name == name for r in records) == len(rounds)
+    assert "decode.finite_fetch" not in names
+    assert report.rounds_ahead == sum(r.attrs["ahead"] for r in rounds)
+    assert (report.rounds_ahead > 0) == (not spec_decode)
 
     prefills = rec.records(name="serve.prefill")
     assert sorted(p.attrs["rid"] for p in prefills) == [0, 1, 2, 3]
@@ -306,6 +318,7 @@ def _train_step_text():
 def _decode_step_text():
     from horovod_tpu.serving import (CacheConfig, PagedKVCache,
                                      build_decode_step, cache_sharding)
+    from horovod_tpu.serving.decode import no_round
     mesh = _mesh1()
     params = LlamaLM(CFG, dtype=jnp.float32).init(
         jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
@@ -320,7 +333,7 @@ def _decode_step_text():
     return jax.jit(step._fn).lower(
         params, cache.k, cache.v, jnp.zeros((2,), jnp.int32),
         cache.lengths_device(), cache.table_device(),
-        jnp.ones((2,), bool)).as_text(debug_info=True)
+        jnp.ones((2,), bool), no_round(2)).as_text(debug_info=True)
 
 
 _STAGES = ["hvd_exchange/compress", "hvd_exchange/collective",
