@@ -388,7 +388,7 @@ def _flash_decode(q, k, v, lengths, scale: float, bk: int):
 MLA_PAGES_PER_BLOCK = 64
 
 
-def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
+def mla_decode_attention(q, pool, page_table, *, layer, lengths,
                          value_dim: int, scale: float,
                          force_reference: bool = False):
     """Single-token decode attention in latent attention's ABSORBED form,
@@ -399,7 +399,11 @@ def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
     (``q_nope @ W_kvb^K``), the rest its rotated part.  ``pool``:
     ``(layers, pages, page_size, w)``, the cache's one pool: a token's
     row is its normalised latent (``value_dim`` columns) and, beside it,
-    the one rotated key ALL heads share.  ``page_table``: ``(b,
+    the one rotated key ALL heads share.  ``layer``: the plane of the
+    pool to read, a Python int (part of the kernel) or a traced int32
+    scalar (a looped model reads plane ``pass * layers + layer`` from
+    inside a rolled loop over its passes; the kernel then takes it as a
+    scalar-prefetched operand).  ``page_table``: ``(b,
     pages_per_slot)`` int32, row ``i``'s pages in order; ``lengths``:
     ``(b,)`` live tokens of each row.  Scores are ``(q . row) * scale``
     over the whole row; the values are the row's first ``value_dim``
@@ -427,10 +431,10 @@ def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
     if lengths.shape != (b,):
         raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
     if not force_reference and _pallas.pallas_enabled("mla_decode"):
-        return _mla_decode(q, pool, page_table, lengths, int(layer),
+        return _mla_decode(q, pool, page_table, lengths, layer,
                            value_dim, float(scale), MLA_PAGES_PER_BLOCK)
     s = page_table.shape[1] * pool.shape[2]
-    kv = pool[layer][page_table].reshape(b, s, w).astype(q.dtype)
+    kv = pool[layer, page_table].reshape(b, s, w).astype(q.dtype)
     logits = jnp.einsum("bhw,bsw->bhs", q, kv,
                         preferred_element_type=jnp.float32) * scale
     live = jnp.arange(s)[None, None, :] < lengths[:, None, None]
@@ -442,16 +446,19 @@ def mla_decode_attention(q, pool, page_table, *, layer: int, lengths,
 
 
 def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
-                       q_ref, pool_ref, o_ref, buf, sem, m_scr, l_scr,
-                       acc_scr, *, layer, scale, ppb, page, value_dim,
+                       *refs, plane, scale, ppb, page, value_dim,
                        kv_heads=1, value_off=0):
     """Grid ``(items,)``, sequential: item ``i`` is block ``blk_ref[i]``
     (``ppb`` pages) of row ``slot_ref[i]``; the first ``n_ref[0]`` items
     are live, a row's items follow one another, and the online-softmax
     state lives in VMEM scratch across them, as in ``_decode_kernel``.
     While item ``i`` computes out of one half of ``buf``, the pages of
-    item ``i + 1`` are on their way into the other.  The operands go to
-    the MXU in their own type (bfloat16 on the chip) with float32
+    item ``i + 1`` are on their way into the other.  Every page is read
+    from plane ``plane`` of the pool: a Python int, part of the program
+    (a DMA's address then costs the scalar core nothing: measured, 7% of
+    this kernel's time over 1 KB rows), or None, and then a sixth
+    prefetched scalar before ``q_ref`` names it.  The operands go to the
+    MXU in their own type (bfloat16 on the chip) with float32
     accumulation.
 
     ``kv_heads == 1``: every query head reads the whole row as its key
@@ -463,8 +470,10 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
     key/value head's tile-aligned columns and a row mask keeps its own:
     the MXU's cost is the key tiles it is loaded with, not the 8 rows
     that stream past them, and no array is ever cut below a tile."""
+    *plane_ref, q_ref, pool_ref, o_ref, buf, sem, m_scr, l_scr, acc_scr = refs
     i = pl.program_id(0)
     n_items = n_ref[0]
+    layer = plane_ref[0][0] if plane is None else plane
     bk = ppb * page
     heads, dk = q_ref.shape[1], q_ref.shape[2]
     rep = heads // kv_heads
@@ -556,7 +565,7 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
             o_ref[0] = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
 
 
-def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
+def _mla_decode(q, pool, page_table, lengths, layer, value_dim: int,
                 scale: float, ppb: int, *, kv_heads: int = 1,
                 value_off: int = 0, name: str = "hvd_mla_decode"):
     b, h, dk = q.shape
@@ -583,12 +592,17 @@ def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
         # The last block of a full row reads table entries past its end.
         page_table = jnp.pad(page_table, ((0, 0), (0, per_row * ppb - pps)))
 
-    def row(i, lens, table, slots, blks, n):
+    def row(i, lens, table, slots, blks, n, *plane):
         # Items past the last live one name its row again: nothing moves.
         return slots[jnp.minimum(i, n[0] - 1)], 0, 0
 
-    kernel = functools.partial(_mla_decode_kernel, layer=layer, scale=scale,
-                               ppb=ppb, page=page, value_dim=value_dim)
+    # A plane known when the program is built is part of it; a traced one
+    # (a looped model's ``pass * layers + layer``) rides as a scalar.
+    traced = (layer.astype(jnp.int32).reshape(1),) \
+        if isinstance(layer, jax.Array) else ()
+    kernel = functools.partial(_mla_decode_kernel, scale=scale, ppb=ppb,
+                               page=page, value_dim=value_dim,
+                               plane=None if traced else int(layer))
     if kv_heads > 1:
         kernel = functools.partial(kernel, kv_heads=kv_heads,
                                    value_off=value_off)
@@ -596,7 +610,7 @@ def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
+                num_scalar_prefetch=5 + len(traced),
                 grid=(items,),
                 in_specs=[pl.BlockSpec((1, h, dk), row),
                           pl.BlockSpec(memory_space=pl.ANY)],
@@ -615,10 +629,10 @@ def _mla_decode(q, pool, page_table, lengths, layer: int, value_dim: int,
             name=name,
             interpret=_pallas.interpret_mode(),
         )(lengths, page_table.astype(jnp.int32), slot, blk, n_items,
-          q.astype(pool.dtype), pool)
+          *traced, q.astype(pool.dtype), pool)
 
 
-def cca_decode_attention(q, pool, page_table, *, layer: int, lengths,
+def cca_decode_attention(q, pool, page_table, *, layer, lengths,
                          kv_heads: int, scale: float,
                          force_reference: bool = False):
     """Single-token grouped-query decode attention over rows that hold
@@ -628,8 +642,8 @@ def cca_decode_attention(q, pool, page_table, *, layer: int, lengths,
     ``q``: ``(b, h, d)``; ``pool``: ``(layers, pages, page_size, 2 *
     kv_heads * d)``, a token's row ``[k_0 .. k_{kv-1} | v_0 .. v_{kv-1}]``
     with no head dim (a ``(kv_heads, d)`` entry of two heads would be
-    padded fourfold by the (8, 128) tiling); ``page_table``, ``lengths``
-    as :func:`mla_decode_attention`.  Query head ``i`` attends to
+    padded fourfold by the (8, 128) tiling); ``layer``, ``page_table``,
+    ``lengths`` as :func:`mla_decode_attention`.  Query head ``i`` attends to
     key/value head ``i // (h / kv_heads)``; the result is ``(b, h, d)``
     float32, exactly zero for a row with ``lengths == 0``.
 
@@ -646,12 +660,12 @@ def cca_decode_attention(q, pool, page_table, *, layer: int, lengths,
     if lengths.shape != (b,):
         raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
     if not force_reference and _pallas.pallas_enabled("mla_decode"):
-        return _mla_decode(q, pool, page_table, lengths, int(layer), d,
+        return _mla_decode(q, pool, page_table, lengths, layer, d,
                            float(scale), MLA_PAGES_PER_BLOCK,
                            kv_heads=kv_heads, value_off=kv_heads * d,
                            name="hvd_cca_decode")
     s = page_table.shape[1] * pool.shape[2]
-    kv = pool[layer][page_table].reshape(b, s, 2, kv_heads, d).astype(
+    kv = pool[layer, page_table].reshape(b, s, 2, kv_heads, d).astype(
         q.dtype)
     qg = q.reshape(b, kv_heads, h // kv_heads, d)
     logits = jnp.einsum("bgrd,bsgd->bgrs", qg, kv[:, :, 0],

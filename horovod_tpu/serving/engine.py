@@ -270,7 +270,7 @@ class ServingEngine:
         self.adapters = adapters
         self.lora_alpha = lora_alpha
         self.cache_config = CacheConfig(
-            num_layers=spec.num_layers, slots=self.slots,
+            num_layers=spec.planes, slots=self.slots,
             page_size=self.page_size, max_len=self.max_len,
             dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
             page=spec.page, slot_state=spec.slot_state)
@@ -383,7 +383,8 @@ class ServingEngine:
         rec = _spans.recorder()
         with rec.span("dispatch", name="serve.prefill",
                       leg="serving_prefill", rid=req.rid, slot=slot,
-                      prompt_len=req.prompt_len):
+                      prompt_len=req.prompt_len, passes=self.spec.passes,
+                      planes=self.spec.planes):
             with rec.phase("prefill.dispatch", rid=req.rid):
                 if matched:
                     # Prefix hit: only the tail goes through the forward
@@ -510,11 +511,14 @@ class ServingEngine:
         ``live_tokens`` is what the round's attention reads: each slot's
         resident context and the token this round writes.  ``ahead``: 1
         where the round is dispatched while the one before is still in
-        flight."""
+        flight.  ``passes`` and ``planes``: how often the round runs the
+        layers, and from how many planes of the pool it reads each live
+        token (``LayerSpec.passes``, ``.planes``)."""
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=int(sum(int(self.cache.lengths[s]) + 1
-                                for s in slots)), ahead=int(ahead))
+                                for s in slots)), ahead=int(ahead),
+            passes=self.spec.passes, planes=self.spec.planes)
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """Dispatch one plain continuous-batching decode round over the

@@ -5,7 +5,7 @@ prompt); decode is bandwidth-bound (one token per step over resident
 KV).  Colocating them on one mesh serializes the two regimes: every
 admitted kilotoken prompt stalls the decode batch for a full prefill.
 The fleet splits them -- prefill workers on their own (virtual) mesh
-run :func:`~.decode.prefill_forward` and EXPORT the finished pages;
+run the model's prefill (``LayerSpec.prefill``) and EXPORT the finished pages;
 decode workers import those pages into their own
 :class:`~.kvcache.PagedKVCache` and never burn a step on prompt math.
 
@@ -53,9 +53,10 @@ import numpy as np
 from ..timeline import spans as _spans
 from ..timeline.metrics import registry as _registry
 from .controlplane import FleetScaler
-from .decode import greedy_sample, prefill_forward
+from .decode import greedy_sample
 from .engine import ServingEngine, _pct
 from .kvwire import decode_kv, encode_kv, import_pages, wire_tier
+from .layerspec import layer_spec
 from .router import FleetRouter
 from .scheduler import Request
 
@@ -102,10 +103,16 @@ class PrefillWorker:
         self.prefills = 0
         self.busy_s = 0.0
 
+        spec = layer_spec(config)
+        if spec.slot_state is not None:
+            raise NotImplementedError(
+                "handoff: the KV plane moves pages only, and this model's "
+                f"slots keep state beside their pages "
+                f"({spec.slot_state_holds})")
+
         def _fwd(p, toks, ad, aid):
-            return prefill_forward(p, config, toks, dtype=dtype,
-                                   adapters=ad, adapter_id=aid,
-                                   lora_alpha=16.0)
+            return spec.prefill(p, toks, dtype=dtype, adapters=ad,
+                                adapter_id=aid, lora_alpha=16.0)
 
         self._fwd = jax.jit(_fwd)
 
@@ -118,10 +125,13 @@ class PrefillWorker:
         t0 = time.monotonic()
         with _spans.recorder().span("dispatch", name="fleet_prefill",
                                     leg="serving_fleet_prefill"):
+            # Every plane of the prompt's pages (``LayerSpec.planes``
+            # leading entries a pool; the second pool may be None).
             logits, kl, vl = self._fwd(self.params, prompt_dev[None],
                                        None, None)
             first = int(greedy_sample(logits[:, -1, :])[0])
-            buf = encode_kv(np.asarray(kl[:, 0]), np.asarray(vl[:, 0]),
+            buf = encode_kv(np.asarray(kl[:, 0]),
+                            None if vl is None else np.asarray(vl[:, 0]),
                             page_size=self.page_size, tier=self.tier)
         key = f"r{req.rid}"
         self.kv.put_large(_SCOPE, key, buf)

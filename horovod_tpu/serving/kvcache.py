@@ -605,9 +605,11 @@ class PagedKVCache:
         self._allocated[slot] = len(entries)
         self.lengths[slot] = int(length)
 
-    def adopt_pages(self, k_pages, v_pages) -> List[Tuple[str, int]]:
+    def adopt_pages(self, k_pages, v_pages=None) -> List[Tuple[str, int]]:
         """Materialize STREAMED full pages (``[L, n, page_size, H, D]``,
-        the ``serving.kvwire`` f32 tier) as resident pool pages at
+        the ``serving.kvwire`` f32 tier; ``[planes, n, page_size,
+        *entry]`` of each pool in general, ``v_pages`` None where there is
+        one pool) as resident pool pages at
         refcount 1, owned by the caller.  The disaggregated import path
         then maps them into a slot with :meth:`attach_pages` and drops
         the importer's reference -- exactly the prefix-hit flow, except
@@ -635,8 +637,9 @@ class PagedKVCache:
         dt = jnp.dtype(self.config.dtype)
         dev = jnp.asarray(pids)
         self.k = _pool_set(self.k, jnp.asarray(k_pages, dt), dev)
-        self.v = _pool_set(self.v, jnp.asarray(np.asarray(v_pages), dt),
-                           dev)
+        if self.v is not None:
+            self.v = _pool_set(self.v, jnp.asarray(np.asarray(v_pages), dt),
+                               dev)
         return [("f", int(p)) for p in pids]
 
     def adopt_compressed_pages(self, kq, vq, kscale, vscale

@@ -74,7 +74,10 @@ class WirePages:
     length: int                    # tokens covered (full pages + tail)
     page_size: int
     dtype: str                     # pool dtype of the f32 tier / tail
-    # f32 tier: [L, full, page_size, H, D] in the pool dtype.
+    # f32 tier: [L, full, page_size, H, D] in the pool dtype (``L``:
+    # the pool's planes, passes x layers; ``[L, full, page_size,
+    # *entry]`` whatever a token's entry is, and every ``v`` None where
+    # the model keeps one pool).
     k_pages: Optional[np.ndarray] = None
     v_pages: Optional[np.ndarray] = None
     # fp8 tier: e4m3 pages + one f32 scale per (layer, page, offset) row.
@@ -96,57 +99,57 @@ class WirePages:
 
 
 def _quantize_full_pages(pages: np.ndarray):
-    """PR 14 cold-page codec over ``[L, n, ps, H, D]`` -- the SAME
+    """PR 14 cold-page codec over ``[L, n, ps, *entry]`` -- the SAME
     reshape and reduction axis as ``kvcache._quantize_pages``, so wire
     quantization of a page is bitwise what ``demote_page`` would have
     produced for the identical resident bytes."""
-    l, n, pg, hh, dd = pages.shape
-    q, s = fp8_quantize(jnp.asarray(pages).reshape(l * n * pg, hh * dd),
-                        axis=0)
-    return (np.asarray(q).reshape(l, n, pg, hh, dd),
+    l, n, pg = pages.shape[:3]
+    q, s = fp8_quantize(jnp.asarray(pages).reshape(l * n * pg, -1), axis=0)
+    return (np.asarray(q).reshape(pages.shape),
             np.asarray(s).reshape(l, n, pg))
 
 
-def encode_kv(k_layers, v_layers, *, page_size: int,
+def encode_kv(k_layers, v_layers=None, *, page_size: int,
               tier: Optional[str] = None) -> bytes:
     """Serialize a prompt's post-RoPE K/V (``[L, T, H, D]``, the
     ``prefill_forward`` per-sequence output) into one framed payload of
-    ``T // page_size`` full pages plus an f32 tail."""
+    ``T // page_size`` full pages plus an f32 tail.  In general ``[L, T,
+    *entry]`` a pool, as ``LayerSpec.prefill`` hands them back: ``L`` is
+    the pool's PLANES (every pass of a looped model's every layer: a
+    handoff ships them all), ``v_layers`` None for a model of one
+    pool."""
     tier = tier or wire_tier()
     if tier not in (TIER_F32, TIER_FP8):
         raise ValueError(f"unknown KV wire tier {tier!r}")
-    k = np.asarray(k_layers)
-    v = np.asarray(v_layers)
-    if k.shape != v.shape or k.ndim != 4:
+    pools = [np.asarray(k_layers)] + (
+        [] if v_layers is None else [np.asarray(v_layers)])
+    k = pools[0]
+    if k.ndim < 3 or any(v.shape != k.shape for v in pools[1:]):
         raise ValueError(
-            f"expected matching [L, T, H, D] K/V, got {k.shape} "
-            f"vs {v.shape}")
-    layers, length, heads, hd = k.shape
+            "expected [L, T, *entry] K/V (matching, where there are "
+            f"two), got {' vs '.join(str(z.shape) for z in pools)}")
+    layers, length = k.shape[:2]
+    entry = k.shape[2:]
     if length < 1:
         raise ValueError("cannot encode an empty context")
     full = length // page_size
     tail = length - full * page_size
-    kp = k[:, :full * page_size].reshape(layers, full, page_size,
-                                         heads, hd)
-    vp = v[:, :full * page_size].reshape(layers, full, page_size,
-                                         heads, hd)
+    pages = [z[:, :full * page_size].reshape(layers, full, page_size,
+                                             *entry) for z in pools]
     chunks = []
     if full:
         if tier == TIER_FP8:
-            kq, ks = _quantize_full_pages(kp)
-            vq, vs = _quantize_full_pages(vp)
-            chunks += [kq.tobytes(), vq.tobytes(),
-                       ks.astype(np.float32).tobytes(),
-                       vs.astype(np.float32).tobytes()]
+            quant = [_quantize_full_pages(z) for z in pages]
+            chunks += [q.tobytes() for q, _ in quant]
+            chunks += [s.astype(np.float32).tobytes() for _, s in quant]
         else:
-            chunks += [kp.tobytes(), vp.tobytes()]
+            chunks += [z.tobytes() for z in pages]
     if tail:
-        chunks += [k[:, full * page_size:].tobytes(),
-                   v[:, full * page_size:].tobytes()]
+        chunks += [z[:, full * page_size:].tobytes() for z in pools]
     payload = b"".join(chunks)
     header = json.dumps({
-        "tier": tier, "layers": layers, "kv_heads": heads,
-        "head_dim": hd, "page_size": page_size, "length": length,
+        "tier": tier, "layers": layers, "entry": list(entry),
+        "pools": len(pools), "page_size": page_size, "length": length,
         "dtype": str(k.dtype), "payload_bytes": len(payload),
         "sha256": hashlib.sha256(payload).hexdigest(),
     }, sort_keys=True).encode()
@@ -188,8 +191,9 @@ def decode_kv(buf: bytes) -> WirePages:
             "KV-page content hash mismatch: payload bytes do not match "
             "the header's sha256 (partial write or in-flight corruption)")
     tier = hdr["tier"]
-    layers, heads = int(hdr["layers"]), int(hdr["kv_heads"])
-    hd, ps = int(hdr["head_dim"]), int(hdr["page_size"])
+    layers, ps = int(hdr["layers"]), int(hdr["page_size"])
+    entry = tuple(int(n) for n in hdr["entry"])
+    two = int(hdr["pools"]) == 2
     length = int(hdr["length"])
     dt = np.dtype(hdr["dtype"])
     full = length // ps
@@ -206,24 +210,21 @@ def decode_kv(buf: bytes) -> WirePages:
         off += nbytes
         return arr
 
-    page_elems = layers * full * ps * heads * hd
+    def take_pools(shape, dtype):
+        count = int(np.prod(shape))
+        first = take(count, dtype, shape)
+        return first, (take(count, dtype, shape) if two else None)
+
     if full:
+        pshape = (layers, full, ps) + entry
         if tier == TIER_FP8:
-            pshape = (layers, full, ps, heads, hd)
-            wp.kq = take(page_elems, _FP8_DTYPE, pshape)
-            wp.vq = take(page_elems, _FP8_DTYPE, pshape)
-            wp.kscale = take(layers * full * ps, np.dtype(np.float32),
-                             (layers, full, ps))
-            wp.vscale = take(layers * full * ps, np.dtype(np.float32),
-                             (layers, full, ps))
+            wp.kq, wp.vq = take_pools(pshape, _FP8_DTYPE)
+            wp.kscale, wp.vscale = take_pools((layers, full, ps),
+                                              np.dtype(np.float32))
         else:
-            pshape = (layers, full, ps, heads, hd)
-            wp.k_pages = take(page_elems, dt, pshape)
-            wp.v_pages = take(page_elems, dt, pshape)
+            wp.k_pages, wp.v_pages = take_pools(pshape, dt)
     if tail:
-        tshape = (layers, tail, heads, hd)
-        wp.k_tail = take(layers * tail * heads * hd, dt, tshape)
-        wp.v_tail = take(layers * tail * heads * hd, dt, tshape)
+        wp.k_tail, wp.v_tail = take_pools((layers, tail) + entry, dt)
     return wp
 
 

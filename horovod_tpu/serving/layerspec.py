@@ -11,7 +11,8 @@ programs do not have, and the functions that build those programs.
 
 A config describes itself through a ``layer_spec()`` method
 (:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`,
-:class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`); a
+:class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`,
+:class:`~horovod_tpu.serving.loop_dense.LoopDenseConfig`); a
 ``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
 described here, by the functions of ``serving/decode.py``.
 """
@@ -30,7 +31,7 @@ FEATURES = ("tp", "lora", "spec_decode", "kv_compress", "prefill_chunk",
 class LayerSpec:
     attention: str                    # "gqa" | "mla" | "cca"
     # Trailing dims of ONE token's entry in the first and the second pool
-    # (``[layers, pages, page_size, *dims]``), and what each holds; the
+    # (``[planes, pages, page_size, *dims]``), and what each holds; the
     # second None: the model keeps one pool.
     page: Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]
     page_holds: Tuple[str, Optional[str]]
@@ -41,9 +42,9 @@ class LayerSpec:
     # the pools are whole on every chip).
     tp_page_dim: Optional[int]
     # ``prefill(params, tokens, *, dtype, adapters, adapter_id,
-    # lora_alpha, past) -> (logits, first_layers, second_layers)``, each
-    # ``[layers, batch, t, *page entry]`` (None for a pool not kept);
-    # with ``slot_state`` a fourth: ``[layers, batch, slot_state]``, what
+    # lora_alpha, past) -> (logits, first_planes, second_planes)``, each
+    # ``[planes, batch, t, *page entry]`` (None for a pool not kept);
+    # with ``slot_state`` a fourth: ``[planes, batch, slot_state]``, what
     # the slot keeps once the prompt's last token is in.
     prefill: Callable[..., Any]
     # ``build_step(mesh, *, slots, page_size, pages_per_slot, dtype,
@@ -64,12 +65,18 @@ class LayerSpec:
     step_tells: Tuple[str, ...] = ()
     # Values a slot keeps a layer BESIDE its pages (None: pages are all
     # a sequence has) and what they are.  ``PagedKVCache`` holds them as
-    # ``[layers, slots, slot_state]``: the prefill hands back the row of
+    # ``[planes, slots, slot_state]``: the prefill hands back the row of
     # the prompt's last token, the decode step (which takes the array
     # after ``active`` and returns it after the pools, donated) rewrites
     # the rows of its live slots, release clears a row.
     slot_state: Optional[int] = None
     slot_state_holds: Optional[str] = None
+    # Times a token runs through the layers, over the SAME weights (a
+    # looped model; one for every other).  Each pass keeps keys and
+    # values of its own: the pools (and the slot state) have ``planes``
+    # = ``passes * num_layers`` leading entries, pass ``t`` of layer
+    # ``l`` in plane ``t * num_layers + l``.
+    passes: int = 1
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla", "cca"):
@@ -80,6 +87,8 @@ class LayerSpec:
                 h is None for h in self.page_holds] or self.page[0] is None:
             raise ValueError(
                 f"pools {self.page} and what they hold {self.page_holds}")
+        if self.passes < 1:
+            raise ValueError(f"passes {self.passes}")
         if (self.slot_state is None) != (self.slot_state_holds is None) \
                 or (self.slot_state is not None and self.slot_state < 1):
             raise ValueError(
@@ -88,7 +97,15 @@ class LayerSpec:
 
     @property
     def num_layers(self) -> int:
+        """Layers that have weights."""
         return len(self.ffn)
+
+    @property
+    def planes(self) -> int:
+        """Leading entries of the pools: what sizes, writes, reads,
+        frees, re-prefills or ships the cache counts these, never the
+        layers."""
+        return self.passes * len(self.ffn)
 
     def require(self, **wanted: bool) -> None:
         """Raise ``NotImplementedError``, by name, for each feature that

@@ -1,12 +1,15 @@
 """What the one-chip decode steps share, written once.
 
-``mla_moe.py`` and ``cca_moe.py`` build their decode programs from the
-same parts: the embedding read into a float32 residual stream, where a
-round's token lands in the page pool, a loop over the layers that hands
-each one the pool (and whatever else the model carries), the readout
-over a tied or an untied head, the running ``[routed layers, experts]``
-histogram with what the step tells of its round, and the jit that
-donates every operand the step rewrites.  ``decode.py``'s step is a
+``mla_moe.py``, ``cca_moe.py`` and ``loop_dense.py`` build their decode
+programs from the same parts: the embedding read into a float32
+residual stream, where a round's token lands in the page pool, a loop
+over the layers that hands each one the pool (and whatever else the
+model carries), around it -- for a model whose tokens make several
+passes over the same layers -- ONE rolled loop over the passes with the
+model's own step between two of them, the readout over a tied or an
+untied head, the running ``[routed layers, experts]`` histogram with
+what the step tells of its round, and the jit that donates every operand
+the step rewrites.  ``decode.py``'s step is a
 ``shard_map`` over the ``tp`` axis with adapter banks and an fp8 pool and
 keeps its own loop; it shares ``_dense`` and ``_rmsnorm``, which live
 there.
@@ -14,7 +17,7 @@ there.
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +34,11 @@ class Round(NamedTuple):
     lengths: jax.Array      # [slots] live tokens once it is written
     page_table: jax.Array   # [slots, pages_per_slot]
     active: jax.Array       # [slots] bool
+    # The pool's first plane of the pass being run: 0, a Python int, for
+    # a model of one pass; ``pass * layers``, traced, inside the loop
+    # over the passes.  Layer ``li`` reads and writes plane ``first_plane
+    # + li``.
+    first_plane: object = 0
 
 
 def lane_pad(x, width: int):
@@ -50,10 +58,12 @@ def embed(p, tokens):
     return p["tok_embed"][tokens].astype(jnp.float32)
 
 
-def readout(x, p, eps: float, dtype, *, tied: bool):
-    """Float32 logits over the final norm: against ``lm_head`` or, tied,
-    against the embedding's own rows."""
-    x = _rmsnorm(x, p["final_norm"]["scale"], dtype, eps)
+def readout(x, p, eps: float, dtype, *, tied: bool, normed: bool = False):
+    """Float32 logits over the final norm (``normed``: ``x`` has been
+    through it already): against ``lm_head`` or, tied, against the
+    embedding's own rows."""
+    if not normed:
+        x = _rmsnorm(x, p["final_norm"]["scale"], dtype, eps)
     if not tied:
         return dense_out(x, p["lm_head"], dtype)
     return jax.lax.dot_general(
@@ -85,7 +95,9 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                         eps: float, tied: bool, page_size: int,
                         scratch: int, dtype, tells: Sequence[str],
                         carried: int, meta: dict,
-                        local: Callable = lambda x: None
+                        local: Callable = lambda x: None,
+                        routed: bool = True, passes: int = 1,
+                        after_pass: Optional[Callable] = None
                         ) -> ServingDecodeStep:
     """The jitted step ``name``::
 
@@ -101,7 +113,8 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     and no further; ``local(x)`` before the first), and for a routed
     layer returns its row of the histogram and the ``[experts]`` counts
     of its live slots.  ``routed`` is the
-    running ``[routed layers, experts]`` int32 histogram.  ``told``, the
+    running ``[routed layers, experts]`` int32 histogram (``routed=False``:
+    the step has no such operand and no such output).  ``told``, the
     step's last output, is what it tells of its round, one int32 vector
     ``[tokens | finite | tells]`` (``decode.tell_round``): every slot's
     greedy token over the float32 logits, every slot's finite flag, and
@@ -111,34 +124,84 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     (``decode.round_inputs``).  The pool,
     the carried arrays and ``routed`` are donated and their successors
     returned; ``prev`` is read only.
+
+    ``after_pass`` given (a looped model): a token runs the
+    ``num_layers`` layers ``passes`` times, over the same weights, as ONE
+    rolled loop (``lax.fori_loop``) around the layer bodies: pass ``t``
+    hands the layers ``rnd.first_plane = t * num_layers`` (traced), and
+    ``after_pass(x, p) -> (x, leave)`` runs after each, the last too: the
+    model's own step between two passes and, ``[slots]`` float32, the
+    share of a token that would leave the loop here if it were still in
+    it.  The step then takes one more
+    operand before ``prev`` and hands its successor back in that place,
+    donated: ``exit_mass`` ``[passes]`` float32, to which it adds, a
+    pass, the exit distribution's mass summed over the round's live
+    slots (``leave_t * prod_{j<t} (1 - leave_j)``; what is left, at the
+    last pass).  Every pass is always run and the readout takes what the
+    last one left, which ``after_pass`` has normed.  Without
+    ``after_pass`` there is no loop and no such operand, and the step
+    lowers to what it lowered to before there was one.
     """
+    looped = after_pass is not None
+    if passes > 1 and not looped:
+        raise ValueError(f"{passes} passes and no after_pass")
+
     def step(params, pool, no_pool, tokens, positions, page_table, active,
              *state):
-        *carry, routed, prev = state
+        *state, prev = state
+        mass = state.pop() if looped else None
+        hist = state.pop() if routed else None
+        carry = state
         p = params["params"] if "params" in params else params
         tokens, active = round_inputs(tokens, active, prev)
         x = embed(p, tokens)                                     # [S, d]
         rnd = round_of(positions, page_table, active,
                        page_size=page_size, scratch=scratch)
         told = [jnp.zeros((), jnp.int32) for _ in tells]
-        within = local(x)
-        for li in range(num_layers):
-            x, pool, carry, within, mi, counts = layer(
-                li, p[f"layer_{li}"], x, pool, carry, within, rnd)
-            if counts is not None:
-                routed = routed.at[mi].add(counts)
-                told = [_JOIN[t](was, TELLS[t](counts))
-                        for t, was in zip(tells, told)]
-        logits = readout(x, p, eps, dtype, tied=tied)
-        return (logits, pool, no_pool, *carry, routed,
+
+        def one_pass(x, pool, carry, hist, told, rnd):
+            within = local(x)
+            for li in range(num_layers):
+                x, pool, carry, within, mi, counts = layer(
+                    li, p[f"layer_{li}"], x, pool, carry, within, rnd)
+                if counts is not None:
+                    hist = hist.at[mi].add(counts)
+                    told = [_JOIN[t](was, TELLS[t](counts))
+                            for t, was in zip(tells, told)]
+            return x, pool, list(carry), hist, told
+
+        if not looped:
+            x, pool, carry, hist, told = one_pass(x, pool, carry, hist,
+                                                  told, rnd)
+        else:
+            live = active.astype(jnp.float32)
+
+            def body(t, loop):
+                x, pool, carry, hist, told, mass, stay = loop
+                x, pool, carry, hist, told = one_pass(
+                    x, pool, carry, hist, told,
+                    rnd._replace(first_plane=t * num_layers))
+                x, leave = after_pass(x, p)
+                here = jnp.where(t < passes - 1, leave * stay, stay)
+                return (x, pool, carry, hist, told,
+                        mass.at[t].add(jnp.sum(here * live)),
+                        stay * (1.0 - leave))
+
+            x, pool, carry, hist, told, mass, _ = jax.lax.fori_loop(
+                0, passes, body, (x, pool, list(carry), hist, told, mass,
+                                  jnp.ones(x.shape[:1], jnp.float32)))
+        logits = readout(x, p, eps, dtype, tied=tied, normed=looped)
+        own = ([hist] if routed else []) + ([mass] if looped else [])
+        return (logits, pool, no_pool, *carry, *own,
                 tell_round(logits, told))
 
     step.__name__ = step.__qualname__ = name
     fn = jax.jit(step, donate_argnums=(1,) + tuple(
-        range(7, 8 + carried)))
+        range(7, 7 + carried + routed + looped)))
     return ServingDecodeStep(fn, dict(
         meta, kind="serving_decode", world=1, tp=1, num_layers=num_layers,
-        dtype=str(jnp.dtype(dtype)), lora=False, compress=False))
+        passes=passes, dtype=str(jnp.dtype(dtype)), lora=False,
+        compress=False))
 
 
 def refuse_beyond_one_chip(what: str, mesh, *, width: int, with_lora: bool,
@@ -167,3 +230,18 @@ def publish_routed(hist) -> None:
     for layer, expert in zip(*np.nonzero(hist)):
         counter.labels(layer=int(layer), expert=int(expert)).inc(
             int(hist[layer, expert]))
+
+
+def publish_exit_mass(mass) -> None:
+    """The device's ``[passes]`` exit mass (the exit distribution summed
+    over every live token of every round) into the registry, once a
+    ``serve``."""
+    import numpy as np
+
+    from ..timeline import metrics as _metrics
+    counter = _metrics.registry().counter(
+        "loop.exit_mass",
+        "mass of the exit distribution a pass, summed over decoded tokens",
+        labelnames=("pass",))
+    for t, m in enumerate(np.asarray(mass, np.float64)):
+        counter.labels(**{"pass": t}).inc(float(m))

@@ -117,6 +117,16 @@ def kernels(topology: str) -> int:
             q, pool, page_table, layer=3, lengths=lengths, kv_heads=2,
             scale=128 ** -0.5)
 
+    def loop_decode(q, pool, page_table, lengths):
+        # The plane is a traced scalar: pass t of layer 5 of 48, inside a
+        # rolled loop over four passes.
+        def one_pass(t, acc):
+            return acc + attention.cca_decode_attention(
+                q, pool, page_table, layer=t * 48 + 5, lengths=lengths,
+                kv_heads=16, scale=128 ** -0.5)
+        return jax.lax.fori_loop(0, 4, one_pass,
+                                 jnp.zeros(q.shape, jnp.float32))
+
     def prefill(q, k, v):
         return attention.flash_attention(q, k, v, causal=True)
 
@@ -169,6 +179,16 @@ def kernels(topology: str) -> int:
             spec((1, 8, 512, 128), jnp.bfloat16),
             spec((1, 2, 512, 128), jnp.bfloat16),
             spec((1, 2, 512, 128), jnp.bfloat16)]),
+        # The looped model's page walk: 20 slots out of rows that hold 16
+        # key and 16 value heads of 128 side by side (192 planes of 321
+        # pages of 16 rows, 16 pages a slot), one query row a key head,
+        # the plane traced; and its 128-token prefill, 16 heads over 16.
+        "loop_decode_b20": (loop_decode, [
+            spec((20, 16, 128), jnp.bfloat16),
+            spec((192, 321, 16, 4096), jnp.bfloat16),
+            spec((20, 16), jnp.int32), spec((20,), jnp.int32)]),
+        "flash_loop_prefill_128": (
+            prefill, [spec((1, 16, 128, 128), jnp.bfloat16)] * 3),
         # BERT-Large, batch 32/chip, seq 128: 16 heads of 64.  One block
         # holds the sequence: the head-group kernels, forward and one
         # backward, all 16 heads a grid step.

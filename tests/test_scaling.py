@@ -180,12 +180,17 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # fused, then down) at a decode round's and at an 8k prefill's row
     # tiles, and at 16 experts of 2048 x 2048 (gate and up in column
     # slices); the page walk over rows of two key and two value heads (96
-    # slots) and that model's 512-token prefill, 8 query heads over 2.
+    # slots) and that model's 512-token prefill, 8 query heads over 2;
+    # the same walk over rows of 16 key and 16 value heads (20 slots)
+    # with its plane a traced scalar inside a rolled loop (ONE call in
+    # the loop's body), and that model's 128-token prefill.
     assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
                    "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
                    "head_group": ["flash_cca_prefill_512",
+                                  "flash_loop_prefill_128",
                                   "flash_bert_large",
                                   "flash_mistral_prefill_512"],
+                   "loop_decode_b20": 1, "flash_loop_prefill_128": 1,
                    "flash_decode_b8": 1, "mla_decode_b64": 1,
                    "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2,
                    "moe_gmm_wide_decode": 2, "moe_gmm_wide_prefill_512": 2,
