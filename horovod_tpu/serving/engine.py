@@ -155,6 +155,10 @@ class ServingReport:
     # skipped outright (matched pages attached instead of computed).
     prefill_flops_avoided: float = 0.0
     session_resumes: int = 0
+    # The serve loop's own account of the call: self seconds by span
+    # name (``serve.account``'s ``self_ns``; the root ``serve`` holds
+    # what no phase covers).  They add up to the ``serve`` span's time.
+    loop_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -164,11 +168,12 @@ class ServingReport:
 class _Flight:
     """A decode round the chip has been handed and the host has not read
     yet: its live slots, the step's ``told`` vector (on the device) and
-    when it was dispatched."""
+    when it was dispatched, and the number of its ``decode.round``."""
 
     slots: List[int]
     told: Any
     t0: float
+    round: int
 
 
 @jax.jit
@@ -180,6 +185,33 @@ def _verify_told(logits):
     finite = jnp.isfinite(jnp.sum(logits, axis=(-2, -1)))
     return jnp.concatenate(
         [greedy_sample(logits), finite[:, None].astype(jnp.int32)], axis=1)
+
+
+def _file_account(rec, root, before, **counts) -> Dict[str, float]:
+    """File the ``serve.account`` record of the ``serve`` call whose
+    span ``root`` is still open, and return its self seconds by name.
+
+    ``before``: the recorder's ``(totals(), filed, dropped)`` when the
+    call began.  ``spans`` gives ``{count, total_ns, self_ns}`` for every
+    span name that closed during the call, and for ``serve`` itself as
+    of now: the ``self_ns`` add up to ``wall_ns`` as long as every span
+    of the process lay under this root.  ``filed`` and ``dropped`` are
+    the ring's over the call: a reader that finds fewer than ``filed``
+    records begun since the root's start knows the ring has lost some
+    of them."""
+    totals0, filed0, dropped0 = before
+    wall_ns = root.elapsed_ns()
+    spans = {"serve": {"count": 1, "total_ns": wall_ns,
+                       "self_ns": wall_ns - root.child_ns}}
+    for name, total in rec.totals().items():
+        was = totals0.get(name, (0, 0, 0))
+        if total[0] > was[0]:
+            spans[name] = dict(zip(("count", "total_ns", "self_ns"),
+                                   (a - b for a, b in zip(total, was))))
+    rec.file("serve.account", under="serve", spans=spans, wall_ns=wall_ns,
+             filed=rec.filed - filed0, dropped=rec.dropped - dropped0,
+             **counts)
+    return {name: t["self_ns"] / 1e9 for name, t in spans.items()}
 
 
 def _pct(values: List[float], q: float) -> float:
@@ -354,6 +386,8 @@ class ServingEngine:
         then prefill the remaining tail -- chunked when it is long.
         """
         matched, entries = 0, ()
+        req.prefill_start_s = now()
+        st["prefills"] += 1
         if self._prefix is not None:
             matched, entries = self._prefix.match(req.prompt)
             st["prefix_queries"] += 1
@@ -374,17 +408,23 @@ class ServingEngine:
                 "req": req, "dev": dev, "pos": matched,
                 "start": matched, "past": past}
         else:
-            first = self._do_prefill(slot, req, dev, matched=matched,
-                                     entries=entries)
+            flight = st.get("in_flight")
+            first = self._do_prefill(
+                slot, req, dev, matched=matched, entries=entries,
+                behind=-1 if flight is None else flight.round)
             self._join_decode(st, slot, req, first, now)
 
     def _do_prefill(self, slot: int, req: Request, prompt_dev,
-                    matched: int = 0, entries: Sequence = ()) -> int:
+                    matched: int = 0, entries: Sequence = (),
+                    behind: int = -1) -> int:
+        """``behind``: the number of the decode round in flight while
+        this prefill is dispatched (it queues behind it on the chip), -1
+        where there is none."""
         rec = _spans.recorder()
         with rec.span("dispatch", name="serve.prefill",
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
-                      planes=self.spec.planes):
+                      planes=self.spec.planes, behind=behind):
             with rec.phase("prefill.dispatch", rid=req.rid):
                 if matched:
                     # Prefix hit: only the tail goes through the forward
@@ -426,23 +466,28 @@ class ServingEngine:
         The final chunk's full-context K/V is scattered once -- chunked
         and whole-prompt prefill land the identical cache state.
         """
+        rec = _spans.recorder()
         for slot in list(self._chunking):
             c = self._chunking[slot]
             req: Request = c["req"]
             chunk = c["dev"][c["pos"]:c["pos"] + self.prefill_chunk]
-            with _spans.recorder().span("dispatch", name="prefill_chunk",
-                                        leg="serving_prefill_chunk"):
+            with rec.span("dispatch", name="prefill_chunk",
+                          leg="serving_prefill_chunk"):
                 logits, kl, vl = self._prefill_chunked(
                     self.params, chunk[None], c["past"])
-            c["past"] = (kl, vl)
-            c["pos"] += int(chunk.shape[0])
-            if c["pos"] < req.prompt_len:
-                continue
-            del self._chunking[slot]
-            start = int(c.get("start", 0))
-            self.cache.write_prefill(slot, kl[:, 0, start:],
-                                     vl[:, 0, start:], start=start)
-            first = int(greedy_sample(logits[:, -1, :])[0])
+                c["past"] = (kl, vl)
+                c["pos"] += int(chunk.shape[0])
+                if c["pos"] < req.prompt_len:
+                    continue
+                # The last chunk's span holds the pool write and the
+                # first token's fetch, as ``serve.prefill`` does.
+                del self._chunking[slot]
+                start = int(c.get("start", 0))
+                with rec.phase("prefill.write_kv", rid=req.rid):
+                    self.cache.write_prefill(slot, kl[:, 0, start:],
+                                             vl[:, 0, start:], start=start)
+                with rec.phase("prefill.sample_fetch", rid=req.rid):
+                    first = int(greedy_sample(logits[:, -1, :])[0])
             self._join_decode(st, slot, req, first, now)
 
     def _join_decode(self, st: Dict[str, Any], slot: int, req: Request,
@@ -509,7 +554,12 @@ class ServingEngine:
                     ahead: bool = False):
         """The ``decode.round`` span of one round over ``slots``.
         ``live_tokens`` is what the round's attention reads: each slot's
-        resident context and the token this round writes.  ``ahead``: 1
+        resident context and the token this round writes.  ``round``:
+        the number of the round whose ``decode.dispatch`` lies under
+        this span; ``decode.sample_fetch`` and ``decode.bookkeep`` carry
+        the number of the round they RETIRE themselves, because under
+        the look-ahead they lie under the span of the round after.
+        ``ahead``: 1
         where the round is dispatched while the one before is still in
         flight.  ``passes`` and ``planes``: how often the round runs the
         layers, and from how many planes of the pool it reads each live
@@ -553,6 +603,7 @@ class ServingEngine:
         phase = _spans.recorder().phase
         slots = self._decode_slots()
         flying: Optional[_Flight] = st.get("in_flight")
+        n = int(st["decode_steps"])
         with self._round_span(st, slots, ahead=flying is not None):
             with phase("decode.reserve"):
                 for slot in slots:
@@ -598,7 +649,7 @@ class ServingEngine:
                 self.scheduler.active[slot].in_flight += 1
             st["decode_steps"] += 1
             st["occ_samples"].append(self.scheduler.occupancy)
-            this = _Flight(slots, self._told, t0)
+            this = _Flight(slots, self._told, t0, n)
             if "in_flight" not in st:
                 return self._retire(st, this, now)
             st["in_flight"] = this
@@ -615,7 +666,7 @@ class ServingEngine:
         after its dispatch)."""
         sched = self.scheduler
         phase = _spans.recorder().phase
-        with phase("decode.sample_fetch"):
+        with phase("decode.sample_fetch", round=flight.round):
             sampled, finite, tells = read_told(flight.told, self.slots)
         step_s = time.monotonic() - flight.t0
         poisoned = []
@@ -623,7 +674,7 @@ class ServingEngine:
         # a routed model's touched experts) goes on the bookkeep span.
         told = {name: int(x) for name, x in zip(self.spec.step_tells,
                                                 tells)}
-        with phase("decode.bookkeep", **told):
+        with phase("decode.bookkeep", round=flight.round, **told):
             t_tok = now()
             for slot in flight.slots:
                 req = sched.active[slot]
@@ -676,6 +727,7 @@ class ServingEngine:
         width = k + 1
         self._catch_up(st, now)
         slots = self._decode_slots()
+        n = int(st["decode_steps"])
         reqs = {s: sched.active[s] for s in slots}
         base = {s: int(cache.lengths[s]) for s in slots}
         with self._round_span(st, slots):
@@ -702,11 +754,11 @@ class ServingEngine:
                     args += list(cache.compress_operands())
             t0 = time.monotonic()
             logits, cache.k, cache.v = self.verify_step(*args)
-            with phase("decode.sample_fetch"):
+            with phase("decode.sample_fetch", round=n):
                 told = np.asarray(_verify_told(logits))      # sync point
                 sampled, finite = told[:, :width], told[:, width] != 0
             step_s = time.monotonic() - t0
-            with phase("decode.bookkeep"):
+            with phase("decode.bookkeep", round=n):
                 st["decode_steps"] += 1
                 st["spec_rounds"] = st.get("spec_rounds", 0) + 1
                 st["occ_samples"].append(sched.occupancy)
@@ -823,7 +875,7 @@ class ServingEngine:
             "spec_rounds": 0, "proposed": 0, "accepted": 0,
             "prefix_queries": 0, "prefix_hits": 0,
             "prefill_cached": 0, "prefill_computed": 0,
-            "session_resumes": 0,
+            "session_resumes": 0, "prefills": 0,
             "last_tokens": np.zeros((self.slots,), np.int32),
             "adapter_ids": np.zeros((self.slots,), np.int32),
             # This loop runs one round ahead (``decode_once``): the
@@ -833,8 +885,10 @@ class ServingEngine:
         prompts_dev: Dict[int, Any] = {}
         self._chunking.clear()
 
-        phase = _spans.recorder().phase
-        with phase("serve", requests=len(admissible)), \
+        rec = _spans.recorder()
+        phase = rec.phase
+        before = (rec.totals(), rec.filed, rec.dropped)
+        with phase("serve", requests=len(admissible)) as root, \
                 RequestPrefetcher(admissible, self.prefetch_depth) as feed:
             fetched = next(feed, None)
 
@@ -885,6 +939,10 @@ class ServingEngine:
                 else:
                     self.decode_once(st, now)
 
+            loop_s = _file_account(rec, root, before,
+                                   rounds=int(st["decode_steps"]),
+                                   prefills=int(st["prefills"]))
+
         wall_s = max(time.monotonic() - start, 1e-9)
         if self._step_state:
             # What the step accumulated on the device over this call,
@@ -920,4 +978,4 @@ class ServingEngine:
             prefill_tokens_cached=cached,
             prefill_flops_avoided=(cached / (cached + computed)
                                    if cached + computed else 0.0),
-            session_resumes=int(st["session_resumes"]))
+            session_resumes=int(st["session_resumes"]), loop_s=loop_s)

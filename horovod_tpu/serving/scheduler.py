@@ -126,6 +126,9 @@ class Request:
     slot: int = -1
     tokens: List[int] = dataclasses.field(default_factory=list)
     admit_s: Optional[float] = None
+    # The engine's clock when it took the admitted request up to prefill
+    # it (``ServingEngine._begin_prefill``).
+    prefill_start_s: Optional[float] = None
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
     # The engine's arrival-faithful clock at each emitted token, the
@@ -422,6 +425,7 @@ class ContinuousBatchScheduler:
         _spans.recorder().file(
             "request", under="serve", rid=req.rid,
             arrival_s=req.arrival_s, admit_s=req.admit_s,
+            prefill_start_s=req.prefill_start_s,
             first_token_s=req.first_token_s, done_s=now_s,
             prompt_len=req.prompt_len, token_times=req.token_times)
         self._m_requests.labels(

@@ -218,138 +218,3 @@ class Timeline:
             except OSError:
                 pass
             atexit.unregister(self.close)
-
-
-class DispatchGapMonitor:
-    """Per-window host-dispatch-gap fraction.
-
-    The scan-loop layer exists to shrink host time that is NOT spent
-    inside device dispatch/fetch calls -- Python glue, input handling,
-    the per-step fence.  This monitor measures it directly: wrap every
-    dispatch (step/loop call, final value fetch) in :meth:`dispatch`;
-    per window, ``gap_fraction = 1 - dispatched_time / wall_time`` --
-    the fraction of wall-clock the devices could have been starved by
-    the host.  A k-step scan loop drives it toward zero because one
-    dispatch covers k steps.
-
-    When a :class:`Timeline` is active it feeds a ``host_dispatch_gap``
-    counter track.
-    """
-
-    def __init__(self, timeline: Optional[Timeline] = None):
-        self.timeline = timeline
-        self.windows: list = []
-        self._t0: Optional[float] = None
-        self._dispatched = 0.0
-
-    def begin_window(self) -> None:
-        self._t0 = time.perf_counter()
-        self._dispatched = 0.0
-
-    @contextlib.contextmanager
-    def dispatch(self):
-        """Time one host->device dispatch (or device->host fetch)."""
-        t = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._dispatched += time.perf_counter() - t
-
-    def end_window(self) -> float:
-        """Close the window; returns (and records) its gap fraction."""
-        if self._t0 is None:
-            raise RuntimeError("end_window() without begin_window()")
-        wall = time.perf_counter() - self._t0
-        # Clamp dispatched time into [0, wall]: a clock stepping
-        # backwards mid-window (mocked clocks, NTP slews) must yield a
-        # fraction in [0, 1], never a negative gap or one above 1.
-        dispatched = max(self._dispatched, 0.0)
-        gap = 1.0 - min(dispatched / wall, 1.0) if wall > 0 else 0.0
-        gap = min(max(gap, 0.0), 1.0)
-        self.windows.append(gap)
-        self._t0 = None
-        if self.timeline is not None:
-            self.timeline.counter("host_dispatch_gap", gap)
-        from . import metrics as _metrics
-        _metrics.registry().gauge(
-            "horovod_dispatch_gap_fraction",
-            "Last DispatchGapMonitor window: host time NOT spent "
-            "dispatching (0 = devices never starved)").set(gap)
-        return gap
-
-    @property
-    def gap_fraction(self) -> float:
-        """Mean gap fraction over all closed windows (0.0 if none)."""
-        if not self.windows:
-            return 0.0
-        return float(sum(self.windows) / len(self.windows))
-
-
-class OverlapMonitor:
-    """Per-window exchange-overlap fraction (the backward-overlap metric).
-
-    The microbatched exchange (``training.py``, ``microbatches=k``) exists
-    to hide gradient wire time behind backward compute.  This monitor
-    reports how much of a known communication budget was actually hidden:
-    give it the window's pure-compute time per step (``compute_s``, e.g.
-    measured at n=1 or with the exchange disabled) and the predicted
-    exchange time per step (``comm_s``, e.g. payload bytes / link
-    bandwidth); per window of ``steps`` steps,
-
-        exposed  = max(0, wall/steps - compute_s)   # comm NOT hidden
-        hidden   = max(0, comm_s - exposed)
-        fraction = hidden / comm_s                  # in [0, 1]
-
-    1.0 means the exchange vanished behind compute (perfect overlap);
-    0.0 means every wire second extended the step (no overlap -- the
-    monolithic post-backward exchange).  ``comm_s <= 0`` (single chip, no
-    exchange) records 0.0 by convention: there is nothing to hide.
-
-    When a :class:`Timeline` is active it feeds an ``exchange_overlap``
-    counter track -- the overlap analogue of :class:`DispatchGapMonitor`.
-    """
-
-    def __init__(self, compute_s: float, comm_s: float,
-                 timeline: Optional[Timeline] = None):
-        if compute_s < 0 or comm_s < 0:
-            raise ValueError("compute_s and comm_s must be >= 0")
-        self.compute_s = compute_s
-        self.comm_s = comm_s
-        self.timeline = timeline
-        self.windows: list = []
-        self._t0: Optional[float] = None
-
-    def begin_window(self) -> None:
-        self._t0 = time.perf_counter()
-
-    def end_window(self, steps: int) -> float:
-        """Close a window of ``steps`` steps; returns (and records) its
-        overlap fraction."""
-        if self._t0 is None:
-            raise RuntimeError("end_window() without begin_window()")
-        if steps < 1:
-            raise ValueError(f"steps must be >= 1, got {steps}")
-        wall = time.perf_counter() - self._t0
-        self._t0 = None
-        if self.comm_s <= 0.0:
-            frac = 0.0
-        else:
-            exposed = max(0.0, wall / steps - self.compute_s)
-            hidden = max(0.0, self.comm_s - exposed)
-            frac = min(hidden / self.comm_s, 1.0)
-        self.windows.append(frac)
-        if self.timeline is not None:
-            self.timeline.counter("exchange_overlap", frac)
-        from . import metrics as _metrics
-        _metrics.registry().gauge(
-            "horovod_exchange_overlap_fraction",
-            "Last OverlapMonitor window: fraction of the exchange "
-            "hidden behind backward compute").set(frac)
-        return frac
-
-    @property
-    def overlap_fraction(self) -> float:
-        """Mean overlap fraction over all closed windows (0.0 if none)."""
-        if not self.windows:
-            return 0.0
-        return float(sum(self.windows) / len(self.windows))

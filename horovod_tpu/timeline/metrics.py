@@ -9,8 +9,6 @@ in the runtime feeds --
 - the per-step :class:`StepReport` sampled host-side around each
   executable call (``training.py``; wall time, exchanged wire bytes,
   codec, microbatches, steps-per-exec),
-- :class:`~horovod_tpu.timeline.DispatchGapMonitor` /
-  :class:`~horovod_tpu.timeline.OverlapMonitor` window fractions,
 - ``controller.fusion.plan_cache_stats()`` and
   ``collectives.eager.deferred_fuse_stats()`` (pulled lazily through
   registered collectors so resets stay consistent),
@@ -585,12 +583,6 @@ def install_default_metrics() -> None:
               "Equivalent uncompressed exchange bytes per optimizer step")
     reg.gauge("horovod_compression_ratio",
               "uncompressed / wire bytes of the gradient exchange")
-    reg.gauge("horovod_dispatch_gap_fraction",
-              "Last DispatchGapMonitor window: host time NOT spent "
-              "dispatching (0 = devices never starved)")
-    reg.gauge("horovod_exchange_overlap_fraction",
-              "Last OverlapMonitor window: fraction of the exchange "
-              "hidden behind backward compute")
     reg.gauge("horovod_plan_buckets",
               "Bucket count of the most recently explained exchange plan")
     reg.counter("horovod_elastic_reset_total",

@@ -36,6 +36,13 @@ enters a ``jax.profiler.TraceAnnotation("hvd." + name, **attrs)`` for
 the same interval: while a profiler trace is on, the span lies in the
 trace's host plane on the clock the device's operations are on, so a
 device gap can be laid against what the host was doing.
+
+The recorder also keeps what the ring cannot once it has wrapped: by
+name, how many spans closed, their time and their SELF time (a span's
+duration less its children's on the same thread), whole since
+:meth:`SpanRecorder.reset` (:meth:`SpanRecorder.totals`); and how many
+records it filed and how many of those the ring has pushed out
+(``filed``, ``dropped``).
 """
 
 from __future__ import annotations
@@ -83,6 +90,23 @@ class SpanRecord(NamedTuple):
     attrs: dict
 
 
+class OpenSpan:
+    """A span that is open: a frame of its thread's stack, and what
+    :meth:`SpanRecorder.span` yields.  ``child_ns`` is the time of its
+    children that have closed so far."""
+
+    __slots__ = ("id", "name", "start_ns", "child_ns")
+
+    def __init__(self, id: int, name: str):
+        self.id = id
+        self.name = name
+        self.start_ns = 0
+        self.child_ns = 0
+
+    def elapsed_ns(self) -> int:
+        return time.perf_counter_ns() - self.start_ns
+
+
 _trace_annotation = None
 
 
@@ -112,7 +136,12 @@ class SpanRecorder:
         self._listeners = []
         self._ring: "deque[SpanRecord]" = deque(maxlen=RECORD_RING)
         self._ids = itertools.count(1)
-        self._open = threading.local()   # .stack: [(id, name), ...]
+        self._open = threading.local()   # .stack: [OpenSpan, ...]
+        # name -> [count, total_ns, self_ns] of the spans that closed
+        self._totals: Dict[str, list] = {}
+        #: Records filed, and those of them the ring has pushed out.
+        self.filed = 0
+        self.dropped = 0
 
     # -- wiring -----------------------------------------------------------
     def configure(self, rank: Optional[int] = None,
@@ -195,7 +224,9 @@ class SpanRecorder:
         attached (args carry the tags), and the record ring together
         with a ``TraceAnnotation("hvd." + (name or kind))`` in the
         profiler's trace.  ``attrs`` (``rid``, ``round``, ``slot``...:
-        numbers or strings) go to the record and the annotation."""
+        numbers or strings) go to the record and the annotation.  At
+        its close the span adds to its name's :meth:`totals`.  Yields
+        the :class:`OpenSpan`."""
         label = name or kind
         if leg is not None:
             attrs["leg"] = leg
@@ -210,21 +241,30 @@ class SpanRecorder:
                 args["fuse_key"] = str(fuse_key)
             tl.begin(track, event, args=args)
         stack = self._stack()
-        sid = next(self._ids)
-        parent = stack[-1][0] if stack else None
-        stack.append((sid, label))
-        t0 = time.perf_counter_ns()
+        frame = OpenSpan(next(self._ids), label)
+        parent = stack[-1] if stack else None
+        stack.append(frame)
+        t0 = frame.start_ns = time.perf_counter_ns()
         try:
             with _annotation("hvd." + label, attrs):
-                yield
+                yield frame
         finally:
             t1 = time.perf_counter_ns()
             stack.pop()
             if tl is not None:
                 tl.end(track, event)
+            if parent is not None:
+                parent.child_ns += t1 - t0
             with self._lock:
-                self._ring.append(
-                    SpanRecord(label, t0, t1, sid, parent, attrs))
+                self._keep(SpanRecord(
+                    label, t0, t1, frame.id,
+                    None if parent is None else parent.id, attrs))
+                total = self._totals.get(label)
+                if total is None:
+                    total = self._totals[label] = [0, 0, 0]
+                total[0] += 1
+                total[1] += t1 - t0
+                total[2] += t1 - t0 - frame.child_ns
             if kind != PHASE:
                 self.add(kind, (t1 - t0) / 1e9, leg=leg,
                          bucket_id=bucket_id, fuse_key=fuse_key)
@@ -240,6 +280,13 @@ class SpanRecorder:
             stack = self._open.stack = []
         return stack
 
+    def _keep(self, rec: SpanRecord) -> None:
+        """Into the ring, under the lock."""
+        if len(self._ring) == self._ring.maxlen:
+            self.dropped += 1
+        self.filed += 1
+        self._ring.append(rec)
+
     def file(self, name: str, under: Optional[str] = None,
              **attrs) -> SpanRecord:
         """File a point record (no interval, no annotation): what a
@@ -249,14 +296,14 @@ class SpanRecorder:
         none), else the innermost open span."""
         stack = self._stack()
         if under is None:
-            parent = stack[-1][0] if stack else None
+            parent = stack[-1].id if stack else None
         else:
-            parent = next((sid for sid, label in reversed(stack)
-                           if label == under), None)
+            parent = next((frame.id for frame in reversed(stack)
+                           if frame.name == under), None)
         now = time.perf_counter_ns()
         rec = SpanRecord(name, now, now, next(self._ids), parent, attrs)
         with self._lock:
-            self._ring.append(rec)
+            self._keep(rec)
         return rec
 
     def records(self, name: Optional[str] = None,
@@ -269,6 +316,14 @@ class SpanRecorder:
         return [r for r in out
                 if (name is None or r.name == name)
                 and (since_ns is None or r.start_ns >= since_ns)]
+
+    def totals(self) -> Dict[str, tuple]:
+        """``{name: (count, total_ns, self_ns)}`` over every span that
+        has closed since :meth:`reset`, the ring's length regardless (a
+        copy).  The self times of a closed span and all it held add up
+        to its duration."""
+        with self._lock:
+            return {name: tuple(t) for name, t in self._totals.items()}
 
     # -- trace-time leg registry ------------------------------------------
     def note_leg(self, leg, nbytes: Optional[int] = None,
@@ -339,6 +394,8 @@ class SpanRecorder:
             self.summaries.clear()
             self.legs.clear()
             self._ring.clear()
+            self._totals.clear()
+            self.filed = self.dropped = 0
             self._listeners = []
             self.timeline = None
             self.rank = 0

@@ -292,8 +292,6 @@ def test_metrics_endpoint_end_to_end(monkeypatch):
     for name in ("horovod_step_total", "horovod_step_time_seconds",
                  "horovod_wire_bytes_total", "horovod_wire_bytes_per_step",
                  "horovod_compression_ratio",
-                 "horovod_dispatch_gap_fraction",
-                 "horovod_exchange_overlap_fraction",
                  "horovod_plan_buckets",
                  "horovod_plan_cache_hits_total",
                  "horovod_plan_cache_misses_total",

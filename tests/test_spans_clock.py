@@ -118,8 +118,107 @@ def _sums_unchanged(rec):
     assert summary["legs"]["serving_decode"]["count"] == 1
 
 
+def _dur(r):
+    return r.end_ns - r.start_ns
+
+
+def _self_time(rec):
+    """A span's self time is its duration less its children's; the self
+    times of a closed tree add up to the root's duration, to the
+    nanosecond, and a name's spans are summed."""
+    with rec.phase("outer") as outer:
+        with rec.phase("inner"):
+            with rec.phase("leaf"):
+                pass
+        assert outer.child_ns == _dur(rec.records(name="inner")[0])
+        with rec.phase("inner"):
+            pass
+        assert outer.id == rec.records(name="inner")[0].parent
+    by = {}
+    for r in rec.records():
+        by.setdefault(r.name, []).append(r)
+    totals = rec.totals()
+    assert outer.start_ns == by["outer"][0].start_ns
+    assert totals["inner"][:2] == (2, sum(map(_dur, by["inner"])))
+    assert totals["leaf"] == (1, _dur(by["leaf"][0]), _dur(by["leaf"][0]))
+    assert totals["inner"][2] == totals["inner"][1] - totals["leaf"][1]
+    assert totals["outer"] == (
+        1, _dur(by["outer"][0]),
+        _dur(by["outer"][0]) - totals["inner"][1])
+    assert sum(t[2] for t in totals.values()) == _dur(by["outer"][0])
+    # A copy: the caller's edits stay the caller's.
+    totals["outer"] = None
+    assert rec.totals()["outer"] is not None
+
+
+def _self_time_by_thread(rec):
+    """A span's children are those of its own thread: one that another
+    thread closes meanwhile takes nothing off it."""
+    inside = threading.Event()
+    done = threading.Event()
+
+    def other():
+        with rec.phase("other"):
+            inside.set()
+            assert done.wait(10)
+
+    with rec.phase("mine") as mine:
+        t = threading.Thread(target=other)
+        t.start()
+        assert inside.wait(10)
+        with rec.phase("child"):
+            pass
+        done.set()
+        t.join(10)
+        assert mine.child_ns == _dur(rec.records(name="child")[0])
+    totals = rec.totals()
+    assert totals["other"][1] == totals["other"][2] > 0
+    assert totals["mine"][2] == totals["mine"][1] - totals["child"][1]
+
+
+def _self_time_through_an_exception(rec):
+    """A span that an exception leaves is closed and counted, and comes
+    off its parent like any other."""
+    with rec.phase("outer"):
+        with pytest.raises(KeyError):
+            with rec.phase("failing"):
+                with rec.phase("deep"):
+                    raise KeyError("x")
+        with rec.phase("after"):
+            pass
+    by = {r.name: r for r in rec.records()}
+    assert by["after"].parent == by["outer"].id
+    totals = rec.totals()
+    assert {n: t[0] for n, t in totals.items()} == {
+        "outer": 1, "failing": 1, "deep": 1, "after": 1}
+    assert totals["outer"][2] == _dur(by["outer"]) - _dur(by["failing"]) \
+        - _dur(by["after"])
+    assert sum(t[2] for t in totals.values()) == _dur(by["outer"])
+
+
+def _filed_and_dropped(rec):
+    """What the ring cannot say once it has wrapped: how many records
+    were filed, how many of them it has pushed out; the totals keep
+    counting.  A point record is filed and has no time to total."""
+    assert (rec.filed, rec.dropped) == (0, 0)
+    rec.file("note", rid=1)
+    for i in range(spans.RECORD_RING):
+        with rec.phase("s", i=i):
+            pass
+    assert (rec.filed, rec.dropped) == (spans.RECORD_RING + 1, 1)
+    assert len(rec.records()) == spans.RECORD_RING
+    assert rec.records()[0].attrs == {"i": 0}        # the note went
+    assert set(rec.totals()) == {"s"}
+    assert rec.totals()["s"][0] == spans.RECORD_RING
+    rec.reset()
+    assert (rec.filed, rec.dropped, rec.totals()) == (0, 0, {})
+
+
 @pytest.mark.parametrize("case", [_nested, _threads, _bounded, _filed,
-                                  _filters, _sums_unchanged],
+                                  _filters, _sums_unchanged, _self_time,
+                                  _self_time_by_thread,
+                                  _self_time_through_an_exception,
+                                  _filed_and_dropped],
                          ids=lambda f: f.__name__.strip("_"))
 def test_span_records(case):
     case(spans.SpanRecorder())
@@ -244,6 +343,11 @@ def test_serve_leaves_a_tree_of_spans(params, spec_decode):
                  "decode.bookkeep"):
         assert sum(r.name == name for r in records) == len(rounds)
     assert "decode.finite_fetch" not in names
+    if spec_decode:
+        # A speculative round reads and books itself.
+        for r in records:
+            if r.name in ("decode.sample_fetch", "decode.bookkeep"):
+                assert r.attrs["round"] == by_id[r.parent].attrs["round"]
     assert report.rounds_ahead == sum(r.attrs["ahead"] for r in rounds)
     assert (report.rounds_ahead > 0) == (not spec_decode)
 
@@ -294,6 +398,197 @@ def test_token_latency_sees_a_prefill_that_stalls_the_batch(params):
     assert min(stalled) > max(steps)
     assert report.token_latency_p99_s > max(steps)
     assert report.token_latency_p50_s < min(stalled)
+
+
+# -- the serve loop's account of itself, on every served family ---------------
+
+def _dense_family():
+    return CFG, LlamaLM(CFG, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+
+def _mla_family():
+    from benchmarks.families import joyai_mla_moe
+    from horovod_tpu.serving import mla_moe
+    from test_serving_mla_moe import TINY
+    cfg = joyai_mla_moe.program_config(TINY)
+    return cfg, mla_moe.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _cca_family():
+    from benchmarks.families import zaya_cca_moe
+    from horovod_tpu.serving import cca_moe
+    from test_serving_cca_moe import TINY
+    cfg = zaya_cca_moe.program_config(TINY)
+    return cfg, cca_moe.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def _loop_family():
+    from benchmarks.families import ouro_loop
+    from horovod_tpu.serving import loop_dense
+    from test_serving_loop_dense import TINY
+    cfg = ouro_loop.program_config(TINY)
+    return cfg, loop_dense.init_params(cfg, jax.random.PRNGKey(0))
+
+
+FAMILIES = {"dense": _dense_family, "mla_moe": _mla_family,
+            "cca_moe": _cca_family, "loop_dense": _loop_family}
+
+
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def served(request):
+    """One ``serve`` call of a tiny engine of the family, the look-ahead
+    on: eight requests over three slots, all there at t = 0.  The first
+    three end with round 0, so the loop catches up (every live slot's
+    last token is in flight) and the next three are prefilled with no
+    round ahead of them; the last two join mid-stream, behind a round
+    in flight."""
+    cfg, params = FAMILIES[request.param]()
+    eng = ServingEngine(cfg, params, slots=3, page_size=8, max_len=32,
+                        dtype=jnp.float32)
+    rng = np.random.RandomState(11)
+    reqs = [Request(rid=i, prompt=rng.randint(0, 90, size=n)
+                    .astype(np.int32), max_new_tokens=o, arrival_s=0.0)
+            for i, (n, o) in enumerate(zip([5, 9, 12, 4, 7, 6, 10, 8],
+                                           [2, 2, 2, 6, 3, 9, 4, 5]))]
+    rec = spans.recorder()
+    rec.reset()
+    report = eng.serve(reqs)
+    assert report.completed == len(reqs) and report.rounds_ahead > 0
+    serve, = rec.records(name="serve")
+    return report, serve, rec.records(since_ns=serve.start_ns), reqs
+
+
+def _named(records, name):
+    return [r for r in records if r.name == name]
+
+
+def test_a_fetch_and_a_bookkeep_carry_the_round_they_retire(served):
+    report, serve, records, _ = served
+    by_id = {r.id: r for r in records}
+    rounds = _named(records, "decode.round")
+    assert [r.attrs["round"] for r in rounds] \
+        == list(range(report.decode_steps))
+    # A dispatch says no round of its own: it lies under its round's span.
+    dispatches = _named(records, "decode.dispatch")
+    assert [by_id[d.parent].attrs["round"] for d in dispatches] \
+        == list(range(report.decode_steps))
+    assert not any("round" in d.attrs for d in dispatches)
+    caught_up = 0
+    for name in ("decode.sample_fetch", "decode.bookkeep"):
+        retired = _named(records, name)
+        # Each round is retired once, in the order it was dispatched.
+        assert [r.attrs["round"] for r in retired] \
+            == list(range(report.decode_steps))
+        for r in retired:
+            parent = by_id[r.parent]
+            if parent.name == "decode.round":
+                # Under round n's span the loop reads round n - 1.
+                assert r.attrs["round"] == parent.attrs["round"] - 1
+                assert parent.attrs["ahead"] == 1
+            else:
+                # A catch-up, under the root: the last round dispatched.
+                assert parent is serve
+                assert r.attrs["round"] == max(
+                    x.attrs["round"] for x in rounds
+                    if x.end_ns <= r.start_ns)
+                caught_up += 1
+    assert caught_up >= 2
+    # The round after a catch-up has none in flight before it.
+    after = {r.attrs["round"] + 1
+             for r in _named(records, "decode.sample_fetch")
+             if by_id[r.parent] is serve}
+    assert {r.attrs["round"] for r in rounds if not r.attrs["ahead"]} \
+        == ({0} | after) & set(range(report.decode_steps))
+
+
+def test_a_prefill_says_which_round_it_queued_behind(served):
+    report, _, records, reqs = served
+    by_id = {r.id: r for r in records}
+    dispatched = {by_id[r.parent].attrs["round"]: r
+                  for r in _named(records, "decode.dispatch")}
+    fetched = {r.attrs["round"]: r
+               for r in _named(records, "decode.sample_fetch")}
+    prefills = _named(records, "serve.prefill")
+    assert len(prefills) == len(reqs)
+    for p in prefills:
+        before = [n for n, d in dispatched.items()
+                  if d.end_ns <= p.start_ns]
+        last = max(before, default=-1)
+        in_flight = last >= 0 and fetched[last].end_ns > p.start_ns
+        assert p.attrs["behind"] == (last if in_flight else -1)
+    behind = [p.attrs["behind"] for p in prefills]
+    # The first turn, and the turn after the catch-up that read round 0:
+    # nothing ahead of them.  The last two queue behind a round.
+    assert behind[:6] == [-1] * 6
+    assert all(b >= 0 for b in behind[6:])
+
+
+def test_serve_files_its_account_and_the_report_carries_it(served):
+    report, serve, records, reqs = served
+    account, = _named(records, "serve.account")
+    assert account.parent == serve.id
+    assert account.start_ns == account.end_ns <= serve.end_ns
+    a = account.attrs
+    assert a["rounds"] == report.decode_steps
+    assert a["prefills"] == len(reqs)
+    kept = [r for r in records if r is not serve and r is not account]
+    assert (a["filed"], a["dropped"]) == (len(kept), 0)
+    # Every span of the call, by name, as the ring has them.
+    closed = [r for r in kept if r.end_ns > r.start_ns]
+    assert set(a["spans"]) == {r.name for r in closed} | {"serve"}
+    for name, t in a["spans"].items():
+        if name != "serve":
+            mine = _named(closed, name)
+            assert (t["count"], t["total_ns"]) == (
+                len(mine), sum(r.end_ns - r.start_ns for r in mine))
+    # The identity: every nanosecond of the call is some span's own.
+    own = sum(t["self_ns"] for t in a["spans"].values())
+    assert abs(own - a["wall_ns"]) <= 1e-3 * a["wall_ns"]
+    assert a["spans"]["serve"]["total_ns"] == a["wall_ns"] \
+        <= serve.end_ns - serve.start_ns
+    assert report.loop_s == {name: t["self_ns"] / 1e9
+                             for name, t in a["spans"].items()}
+    assert sum(report.loop_s.values()) == pytest.approx(
+        a["wall_ns"] / 1e9, rel=1e-3)
+    assert report.as_dict()["loop_s"] == report.loop_s
+
+
+def test_a_request_says_when_its_prefill_began(served):
+    _, _, records, reqs = served
+    filed = {r.attrs["rid"]: r.attrs for r in _named(records, "request")}
+    assert sorted(filed) == [r.rid for r in reqs]
+    for req in reqs:
+        a = filed[req.rid]
+        assert a["prefill_start_s"] == req.prefill_start_s
+        assert a["admit_s"] <= a["prefill_start_s"] <= a["first_token_s"]
+
+
+def test_the_last_chunk_of_a_chunked_prefill_holds_its_write_and_fetch(
+        params):
+    """A chunked prefill's stall is one ``prefill_chunk`` tree: the pool
+    write and the first token's fetch lie under the last chunk's span,
+    so the account has no hole there."""
+    eng = ServingEngine(CFG, params, mesh=_mesh1(), slots=2, page_size=8,
+                        max_len=64, prefill_chunk=8)
+    rec = spans.recorder()
+    rec.reset()
+    reqs = _requests([20, 5], [3, 3])
+    report = eng.serve(reqs)
+    assert report.completed == 2
+    chunks = rec.records(name="prefill_chunk")
+    assert len(chunks) == 3
+    kids = [r for r in rec.records() if r.parent in {c.id for c in chunks}]
+    assert [(k.name, k.parent) for k in kids] == [
+        ("prefill.write_kv", chunks[-1].id),
+        ("prefill.sample_fetch", chunks[-1].id)]
+    account, = rec.records(name="serve.account")
+    own = sum(t["self_ns"] for t in account.attrs["spans"].values())
+    assert abs(own - account.attrs["wall_ns"]) \
+        <= 1e-3 * account.attrs["wall_ns"]
+    filed = {r.attrs["rid"]: r.attrs for r in rec.records(name="request")}
+    assert filed[0]["admit_s"] <= filed[0]["prefill_start_s"] \
+        <= filed[0]["first_token_s"]
 
 
 # -- names in the HLO -------------------------------------------------------
