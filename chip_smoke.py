@@ -41,7 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 import horovod_tpu as hvd
 from horovod_tpu import _core, serving
 from horovod_tpu.models import Bert, LlamaLM
-from horovod_tpu.ops.attention import decode_attention
+from horovod_tpu.ops.attention import cca_decode_attention
 from horovod_tpu.training import make_flax_train_step
 from horovod_tpu.utils.platform import configure_compile_cache
 
@@ -195,22 +195,31 @@ def phase_bert(config, dtype, batch_per_chip: int, seq: int, steps: int,
                   mosaic_calls)[0]
 
 
-def _decode_kernel_parity(config, tp: int, slots: int, max_len: int) -> float:
-    """The split-KV kernel against the XLA reference at the decode step's
-    per-chip shape, dead slot and full slot included."""
+def _decode_kernel_parity(config, tp: int, slots: int, page_size: int,
+                          max_len: int) -> float:
+    """The page walk over two pools against the XLA reference at the
+    decode step's per-chip shape, dead slot and full slot included."""
     h, h_kv, d = config.num_heads // tp, config.num_kv_heads // tp, \
         config.head_dim
+    pps = max_len // page_size
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(kq, (slots, h, 1, d), jnp.float32)
-    k = jax.random.normal(kk, (slots, h_kv, max_len, d), jnp.float32)
-    v = jax.random.normal(kv, (slots, h_kv, max_len, d), jnp.float32)
+    q = jax.random.normal(kq, (slots, h, d), jnp.float32)
+    shape = (2, slots * pps + 1, page_size, h_kv * d)
+    keys = jax.random.normal(kk, shape, jnp.float32)
+    values = jax.random.normal(kv, shape, jnp.float32)
+    table = jnp.asarray(np.random.RandomState(3).permutation(
+        slots * pps).reshape(slots, pps), jnp.int32)
     lengths = jnp.asarray(
         np.linspace(0, max_len, slots).astype(np.int32))
+
     # Fresh closures: jit caches traces by function identity.
-    got = jax.jit(lambda q, k, v, n: decode_attention(
-        q, k, v, lengths=n))(q, k, v, lengths)
-    ref = jax.jit(lambda q, k, v, n: decode_attention(
-        q, k, v, lengths=n, force_reference=True))(q, k, v, lengths)
+    def walk(force_reference):
+        return jax.jit(lambda q, k, v, t, n: cca_decode_attention(
+            q, k, t, layer=1, lengths=n, kv_heads=h_kv, scale=d ** -0.5,
+            values=v, force_reference=force_reference))(
+                q, keys, values, table, lengths)
+
+    got, ref = walk(False), walk(True)
     _check(bool(jnp.all(got[0] == 0.0)), "dead slot output is not zero")
     return float(jnp.max(jnp.abs(got - ref)))
 
@@ -266,7 +275,7 @@ def phase_server(config, slots: int, page_size: int, max_len: int,
         cache.table_device(), jnp.zeros((slots,), bool), eng._told))
     _check(mosaic == mosaic_calls,
            f"decode step has {mosaic} Mosaic calls, expected "
-           f"{mosaic_calls} (one split-KV call per layer)")
+           f"{mosaic_calls} (the page walk: one function for every layer)")
     spec = serving.LoadSpec(
         num_requests=num_requests, rate_rps=50.0, prompt_lens=prompt_lens,
         output_lens=output_lens, vocab_size=config.vocab_size, seed=11)
@@ -286,9 +295,10 @@ def phase_server(config, slots: int, page_size: int, max_len: int,
     _check(cache.live_pages == 0 and cache.refcounts_balanced(),
            f"pool did not drain: {cache.live_pages} live pages")
 
-    kernel_err = _decode_kernel_parity(config, len(devices), slots, max_len)
+    kernel_err = _decode_kernel_parity(config, len(devices), slots,
+                                       page_size, max_len)
     _check(kernel_err < 2e-2 if mosaic_calls else kernel_err == 0.0,
-           f"split-KV kernel vs reference: max abs err {kernel_err}")
+           f"page walk vs reference: max abs err {kernel_err}")
     longest = max(requests, key=lambda r: r.prompt_len + len(r.tokens))
     checked = [r for r in requests
                if (r.prompt_len, len(r.tokens))
@@ -356,7 +366,7 @@ def main() -> int:
     print("smoke B bert-large batch=32/chip seq=128 " + _fmt(b), flush=True)
     c = phase_server(LLAMA_1B, slots=8, page_size=16, max_len=1024,
                      prompt_lens=(32, 64, 128), output_lens=(16, 32),
-                     num_requests=12, mosaic_calls=LLAMA_1B.num_layers)
+                     num_requests=12, mosaic_calls=1)
     print("smoke C llama-1b slots=8 page=16 max_len=1024 " + _fmt(c),
           flush=True)
     hvd.shutdown()

@@ -143,18 +143,28 @@ def meta_from_step(step) -> Optional[dict]:
     return dict(meta) if isinstance(meta, dict) else None
 
 
-def _attach_kernel_contracts(expected: ExpectedExchange
+# The decode-attention family a serving step does NOT call, by how it
+# reads the cache (``ServingDecodeStep.meta["attention"]``): a step that
+# walks the page table runs ``mla_decode``'s kernel and never the
+# split-KV one; one that gathers slot views (verify, fp8) the reverse.
+_OTHER_DECODE_FAMILY = {"walk": "flash_decode", "view": "mla_decode"}
+
+
+def _attach_kernel_contracts(expected: ExpectedExchange, meta: dict
                              ) -> ExpectedExchange:
     """Make the expectation kernel-aware instead of declining.
 
-    Active Pallas families are recorded on ``expected.kernels``; any
-    collective legs a family's contract registers are appended to the
-    priced ops (today every contract is collective-free with zero wire
-    delta, so this only annotates).  ``trace_audit`` separately enforces
-    the collective-free claim by walking ``pallas_call`` sub-jaxprs.
+    Active Pallas families are recorded on ``expected.kernels`` (for a
+    serving step, less the decode-attention family it does not call);
+    any collective legs a family's contract registers are appended to
+    the priced ops (today every contract is collective-free with zero
+    wire delta, so this only annotates).  ``trace_audit`` separately
+    enforces the collective-free claim by walking ``pallas_call``
+    sub-jaxprs.
     """
     from ..ops import pallas as _pallas
-    active = _pallas.active_kernels()
+    other = _OTHER_DECODE_FAMILY.get(meta.get("attention"))
+    active = tuple(k for k in _pallas.active_kernels() if k != other)
     if not active or not expected.supported:
         return expected
     expected.kernels = active
@@ -170,7 +180,8 @@ def _attach_kernel_contracts(expected: ExpectedExchange
 def expected_exchange(params, meta: dict) -> ExpectedExchange:
     """Derive the collective contract for a step built with ``meta``
     (kernel-aware: see :func:`_attach_kernel_contracts`)."""
-    expected = _attach_kernel_contracts(_expected_exchange(params, meta))
+    expected = _attach_kernel_contracts(_expected_exchange(params, meta),
+                                        meta)
     if meta.get("guard") and expected.supported:
         # The SDC guard screen: one f32[2] psum (nonfinite count +
         # grad-norm square) riding beside the gradient exchange,
@@ -370,6 +381,10 @@ def _expected_serving_decode(meta: dict) -> ExpectedExchange:
     just wider.  Size-1-axis psums are NOT elided at trace time, so the
     contract holds at tp=1.  fp8 KV compression is wire-neutral here:
     the dequant blend is local gather arithmetic, no new collectives.
+    So is how the step reads its cache (``meta["attention"]``): the
+    decode step's page walk (kernel family ``mla_decode``) and the
+    verify step's split-KV kernel over a gathered view
+    (``flash_decode``) are both local to a ``tp`` shard's heads.
 
     Per-slot LoRA banks are declined, not guessed: the adapter gather is
     an indexing pattern the pricing model does not cover, and a wrong

@@ -467,9 +467,9 @@ def mla_decode_attention(q, pool, page_table, *, layer, lengths,
 
 
 def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
-                       plane_ref, q_ref, pool_ref, o_ref, buf, sem, m_scr,
-                       l_scr, acc_scr, *, scale, ppb, sub, pps, page,
-                       value_dim, kv_heads, value_off):
+                       plane_ref, q_ref, pool_ref, *rest, scale, ppb, sub,
+                       pps, page, value_dim, kv_heads, value_off,
+                       two_pools):
     """One program instance, a loop over the live items: item ``i`` is
     block ``blk_ref[i]`` (``ppb`` pages) of row ``slot_ref[i]``; there
     are ``n_ref[0]`` of them, a row's items follow one another, an idle
@@ -521,7 +521,19 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
     (heads / kv_heads)``.  EVERY query head is multiplied against each
     key/value head's tile-aligned columns and a row mask keeps its own:
     the MXU's cost is the key tiles it is loaded with, not the 8 rows
-    that stream past them, and no array is ever cut below a tile."""
+    that stream past them, and no array is ever cut below a tile.
+
+    ``two_pools`` (a dense model's cache: keys in one pool, values in
+    another, same page ids): ``rest`` begins with the second pool and
+    holds a second buffer; every page is copied from each pool into its
+    buffer, both copies signal the block's one semaphore, a run of
+    pages is waited for once a pool, and the values are the second
+    buffer's columns from ``value_off``.  A static branch: with one pool
+    the kernel is what it was."""
+    if two_pools:
+        vpool_ref, o_ref, buf, vbuf, sem, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, buf, sem, m_scr, l_scr, acc_scr = rest
     n_items = n_ref[0]
     layer = plane_ref[0]
     bk = ppb * page
@@ -562,6 +574,10 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
             pltpu.make_async_copy(
                 pool_ref.at[layer, src], buf.at[which, j],
                 sem.at[which]).start()
+            if two_pools:
+                pltpu.make_async_copy(
+                    vpool_ref.at[layer, src], vbuf.at[which, j],
+                    sem.at[which]).start()
 
         def whole(c, carry):
             at = c * _ISSUE_RUN
@@ -584,6 +600,10 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
             pltpu.make_async_copy(
                 pool_ref.at[layer, pl.ds(0, k)], buf.at[which, pl.ds(0, k)],
                 sem.at[which]).wait()
+            if two_pools:
+                pltpu.make_async_copy(
+                    vpool_ref.at[layer, pl.ds(0, k)],
+                    vbuf.at[which, pl.ds(0, k)], sem.at[which]).wait()
 
         by_runs(n, run, runs)
 
@@ -593,6 +613,8 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
         keys = k * sub * page
         kv = buf[which, pl.ds(at * sub, k * sub)].reshape(
             keys, buf.shape[-1])                      # (keys, w)
+        vals = kv if not two_pools else vbuf[
+            which, pl.ds(at * sub, k * sub)].reshape(keys, vbuf.shape[-1])
         s = own([jax.lax.dot_general(
             q_ref[row], kv if kv_heads == 1 else kv[:, j * dk:(j + 1) * dk],
             (((1,), (1,)), ((), ())),
@@ -608,8 +630,8 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + own([jax.lax.dot_general(
-            p.astype(kv.dtype),
-            kv[:, value_off + j * value_dim:value_off + (j + 1) * value_dim],
+            p.astype(vals.dtype),
+            vals[:, value_off + j * value_dim:value_off + (j + 1) * value_dim],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
             for j in range(kv_heads)])
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -620,6 +642,8 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
     # but never a NaN's bit pattern.
     o_ref[...] = jnp.zeros_like(o_ref)
     buf[...] = jnp.zeros_like(buf)
+    if two_pools:
+        vbuf[...] = jnp.zeros_like(vbuf)
 
     def item(i):
         which = i % bufs
@@ -668,16 +692,20 @@ def _walk_sizes():
 @functools.partial(jax.jit, static_argnames=(
     "value_dim", "scale", "ppb", "sub_keys", "budget", "kv_heads",
     "value_off", "name"))
-def _mla_decode(q, pool, page_table, lengths, layer, *, value_dim: int,
-                scale: float, ppb: int, sub_keys: int, budget: int,
-                kv_heads: int = 1, value_off: int = 0,
+def _mla_decode(q, pool, page_table, lengths, layer, values=None, *,
+                value_dim: int, scale: float, ppb: int, sub_keys: int,
+                budget: int, kv_heads: int = 1, value_off: int = 0,
                 name: str = "hvd_mla_decode"):
     """The walk as ONE jitted function whose plane is a traced scalar: a
     model calls it once a layer, and a kernel traced and lowered a layer
     cost ZAYA's start-up 11 s and Ouro's 20 (PERF.md section 6, PR 34);
-    so every layer shares one trace and one lowered function."""
+    so every layer shares one trace and one lowered function.
+    ``values``: the second pool of a cache that keeps keys and values
+    apart (None: ``pool``'s rows hold both); a page then costs a block
+    both its rows."""
     b, h, dk = q.shape
     w = pool.shape[3]
+    pools = (pool,) if values is None else (pool, values)
     page = pool.shape[2]
     pps = page_table.shape[1]
     ppb = min(ppb, pps)
@@ -685,7 +713,7 @@ def _mla_decode(q, pool, page_table, lengths, layer, *, value_dim: int,
     # halve the block until they fit the budget, then take a third where
     # that fits too.
     resident = q.size * pool.dtype.itemsize + b * h * value_dim * 4
-    block = page * w * pool.dtype.itemsize
+    block = page * sum(z.shape[3] for z in pools) * pool.dtype.itemsize
     while ppb > 8 and resident + 2 * ppb * block > budget:
         ppb //= 2
     bufs = 3 if resident + 3 * ppb * block <= budget else 2
@@ -719,18 +747,20 @@ def _mla_decode(q, pool, page_table, lengths, layer, *, value_dim: int,
     kernel = functools.partial(_mla_decode_kernel, scale=scale, ppb=ppb,
                                sub=sub, pps=per_row * ppb, page=page,
                                value_dim=value_dim, kv_heads=kv_heads,
-                               value_off=value_off)
+                               value_off=value_off,
+                               two_pools=values is not None)
     with jax.named_scope(name):
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=6,
                 grid=(1,),
-                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
-                          pl.BlockSpec(memory_space=pl.ANY)],
+                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
+                + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
                 out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
                 scratch_shapes=[
-                    pltpu.VMEM((bufs, ppb, page, w), pool.dtype),
+                    pltpu.VMEM((bufs, ppb, page, z.shape[3]), z.dtype)
+                    for z in pools] + [
                     pltpu.SemaphoreType.DMA((bufs,)),
                     pltpu.VMEM((h, _LANES), jnp.float32),
                     pltpu.VMEM((h, _LANES), jnp.float32),
@@ -744,11 +774,11 @@ def _mla_decode(q, pool, page_table, lengths, layer, *, value_dim: int,
             name=name,
             interpret=_pallas.interpret_mode(),
         )(lengths, page_table.reshape(-1), slot, blk, n_items, plane,
-          q.astype(pool.dtype), pool)
+          q.astype(pool.dtype), *pools)
 
 
 def cca_decode_attention(q, pool, page_table, *, layer, lengths,
-                         kv_heads: int, scale: float,
+                         kv_heads: int, scale: float, values=None,
                          force_reference: bool = False):
     """Single-token grouped-query decode attention over rows that hold
     every key/value head side by side, read straight out of the page
@@ -762,25 +792,48 @@ def cca_decode_attention(q, pool, page_table, *, layer, lengths,
     key/value head ``i // (h / kv_heads)``; the result is ``(b, h, d)``
     float32, exactly zero for a row with ``lengths == 0``.
 
+    ``values``: the cache's SECOND pool, where it keeps keys and values
+    apart (a dense model's ``k_pool`` and ``v_pool``): ``pool``'s rows
+    are then ``[k_0 .. k_{kv-1}]`` alone and ``values``' rows ``[v_0 ..
+    v_{kv-1}]``, both ``(layers, pages, page_size, kv_heads * d)`` under
+    the same page ids.
+
     The kernel is ``hvd_mla_decode``'s walk of the page table under the
     name ``hvd_cca_decode`` (same family switch): one copy of a block's
-    live pages serves every head, keys and values alike."""
+    live pages serves every head, keys and values alike; with two pools,
+    one copy out of each."""
     b, h, d = q.shape
-    if pool.ndim != 4 or pool.shape[3] != 2 * kv_heads * d \
-            or h % kv_heads or page_table.shape[0] != b:
+    rows = 1 if values is not None else 2
+    if pool.ndim != 4 or pool.shape[3] != rows * kv_heads * d \
+            or h % kv_heads or page_table.shape[0] != b \
+            or (values is not None and (values.shape != pool.shape
+                                        or values.dtype != pool.dtype)):
         raise ValueError(
             f"cca_decode_attention: q {q.shape}, pool {pool.shape}, "
-            f"page_table {page_table.shape} and kv_heads {kv_heads} do not "
-            "fit together")
+            + ("" if values is None else f"values {values.shape}, ")
+            + f"page_table {page_table.shape} and kv_heads {kv_heads} do "
+            "not fit together")
     if lengths.shape != (b,):
         raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
     if not force_reference and _pallas.pallas_enabled("mla_decode"):
         return _mla_decode(q, pool, page_table, lengths,
-                           jnp.asarray(layer, jnp.int32), value_dim=d,
-                           scale=float(scale), kv_heads=kv_heads,
-                           value_off=kv_heads * d, name="hvd_cca_decode",
+                           jnp.asarray(layer, jnp.int32), values,
+                           value_dim=d, scale=float(scale),
+                           kv_heads=kv_heads,
+                           value_off=0 if values is not None
+                           else kv_heads * d, name="hvd_cca_decode",
                            **_walk_sizes())
     s = page_table.shape[1] * pool.shape[2]
+    if values is not None:
+        # Kernels off: the gathered view through :func:`decode_attention`'s
+        # own reference, the op shapes a verify step's rows run, so that
+        # speculative streams stay bit for bit plain decode's.
+        def view(z):
+            return z[layer, page_table].reshape(
+                b, s, kv_heads, d).transpose(0, 2, 1, 3).astype(q.dtype)
+        return decode_attention(
+            q[:, :, None], view(pool), view(values), lengths=lengths,
+            scale=scale, force_reference=True)[:, :, 0].astype(jnp.float32)
     kv = pool[layer, page_table].reshape(b, s, 2, kv_heads, d).astype(
         q.dtype)
     qg = q.reshape(b, kv_heads, h // kv_heads, d)

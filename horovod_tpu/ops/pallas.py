@@ -63,8 +63,9 @@ KERNEL_CONTRACTS = {
         "collectives": (),
         "wire_delta_bytes": 0,
         "site": "ops.attention.decode_attention",
-        "note": "split-KV decode kernel; the serving step's two "
-                "row-parallel psums per layer stay in XLA",
+        "note": "split-KV decode kernel over a gathered slot view (the "
+                "dense verify step and the fp8 path); the serving step's "
+                "two row-parallel psums per layer stay in XLA",
     },
     "mla_decode": {
         "collectives": (),
@@ -73,8 +74,12 @@ KERNEL_CONTRACTS = {
         "note": "decode kernel that walks the page table of a pool with no "
                 "head dim: absorbed latent attention (hvd_mla_decode) and, "
                 "general over key/value heads, ops.attention."
-                "cca_decode_attention (hvd_cca_decode); tp = 1, no "
-                "exchange.  One jitted function for every layer (the plane "
+                "cca_decode_attention (hvd_cca_decode), which also takes "
+                "keys and values from two pools under one table: the "
+                "dense decode step calls it so inside its shard_map, "
+                "local to a tp shard's heads, the step's two row-parallel "
+                "psums a layer staying in XLA; no exchange of its own.  "
+                "One jitted function for every layer (the plane "
                 "a prefetched scalar), one program instance that loops over "
                 "the live (row, block) items with every row's query and "
                 "result resident in VMEM; blocks sized from a VMEM budget "

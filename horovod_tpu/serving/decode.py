@@ -30,6 +30,21 @@ adapter pair by a per-slot ``adapter_ids`` operand INSIDE the step, so
 one base model serves heterogeneous adapters in one decode batch
 (tensor-parallel meshes decline the banks -- adapters stay tp=1).
 
+How the step reads the cache.  A token's K and V are one row each of
+``num_kv_heads * head_dim`` columns in ``k_pool`` and ``v_pool``
+(``[layers, pages + 1, page_size, row]``, no head dim).  The one-token
+step over uncompressed pools WALKS THE PAGE TABLE
+(:func:`~horovod_tpu.ops.attention.cca_decode_attention` over both
+pools): the live pages of every slot are copied out of the whole pools
+by their ids, no ``pool[layer]``, no gathered slot view, no transpose,
+no padded keys.  The verify step and the fp8 path gather the slot view
+(``[slots, max_len, row]``, a reshape away from heads) for
+:func:`~horovod_tpu.ops.attention.verify_attention` /
+:func:`~horovod_tpu.ops.attention.decode_attention`.  Which of the two
+a step does follows from what it is built for (``width``,
+``compress``), and its ``_meta["attention"]`` says it: ``"walk"`` or
+``"view"``.
+
 Shared read-only pages (PR 16): the decode step never sees page
 ownership -- it reads K/V through the slot's ``page_table`` row and
 masks positions at or beyond ``lengths[slot]``, so two slots whose
@@ -56,8 +71,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..models.transformer import LlamaConfig, rotary_embedding
-from ..ops.attention import (decode_attention, flash_attention,
-                             verify_attention)
+from ..ops import pallas as _pallas
+from ..ops.attention import (cca_decode_attention, decode_attention,
+                             flash_attention, verify_attention)
 from ..parallel.tp import row_parallel
 from ..timeline import spans as _spans
 
@@ -125,16 +141,17 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
     """Forward a prompt batch, returning ``(logits, k_layers, v_layers)``.
 
     ``tokens``: ``[b, t]`` int32.  ``k_layers``/``v_layers``:
-    ``[num_layers, b, t, num_kv_heads, head_dim]`` post-RoPE -- the
-    layout :meth:`PagedKVCache.write_prefill` scatters (squeeze the batch
-    dim for the per-slot write).  Padding isolation via ``segment_ids``
+    ``[num_layers, b, t, num_kv_heads * head_dim]`` post-RoPE, head ``j``
+    in columns ``j * head_dim .. (j + 1) * head_dim`` -- the cache's rows,
+    as :meth:`PagedKVCache.write_prefill` scatters them (squeeze the
+    batch dim for the per-slot write).  Padding isolation via ``segment_ids``
     follows the model convention (pad tokens get segment 0).
 
     ``adapters``/``adapter_id``: banked LoRA tree + the ONE adapter this
     prompt uses (prefill admits one request at a time).
 
     ``past``: chunked prefill continuation -- a ``(k_layers, v_layers)``
-    pair from the previous chunks (``[num_layers, b, t_past, kv_heads,
+    pair from the previous chunks (``[num_layers, b, t_past, kv_heads *
     head_dim]`` each).  ``tokens`` is then the CURRENT chunk only; its
     queries attend over ``past ++ chunk`` keys with the bottom-right
     aligned causal mask (exactly the KV-cache convention
@@ -165,6 +182,17 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
     ad = (adapters["params"] if adapters is not None and
           "params" in adapters else adapters)
     ks, vs = [], []
+
+    def heads_of(rows):
+        """Cache rows ``[b, t, kv_heads * head_dim]`` as attention takes
+        them, ``[b, kv_heads, t, head_dim]``."""
+        return rows.reshape(*rows.shape[:2], cfg.num_kv_heads,
+                            cfg.head_dim).transpose(0, 2, 1, 3)
+
+    def rows_of(heads):
+        return heads.transpose(0, 2, 1, 3).reshape(
+            heads.shape[0], heads.shape[2], -1)
+
     for li in range(cfg.num_layers):
         blk = p[f"layer_{li}"]
         abk = None if ad is None else ad.get(f"layer_{li}")
@@ -184,35 +212,30 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
                    lora_alpha=lora_alpha)
         q = q.reshape(b, t, cfg.num_heads, cfg.head_dim).transpose(
             0, 2, 1, 3)
-        k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim).transpose(
-            0, 2, 1, 3)
-        v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim).transpose(
-            0, 2, 1, 3)
+        v_rows = v
+        k = rotary_embedding(heads_of(k), positions, cfg.rope_theta)
+        v = heads_of(v)
         q = rotary_embedding(q, positions, cfg.rope_theta)
-        k = rotary_embedding(k, positions, cfg.rope_theta)
+        # The cache's rows: what V's projection gave, and K's after RoPE.
+        k_rows = rows_of(k)
         if past is not None:
             # Chunk continuation: this chunk's queries see every past
             # key; the bottom-right aligned causal mask handles the
-            # within-chunk triangle.  past k/v arrive in cache layout
-            # [b, t_past, H, D] -- move time back to the attention axis.
-            k_full = jnp.concatenate(
-                [past[0][li].transpose(0, 2, 1, 3).astype(k.dtype), k],
-                axis=2)
-            v_full = jnp.concatenate(
-                [past[1][li].transpose(0, 2, 1, 3).astype(v.dtype), v],
-                axis=2)
-        else:
-            k_full, v_full = k, v
-        o = flash_attention(q, k_full, v_full, causal=True,
+            # within-chunk triangle.  past k/v arrive as cache rows
+            # [b, t_past, H * D]: the FULL context (past ++ chunk) goes
+            # back the same way, so chunk callers chain by replacement.
+            k_rows = jnp.concatenate(
+                [past[0][li].astype(k.dtype), k_rows], axis=1)
+            v_rows = jnp.concatenate(
+                [past[1][li].astype(v.dtype), v_rows], axis=1)
+            k, v = heads_of(k_rows), heads_of(v_rows)
+        o = flash_attention(q, k, v, causal=True,
                             segment_ids=segment_ids)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, -1)
         x = x + _dense(o, attn["wo"], dtype, lora_select=lora("attn", "wo"),
                        lora_alpha=lora_alpha)
-        # Cache layout: [b, t, kv_heads, head_dim], post-RoPE -- the
-        # FULL context (past ++ chunk) so chunk callers chain by
-        # replacement.
-        ks.append(k_full.transpose(0, 2, 1, 3))
-        vs.append(v_full.transpose(0, 2, 1, 3))
+        ks.append(k_rows)
+        vs.append(v_rows)
 
         h = _rmsnorm(x, blk["mlp_norm"]["scale"], dtype)
         mlp = blk["mlp"]
@@ -269,6 +292,13 @@ class ServingDecodeStep:
         self._meta = meta
         self._leg = leg
 
+    @property
+    def meta(self) -> dict:
+        """What the step was built as; ``"attention"`` is ``"walk"``
+        where it reads attention out of the page pools by walking the
+        page table, ``"view"`` where it gathers slot views."""
+        return self._meta
+
     def __getattr__(self, name):
         return getattr(self._fn, name)
 
@@ -299,10 +329,14 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     ``tokens``/``positions``/``active``: ``[slots]`` (current token, its
     absolute position == live length before this step, slot liveness).
     ``page_table``: ``[slots, pages_per_slot]``.  The step writes the new
-    token's post-RoPE K/V into its page in-step, attends over the
-    length-masked slot view, and returns replicated next-token logits.
-    Idle slots produce zero attention output (dead-row convention) and
-    their logits are discarded by the engine.
+    token's post-RoPE K/V rows into their page in-step, attends over the
+    slot's live rows and returns replicated next-token logits.  Over
+    uncompressed pools it reads them by walking the page table
+    (``hvd_cca_decode`` over both pools, local to a ``tp`` shard's
+    heads; ``_meta["attention"] == "walk"``); the fp8 path gathers the
+    length-masked slot view (``"view"``).  Idle slots produce zero
+    attention output (dead-row convention) and their logits are
+    discarded by the engine.
 
     The step tells its round itself.  ``told``, its last output, is one
     int32 vector ``[tokens | finite | tells]`` (:func:`tell_round`):
@@ -331,8 +365,8 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     (the last sampled token followed by ``width - 1`` drafts), every
     column's K/V is scattered to its own (page, offset) in-step, and
     attention runs :func:`~horovod_tpu.ops.attention.verify_attention`
-    -- the same paged gather, with the length mask extended one key per
-    draft column.  Logits come back ``[slots, width, vocab]``, target
+    over the gathered slot view, with the length mask extended one key
+    per draft column.  Logits come back ``[slots, width, vocab]``, target
     argmaxes for ALL width positions from ONE dispatch.  Columns past a
     slot's accepted prefix leave garbage K/V above the rolled-back
     length -- unreachable by the masking contract, exactly like a
@@ -367,6 +401,10 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     kvh_l = cfg.num_kv_heads // tp
     hd = cfg.head_dim
     kind = "serving_decode" if width == 1 else "serving_verify"
+    # One token a slot over pools that hold every row as written: read
+    # by walking the page table.  A verify step's queries are ``width``
+    # wide and the fp8 path blends two pools a page: they gather a view.
+    walk = width == 1 and not compress
     # Per-layer TP psum rows come from the shared exchange-plan IR
     # (planned once, rendered verbatim by spans/auditor): legs[2*li] is
     # layer li's attn_wo psum, legs[2*li + 1] its mlp_down psum.
@@ -422,15 +460,20 @@ def build_decode_step(config: LlamaConfig, mesh, *,
             off = pos2 % page_size
 
         def gather_view(li, pool, qpool=None, scale=None):
-            view = pool[li][page_table]     # [S, pps, page, kvh_l, hd]
+            view = pool[li][page_table]     # [S, pps, page, kvh_l * hd]
             if compress:
                 deq = (qpool[li][ctable].astype(jnp.float32)
-                       * scale[li][ctable][..., None, None]
-                       ).astype(view.dtype)
-                view = jnp.where(cmask[..., None, None, None], deq, view)
+                       * scale[li][ctable][..., None]).astype(view.dtype)
+                view = jnp.where(cmask[..., None, None], deq, view)
             return view.reshape(
                 s, pages_per_slot * page_size, kvh_l, hd
             ).transpose(0, 2, 1, 3)
+
+        def rows(z):
+            """``[S, kvh_l, W, hd]`` as the pools hold it: ``[S(, W),
+            kvh_l * hd]``."""
+            z = z.transpose(0, 2, 1, 3).reshape(s, width, kvh_l * hd)
+            return (z[:, 0] if width == 1 else z).astype(k_pool.dtype)
 
         def select(a, b):
             return a[adapter_ids], b[adapter_ids]
@@ -462,35 +505,33 @@ def build_decode_step(config: LlamaConfig, mesh, *,
             q = rotary_embedding(q, pos2, cfg.rope_theta)
             k = rotary_embedding(k, pos2, cfg.rope_theta)
 
-            # In-step cache write: each column's K/V lands at its
-            # (page, offset) -- one scatter per pool per layer.
-            pool_dt = k_pool.dtype
-            if width == 1:
-                k_pool = k_pool.at[li, page, off].set(
-                    k[:, :, 0, :].astype(pool_dt))
-                v_pool = v_pool.at[li, page, off].set(
-                    v[:, :, 0, :].astype(pool_dt))
-            else:
-                k_pool = k_pool.at[li, page, off].set(
-                    k.transpose(0, 2, 1, 3).astype(pool_dt))
-                v_pool = v_pool.at[li, page, off].set(
-                    v.transpose(0, 2, 1, 3).astype(pool_dt))
+            # In-step cache write: each column's K/V row lands at its
+            # (page, offset) -- one scatter per pool per layer, in place
+            # (the layer is indexed like the page: no slice of a pool).
+            k_pool = k_pool.at[li, page, off].set(rows(k))
+            v_pool = v_pool.at[li, page, off].set(rows(v))
 
-            # Slot view: gather this slot's pages -> [S, kvh, max_len, d]
-            # (cold pages dequantised from the e4m3 pool when present).
-            if compress:
-                ks = gather_view(li, k_pool, kq_pool, kscale)
-                vs = gather_view(li, v_pool, vq_pool, vscale)
-            else:
-                ks = gather_view(li, k_pool)
-                vs = gather_view(li, v_pool)
             lengths = jnp.where(active, positions + 1, 0)
-            if width == 1:
-                o = decode_attention(q.astype(dtype), ks.astype(dtype),
-                                     vs.astype(dtype), lengths=lengths)
+            if walk:
+                # Straight out of both pools, by the page table.
+                o = cca_decode_attention(
+                    q[:, :, 0].astype(dtype), k_pool, page_table, layer=li,
+                    lengths=lengths, kv_heads=kvh_l, scale=hd ** -0.5,
+                    values=v_pool)[:, :, None]
             else:
-                o = verify_attention(q.astype(dtype), ks.astype(dtype),
-                                     vs.astype(dtype), lengths=lengths)
+                # Slot view: gather this slot's pages -> [S, kvh, max_len,
+                # d] (cold pages dequantised from the e4m3 pool when
+                # present).
+                if compress:
+                    ks = gather_view(li, k_pool, kq_pool, kscale)
+                    vs = gather_view(li, v_pool, vq_pool, vscale)
+                else:
+                    ks = gather_view(li, k_pool)
+                    vs = gather_view(li, v_pool)
+                attend = decode_attention if width == 1 \
+                    else verify_attention
+                o = attend(q.astype(dtype), ks.astype(dtype),
+                           vs.astype(dtype), lengths=lengths)
             o = o.transpose(0, 2, 1, 3).reshape(s, width, heads_l * hd)
 
             # Row-parallel closures: the activation allreduce routes
@@ -531,7 +572,7 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     n_last = 1 if width == 1 else 0
 
     def _build(params_example, adapters_example=None):
-        pool_spec = P(None, None, None, tp_axis, None)
+        pool_spec = P(None, None, None, tp_axis)
         in_specs = [decode_param_specs(params_example, tp_axis),
                     pool_spec, pool_spec, P(), P(), P(), P()]
         if compress:
@@ -557,22 +598,33 @@ def build_decode_step(config: LlamaConfig, mesh, *,
     # serving steps sharing exchange structure (same config/slots/width)
     # on the same mesh share one compiled executable.
     def step(*args):
-        # The fingerprint keys the exchange structure; the extras pin
-        # the non-exchange statics (page geometry, arg arity, mesh) the
-        # compiled program also depends on.
+        # The fingerprint keys the exchange structure (layers, slots,
+        # width, d_model, dtype, axis); the extras pin EVERY other
+        # constant the traced program closes over: arg arity, page
+        # geometry, mesh, how it reads the cache, and the model's own
+        # (RoPE's base, the head counts and size, LoRA's alpha), and
+        # whether the attention kernel's switch is on (read when the
+        # step is traced).  Two engines of one process that differ in
+        # any of them must not share a step.  (``ffn_hidden`` and the
+        # vocabulary are shapes of the params, which ``jax.jit`` keys on
+        # itself.)
         fn = _fusion.plan_executable(
             splan,
             lambda: _build(
                 args[0],
                 args[n_base] if len(args) > n_base + n_last else None),
             extra=(len(args), bool(compress), int(page_size),
-                   int(pages_per_slot), mesh))
+                   int(pages_per_slot), mesh, walk, float(cfg.rope_theta),
+                   int(cfg.num_heads), int(cfg.num_kv_heads), int(hd),
+                   float(lora_alpha), _pallas.pallas_enabled(
+                       "mla_decode" if walk else "flash_decode")))
         return fn(*args)
 
     meta = {"kind": kind, "world": tp, "tp": tp,
             "num_layers": cfg.num_layers, "d_model": cfg.d_model,
             "slots": int(slots), "dtype": str(jnp.dtype(dtype)),
-            "lora": bool(with_lora), "compress": bool(compress)}
+            "lora": bool(with_lora), "compress": bool(compress),
+            "attention": "walk" if walk else "view"}
     if width > 1:
         meta["width"] = int(width)
     return ServingDecodeStep(step, meta, leg=kind)

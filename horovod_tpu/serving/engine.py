@@ -551,8 +551,10 @@ class ServingEngine:
 
     # -- one decode round (shared with serving.controlplane) ---------------
     def _round_span(self, st: Dict[str, Any], slots: List[int],
-                    ahead: bool = False):
-        """The ``decode.round`` span of one round over ``slots``.
+                    ahead: bool = False, step=None):
+        """The ``decode.round`` span of one round over ``slots``,
+        dispatched through ``step`` (the decode step; a speculative
+        round hands in its verify step).
         ``live_tokens`` is what the round's attention reads: each slot's
         resident context and the token this round writes.  ``round``:
         the number of the round whose ``decode.dispatch`` lies under
@@ -566,14 +568,19 @@ class ServingEngine:
         token (``LayerSpec.passes``, ``.planes``).  ``pages``: the pages
         a page walk copies in ONE plane this round, each slot's live
         tokens rounded up to whole pages (with ``planes`` and a kernel's
-        time, a trace gives the nanoseconds a page)."""
+        time, a trace gives the nanoseconds a page).  ``walk``: 1 where
+        the step reads attention by that walk (its ``meta["attention"]``),
+        0 where it gathers slot views (verify, the fp8 path)."""
         live = [int(self.cache.lengths[s]) + 1 for s in slots]
         page = self.page_size
+        # (A test's stand-in for the step may be a bare function.)
+        meta = getattr(step or self.step, "meta", {})
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=sum(live), ahead=int(ahead),
             passes=self.spec.passes, planes=self.spec.planes,
-            pages=sum(-(-n // page) for n in live))
+            pages=sum(-(-n // page) for n in live),
+            walk=int(meta.get("attention") == "walk"))
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """Dispatch one plain continuous-batching decode round over the
@@ -730,7 +737,7 @@ class ServingEngine:
         n = int(st["decode_steps"])
         reqs = {s: sched.active[s] for s in slots}
         base = {s: int(cache.lengths[s]) for s in slots}
-        with self._round_span(st, slots):
+        with self._round_span(st, slots, step=self.verify_step):
             with phase("decode.reserve"):
                 for s in slots:
                     # Room for this round's widest write, capped at the

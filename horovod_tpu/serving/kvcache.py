@@ -2,18 +2,22 @@
 
 Physical layout is a fixed page pool per layer::
 
-    k, v: [num_layers, num_pages, page_size, num_kv_heads, head_dim]
+    k, v: [num_layers, num_pages, page_size, num_kv_heads * head_dim]
 
-(or, where ``CacheConfig.page`` says what one token holds in each pool,
-``[num_layers, num_pages, page_size, *page[0]]`` and ``*page[1]``; a
+A token's entry is ONE row with no head dim, head ``j`` in columns ``j *
+head_dim .. (j + 1) * head_dim``: the decode step's page walk copies
+whole pages and cuts a head's keys out of a page as tile-aligned
+columns, which a ``[kv_heads, head_dim]`` entry does not allow.  (Where
+``CacheConfig.page`` says what one token holds in each pool the pools
+are ``[num_layers, num_pages, page_size, *page[0]]`` and ``*page[1]``; a
 ``page[1]`` of None means there is no second pool and ``v`` is None: a
 latent-attention model keeps its normalised latent and its one rotated
-key side by side in ONE row, with no head dim at all -- pages,
-refcounts, the table and every write path are the same), sharded over
-the ``tp`` mesh axis on the kv-head dim (the same split the
+key side by side in ONE row -- pages, refcounts, the table and every
+write path are the same.)  The pools are sharded over the ``tp`` mesh
+axis on the row: contiguous heads a shard, the same split the
 tensor-parallel decode step gives the attention projections, so a rank's
 cache shard pairs exactly with its ``wk``/``wv`` kernel shards and no
-cross-rank traffic ever touches the cache).  The LOGICAL view -- which
+cross-rank traffic ever touches the cache.  The LOGICAL view -- which
 pages belong to which batch slot, and how many tokens are live -- is
 host-side metadata: an int32 ``page_table[slots, pages_per_slot]`` plus a
 ``lengths[slots]`` vector, shipped into the compiled step as plain
@@ -81,8 +85,9 @@ class CacheConfig:
     """Static shape of the pool (identical on every rank and mesh size)."""
 
     num_layers: int
-    # K and V of ``[num_kv_heads, head_dim]`` a token -- or ``page``, not
-    # both: the pools' shapes have ONE source.
+    # K and V of ``num_kv_heads * head_dim`` columns a token, one row in
+    # each of two pools -- or ``page``, not both: the pools' shapes have
+    # ONE source.
     num_kv_heads: Optional[int] = None
     head_dim: Optional[int] = None
     _: dataclasses.KW_ONLY
@@ -94,8 +99,7 @@ class CacheConfig:
     hot_pages: int = 1             # full pages behind the head kept f32
     # Trailing dims of ONE token's entry in the first and second pool
     # (the second None: one pool only), in place of ``num_kv_heads`` and
-    # ``head_dim`` (which are then filled in where both pools hold the
-    # same two dims, and stay None elsewhere).
+    # ``head_dim`` (which then stay None).
     page: Optional[Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]] = None
     # Values a slot keeps a layer beside its pages (``LayerSpec.
     # slot_state``); None: no such state.
@@ -108,18 +112,16 @@ class CacheConfig:
                 "give num_kv_heads and head_dim, or page, and not both: "
                 f"{heads}, {self.page}")
         if self.page is None:
-            object.__setattr__(self, "page", (heads, heads))
+            row = (int(self.num_kv_heads) * int(self.head_dim),)
+            object.__setattr__(self, "page", (row, row))
         else:
-            page = tuple(None if e is None else tuple(int(n) for n in e)
-                         for e in self.page)
-            object.__setattr__(self, "page", page)
-            if page[0] == page[1] and len(page[0]) == 2:
-                object.__setattr__(self, "num_kv_heads", page[0][0])
-                object.__setattr__(self, "head_dim", page[0][1])
-        if self.compress and self.num_kv_heads is None:
+            object.__setattr__(self, "page", tuple(
+                None if e is None else tuple(int(n) for n in e)
+                for e in self.page))
+        if self.compress and self.page[1] is None:
             raise NotImplementedError(
-                "the fp8 cold pool holds [kv_heads, head_dim] entries "
-                f"only, not {self.page}")
+                "the fp8 cold pool mirrors TWO pools (six step operands), "
+                f"not {self.page}")
         if self.max_len % self.page_size:
             raise ValueError(
                 f"max_len {self.max_len} not a multiple of page_size "
@@ -220,7 +222,6 @@ class PagedKVCache:
         c = config
         # +1: trailing scratch page, the write sink for idle slots.
         lead = (c.num_layers, c.num_pages + 1, c.page_size)
-        shape = lead + (c.num_kv_heads, c.head_dim)      # the fp8 pools'
         k = jnp.zeros(lead + c.entries[0], jnp.dtype(c.dtype))
         v = None if c.entries[1] is None else jnp.zeros(
             lead + c.entries[1], jnp.dtype(c.dtype))
@@ -259,14 +260,13 @@ class PagedKVCache:
         # decode/verify steps wherever ``comp_mask`` is set.
         self.compress = bool(c.compress)
         if self.compress:
-            self.kq = jnp.zeros(shape, jnp.float8_e4m3fn)
-            self.vq = jnp.zeros(shape, jnp.float8_e4m3fn)
+            self.kq = jnp.zeros(lead + c.entries[0], jnp.float8_e4m3fn)
+            self.vq = jnp.zeros(lead + c.entries[1], jnp.float8_e4m3fn)
             if sharding is not None:
                 self.kq = jax.device_put(self.kq, sharding)
                 self.vq = jax.device_put(self.vq, sharding)
-            sshape = (c.num_layers, c.num_pages + 1, c.page_size)
-            self.kscale = jnp.ones(sshape, jnp.float32)
-            self.vscale = jnp.ones(sshape, jnp.float32)
+            self.kscale = jnp.ones(lead, jnp.float32)
+            self.vscale = jnp.ones(lead, jnp.float32)
             self.cpage_table = np.zeros((c.slots, c.pages_per_slot),
                                         np.int32)
             self.comp_mask = np.zeros((c.slots, c.pages_per_slot), bool)
@@ -606,10 +606,9 @@ class PagedKVCache:
         self.lengths[slot] = int(length)
 
     def adopt_pages(self, k_pages, v_pages=None) -> List[Tuple[str, int]]:
-        """Materialize STREAMED full pages (``[L, n, page_size, H, D]``,
-        the ``serving.kvwire`` f32 tier; ``[planes, n, page_size,
-        *entry]`` of each pool in general, ``v_pages`` None where there is
-        one pool) as resident pool pages at
+        """Materialize STREAMED full pages (``[planes, n, page_size,
+        *entry]`` of each pool, the ``serving.kvwire`` f32 tier;
+        ``v_pages`` None where there is one pool) as resident pool pages at
         refcount 1, owned by the caller.  The disaggregated import path
         then maps them into a slot with :meth:`attach_pages` and drops
         the importer's reference -- exactly the prefix-hit flow, except
@@ -679,7 +678,7 @@ class PagedKVCache:
     def gather_pages(self, entries: Sequence[Tuple[str, int]]) -> tuple:
         """Materialize page contents as chunked-prefill ``past``
         operands: ``(k, v)`` each ``[num_layers, 1, n * page_size,
-        num_kv_heads, head_dim]``, fp8-demoted pages dequantized
+        *entry]``, fp8-demoted pages dequantized
         through their per-row scales (same blend the decode gather
         does)."""
         c = self.config
@@ -695,14 +694,16 @@ class PagedKVCache:
                  getattr(self, "kscale", None)),
                 (self.v, getattr(self, "vq", None),
                  getattr(self, "vscale", None))):
-            view = pool[:, jnp.asarray(fp)]        # [L, n, ps, H, D]
+            view = pool[:, jnp.asarray(fp)]        # [L, n, ps, *entry]
             if any_c:
                 cpd = jnp.asarray(cp)
+                entry = (1,) * (view.ndim - 3)
+                rows = scale[:, cpd]               # one scale a row
                 deq = (qpool[:, cpd].astype(jnp.float32)
-                       * scale[:, cpd][..., None, None]).astype(
-                           view.dtype)
+                       * rows.reshape(rows.shape + entry)
+                       ).astype(view.dtype)
                 view = jnp.where(
-                    jnp.asarray(cmask)[None, :, None, None, None],
+                    jnp.asarray(cmask).reshape((1, -1, 1) + entry),
                     deq, view)
             l, n, ps = view.shape[:3]
             out.append(view.reshape(l, n * ps, *view.shape[3:])[:, None])
@@ -740,10 +741,10 @@ class PagedKVCache:
                       start: int = 0, state=None) -> None:
         """Scatter a prefilled prompt's K/V into the slot's pages.
 
-        ``k_layers``/``v_layers``: ``[num_layers, t, num_kv_heads,
-        head_dim]`` (post-RoPE, as the decode step expects; ``[num_layers,
-        t, *entry]`` of each pool where ``CacheConfig.page`` is set, and
-        ``v_layers`` None where there is one pool).  Reserves
+        ``k_layers``/``v_layers``: ``[num_layers, t, *entry]`` of each
+        pool (``num_kv_heads * head_dim`` columns, post-RoPE, as the
+        decode step expects; ``v_layers`` None where there is one pool).
+        Reserves
         pages for ``start + t`` tokens and sets ``lengths[slot] =
         start + t``.  ``start`` is the prefix-cache seam: a matched
         prefix's pages are already attached and immutable, only the
@@ -758,7 +759,7 @@ class PagedKVCache:
         pages = jnp.asarray(self.page_table[slot][pos // c.page_size])
         offs = jnp.asarray(pos % c.page_size)
         dt = jnp.dtype(c.dtype)
-        # One scatter per pool: [L, t, H, D] lands at (page, off) pairs.
+        # One scatter per pool: [L, t, *entry] lands at (page, off) pairs.
         self.k = _pool_set(self.k, k_layers.astype(dt), pages, offs)
         if self.v is not None:
             self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
@@ -1079,22 +1080,22 @@ class PrefixCache:
 
 def _quantize_pages(pool, pids):
     """fp8-quantize pages ``pids`` of one pool through the PR 5 codec:
-    one max-abs e4m3 scale per (layer, page, offset) row over the
-    ``[kv_heads * head_dim]`` vector, so a never-written row (absmax 0)
-    roundtrips to exact zeros with scale 1.  Returns
-    ``(q [L, n, page, H, D] e4m3, scales [L, n, page] f32)``."""
+    one max-abs e4m3 scale per (layer, page, offset) row over the whole
+    entry, so a never-written row (absmax 0) roundtrips to exact zeros
+    with scale 1.  Returns ``(q [L, n, page, *entry] e4m3, scales [L, n,
+    page] f32)``."""
     x = pool[:, pids]
-    l, n, pg, hh, dd = x.shape
-    q, s = fp8_quantize(x.reshape(l * n * pg, hh * dd), axis=0)
-    return q.reshape(l, n, pg, hh, dd), s.reshape(l, n, pg)
+    l, n, pg = x.shape[:3]
+    q, s = fp8_quantize(x.reshape(l * n * pg, -1), axis=0)
+    return q.reshape(x.shape), s.reshape(l, n, pg)
 
 
-def cache_sharding(mesh, tp_axis: str = "tp", *, entry_rank: int = 2,
+def cache_sharding(mesh, tp_axis: str = "tp", *, entry_rank: int = 1,
                    split: Optional[int] = 0):
     """NamedSharding of a pool ``[layers, pages, page_size, *entry]``:
-    the ``tp`` axis splits dim ``split`` of a token's entry (the kv-head
-    dim of a ``[kv_heads, head_dim]`` entry by default; None: the pool is
-    whole on every chip)."""
+    the ``tp`` axis splits dim ``split`` of a token's entry (by default
+    the one row of ``kv_heads * head_dim`` columns, contiguous heads a
+    shard; None: the pool is whole on every chip)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     if mesh is None:
         return None

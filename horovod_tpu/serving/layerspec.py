@@ -151,7 +151,10 @@ def _llama_spec(config) -> LayerSpec:
             return decode.build_verify_step(config, mesh, width=width, **kw)
         return decode.build_decode_step(config, mesh, **kw)
 
-    entry = (config.num_kv_heads, config.head_dim)
+    # One row a token in each pool, head ``j`` in columns ``j * head_dim
+    # .. (j + 1) * head_dim``: the decode step's page walk reads it in
+    # place (``ops.attention.cca_decode_attention``).
+    entry = (config.num_kv_heads * config.head_dim,)
     return LayerSpec(
         attention="gqa", page=(entry, entry),
         page_holds=("rotated keys", "values"),

@@ -201,7 +201,7 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     return ServingDecodeStep(fn, dict(
         meta, kind="serving_decode", world=1, tp=1, num_layers=num_layers,
         passes=passes, dtype=str(jnp.dtype(dtype)), lora=False,
-        compress=False))
+        compress=False, attention="walk"))
 
 
 def refuse_beyond_one_chip(what: str, mesh, *, width: int, with_lora: bool,

@@ -25,6 +25,18 @@ that XLA's all-reduce combiner makes ONE many-operand all-reduce of them,
 each operand in its own tiled layout, and puts no buffer back, is the
 compiler's doing and is held here.
 
+``<topology> dense_step [layers [tp]]``: compiles the dense decode step
+(``serving/decode.py``) at Mistral-7B's widths (32/8 heads of 128, FFN
+14,336, 32 slots, 96 pages of 16 tokens a slot, bfloat16; 4 layers by
+default) for ONE chip of the topology (or ``tp`` of them, each with its
+shard of the heads) and prints how it reads and writes
+its cache: its Mosaic calls, whether both pools are aliased in place,
+the module's temporary bytes, and every instruction of the optimized
+module cut out of a pool (one of its dims is the pool's page count) whose
+result is at least a pool PLANE in size, other than the in-place row
+writes, whose result IS the pool (``pool[layer]`` materialised, a
+gathered view, a relayout: there must be none).
+
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
@@ -117,6 +129,11 @@ def kernels(topology: str) -> int:
             q, pool, page_table, layer=3, lengths=lengths, kv_heads=2,
             scale=128 ** -0.5)
 
+    def dense_decode(q, keys, values, page_table, lengths):
+        return attention.cca_decode_attention(
+            q, keys, page_table, layer=3, lengths=lengths, kv_heads=8,
+            scale=128 ** -0.5, values=values)
+
     def loop_decode(q, pool, page_table, lengths):
         # The plane is a traced scalar: pass t of layer 5 of 48, inside a
         # rolled loop over four passes.
@@ -205,6 +222,20 @@ def kernels(topology: str) -> int:
             spec((1, 32, 1024, 128), jnp.bfloat16),
             spec((1, 8, 1024, 128), jnp.bfloat16),
             spec((1, 8, 1024, 128), jnp.bfloat16)]),
+        # Mistral-7B's decode of 32 slots out of TWO pools (16 layers of
+        # 3,073 pages of 16 rows, 96 pages a slot), a row of eight key
+        # heads of 128 in one and eight value heads in the other; and
+        # LLAMA_1B's (chip_smoke.py: float32, 8 slots, 64 pages a slot).
+        "dense_decode_b32": (dense_decode, [
+            spec((32, 32, 128), jnp.bfloat16),
+            spec((16, 3073, 16, 1024), jnp.bfloat16),
+            spec((16, 3073, 16, 1024), jnp.bfloat16),
+            spec((32, 96), jnp.int32), spec((32,), jnp.int32)]),
+        "dense_decode_f32_b8": (dense_decode, [
+            spec((8, 16, 128), jnp.float32),
+            spec((16, 513, 16, 1024), jnp.float32),
+            spec((16, 513, 16, 1024), jnp.float32),
+            spec((8, 64), jnp.int32), spec((8,), jnp.int32)]),
         # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
         "flash_decode_b8": (decode, [
             spec((8, 16, 1, 128), jnp.float32),
@@ -222,6 +253,93 @@ def kernels(topology: str) -> int:
             head_group.append(name)
     out["head_group"] = head_group
     print(json.dumps(out))
+    return 0
+
+
+def dense_step(topology: str, layers: int = 4, tp: int = 1) -> int:
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.controller import fusion
+    from horovod_tpu.models.transformer import LlamaConfig, LlamaLM
+    from horovod_tpu.ops import pallas
+    from horovod_tpu.serving import build_decode_step
+    from horovod_tpu.serving.decode import decode_param_specs, no_round
+
+    pallas.interpret_mode = lambda: False
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    mesh = Mesh(np.asarray(td.devices[:tp]), ("tp",))
+    cfg = LlamaConfig(vocab_size=32768, num_layers=layers, num_heads=32,
+                      num_kv_heads=8, head_dim=128, d_model=4096,
+                      ffn_hidden=14336, rope_theta=1e6, max_seq_len=32768)
+    slots, page, pps = 32, 16, 96
+    bf = jnp.bfloat16
+
+    def on(spec):
+        return NamedSharding(mesh, spec)
+
+    shapes = jax.eval_shape(LlamaLM(cfg, dtype=bf).init,
+                            jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4), jnp.int32))
+    params = jax.tree.map(
+        lambda z, spec: jax.ShapeDtypeStruct(z.shape, bf, sharding=on(spec)),
+        shapes, decode_param_specs(shapes))
+    pool = jax.ShapeDtypeStruct(
+        (layers, slots * pps + 1, page, cfg.num_kv_heads * cfg.head_dim),
+        bf, sharding=on(P(None, None, None, "tp")))
+
+    def whole(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on(P()))
+
+    args = (params, pool, pool, whole((slots,), jnp.int32),
+            whole((slots,), jnp.int32), whole((slots, pps), jnp.int32),
+            whole((slots,), jnp.bool_), whole(no_round(slots).shape,
+                                              jnp.int32))
+    # The step builds its jitted program at its first call: take what it
+    # builds instead of calling it.
+    built = []
+    fusion.plan_executable = lambda plan, build, extra=(): built.append(
+        build()) or (lambda *a: None)
+    step = build_decode_step(cfg, mesh, slots=slots, page_size=page,
+                             pages_per_slot=pps, dtype=bf)
+    step(*args)
+    lowered = built[0].lower(*args)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    plane = (slots * pps + 1) * page * cfg.num_kv_heads * cfg.head_dim // tp
+    local = pool.shape[:3] + (pool.shape[3] // tp,)
+    big, writes = [], 0
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (\w+)\[([\d,]*)\]\S* (\S+?)\(",
+                     line)
+        if not m or m.group(4) in ("parameter", "get-tuple-element",
+                                   "tuple", "bitcast"):
+            continue
+        dims = tuple(int(d) for d in m.group(3).split(",") if d)
+        if slots * pps + 1 not in dims or int(np.prod(dims)) < plane:
+            continue
+        if dims == local and m.group(4) in ("fusion", "scatter"):
+            writes += m.group(4) == "fusion"
+        else:
+            big.append(f"{m.group(4)} {m.group(2)}[{m.group(3)}]")
+    header = text[:text.index("\n")]
+    print(json.dumps({
+        "attention": step.meta["attention"],
+        "mosaic_calls": lowered.as_text().count("tpu_custom_call"),
+        "aliased_params": sorted(int(i) for i in re.findall(
+            r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)),
+        "pool_params": [len(jax.tree.leaves(params)),
+                        len(jax.tree.leaves(params)) + 1],
+        "pool_writes": writes,
+        "plane_sized": sorted(set(big)),
+        "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes),
+    }))
     return 0
 
 
@@ -275,6 +393,9 @@ if __name__ == "__main__":
     topo = sys.argv[1] if len(sys.argv) > 1 else "v5e:2x4"
     if sys.argv[2:] == ["exchange"]:
         sys.exit(exchange(topo))
+    if sys.argv[2:3] == ["dense_step"]:
+        os.environ["HOROVOD_PALLAS"] = "1"
+        sys.exit(dense_step(topo, *(int(a) for a in sys.argv[3:5])))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

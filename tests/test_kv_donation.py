@@ -52,10 +52,10 @@ def _make_cache(ndev, slots=4, page_size=8, max_len=64):
 
 
 def _rows(cache, t, seed=0):
-    """``[L, t, H, D]`` K and V rows a prefill would hand the cache."""
+    """``[L, t, H * D]`` K and V rows a prefill would hand the cache."""
     c = cache.config
     rng = np.random.RandomState(seed)
-    shape = (c.num_layers, t, c.num_kv_heads, c.head_dim)
+    shape = (c.num_layers, t, *c.entries[0])
     return (jnp.asarray(rng.normal(size=shape), jnp.float32),
             jnp.asarray(rng.normal(size=shape), jnp.float32))
 
@@ -120,8 +120,8 @@ def _cow_clone(params, mesh, ccfg, cache):
 def _adopt_pages(params, mesh, ccfg, cache):
     c = cache.config
     rng = np.random.RandomState(3)
-    pages = rng.normal(size=(c.num_layers, 2, c.page_size, c.num_kv_heads,
-                             c.head_dim)).astype(np.float32)
+    pages = rng.normal(size=(c.num_layers, 2, c.page_size,
+                             *c.entries[0])).astype(np.float32)
     given = cache.k, cache.v
     entries = cache.adopt_pages(pages, pages * 2)
     got = np.asarray(cache.v[:, [pid for _, pid in entries]])

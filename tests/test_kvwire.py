@@ -45,8 +45,8 @@ def _cache(compress=False, slots=4, max_len=64):
 
 def _kv(T, seed=0):
     rng = np.random.RandomState(seed)
-    k = rng.randn(L, T, H, D).astype(np.float32)
-    v = rng.randn(L, T, H, D).astype(np.float32)
+    k = rng.randn(L, T, H * D).astype(np.float32)     # the cache's rows
+    v = rng.randn(L, T, H * D).astype(np.float32)
     return k, v
 
 
@@ -66,10 +66,10 @@ def test_f32_roundtrip_bitwise():
     wp = decode_kv(encode_kv(k, v, page_size=PS, tier="f32"))
     assert (wp.length, wp.page_size) == (21, PS)
     assert wp.full_pages == 2 and wp.tail_tokens == 5
-    want_k = k[:, :16].reshape(L, 2, PS, H, D)
+    want_k = k[:, :16].reshape(L, 2, PS, H * D)
     assert wp.k_pages.tobytes() == want_k.tobytes()
     assert wp.v_pages.tobytes() == \
-        v[:, :16].reshape(L, 2, PS, H, D).tobytes()
+        v[:, :16].reshape(L, 2, PS, H * D).tobytes()
     assert wp.k_tail.tobytes() == k[:, 16:].tobytes()
     assert wp.v_tail.tobytes() == v[:, 16:].tobytes()
 

@@ -470,6 +470,196 @@ def test_decode_attention_validation():
 
 
 # ---------------------------------------------------------------------------
+# The page walk over TWO pools: the dense decode step's attention.
+# ---------------------------------------------------------------------------
+
+from horovod_tpu.ops.attention import cca_decode_attention
+
+_PAGE, _PPS, _PPB = 8, 12, 4        # a block of 32 keys, three a slot
+
+
+def _two_pools(seed, lengths, h, kvh, d=128):
+    """Keys in one pool, values in another (rows of ``kvh`` heads side by
+    side, two planes), a shuffled page table, and one query a row."""
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    shape = (2, b * _PPS + 1, _PAGE, kvh * d)
+    keys = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    values = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    table = jnp.asarray(rng.permutation(b * _PPS).reshape(b, _PPS),
+                        jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
+    return q, keys, values, table, jnp.asarray(lengths, jnp.int32)
+
+
+def _walk_on(monkeypatch, sub=16):
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(_attn, "MLA_PAGES_PER_BLOCK", _PPB)
+    monkeypatch.setattr(_attn, "MLA_KEYS_PER_SUB_BLOCK", sub)
+
+
+def _slot_view(pool, table, kvh, d=128):
+    """``[b, kvh, max_len, d]`` of plane 1: what the step used to gather."""
+    b = table.shape[0]
+    return pool[1][table].reshape(b, _PPS * _PAGE, kvh, d).transpose(
+        0, 2, 1, 3)
+
+
+# Lengths that end inside a page (5, 37), on a page's edge (8, 40),
+# inside a block and on its edge (33; 32, 64), at ``max_len`` (96); a
+# dead row.
+_LENGTHS = [5, 8, 33, 32, 37, 40, 64, 95, 96, 0, 1]
+
+
+@pytest.mark.parametrize("h,kvh", [(32, 8), (8, 2)])
+@pytest.mark.parametrize("sub", [16, 256])
+def test_two_pool_walk_matches_attention_reference(monkeypatch, h, kvh,
+                                                   sub):
+    q, keys, values, table, lens = _two_pools(h + sub, _LENGTHS, h, kvh)
+    rep = h // kvh
+    kv_seg = (jnp.arange(_PPS * _PAGE)[None, :]
+              < lens[:, None]).astype(jnp.int32)
+    want = attention_reference(
+        q[:, :, None], jnp.repeat(_slot_view(keys, table, kvh), rep, 1),
+        jnp.repeat(_slot_view(values, table, kvh), rep, 1),
+        segment_ids=jnp.ones((len(_LENGTHS), 1), jnp.int32),
+        kv_segment_ids=kv_seg)[:, :, 0]
+    _walk_on(monkeypatch, sub)
+    got = cca_decode_attention(q, keys, table, layer=1, lengths=lens,
+                               kv_heads=kvh, scale=128 ** -0.5,
+                               values=values)
+    assert got.shape == (len(_LENGTHS), h, 128) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    assert not np.any(np.asarray(got[_LENGTHS.index(0)]))    # exactly zero
+    # Kernels off, the same call IS ``decode_attention``'s reference over
+    # the gathered view, bit for bit: a verify step's rows stay a decode
+    # step's.
+    off = cca_decode_attention(q, keys, table, layer=1, lengths=lens,
+                               kv_heads=kvh, scale=128 ** -0.5,
+                               values=values, force_reference=True)
+    ref = decode_attention(q[:, :, None], _slot_view(keys, table, kvh),
+                           _slot_view(values, table, kvh), lengths=lens,
+                           force_reference=True)[:, :, 0]
+    np.testing.assert_array_equal(np.asarray(off), np.asarray(ref))
+
+
+def test_two_pool_walk_plane_is_traced_or_static(monkeypatch):
+    """One jitted function for every layer: the plane as a Python int
+    and as a traced scalar read the same rows."""
+    q, keys, values, table, lens = _two_pools(3, [40, 0, 9], 8, 2)
+    _walk_on(monkeypatch)
+    kw = dict(lengths=lens, kv_heads=2, scale=0.1, values=values)
+    static = cca_decode_attention(q, keys, table, layer=1, **kw)
+    traced = jax.jit(lambda t: cca_decode_attention(
+        q, keys, table, layer=t, **kw))(jnp.int32(1))
+    np.testing.assert_array_equal(np.asarray(static), np.asarray(traced))
+    other = cca_decode_attention(q, keys, table, layer=0, **kw)
+    assert np.abs(np.asarray(other) - np.asarray(static)).max() > 1e-3
+
+
+@pytest.mark.parametrize("which", ["keys", "values", "both"])
+def test_two_pool_walk_never_reads_past_the_length(monkeypatch, which):
+    """Recycled-page garbage past ``lengths`` (huge, finite), in either
+    pool: the rest of a live page, every page after it, a dead row's
+    whole list.  Nothing moves by a bit."""
+    lengths = [5, 37, 0, 64, 1]
+    q, keys, values, table, lens = _two_pools(11, lengths, 8, 2)
+    _walk_on(monkeypatch)
+    kw = dict(layer=1, lengths=lens, kv_heads=2, scale=128 ** -0.5)
+    clean = cca_decode_attention(q, keys, table, values=values, **kw)
+
+    def poisoned(pool):
+        flat = np.array(pool[1][table]).reshape(len(lengths), -1,
+                                                pool.shape[-1])
+        for i, n in enumerate(lengths):
+            flat[i, n:] = 1e30
+        return pool.at[1, table].set(jnp.asarray(flat.reshape(
+            len(lengths), _PPS, _PAGE, -1)))
+
+    dirty_k = poisoned(keys) if which != "values" else keys
+    dirty_v = poisoned(values) if which != "keys" else values
+    got = cca_decode_attention(q, dirty_k, table, values=dirty_v, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    assert not np.any(np.asarray(got[2]))
+
+
+def test_two_pool_walk_refuses_pools_that_do_not_match():
+    q, keys, values, table, lens = _two_pools(0, [4, 4], 8, 2)
+    kw = dict(layer=0, lengths=lens, kv_heads=2, scale=1.0)
+    with pytest.raises(ValueError, match="do not fit together"):
+        cca_decode_attention(q, keys, table, values=values[:, :-1], **kw)
+    with pytest.raises(ValueError, match="do not fit together"):
+        # One pool of this width is ONE key/value head of 128, not two.
+        cca_decode_attention(q, keys, table, **kw)
+
+
+# What the walk lowers to for the TPU with ONE pool, at the three served
+# shapes: sha256 of the lowered text with each Mosaic body printed as
+# MLIR without source locations (the recipe of
+# ``.claude/skills/verify/SKILL.md``).  Recorded on PR 37's tree: the
+# second pool is a STATIC branch, and ``hvd_mla_decode`` /
+# ``hvd_cca_decode`` in JoyAI's, ZAYA's and Ouro's cells must stay the
+# program they were.  A change of the one-pool kernel itself re-records
+# these, and owes those three cells a measurement.
+_ONE_POOL_LOWERED = {
+    "zaya": "7c134b5debaf4f726b478fcf46cf0b8712bce9637cf1a9d5947a17b901ea87db",
+    "ouro": "1612732b6a3731f777c426eb6f26b813cc2180d6f1c47ce1949e9444be4721ca",
+    "joyai": "435906f506d3563cc70ad4590b1df2d3ab8371ba85e4d833dc22f4c91d73bf09",
+}
+
+
+def _lowered_for_tpu(fn, *args):
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jmlir
+    from jaxlib.mlir import ir
+
+    def body(match):
+        with jmlir.make_ir_context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            return ir.Module.parse(base64.b64decode(
+                match.group(1))).operation.get_asm(enable_debug_info=False)
+
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    return re.sub(r'(?<=body\\22: \\22)([A-Za-z0-9+/=]+)(?=\\22)', body,
+                  text)
+
+
+@pytest.mark.parametrize("cell", list(_ONE_POOL_LOWERED))
+def test_one_pool_walk_lowers_to_what_it_was(monkeypatch, cell):
+    import hashlib
+
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(_attn._pallas, "interpret_mode", lambda: False)
+    S, bf, i32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.int32
+    fn, args = {
+        # 96 slots, 8 query heads over 2 key heads, rows [k k | v v].
+        "zaya": (lambda q, pool, t, n: cca_decode_attention(
+            q, pool, t, layer=3, lengths=n, kv_heads=2, scale=128 ** -0.5),
+            (S((96, 8, 128), bf), S((24, 9217, 16, 512), bf),
+             S((96, 96), i32), S((96,), i32))),
+        # 20 slots, 16 heads over 16, the plane a traced scalar.
+        "ouro": (lambda q, pool, t, n, l: cca_decode_attention(
+            q, pool, t, layer=l, lengths=n, kv_heads=16, scale=128 ** -0.5),
+            (S((20, 16, 128), bf), S((192, 321, 16, 4096), bf),
+             S((20, 16), i32), S((20,), i32), S((), i32))),
+        # 64 slots, 32 heads over one latent row of 512 + 64 (+ 64).
+        "joyai": (lambda q, pool, t, n: _attn.mla_decode_attention(
+            q, pool, t, layer=2, lengths=n, value_dim=512, scale=0.07),
+            (S((64, 32, 640), bf), S((5, 34817, 16, 640), bf),
+             S((64, 544), i32), S((64,), i32))),
+    }[cell]
+    text = _lowered_for_tpu(fn, *args)
+    assert "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _ONE_POOL_LOWERED[cell]
+
+
+# ---------------------------------------------------------------------------
 # The unified HOROVOD_PALLAS switch (ops.pallas).
 # ---------------------------------------------------------------------------
 

@@ -183,7 +183,9 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # slots) and that model's 512-token prefill, 8 query heads over 2;
     # the same walk over rows of 16 key and 16 value heads (20 slots)
     # with its plane a traced scalar inside a rolled loop (ONE call in
-    # the loop's body), and that model's 128-token prefill.
+    # the loop's body), and that model's 128-token prefill; the same walk
+    # over TWO pools of eight heads a row, the dense decode step's, at
+    # Mistral-7B's 32 slots (bfloat16) and LLAMA_1B's 8 (float32).
     assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
                    "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
                    "head_group": ["flash_cca_prefill_512",
@@ -194,7 +196,31 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
                    "flash_decode_b8": 1, "mla_decode_b64": 1,
                    "moe_gmm_decode": 2, "moe_gmm_prefill_8k": 2,
                    "moe_gmm_wide_decode": 2, "moe_gmm_wide_prefill_512": 2,
-                   "cca_decode_b96": 1, "flash_cca_prefill_512": 1}
+                   "cca_decode_b96": 1, "flash_cca_prefill_512": 1,
+                   "dense_decode_b32": 1, "dense_decode_f32_b8": 1}
+
+
+def test_topology_aot_dense_decode_step_reads_the_pools_in_place():
+    """Mistral-7B's decode step (four of its layers), compiled for one
+    v5e chip: attention is ONE Mosaic function (the page walk, shared by
+    every layer), both pools are aliased to their successors, the row
+    writes are the only instructions as large as a pool, and nothing cut
+    out of a pool is as large as one of its planes: no ``pool[layer]``,
+    no gathered view of every slot, no relayout (the parent's step held
+    a 100 MB slice and a 100 MB fusion a layer and 306 MB of temporaries
+    where this holds 37)."""
+    out = _topology_worker("v5e:2x2", "dense_step")
+    assert out["attention"] == "walk" and out["mosaic_calls"] == 1
+    assert out["aliased_params"] == out["pool_params"]
+    assert out["pool_writes"] == 2 * 4       # K and V, a layer
+    assert out["plane_sized"] == []
+    assert out["temp_bytes"] < 3073 * 16 * 1024 * 2     # one plane
+    # Over four chips each shard walks its own two heads' columns: the
+    # Mosaic call compiles inside the ``shard_map``, in place as well.
+    out = _topology_worker("v5e:2x2", "dense_step", "4", "4")
+    assert out["attention"] == "walk" and out["mosaic_calls"] == 1
+    assert out["aliased_params"] == out["pool_params"]
+    assert out["pool_writes"] == 2 * 4 and out["plane_sized"] == []
 
 
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():

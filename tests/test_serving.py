@@ -110,9 +110,13 @@ def test_cache_layout_invariant_across_mesh_sizes():
         layouts.append(cache.layout())
     assert all(l == layouts[0] for l in layouts[1:])
     # Sharded pool global shape equals the declared layout regardless of
-    # how many ranks split the kv-head dim.
+    # how many ranks split the row: one row a token, no head dim, a
+    # shard holding its contiguous heads (here one of the eight).
     _, _, cache8 = _make_cache(8)
     assert list(cache8.k.shape) == layouts[0]["kv_shape"]
+    assert layouts[0]["kv_shape"][3:] == [CFG.num_kv_heads * CFG.head_dim]
+    assert cache8.k.sharding.shard_shape(cache8.k.shape)[3:] \
+        == (CFG.head_dim,)
 
 
 def test_slot_eviction_reuse_no_stale_attention_mass(base_params):
@@ -201,7 +205,7 @@ def test_write_prefill_sets_length_and_pages():
                        page_size=4, max_len=16)
     cache = PagedKVCache(ccfg)
     t = 6
-    kl = jnp.arange(2 * t * 2 * 4, dtype=jnp.float32).reshape(2, t, 2, 4)
+    kl = jnp.arange(2 * t * 2 * 4, dtype=jnp.float32).reshape(2, t, 8)
     cache.write_prefill(1, kl, kl * 2)
     assert int(cache.lengths[1]) == t
     assert cache.free_pages == ccfg.num_pages - 2
@@ -324,6 +328,186 @@ def test_request_prefetcher_order_and_error():
 
     with pytest.raises(Boom):
         list(RequestPrefetcher(BadList(reqs), depth=1))
+
+
+# ---------------------------------------------------------------------------
+# The decode step walks the page table; the fp8 and verify steps gather views
+# ---------------------------------------------------------------------------
+
+
+def _two_live_slots(params, cache, tokens):
+    """Slots 0 and 2 hold 13 and 24 prompt tokens; slots 1 and 3 idle."""
+    for slot, t in ((0, 13), (2, 24)):
+        _, kl, vl = prefill_forward(params, CFG, tokens[slot:slot + 1, :t])
+        cache.write_prefill(slot, kl[:, 0], vl[:, 0])
+        cache.reserve(slot, t + 3)
+    slots = cache.config.slots
+    return jnp.zeros((slots,), bool).at[jnp.asarray([0, 2])].set(True)
+
+
+@pytest.mark.parametrize("kernels", ["0", "1"])
+def test_walk_step_and_view_step_agree_on_one_cache(base_params,
+                                                    monkeypatch, kernels):
+    """Width 1 over uncompressed pools the step WALKS the page table;
+    built for the fp8 pool (with nothing compressed yet) it gathers slot
+    views of the same rows.  Same logits, same tokens, same pools: bit
+    for bit with the kernels off (one reference under both), to rounding
+    with ``hvd_cca_decode`` and ``hvd_flash_decode`` interpreted."""
+    monkeypatch.setenv("HOROVOD_PALLAS_DECODE", kernels)
+    _, params = base_params
+    mesh = mesh_1d(1)
+    ccfg = CacheConfig(num_layers=CFG.num_layers,
+                       num_kv_heads=CFG.num_kv_heads, head_dim=CFG.head_dim,
+                       slots=4, page_size=8, max_len=64, compress=True)
+    cache = PagedKVCache(ccfg, cache_sharding(mesh))
+    kw = dict(slots=4, page_size=8, pages_per_slot=ccfg.pages_per_slot)
+    walk = build_decode_step(CFG, mesh, **kw)
+    view = build_decode_step(CFG, mesh, compress=True, **kw)
+    assert walk.meta["attention"] == "walk"
+    assert view.meta["attention"] == "view"
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 32), 0,
+                                CFG.vocab_size)
+    active = _two_live_slots(params, cache, tokens)
+    table, base = cache.table_device(), cache.lengths_device()
+    pools = {"walk": (jnp.copy(cache.k), jnp.copy(cache.v)),
+             "view": (cache.k, cache.v)}
+    told = {"walk": no_round(4), "view": no_round(4)}
+    same = np.testing.assert_array_equal if kernels == "0" else \
+        (lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5))
+    for i in range(3):
+        # Round 0 feeds the host's tokens; after it the step reads its
+        # own from ``told`` (-1).
+        tok = tokens[:, 31] if i == 0 else jnp.full((4,), -1, jnp.int32)
+        out = {}
+        for name, step, extra in (
+                ("walk", walk, ()),
+                ("view", view, cache.compress_operands())):
+            logits, k, v, told[name] = step(
+                params, *pools[name], tok, base + i, table, active, *extra,
+                told[name])
+            pools[name] = (k, v)
+            out[name] = np.asarray(logits)
+        same(out["walk"][[0, 2]], out["view"][[0, 2]])
+        np.testing.assert_array_equal(np.asarray(told["walk"]),
+                                      np.asarray(told["view"]))
+    for a, b in zip(pools["walk"], pools["view"]):
+        same(np.asarray(a), np.asarray(b))
+
+
+def test_tp_shards_hold_contiguous_heads_of_head_less_rows(base_params):
+    """One row a token, split over ``tp`` into contiguous heads: the
+    8-device step writes and reads what the 1-device step does (the
+    pools' global bytes agree), and device ``i`` holds head ``i``."""
+    _, params = base_params
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (4, 32), 0,
+                                CFG.vocab_size)
+    got = {}
+    for ndev in (1, 8):
+        mesh, ccfg, cache = _make_cache(ndev)
+        active = _two_live_slots(params, cache, tokens)
+        step = build_decode_step(CFG, mesh, slots=ccfg.slots,
+                                 page_size=ccfg.page_size,
+                                 pages_per_slot=ccfg.pages_per_slot)
+        assert step.meta["attention"] == "walk" and step.meta["tp"] == ndev
+        logits, cache.k, cache.v, _ = step(
+            params, cache.k, cache.v, tokens[:, 31], cache.lengths_device(),
+            cache.table_device(), active, no_round(ccfg.slots))
+        got[ndev] = (np.asarray(logits), np.asarray(cache.k),
+                     np.asarray(cache.v), cache)
+    np.testing.assert_allclose(got[1][0][[0, 2]], got[8][0][[0, 2]],
+                               rtol=1e-4, atol=1e-4)
+    # The rows the round wrote (token 13 of slot 0: page 1, offset 5).
+    cache = got[8][3]
+    page = int(cache.page_table[0, 1])
+    for pool in (1, 2):
+        np.testing.assert_allclose(got[1][pool][:, page, 5],
+                                   got[8][pool][:, page, 5],
+                                   rtol=1e-5, atol=1e-5)
+        assert np.abs(got[8][pool][:, page, 5]).min() > 0
+    hd = CFG.head_dim
+    for i, shard in enumerate(sorted(cache.k.addressable_shards,
+                                     key=lambda s: s.index[3].start)):
+        assert shard.index[3] == slice(i * hd, (i + 1) * hd)
+        np.testing.assert_array_equal(
+            np.asarray(shard.data), got[8][1][..., i * hd:(i + 1) * hd])
+
+
+def test_two_engines_of_one_process_each_rotate_by_their_own_theta(
+        base_params):
+    """The step is memoized for the process, and RoPE's base is a
+    constant of its trace: it is part of the memo's key (it was not, and
+    the second of two configurations that differed in nothing else
+    decoded with the first's; PERF.md, PR 37).  Each decodes like its
+    OWN full-context forward."""
+    import dataclasses
+    _, params = base_params
+    T, t0 = 14, 8
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (1, T), 0,
+                                CFG.vocab_size)
+    fulls = []
+    for theta in (5e5, 1e6):
+        cfg = dataclasses.replace(CFG, rope_theta=theta)
+        full = np.asarray(LlamaLM(cfg, dtype=jnp.float32).apply(
+            params, tokens))
+        fulls.append(full)
+        mesh, ccfg, cache = _make_cache(1)
+        _, kl, vl = prefill_forward(params, cfg, tokens[:, :t0])
+        cache.write_prefill(0, kl[:, 0], vl[:, 0])
+        step = build_decode_step(cfg, mesh, slots=ccfg.slots,
+                                 page_size=ccfg.page_size,
+                                 pages_per_slot=ccfg.pages_per_slot)
+        got = _decode_sequence(params, step, cache, tokens, t0, T)
+        np.testing.assert_allclose(got, full[0, t0:T], rtol=1e-4, atol=1e-4)
+    # The two do differ by more than the tolerance they are held to.
+    assert np.abs(fulls[0][0, t0:T] - fulls[1][0, t0:T]).max() > 1e-3
+
+
+def test_auditor_names_the_attention_kernel_the_step_calls(base_params,
+                                                           monkeypatch):
+    """``ExpectedExchange.kernels``: the dense decode step walks
+    (``mla_decode``'s kernel, never the split-KV one); the verify step
+    and the fp8 path gather views (``flash_decode``, never the walk)."""
+    from horovod_tpu.serving import build_verify_step
+    _, params = base_params
+    mesh, ccfg, _ = _make_cache(1)
+    kw = dict(slots=ccfg.slots, page_size=ccfg.page_size,
+              pages_per_slot=ccfg.pages_per_slot)
+    steps = {"walk": build_decode_step(CFG, mesh, **kw),
+             "view": build_decode_step(CFG, mesh, compress=True, **kw),
+             "verify": build_verify_step(CFG, mesh, width=3, **kw)}
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    names = {name: expected_exchange(params, meta_from_step(step)).kernels
+             for name, step in steps.items()}
+    assert "mla_decode" in names["walk"]
+    assert "flash_decode" not in names["walk"]
+    for name in ("view", "verify"):
+        assert meta_from_step(steps[name])["attention"] == "view"
+        assert "flash_decode" in names[name]
+        assert "mla_decode" not in names[name]
+    assert {len(v) for v in names.values()} == {5}   # the other families
+    monkeypatch.setenv("HOROVOD_PALLAS", "0")
+    assert expected_exchange(
+        params, meta_from_step(steps["walk"])).kernels == ()
+
+
+def test_decode_round_span_says_whether_the_step_walks(base_params):
+    """``decode.round`` carries ``walk``: 1 under the plain engine, 0
+    where the engine's step is the fp8 path's."""
+    _, params = base_params
+    rec = spans.recorder()
+    for kw, want in ((dict(), 1), (dict(kv_compress=True), 0)):
+        eng = ServingEngine(CFG, params, mesh=mesh_1d(1), slots=4,
+                            page_size=8, max_len=64, **kw)
+        assert eng.step.meta["attention"] == ("walk" if want else "view")
+        rec.reset()
+        report = eng.serve(generate(LoadSpec(
+            num_requests=4, rate_rps=200.0, prompt_lens=(4, 9),
+            output_lens=(3, 5), vocab_size=CFG.vocab_size, seed=2)))
+        assert report.completed == 4
+        rounds = rec.records(name="decode.round")
+        assert rounds and len(rounds) == report.decode_steps
+        assert {r.attrs["walk"] for r in rounds} == {want}
+        assert all(r.attrs["pages"] >= r.attrs["slots"] for r in rounds)
 
 
 # ---------------------------------------------------------------------------
@@ -629,7 +813,7 @@ def test_prefix_cache_radix_match_insert_and_refcounts():
     pc = PrefixCache(cache, session_ttl_steps=4)
     prompt = np.arange(10, dtype=np.int32)   # 2 full pages + tail
     assert pc.match(prompt) == (0, [])       # cold tree
-    kl = jnp.ones((1, 10, 2, 4), jnp.float32)
+    kl = jnp.ones((1, 10, 8), jnp.float32)
     cache.write_prefill(0, kl, kl)
     assert pc.insert(prompt, 0) == 2
     assert pc.insert(prompt, 0) == 0         # idempotent
@@ -667,7 +851,7 @@ def test_prefix_cache_session_pin_ttl_expiry():
     cache = PagedKVCache(ccfg)
     pc = PrefixCache(cache, session_ttl_steps=3)
     prompt = np.arange(8, dtype=np.int32)
-    kl = jnp.ones((1, 8, 2, 4), jnp.float32)
+    kl = jnp.ones((1, 8, 8), jnp.float32)
     cache.write_prefill(0, kl, kl)
     pc.insert(prompt, 0)
     cache.free_slot(0)
@@ -695,8 +879,8 @@ def test_prefix_cache_demotes_to_fp8_then_stays_matchable():
     pc = PrefixCache(cache)
     rng = np.random.RandomState(3)
     prompt = np.arange(8, dtype=np.int32)
-    kl = jnp.asarray(rng.randn(1, 8, 2, 4).astype(np.float32))
-    vl = jnp.asarray(rng.randn(1, 8, 2, 4).astype(np.float32))
+    kl = jnp.asarray(rng.randn(1, 8, 8).astype(np.float32))
+    vl = jnp.asarray(rng.randn(1, 8, 8).astype(np.float32))
     cache.write_prefill(0, kl, vl)
     pc.insert(prompt, 0)
     cache.free_slot(0)
@@ -712,7 +896,7 @@ def test_prefix_cache_demotes_to_fp8_then_stays_matchable():
 
     # gather_pages dequantizes the demoted pages for the tail prefill.
     pk, pv = cache.gather_pages(entries)
-    assert pk.shape == (1, 1, 8, 2, 4)
+    assert pk.shape == (1, 1, 8, 8)
     np.testing.assert_allclose(np.asarray(pk)[0, 0], np.asarray(kl)[0],
                                rtol=0.2, atol=0.1)
     pc.drop_all()

@@ -193,7 +193,10 @@ def test_a_llama_config_is_one_instance_of_the_same_description():
     from horovod_tpu.models.transformer import LLAMA_SERVE
     spec = layer_spec(LLAMA_SERVE)
     assert spec.attention == "gqa" and spec.tied_head
-    assert spec.page == ((8, 16), (8, 16)) and not spec.unsupported
+    # One row of 8 heads x 16 a token in each pool, no head dim: the
+    # rows the decode step's page walk reads in place.
+    assert spec.page == ((128,), (128,)) and spec.tp_page_dim == 0
+    assert not spec.unsupported
     assert spec.ffn == ("dense",) * LLAMA_SERVE.num_layers
     with pytest.raises(TypeError, match="layer_spec"):
         layer_spec(object())

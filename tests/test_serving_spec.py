@@ -99,11 +99,18 @@ def test_spec_decode_exact_even_with_weak_drafter(base_params):
 
 
 def test_spec_round_accounting_and_metric_family(base_params):
+    from horovod_tpu.timeline import spans
     _, params = base_params
     drafter = ModelDrafter(CFG, params, slots=4, page_size=8, max_len=64,
                            dtype=jnp.float32)
+    spans.recorder().reset()
     _, rep = _serve_streams(params, ndev=1, spec_decode=True, spec_k=3,
                             drafter=drafter)
+    # A speculative round is dispatched through the verify step, which
+    # gathers slot views: its ``decode.round`` says ``walk`` 0.
+    rounds = spans.recorder().records(name="decode.round")
+    assert len(rounds) >= rep.spec_rounds > 0
+    assert sum(1 - r.attrs["walk"] for r in rounds) == rep.spec_rounds
     # k drafts per active slot per round, so proposed is a positive
     # multiple of k and at least one slot's worth per round.
     assert rep.proposed_tokens >= rep.spec_rounds * 3 > 0

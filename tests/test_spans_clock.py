@@ -643,8 +643,10 @@ _STAGES = ["hvd_exchange/compress", "hvd_exchange/collective",
     # The chunked exchange splits a vector: the same step packs.
     (_train_step_text, {"HOROVOD_EXCHANGE_CHUNK_MB": "1"},
      ["hvd_exchange/pack"] + _STAGES + ["hvd_exchange/unpack"], []),
+    # The dense decode step reads attention by the page walk: the
+    # split-KV kernel over a gathered view is the verify step's.
     (_decode_step_text, {"HOROVOD_PALLAS_DECODE": "1"},
-     ["hvd_flash_decode"], []),
+     ["hvd_cca_decode"], ["hvd_flash_decode"]),
 ], ids=["train_step", "train_step_packed", "decode_step"])
 def test_lowered_text_carries_the_names(monkeypatch, text_of, env, names,
                                         absent):
