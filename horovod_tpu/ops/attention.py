@@ -143,7 +143,8 @@ def _block_lane(seq: int, preferred: int) -> int:
 
 def attention_reference(q, k, v, *, causal: bool = False,
                         scale: Optional[float] = None,
-                        segment_ids=None, kv_segment_ids=None):
+                        segment_ids=None, kv_segment_ids=None,
+                        window: Optional[int] = None):
     """Plain XLA attention. q,k,v: (batch, heads, seq, head_dim).
 
     Causal masking is bottom-right aligned: with ``tq < tk`` (decode with a
@@ -155,6 +156,10 @@ def attention_reference(q, k, v, *, causal: bool = False,
     segment of their own).  A DEAD row (segment matches no key, i.e.
     pure padding) produces ZERO output and zero gradients, identical
     between this reference and the Pallas kernels.
+
+    ``window`` (with ``causal``): a query at absolute position ``i``
+    attends keys ``i - window < j <= i``: ``window`` keys, itself among
+    them.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -163,6 +168,9 @@ def attention_reference(q, k, v, *, causal: bool = False,
     if causal:
         tq, tk = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((tq, tk), bool),
+                              k=tk - tq - window)
         logits = jnp.where(mask, logits, _NEG_INF)
     if segment_ids is not None:
         if kv_segment_ids is None:
@@ -467,9 +475,9 @@ def mla_decode_attention(q, pool, page_table, *, layer, lengths,
 
 
 def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
-                       plane_ref, q_ref, pool_ref, *rest, scale, ppb, sub,
-                       pps, page, value_dim, kv_heads, value_off,
-                       two_pools):
+                       plane_ref, *rest, scale, ppb, sub, pps, page,
+                       value_dim, kv_heads, value_off, two_pools,
+                       windowed=False):
     """One program instance, a loop over the live items: item ``i`` is
     block ``blk_ref[i]`` (``ppb`` pages) of row ``slot_ref[i]``; there
     are ``n_ref[0]`` of them, a row's items follow one another, an idle
@@ -529,7 +537,16 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
     buffer, both copies signal the block's one semaphore, a run of
     pages is waited for once a pool, and the values are the second
     buffer's columns from ``value_off``.  A static branch: with one pool
-    the kernel is what it was."""
+    the kernel is what it was.
+
+    ``windowed`` (a window layer's planes: a row's table holds the pages
+    of its window only, oldest first): one more prefetched operand
+    before the queries, ``start_ref``, the rows at the head of each
+    row's first page that have left the window; the math masks them as
+    it masks the rows past the row's end.  A static branch too."""
+    if windowed:
+        start_ref, *rest = rest
+    q_ref, pool_ref, *rest = rest
     if two_pools:
         vpool_ref, o_ref, buf, vbuf, sem, m_scr, l_scr, acc_scr = rest
     else:
@@ -623,6 +640,8 @@ def _mla_decode_kernel(len_ref, table_ref, slot_ref, blk_ref, n_ref,
         cols = first_col + at * sub * page + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         live = cols < length
+        if windowed:
+            live &= cols >= start_ref[row]
         s = jnp.where(live, s, _NEG_INF)
         m_prev = m_scr[:, :1]                         # (h, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -692,7 +711,8 @@ def _walk_sizes():
 @functools.partial(jax.jit, static_argnames=(
     "value_dim", "scale", "ppb", "sub_keys", "budget", "kv_heads",
     "value_off", "name"))
-def _mla_decode(q, pool, page_table, lengths, layer, values=None, *,
+def _mla_decode(q, pool, page_table, lengths, layer, values=None,
+                starts=None, *,
                 value_dim: int, scale: float, ppb: int, sub_keys: int,
                 budget: int, kv_heads: int = 1, value_off: int = 0,
                 name: str = "hvd_mla_decode"):
@@ -702,7 +722,9 @@ def _mla_decode(q, pool, page_table, lengths, layer, values=None, *,
     so every layer shares one trace and one lowered function.
     ``values``: the second pool of a cache that keeps keys and values
     apart (None: ``pool``'s rows hold both); a page then costs a block
-    both its rows."""
+    both its rows.  ``starts`` (``[b]`` int32; None: no window): the
+    rows at the head of each row's table that its window has left
+    behind (``_mla_decode_kernel``'s ``windowed``)."""
     b, h, dk = q.shape
     w = pool.shape[3]
     pools = (pool,) if values is None else (pool, values)
@@ -748,12 +770,17 @@ def _mla_decode(q, pool, page_table, lengths, layer, values=None, *,
                                sub=sub, pps=per_row * ppb, page=page,
                                value_dim=value_dim, kv_heads=kv_heads,
                                value_off=value_off,
-                               two_pools=values is not None)
+                               two_pools=values is not None,
+                               **({} if starts is None
+                                  else {"windowed": True}))
+    scalars = (lengths, page_table.reshape(-1), slot, blk, n_items, plane)
+    if starts is not None:
+        scalars += (starts.astype(jnp.int32),)
     with jax.named_scope(name):
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=6,
+                num_scalar_prefetch=len(scalars),
                 grid=(1,),
                 in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)]
                 + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -773,12 +800,12 @@ def _mla_decode(q, pool, page_table, lengths, layer, values=None, *,
                 disable_bounds_checks=True),
             name=name,
             interpret=_pallas.interpret_mode(),
-        )(lengths, page_table.reshape(-1), slot, blk, n_items, plane,
-          q.astype(pool.dtype), *pools)
+        )(*scalars, q.astype(pool.dtype), *pools)
 
 
 def cca_decode_attention(q, pool, page_table, *, layer, lengths,
                          kv_heads: int, scale: float, values=None,
+                         window: Optional[int] = None,
                          force_reference: bool = False):
     """Single-token grouped-query decode attention over rows that hold
     every key/value head side by side, read straight out of the page
@@ -801,9 +828,36 @@ def cca_decode_attention(q, pool, page_table, *, layer, lengths,
     The kernel is ``hvd_mla_decode``'s walk of the page table under the
     name ``hvd_cca_decode`` (same family switch): one copy of a block's
     live pages serves every head, keys and values alike; with two pools,
-    one copy out of each."""
+    one copy out of each.
+
+    ``window`` (a window layer's planes): a row sees its last ``window``
+    tokens, itself among them, and nothing older.  ``page_table`` is then
+    the WINDOW GROUP's table, ``(b, pages)`` with ``pages >=
+    ceil(window / page_size) + 1``, a ring: the page that holds tokens
+    ``n * page_size ..`` of row ``i`` is entry ``n % pages`` (a page is
+    written again once its tokens have left the window).  ``lengths`` are
+    the rows' live tokens as for a full layer.  The walk is handed the
+    ring turned so that a row's oldest window page comes first, copies
+    those pages only and masks the rows of the first that are older than
+    the window; it runs under the name ``hvd_swa_decode``."""
     b, h, d = q.shape
     rows = 1 if values is not None else 2
+    starts = None
+    if window is not None:
+        page, pages = pool.shape[2], page_table.shape[1]
+        if window < 1 or pages < -(-window // page) + 1:
+            raise ValueError(
+                f"cca_decode_attention: a window of {window} over pages "
+                f"of {page} needs {-(-window // page) + 1} table entries "
+                f"a row, got {pages}")
+        lengths = lengths.astype(jnp.int32)
+        oldest = jnp.maximum(lengths - window, 0)
+        first = oldest // page
+        page_table = jnp.take_along_axis(
+            page_table, (first[:, None] + jnp.arange(pages)) % pages,
+            axis=1)
+        starts = oldest - first * page
+        lengths = lengths - first * page
     if pool.ndim != 4 or pool.shape[3] != rows * kv_heads * d \
             or h % kv_heads or page_table.shape[0] != b \
             or (values is not None and (values.shape != pool.shape
@@ -817,13 +871,33 @@ def cca_decode_attention(q, pool, page_table, *, layer, lengths,
         raise ValueError(f"lengths must be ({b},), got {lengths.shape}")
     if not force_reference and _pallas.pallas_enabled("mla_decode"):
         return _mla_decode(q, pool, page_table, lengths,
-                           jnp.asarray(layer, jnp.int32), values,
+                           jnp.asarray(layer, jnp.int32), values, starts,
                            value_dim=d, scale=float(scale),
                            kv_heads=kv_heads,
                            value_off=0 if values is not None
-                           else kv_heads * d, name="hvd_cca_decode",
-                           **_walk_sizes())
+                           else kv_heads * d,
+                           name="hvd_cca_decode" if window is None
+                           else "hvd_swa_decode", **_walk_sizes())
     s = page_table.shape[1] * pool.shape[2]
+    if window is not None:
+        def view(z, lo):
+            return z[layer, page_table].reshape(b, s, -1)[
+                ..., lo:lo + kv_heads * d].reshape(b, s, kv_heads, d
+                                                   ).astype(q.dtype)
+        keys = view(pool, 0)
+        vals = view(pool, kv_heads * d) if values is None \
+            else view(values, 0)
+        qg = q.reshape(b, kv_heads, h // kv_heads, d)
+        logits = jnp.einsum("bgrd,bsgd->bgrs", qg, keys,
+                            preferred_element_type=jnp.float32) * scale
+        cols = jnp.arange(s)[None, None, None, :]
+        live = (cols < lengths[:, None, None, None]) & (
+            cols >= starts[:, None, None, None])
+        logits = jnp.where(live, logits, _NEG_INF)
+        probs = jnp.where(live, jax.nn.softmax(logits, axis=-1), 0.0)
+        return jnp.einsum("bgrs,bsgd->bgrd", probs.astype(vals.dtype),
+                          vals, preferred_element_type=jnp.float32
+                          ).reshape(b, h, d)
     if values is not None:
         # Kernels off: the gathered view through :func:`decode_attention`'s
         # own reference, the op shapes a verify step's rows run, so that
@@ -847,10 +921,22 @@ def cca_decode_attention(q, pool, page_table, *, layer, lengths,
                       preferred_element_type=jnp.float32).reshape(b, h, d)
 
 
-def _causal_mask(s, qi, ki, bq, bk, off):
+def _causal_mask(s, qi, ki, bq, bk, off, window=None):
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + off
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep &= rows - cols < window
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _band_first(qi, bq: int, bk: int, off: int, window: int):
+    """The first key block that holds a key some query of block ``qi``
+    sees through a window of ``window`` (``qi`` a Python int or traced)."""
+    oldest = qi * bq + off - (window - 1)
+    if isinstance(oldest, int):
+        return max(oldest, 0) // bk
+    return jnp.maximum(oldest, 0) // bk
 
 
 def _seg_mask(s, qseg_ref, kseg_ref):
@@ -885,16 +971,28 @@ def _seg_live(live, qseg_ref, kseg_ref):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
-                bq, bk, nk, off):
+                bq, bk, nk, off, window=None):
+    """``window`` (causal, no segment ids, forward only): the grid's last
+    dim runs the ``nk`` key blocks that can reach a query block's band,
+    not all of them: step ``j`` of query block ``qi`` takes key block
+    ``_band_first(qi) + j``; a step past the diagonal is predicated off
+    as any block above it is.  No logsumexp leaves the kernel (a
+    lane-broadcast float32 tile a query row: 268 MB at 64 heads of 8,192
+    rows, for a backward pass that a served prefill never runs)."""
     if has_seg:
         qseg_ref, kseg_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
+    elif window is not None:
+        o_ref, m_scr, l_scr, acc_scr = rest
+        qseg_ref = kseg_ref = lse_ref = None
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
         qseg_ref = kseg_ref = None
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = ki = pl.program_id(3)
+    if window is not None:
+        ki = _band_first(qi, bq, bk, off, window) + step
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -915,7 +1013,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
-            s = _causal_mask(s, qi, ki, bq, bk, off)
+            s = _causal_mask(s, qi, ki, bq, bk, off, window)
         if has_seg:
             s = _seg_mask(s, qseg_ref, kseg_ref)
 
@@ -932,7 +1030,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finish():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -949,7 +1047,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
             o = jnp.where(dead, 0.0, o)
             lse = jnp.where(dead, -_NEG_INF, lse)
         o_ref[0, 0] = o.astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[-2:])
+        if lse_ref is not None:
+            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[-2:])
 
 
 def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
@@ -1008,6 +1107,48 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
             interpret=_pallas.interpret_mode(),
         )(*operands)
     return o, lse[..., 0]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "bq", "bk"))
+def _flash_swa_fwd(q, k, v, *, scale, window, bq, bk):
+    """The blocked forward kernel over a causal band of ``window`` keys,
+    under the name ``hvd_flash_swa_fwd``: the last grid dim is the key
+    blocks a query block's band can touch (two at the default blocks and
+    a window under a block), so a long prompt does the work of its band's
+    blocks and not of the triangle's.  Forward only: a served prefill."""
+    batch, heads, tq, d = q.shape
+    tk = k.shape[2]
+    rep = heads // k.shape[1]
+    nq, nkb = tq // bq, tk // bk
+    off = tk - tq
+    band = max((i * bq + bq - 1 + off) // bk
+               - _band_first(i, bq, bk, off, window) + 1 for i in range(nq))
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=True,
+                               has_seg=False, bq=bq, bk=bk, nk=band,
+                               off=off, window=window)
+
+    def kv_block(b, h, i, j):
+        return (b, h // rep, jnp.minimum(
+            _band_first(i, bq, bk, off, window) + j, nkb - 1), 0)
+
+    with jax.named_scope("hvd_flash_swa_fwd"):
+        return pl.pallas_call(
+            kernel,
+            grid=(batch, heads, nq, band),
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bk, d), kv_block),
+                pl.BlockSpec((1, 1, bk, d), kv_block)],
+            out_specs=pl.BlockSpec((1, 1, bq, d),
+                                   lambda b, h, i, j: (b, h, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32)],
+            name="hvd_flash_swa_fwd",
+            interpret=_pallas.interpret_mode(),
+        )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -1449,6 +1590,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     segment_ids=None, kv_segment_ids=None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_kv: int = DEFAULT_BLOCK_KV,
+                    window: Optional[int] = None,
                     force_reference: bool = False):
     """Fused attention. q: (b, h, t, d); k, v: (b, h_kv, s, d).
 
@@ -1462,6 +1604,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     gradients).  ``kv_segment_ids`` (``(b, s)``) defaults to
     ``segment_ids`` when the key sequence has the same length; it is
     required for cross-length attention.  Composes with ``causal``.
+
+    ``window`` (with ``causal``, without segment ids; forward only): a
+    query at absolute position ``i`` sees the keys ``i - window < j <=
+    i``.  The kernel (``hvd_flash_swa_fwd``) SKIPS the key blocks wholly
+    outside that band: they are not in its grid.
 
     Dispatch: Pallas kernels when running on TPU (or ``HOROVOD_PALLAS=1``
     / ``HOROVOD_PALLAS_FLASH=1``, which use the interpreter off-TPU --
@@ -1483,6 +1630,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     tq, tk = q.shape[2], k.shape[2]
+    if window is not None and (not causal or segment_ids is not None
+                               or window < 1):
+        raise ValueError(
+            f"a window ({window}) goes with causal attention and without "
+            "segment ids")
     if segment_ids is not None:
         if kv_segment_ids is None:
             if tq != tk:
@@ -1519,7 +1671,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
             v = jnp.repeat(v, rep, axis=1)
         return attention_reference(q, k, v, causal=causal, scale=scale,
                                    segment_ids=segment_ids,
-                                   kv_segment_ids=kv_segment_ids)
+                                   kv_segment_ids=kv_segment_ids,
+                                   window=window)
+    if window is not None:
+        return _flash_swa_fwd(q, k, v, scale=float(scale),
+                              window=int(window), bq=int(rbq), bk=int(rbk))
     if segment_ids is not None:
         return _flash_seg(q, k, v, segment_ids, kv_segment_ids,
                           float(scale), bool(causal),

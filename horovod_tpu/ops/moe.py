@@ -27,7 +27,13 @@ left alone):
   dim of the stacked weights): it routes over all of them, computes its
   own experts' part and adds the shared expert only where
   ``with_shared`` says so -- the cut expert parallelism asks for, with
-  no exchange on one chip.
+  no exchange on one chip.  A share's rows follow the pairs routed to
+  ITS experts, not all the pairs: the grouped matmul runs over
+  :func:`pass_rows` padded rows a pass (twice what even routing brings
+  the share, plus the padding), and a second pass takes what a skewed
+  router sends beyond that, so nothing is dropped and nothing is sized
+  for the pairs held elsewhere (8,192 prompt tokens, top 8 of 128, 16
+  held: 18,432 rows a pass, where all the pairs are 67,584);
 
 The Mosaic call is named ``hvd_moe_gmm`` (the trace's name for it); off
 the TPU the same tiles go through a ``jax.numpy`` loop-free reference
@@ -151,6 +157,17 @@ def _padded_rows(pairs: int, held: int, tm: int) -> int:
     return -(-worst // tm) * tm
 
 
+def pass_rows(pairs: int, held: int, num_experts: int, tm: int) -> int:
+    """Padded rows ONE pass of the grouped matmul takes.  A layer that
+    holds every expert lays all its pairs out at once (``_padded_rows``,
+    as ever).  A share lays out at most twice the pairs even routing
+    brings its ``held`` of ``num_experts`` experts; further passes take
+    the rest (:func:`moe_ffn`)."""
+    if held < num_experts:
+        pairs = min(pairs, 2 * -(-pairs * held // num_experts))
+    return _padded_rows(pairs, held, tm)
+
+
 def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool, tile_axis: int):
     """One row tile against its expert's weight block (or a column slice
     of it).  ``swiglu``: ``silu(x @ w_gate) * (x @ w_up)`` in one pass
@@ -269,6 +286,42 @@ def grouped_matmul(x, ws, tile_expert, active, *, tm: int,
     return _gmm_reference(x, tuple(ws), tile_expert, active, tm)
 
 
+def _share_passes(experts, lay: Layout, weights, rows: int, tm: int, shape):
+    """A share's part of the routed sum, ``rows`` padded rows a pass: pass
+    ``w`` takes the tiles ``w * rows / tm ..`` of the layout (whose index
+    arrays cover every pair; only the gathered rows are sized by the
+    pass) and adds each token's pairs that lie there, a choice at a time
+    (``[tokens, top_k, d]`` in float32 is gigabytes at a long prompt's
+    width).  The passes run while there are active tiles: one under even
+    routing."""
+    pad = -lay.src.shape[0] % rows
+    src = jnp.pad(lay.src, (0, pad))
+    tile_expert = jnp.pad(lay.tile_expert, (0, pad // tm))
+    tiles = rows // tm
+
+    def one(w, y):
+        ys = experts(
+            jax.lax.dynamic_slice(src, (w * rows,), (rows,)),
+            jax.lax.dynamic_slice(tile_expert, (w * tiles,), (tiles,)),
+            jnp.clip(lay.active - w * tiles, 0, tiles))
+        at = lay.dest - w * rows
+        here = lay.held & (at >= 0) & (at < rows)
+        at = jnp.clip(at, 0, rows - 1)
+
+        def choice(c, y):
+            # A loop, not eight gathers side by side: one ``[tokens, d]``
+            # gather is live at a time.
+            col = jax.lax.dynamic_index_in_dim(at, c, 1, keepdims=False)
+            keep = jax.lax.dynamic_index_in_dim(here, c, 1)
+            g = jax.lax.dynamic_index_in_dim(weights, c, 1)
+            return y + jnp.where(keep, ys[col].astype(jnp.float32) * g, 0.0)
+
+        return jax.lax.fori_loop(0, weights.shape[1], choice, y)
+
+    return jax.lax.fori_loop(0, (lay.active[0] + tiles - 1) // tiles, one,
+                             jnp.zeros(shape, jnp.float32))
+
+
 def moe_ffn(h, params, routing: Routing, *, num_experts: int,
             first: int = 0, with_shared: bool = True, live=None,
             force_reference: bool = False):
@@ -291,17 +344,25 @@ def moe_ffn(h, params, routing: Routing, *, num_experts: int,
     tm = row_tile(t * top_k, num_experts)
     lay = layout(routing.experts, num_experts, tm, first=first, held=held,
                  live=live)
-    xs = h[lay.src]
-    act = grouped_matmul(xs, (ex["w_gate"].astype(dtype),
-                              ex["w_up"].astype(dtype)),
-                         lay.tile_expert, lay.active, tm=tm,
-                         force_reference=force_reference)
-    ys = grouped_matmul(act, (ex["w_down"].astype(dtype),),
-                        lay.tile_expert, lay.active, tm=tm,
-                        force_reference=force_reference)
-    picked = jnp.where(lay.held[..., None], ys[lay.dest].astype(jnp.float32),
-                       0.0)
-    y = jnp.einsum("tkd,tk->td", picked, routing.weights)
+
+    def experts(src, tile_expert, active):
+        act = grouped_matmul(h[src], (ex["w_gate"].astype(dtype),
+                                      ex["w_up"].astype(dtype)),
+                             tile_expert, active, tm=tm,
+                             force_reference=force_reference)
+        return grouped_matmul(act, (ex["w_down"].astype(dtype),),
+                              tile_expert, active, tm=tm,
+                              force_reference=force_reference)
+
+    rows = pass_rows(t * top_k, held, num_experts, tm)
+    if rows >= lay.src.shape[0]:
+        ys = experts(lay.src, lay.tile_expert, lay.active)
+        picked = jnp.where(lay.held[..., None],
+                           ys[lay.dest].astype(jnp.float32), 0.0)
+        y = jnp.einsum("tkd,tk->td", picked, routing.weights)
+    else:
+        y = _share_passes(experts, lay, routing.weights, rows, tm,
+                          (t, h.shape[1]))
     if with_shared:
         sh = params["shared"]
         gate = h @ sh["w_gate"]["kernel"].astype(dtype)

@@ -52,7 +52,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..core.config import _env, _env_bool, _env_int
 from ..timeline import spans as _spans
 from .decode import greedy_sample, no_round, read_told
-from .kvcache import CacheConfig, PagedKVCache, PrefixCache
+from .kvcache import (CacheConfig, PagedKVCache, PrefixCache,
+                      window_rows_from)
 from .layerspec import layer_spec
 from .scheduler import (ContinuousBatchScheduler, Request,
                         parse_tenant_classes)
@@ -305,7 +306,8 @@ class ServingEngine:
             num_layers=spec.planes, slots=self.slots,
             page_size=self.page_size, max_len=self.max_len,
             dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
-            page=spec.page, slot_state=spec.slot_state)
+            page=spec.page, slot_state=spec.slot_state,
+            window_layers=spec.window_planes, window=spec.window)
         self.cache = PagedKVCache(self.cache_config,
                                   spec.pool_sharding(mesh))
         # Admission must price the widest step a slot can take: k drafts
@@ -421,11 +423,18 @@ class ServingEngine:
         this prefill is dispatched (it queues behind it on the chip), -1
         where there is none."""
         rec = _spans.recorder()
+        # With a window group: the rows a window plane is written, the
+        # prompt's last ones.
+        windowed = {} if self.spec.window is None else {
+            "window_rows": req.prompt_len - window_rows_from(
+                req.prompt_len, self.spec.window),
+            "window_planes": self.spec.window_planes}
         with rec.span("dispatch", name="serve.prefill",
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
-                      planes=self.spec.planes, behind=behind):
+                      planes=self.spec.planes, behind=behind, **windowed):
             with rec.phase("prefill.dispatch", rid=req.rid):
+                state = []
                 if matched:
                     # Prefix hit: only the tail goes through the forward
                     # pass, conditioned on the cached pages as past K/V
@@ -442,7 +451,9 @@ class ServingEngine:
                     kl = kl[:, 0]
                     vl = None if vl is None else vl[:, 0]
             with rec.phase("prefill.write_kv", rid=req.rid):
-                self.cache.write_prefill(slot, kl, vl, start=matched)
+                self.cache.write_prefill(
+                    slot, kl, vl, start=matched,
+                    window_rows=self._window_rows(state))
             if self.spec.slot_state is not None:
                 # What the slot keeps beside its pages: the prompt's
                 # trailing rows (a hit or a chunk would need them of the
@@ -455,6 +466,14 @@ class ServingEngine:
             with rec.phase("prefill.sample_fetch", rid=req.rid):
                 first = int(greedy_sample(logits[:, -1, :])[0])
         return first
+
+    def _window_rows(self, beyond: list):
+        """The window planes' rows of ONE prompt out of what a prefill
+        returned beyond its two planes (``LayerSpec.attn_kinds``: the
+        last of them; popped), None for a model without window layers."""
+        if self.spec.window is None:
+            return None
+        return tuple(rows[:, 0] for rows in beyond.pop())
 
     def _advance_chunks(self, st: Dict[str, Any], now) -> None:
         """Push each in-progress chunked prefill forward by ONE chunk.
@@ -575,12 +594,22 @@ class ServingEngine:
         page = self.page_size
         # (A test's stand-in for the step may be a bare function.)
         meta = getattr(step or self.step, "meta", {})
+        windowed = {}
+        if self.spec.window is not None:
+            # What a window layer's walk reads in ONE of its planes: a
+            # slot's last ``window`` tokens, and the pages they lie in.
+            w = self.spec.window
+            windowed = dict(
+                window_tokens=sum(min(n, w) for n in live),
+                window_pages=sum((n - 1) // page - max(n - w, 0) // page + 1
+                                 for n in live),
+                window_planes=self.spec.window_planes)
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=sum(live), ahead=int(ahead),
             passes=self.spec.passes, planes=self.spec.planes,
             pages=sum(-(-n // page) for n in live),
-            walk=int(meta.get("attention") == "walk"))
+            walk=int(meta.get("attention") == "walk"), **windowed)
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """Dispatch one plain continuous-batching decode round over the
@@ -629,6 +658,8 @@ class ServingEngine:
                         jnp.asarray(tokens),
                         cache.lengths_device(), cache.table_device(),
                         jnp.asarray(active)]
+                if cache.window_table is not None:
+                    args.append(cache.window_table_device())
                 if self.kv_compress:
                     args += list(cache.compress_operands())
                 if self.adapters is not None:
@@ -638,14 +669,14 @@ class ServingEngine:
             # Beside the pools a step may carry device state of its own
             # (donated in, handed back).  The cache's slot state (where
             # the model has one) leads it: the step advances the rows of
-            # its live slots.  Last comes what the round tells of
-            # itself, the one thing the host fetches.
-            own = () if cache.state is None else (cache.state,)
+            # its live slots; then the window group's pools.  Last comes
+            # what the round tells of itself, the one thing the host
+            # fetches.
+            own = cache.carried
             n_state = len(own) + len(self._step_state)
             out = self.step(*args, *own, *self._step_state, self._told)
             _, cache.k, cache.v = out[:3]
-            if own:
-                cache.state = out[3]
+            cache.take_carried(out[3:3 + len(own)])
             self._step_state = out[3 + len(own):3 + n_state]
             self._told = out[-1]
             # Sent to the host as soon as the round ends, whenever the
@@ -850,9 +881,11 @@ class ServingEngine:
                 else None
             _, kl, vl, *state = self._prefill(
                 self.params, jnp.asarray(full)[None], self.adapters, aid)
+            window_rows = self._window_rows(state)
             self.cache.write_prefill(
                 slot, kl[:, 0], None if vl is None else vl[:, 0],
-                state=state[0][:, 0] if state else None)
+                state=state[0][:, 0] if state else None,
+                window_rows=window_rows)
         if self.drafter is not None:
             self.drafter.re_prefill(slot, req)
         return int(req.tokens[-1])

@@ -104,6 +104,7 @@ class PrefillWorker:
         self.busy_s = 0.0
 
         spec = layer_spec(config)
+        spec.require(handoff=True)
         if spec.slot_state is not None:
             raise NotImplementedError(
                 "handoff: the KV plane moves pages only, and this model's "
@@ -150,6 +151,7 @@ class DecodeWorker:
         self.engine = engine
         self.kv = kv
         self.busy_s = 0.0
+        engine.spec.require(handoff=True)
         if engine.cache.state is not None:
             raise NotImplementedError(
                 "handoff: the KV plane moves pages only, and this model's "

@@ -27,6 +27,21 @@ zeroed: every read masks positions ``>= lengths`` through
 stale keys are unreachable by construction (the eviction/reuse test
 asserts this bit-for-bit).
 
+A WINDOW GROUP (``CacheConfig.window_layers`` planes of layers that
+read the last ``window`` tokens only) lives beside those pools with
+pools, a free list and a page table of its own::
+
+    wk, wv: [window_layers, slots * window_pages_per_slot + 1,
+             page_size, row]
+
+A slot holds at most ``window_pages_per_slot = ceil(window / page_size)
++ 1`` of its pages, as a ring: the page of tokens ``n * page_size ..``
+is ``window_table[slot, n % window_pages_per_slot]``, and once the
+window has moved past a page's tokens the page is written again (the
+counter ``kv.window_pages_reused``).  A read masks what is older than
+the window and what is past the length, so a reused page's stale rows
+are as unreachable as a recycled full page's.
+
 Pages are allocated lazily from a free list as a slot's sequence grows
 and returned wholesale on eviction -- continuous batching recycles slots
 mid-flight, so the pool, not the slot count, bounds resident KV bytes.
@@ -104,6 +119,10 @@ class CacheConfig:
     # Values a slot keeps a layer beside its pages (``LayerSpec.
     # slot_state``); None: no such state.
     slot_state: Optional[int] = None
+    # Planes of the WINDOW GROUP (``LayerSpec.window_planes``; 0: there
+    # is none) and the window's length in tokens.
+    window_layers: int = 0
+    window: Optional[int] = None
 
     def __post_init__(self):
         heads = (self.num_kv_heads, self.head_dim)
@@ -128,6 +147,27 @@ class CacheConfig:
                 f"{self.page_size}")
         if self.hot_pages < 0:
             raise ValueError(f"hot_pages must be >= 0: {self.hot_pages}")
+        if (self.window_layers > 0) != (self.window is not None) or (
+                self.window is not None and self.window < 1):
+            raise ValueError(
+                f"{self.window_layers} window planes and a window of "
+                f"{self.window}")
+        if self.window_layers and (self.compress or self.page[1] is None):
+            raise NotImplementedError(
+                "a window group goes with two pools and no fp8 cold "
+                f"pool: compress {self.compress}, pools {self.page}")
+
+    @property
+    def window_pages_per_slot(self) -> int:
+        """Pages a slot holds in a window plane at most: the window's,
+        and one more for a window that straddles page boundaries."""
+        if not self.window_layers:
+            return 0
+        return -(-self.window // self.page_size) + 1
+
+    @property
+    def window_num_pages(self) -> int:
+        return self.slots * self.window_pages_per_slot
 
     @property
     def entries(self) -> tuple:
@@ -156,7 +196,7 @@ class CacheConfig:
         the pool shape, page table geometry and dtype never depend on
         how many ranks the kv-head dim is split over (asserted by
         tests/test_serving.py across 1- and 8-device meshes)."""
-        return {
+        out = {
             "kv_shape": [self.num_layers, self.num_pages + 1,
                          self.page_size, *self.entries[0]],
             "page_table_shape": [self.slots, self.pages_per_slot],
@@ -166,6 +206,26 @@ class CacheConfig:
             "scratch_page": self.scratch_page,
             "dtype": str(jnp.dtype(self.dtype)),
         }
+        if self.window_layers:
+            # (A model without window layers describes what it always
+            # did: no key is added to its layout.)
+            out.update(
+                window=self.window,
+                window_kv_shape=[self.window_layers,
+                                 self.window_num_pages + 1,
+                                 self.page_size, *self.entries[0]],
+                window_table_shape=[self.slots,
+                                    self.window_pages_per_slot],
+                window_pages_per_slot=self.window_pages_per_slot,
+                window_num_pages=self.window_num_pages,
+                window_scratch_page=self.window_num_pages)
+        return out
+
+
+def window_rows_from(length: int, window: int) -> int:
+    """The first row a window plane keeps of a prompt of ``length``
+    tokens: the oldest token that the NEXT token's window still sees."""
+    return max(length + 1 - window, 0)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -241,6 +301,27 @@ class PagedKVCache:
                 self.state = jax.device_put(
                     self.state, jax.sharding.NamedSharding(
                         sharding.mesh, jax.sharding.PartitionSpec()))
+        # The window group: pools, table, free list and per-slot page
+        # counts of its own (None and empty where the model has no
+        # window layer).
+        self.wk = self.wv = None
+        self.window_table = None
+        self._wallocated = np.zeros((c.slots,), np.int32)
+        self._wfree: List[int] = []
+        if c.window_layers:
+            wlead = (c.window_layers, c.window_num_pages + 1, c.page_size)
+            self.wk = jnp.zeros(wlead + c.entries[0], jnp.dtype(c.dtype))
+            self.wv = jnp.zeros(wlead + c.entries[1], jnp.dtype(c.dtype))
+            if sharding is not None:
+                self.wk = jax.device_put(self.wk, sharding)
+                self.wv = jax.device_put(self.wv, sharding)
+            self.window_table = np.zeros(
+                (c.slots, c.window_pages_per_slot), np.int32)
+            self._wfree = list(range(c.window_num_pages - 1, -1, -1))
+            self._m_reused = _registry().counter(
+                "kv.window_pages_reused",
+                "pages a window plane's slot wrote again once their "
+                "tokens had left the window")
         # Host-side logical view.  Unallocated table entries point at
         # page 0 -- harmless, reads beyond ``lengths`` are masked.
         self.page_table = np.zeros((c.slots, c.pages_per_slot), np.int32)
@@ -296,18 +377,27 @@ class PagedKVCache:
 
     @property
     def live_pages(self) -> int:
-        """Physical f32 pages with at least one holder.  The pool
+        """Physical f32 pages with at least one holder, the window
+        group's among them.  The pool
         invariant under sharing is ``free_pages + live_pages ==
-        num_pages`` (``allocated_pages`` counts TABLE ENTRIES and
-        double-counts a page shared by two slots)."""
-        return int((self._refcount > 0).sum())
+        num_pages`` of the full group (``allocated_pages`` counts TABLE
+        ENTRIES and double-counts a page shared by two slots)."""
+        return int((self._refcount > 0).sum()) + self.window_live_pages
+
+    @property
+    def window_live_pages(self) -> int:
+        """Pages of the window group that a slot holds (never shared)."""
+        return int(self._wallocated.sum())
 
     def refcounts_balanced(self) -> bool:
         """True when every page is either on a free list (refcount 0)
         or held (refcount > 0) with the free lists consistent -- the
         drain-time leak check."""
-        ok = len(self._free) + self.live_pages == self.config.num_pages
+        ok = len(self._free) + int((self._refcount > 0).sum()) \
+            == self.config.num_pages
         ok = ok and not any(self._refcount[p] for p in self._free)
+        ok = ok and len(self._wfree) + self.window_live_pages \
+            == self.config.window_num_pages
         if self.compress:
             live_c = int((self._crefcount > 0).sum())
             ok = ok and len(self._cfree) + live_c == self.config.num_pages
@@ -349,8 +439,11 @@ class PagedKVCache:
         page_f32 = c.num_layers * c.page_size * row \
             * jnp.dtype(c.dtype).itemsize
         page_fp8 = c.num_layers * c.page_size * (row + 8)
+        page_window = c.window_layers * c.page_size * row \
+            * jnp.dtype(c.dtype).itemsize
         return (self.allocated_pages * page_f32
-                + self.compressed_pages * page_fp8)
+                + self.compressed_pages * page_fp8
+                + self.window_live_pages * page_window)
 
     def _cold_candidates(self, exclude: Optional[int] = None
                          ) -> List[int]:
@@ -410,7 +503,9 @@ class PagedKVCache:
         need = -(-max(int(length), 1) // self.config.page_size)
         if need > avail() and self.reclaim_cb is not None:
             self.reclaim_cb(need - avail())
-        return need <= avail()
+        # The window group: a slot's ring at its fullest.
+        return need <= avail() and min(
+            need, self.config.window_pages_per_slot) <= len(self._wfree)
 
     def reserve(self, slot: int, length: int,
                 writable_from: Optional[int] = None) -> None:
@@ -427,6 +522,13 @@ class PagedKVCache:
             raise ValueError(f"length {length} exceeds max_len {c.max_len}")
         need = -(-int(length) // c.page_size)
         have = int(self._allocated[slot])
+        ring_short = min(need, c.window_pages_per_slot) \
+            - int(self._wallocated[slot]) - len(self._wfree)
+        if ring_short > 0:
+            # Before a page of either group is taken.
+            raise RuntimeError(
+                f"window page pool exhausted: slot {slot} is "
+                f"{ring_short} page(s) short")
         if need > have:
             short = need - have - len(self._free)
             if short > 0 and self.reclaim_cb is not None:
@@ -443,8 +545,26 @@ class PagedKVCache:
                 self._refcount[pid] = 1
                 self.page_table[slot, i] = pid
             self._allocated[slot] = need
+        if c.window_layers:
+            self._reserve_window(slot, need, have)
         if writable_from is not None:
             self._make_writable(slot, writable_from)
+
+    def _reserve_window(self, slot: int, need: int, have: int) -> None:
+        """The window group's side of :meth:`reserve`: the slot's ring
+        grows to ``min(need, window_pages_per_slot)`` pages; a page
+        beyond that is one of the ring's own, taken back from tokens the
+        window has left (``have``: the pages the slot's sequence had
+        before this call; a prompt's prefill, which starts from none,
+        writes its last pages only and takes nothing back)."""
+        ring = self.config.window_pages_per_slot
+        held = int(self._wallocated[slot])
+        want = min(need, ring)
+        for i in range(held, want):
+            self.window_table[slot, i] = self._wfree.pop()
+        self._wallocated[slot] = max(held, want)
+        if have and need > max(have, ring):
+            self._m_reused.inc(need - max(have, ring))
 
     def _make_writable(self, slot: int, from_pos: int) -> None:
         """Copy-on-write guard: clone every still-shared page covering
@@ -544,6 +664,9 @@ class PagedKVCache:
             else:
                 self.drop_page_ref(int(self.page_table[slot, i]))
         self._allocated[slot] = 0
+        for i in range(int(self._wallocated[slot]) - 1, -1, -1):
+            self._wfree.append(int(self.window_table[slot, i]))
+        self._wallocated[slot] = 0
         if self.compress:
             self._cheld[slot] = 0
         if self.state is not None and self.lengths[slot]:
@@ -564,7 +687,7 @@ class PagedKVCache:
         """
         freed = 0
         for slot in range(self.config.slots):
-            n = int(self._allocated[slot])
+            n = int(self._allocated[slot]) + int(self._wallocated[slot])
             if n:
                 freed += n
                 self.free_slot(slot)
@@ -738,7 +861,7 @@ class PagedKVCache:
         self.state = _state_set(self.state, rows, jnp.int32(slot))
 
     def write_prefill(self, slot: int, k_layers, v_layers,
-                      start: int = 0, state=None) -> None:
+                      start: int = 0, state=None, window_rows=None) -> None:
         """Scatter a prefilled prompt's K/V into the slot's pages.
 
         ``k_layers``/``v_layers``: ``[num_layers, t, *entry]`` of each
@@ -751,10 +874,22 @@ class PagedKVCache:
         tail ``[start:]`` is scattered (through the copy-on-write
         guard, so a partial shared page is cloned first).  ``state``:
         the prefill's trailing rows for :meth:`write_state`, where the
-        caller does not write them itself."""
+        caller does not write them itself.  ``window_rows``: with a
+        window group, the pair ``(first, second)`` of its planes' rows
+        ``[window_layers, rows, *entry]``: the prompt's LAST rows, from
+        :func:`window_rows_from` on -- a window plane is written those
+        only, into the slot's ring."""
         c = self.config
         t = int(k_layers.shape[1])
+        if (window_rows is not None) != bool(c.window_layers) or (
+                c.window_layers and start):
+            raise ValueError(
+                f"{c.window_layers} window planes, window rows "
+                f"{'given' if window_rows is not None else 'missing'}, "
+                f"start {start}")
         self.reserve(slot, start + t, writable_from=start)
+        if window_rows is not None:
+            self._write_window(slot, t, *window_rows)
         pos = np.arange(start, start + t)
         pages = jnp.asarray(self.page_table[slot][pos // c.page_size])
         offs = jnp.asarray(pos % c.page_size)
@@ -766,6 +901,22 @@ class PagedKVCache:
         if state is not None:
             self.write_state(slot, state)
         self.lengths[slot] = start + t
+
+    def _write_window(self, slot: int, length: int, wk_rows, wv_rows
+                      ) -> None:
+        c = self.config
+        first = window_rows_from(length, c.window)
+        if int(wk_rows.shape[1]) != length - first:
+            raise ValueError(
+                f"a window plane keeps rows {first}-{length - 1} of a "
+                f"prompt of {length}, got {int(wk_rows.shape[1])} rows")
+        pos = np.arange(first, length)
+        pages = jnp.asarray(self.window_table[slot][
+            pos // c.page_size % c.window_pages_per_slot])
+        offs = jnp.asarray(pos % c.page_size)
+        dt = jnp.dtype(c.dtype)
+        self.wk = _pool_set(self.wk, wk_rows.astype(dt), pages, offs)
+        self.wv = _pool_set(self.wv, wv_rows.astype(dt), pages, offs)
 
     def grow(self, slot: int) -> None:
         """Account one decoded token (the decode step already wrote its
@@ -783,6 +934,25 @@ class PagedKVCache:
 
     def lengths_device(self) -> jnp.ndarray:
         return jnp.asarray(np.array(self.lengths))
+
+    def window_table_device(self) -> jnp.ndarray:
+        return jnp.asarray(np.array(self.window_table))
+
+    @property
+    def carried(self) -> tuple:
+        """What the cache owns beside ``k`` and ``v`` and a decode step
+        rewrites: the slot state, the window group's pools.  The step
+        takes them after its read-only operands, donated, and returns
+        their successors after the pools (:meth:`take_carried`)."""
+        return tuple(x for x in (self.state, self.wk, self.wv)
+                     if x is not None)
+
+    def take_carried(self, arrays) -> None:
+        arrays = list(arrays)
+        if self.state is not None:
+            self.state = arrays.pop(0)
+        if self.wk is not None:
+            self.wk, self.wv = arrays
 
     def ctable_device(self) -> jnp.ndarray:
         return jnp.asarray(np.array(self.cpage_table))

@@ -2,9 +2,10 @@
 
 ``ServingEngine`` builds its prefill, its decode step and its
 ``CacheConfig`` from a :class:`LayerSpec`, never from a model's own
-config fields: what kind of attention a layer has, what ONE token holds
-in a page of each of the cache's two pools, what kind of feed-forward
-each layer has, what a SLOT keeps beside its pages (state of the
+config fields: what kind of attention the model has and, a layer,
+whether it reads the whole context or a window of it, what ONE token
+holds in a page of each of the cache's two pools, what kind of
+feed-forward each layer has, what a SLOT keeps beside its pages (state of the
 sequence that no token's page entry holds), whether the head is the
 embedding transposed, which of the engine's features the model's
 programs do not have, and the functions that build those programs.
@@ -12,7 +13,8 @@ programs do not have, and the functions that build those programs.
 A config describes itself through a ``layer_spec()`` method
 (:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`,
 :class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`,
-:class:`~horovod_tpu.serving.loop_dense.LoopDenseConfig`); a
+:class:`~horovod_tpu.serving.loop_dense.LoopDenseConfig`,
+:class:`~horovod_tpu.serving.swa_moe.SwaMoeConfig`); a
 ``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
 described here, by the functions of ``serving/decode.py``.
 """
@@ -24,7 +26,7 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 
 # The engine's optional features, as ``LayerSpec.unsupported`` names them.
 FEATURES = ("tp", "lora", "spec_decode", "kv_compress", "prefill_chunk",
-            "prefix_cache")
+            "prefix_cache", "handoff")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,12 +79,48 @@ class LayerSpec:
     # = ``passes * num_layers`` leading entries, pass ``t`` of layer
     # ``l`` in plane ``t * num_layers + l``.
     passes: int = 1
+    # What a layer's attention reads: "full" (every token of the
+    # context: its planes grow with the sequence) or "window" (the last
+    # ``window`` tokens, the current one among them: its planes keep
+    # ``ceil(window / page_size) + 1`` pages a slot, written round and
+    # round).  None: every layer is "full", and the model describes
+    # itself as before there were kinds.  The two kinds live in two
+    # groups of planes (``PagedKVCache``), each under a page table of
+    # its own: full layer number ``i`` reads plane ``i`` of the pools,
+    # window layer number ``j`` plane ``j`` of the window pools.  With a
+    # window group the prefill returns one thing more, last: the pair
+    # ``(first, second)`` of the window planes' rows ``[window planes,
+    # batch, rows, *page entry]``, the prompt's LAST rows
+    # (``kvcache.window_rows_from``); its first and second
+    # planes are the full layers' alone.  The decode step takes the
+    # window group's table after ``active`` and the window pools, donated,
+    # before its own state.
+    attn_kinds: Optional[Tuple[str, ...]] = None
+    window: Optional[int] = None
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla", "cca"):
             raise ValueError(f"attention kind {self.attention!r}")
         if set(self.ffn) - {"dense", "moe"}:
             raise ValueError(f"feed-forward kinds {sorted(set(self.ffn))}")
+        kinds = self.attn_kinds
+        if kinds is not None and (
+                set(kinds) - {"full", "window"} or len(kinds) != len(self.ffn)
+                or "full" not in kinds):
+            raise ValueError(
+                f"attention kinds {kinds} of {len(self.ffn)} layers: "
+                '"full" or "window" a layer, one of them at least "full"')
+        if (self.window is not None) != (
+                kinds is not None and "window" in kinds) \
+                or (self.window is not None and self.window < 1):
+            raise ValueError(
+                f"window {self.window} and attention kinds {kinds}: a "
+                "window's length goes with window layers")
+        if self.window is not None and (self.passes != 1
+                                        or self.page[1] is None):
+            raise NotImplementedError(
+                "a window group is built for one pass over two pools: "
+                f"{self.passes} passes, pools {self.page}")
         if [e is None for e in self.page] != [
                 h is None for h in self.page_holds] or self.page[0] is None:
             raise ValueError(
@@ -104,8 +142,13 @@ class LayerSpec:
     def planes(self) -> int:
         """Leading entries of the pools: what sizes, writes, reads,
         frees, re-prefills or ships the cache counts these, never the
-        layers."""
-        return self.passes * len(self.ffn)
+        layers.  The full layers' alone, where some are window layers."""
+        return self.passes * (len(self.ffn) - self.window_planes)
+
+    @property
+    def window_planes(self) -> int:
+        """Leading entries of the window group's pools."""
+        return (self.attn_kinds or ()).count("window")
 
     def require(self, **wanted: bool) -> None:
         """Raise ``NotImplementedError``, by name, for each feature that

@@ -39,6 +39,9 @@ class Round(NamedTuple):
     # over the passes.  Layer ``li`` reads and writes plane ``first_plane
     # + li``.
     first_plane: object = 0
+    # The window group's page table ``[slots, ring]``, where the model
+    # has window layers (``build_one_chip_step``'s ``window_group``).
+    window_table: Optional[jax.Array] = None
 
 
 def lane_pad(x, width: int):
@@ -97,7 +100,9 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                         carried: int, meta: dict,
                         local: Callable = lambda x: None,
                         routed: bool = True, passes: int = 1,
-                        after_pass: Optional[Callable] = None
+                        after_pass: Optional[Callable] = None,
+                        window_group: bool = False,
+                        held: Optional[slice] = None
                         ) -> ServingDecodeStep:
     """The jitted step ``name``::
 
@@ -141,6 +146,15 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     last one left, which ``after_pass`` has normed.  Without
     ``after_pass`` there is no loop and no such operand, and the step
     lowers to what it lowered to before there was one.
+
+    A model of TWO pools hands the second in ``None``'s place: ``layer``
+    then gets and returns the pair, and both are donated.
+    ``window_group`` (a model with window layers): behind ``active`` the
+    step takes the window group's page table, read only, which ``layer``
+    finds as ``rnd.window_table``, and the group's two pools, donated,
+    which lead the ``carried`` arrays.  ``held`` (a chip that holds a
+    share of the experts): the slice of a routed layer's counts that the
+    ``tells`` are made of; the histogram keeps the router's whole width.
     """
     looped = after_pass is not None
     if passes > 1 and not looped:
@@ -149,14 +163,18 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     def step(params, pool, no_pool, tokens, positions, page_table, active,
              *state):
         *state, prev = state
+        window_table = state.pop(0) if window_group else None
+        two_pools = no_pool is not None
+        if two_pools:
+            pool = (pool, no_pool)
         mass = state.pop() if looped else None
         hist = state.pop() if routed else None
         carry = state
         p = params["params"] if "params" in params else params
         tokens, active = round_inputs(tokens, active, prev)
         x = embed(p, tokens)                                     # [S, d]
-        rnd = round_of(positions, page_table, active,
-                       page_size=page_size, scratch=scratch)
+        rnd = round_of(positions, page_table, active, page_size=page_size,
+                       scratch=scratch)._replace(window_table=window_table)
         told = [jnp.zeros((), jnp.int32) for _ in tells]
 
         def one_pass(x, pool, carry, hist, told, rnd):
@@ -166,6 +184,8 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                     li, p[f"layer_{li}"], x, pool, carry, within, rnd)
                 if counts is not None:
                     hist = hist.at[mi].add(counts)
+                    if held is not None:
+                        counts = counts[held]
                     told = [_JOIN[t](was, TELLS[t](counts))
                             for t, was in zip(tells, told)]
             return x, pool, list(carry), hist, told
@@ -192,12 +212,15 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                                   jnp.ones(x.shape[:1], jnp.float32)))
         logits = readout(x, p, eps, dtype, tied=tied, normed=looped)
         own = ([hist] if routed else []) + ([mass] if looped else [])
+        if two_pools:
+            pool, no_pool = pool
         return (logits, pool, no_pool, *carry, *own,
                 tell_round(logits, told))
 
     step.__name__ = step.__qualname__ = name
-    fn = jax.jit(step, donate_argnums=(1,) + tuple(
-        range(7, 7 + carried + routed + looped)))
+    first = 7 + window_group
+    fn = jax.jit(step, donate_argnums=(1, 2) + tuple(range(
+        first, first + 2 * window_group + carried + routed + looped)))
     return ServingDecodeStep(fn, dict(
         meta, kind="serving_decode", world=1, tp=1, num_layers=num_layers,
         passes=passes, dtype=str(jnp.dtype(dtype)), lora=False,

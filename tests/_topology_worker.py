@@ -37,6 +37,15 @@ result is at least a pool PLANE in size, other than the in-place row
 writes, whose result IS the pool (``pool[layer]`` materialised, a
 gathered view, a relayout: there must be none).
 
+``<topology> swa_step [slots [prompt ...]]``: compiles, for ONE chip,
+the decode step of ``serving/swa_moe.py`` at K-EXAONE-236B-A23B's widths
+and this repo's cut of it (8 layers ``L L L G L L L G``, the dense layer
+first, 16 of 128 experts held, 19,200 rows of the vocabulary, 32 slots
+by default, 16-token pages, contexts to 9,216, bfloat16) and its prefill
+at each ``prompt`` length (8,192 by default), and prints what each
+holds: Mosaic calls by name, the pools aliased in place, argument,
+temporary and total bytes against the chip's 16 GiB.
+
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
@@ -133,6 +142,14 @@ def kernels(topology: str) -> int:
         return attention.cca_decode_attention(
             q, keys, page_table, layer=3, lengths=lengths, kv_heads=8,
             scale=128 ** -0.5, values=values)
+
+    def swa_decode(q, keys, values, page_table, lengths):
+        return attention.cca_decode_attention(
+            q, keys, page_table, layer=3, lengths=lengths, kv_heads=8,
+            scale=128 ** -0.5, values=values, window=128)
+
+    def swa_prefill(q, k, v):
+        return attention.flash_attention(q, k, v, causal=True, window=128)
 
     def loop_decode(q, pool, page_table, lengths):
         # The plane is a traced scalar: pass t of layer 5 of 48, inside a
@@ -236,6 +253,25 @@ def kernels(topology: str) -> int:
             spec((16, 513, 16, 1024), jnp.float32),
             spec((16, 513, 16, 1024), jnp.float32),
             spec((8, 64), jnp.int32), spec((8,), jnp.int32)]),
+        # K-EXAONE's window layers (PR 39): the walk over a slot's ring of
+        # nine pages out of the window group's two pools, 64 query heads
+        # over eight, under its own name; and the banded prefill kernel
+        # over 8,192 tokens (two key blocks of 512 a query block) and
+        # over 512 (one block: the blocked kernel, never the head-group
+        # one, which knows no window).
+        "swa_decode_b32": (swa_decode, [
+            spec((32, 64, 128), jnp.bfloat16),
+            spec((6, 289, 16, 1024), jnp.bfloat16),
+            spec((6, 289, 16, 1024), jnp.bfloat16),
+            spec((32, 9), jnp.int32), spec((32,), jnp.int32)]),
+        "flash_swa_prefill_8k": (swa_prefill, [
+            spec((1, 64, 8192, 128), jnp.bfloat16),
+            spec((1, 8, 8192, 128), jnp.bfloat16),
+            spec((1, 8, 8192, 128), jnp.bfloat16)]),
+        "flash_swa_prefill_512": (swa_prefill, [
+            spec((1, 64, 512, 128), jnp.bfloat16),
+            spec((1, 8, 512, 128), jnp.bfloat16),
+            spec((1, 8, 512, 128), jnp.bfloat16)]),
         # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
         "flash_decode_b8": (decode, [
             spec((8, 16, 1, 128), jnp.float32),
@@ -251,6 +287,9 @@ def kernels(topology: str) -> int:
         out[name] = text.count("tpu_custom_call")
         if "hvd_flash_hg_fwd" in text:
             head_group.append(name)
+        if name.startswith(("swa_", "flash_swa_")):
+            assert ("hvd_swa_decode" in text) == name.startswith("swa_")
+            assert ("hvd_flash_swa_fwd" in text) == name.startswith("flash_")
     out["head_group"] = head_group
     print(json.dumps(out))
     return 0
@@ -343,6 +382,89 @@ def dense_step(topology: str, layers: int = 4, tp: int = 1) -> int:
     return 0
 
 
+def swa_step(topology: str, slots: int = 32, *prompts: int) -> int:
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu.ops import pallas
+    from horovod_tpu.serving import swa_moe
+    from horovod_tpu.serving.decode import no_round
+
+    pallas.interpret_mode = lambda: False
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    mesh = Mesh(np.asarray(td.devices[:1]), ("tp",))
+    cfg = swa_moe.SwaMoeConfig(
+        vocab_size=153600, d_model=6144, num_heads=64, num_kv_heads=8,
+        head_dim=128, ffn_hidden=18432, moe_hidden=2048, num_experts=128,
+        experts_per_token=8, attn_kinds=("window",) * 3 + ("full",)
+        + ("window",) * 3 + ("full",), ffn_kinds=("dense",) + ("moe",) * 7,
+        window=128, routed_scale=2.5, max_seq_len=262144, experts_held=16,
+        vocab_held=19200)
+    page, max_len, bf = 16, 9216, jnp.bfloat16
+    pps, ring = max_len // page, 128 // page + 1
+    on = NamedSharding(mesh, P())
+
+    def whole(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    params = jax.tree.map(lambda z: whole(z.shape, bf),
+                          swa_moe.param_shapes(cfg, bf))
+    weights = sum(int(np.prod(z.shape)) * 2 for z in jax.tree.leaves(params))
+    pool = whole((2, slots * pps + 1, page, cfg.kv_width), bf)
+    wpool = whole((6, slots * ring + 1, page, cfg.kv_width), bf)
+    out = {"weight_bytes": weights,
+           "cache_bytes": 2 * 2 * (int(np.prod(pool.shape))
+                                   + int(np.prod(wpool.shape)))}
+
+    def report(name, lowered):
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        text = lowered.as_text()
+        header = compiled.as_text()
+        header = header[:header.index("\n")]
+        out[name] = {
+            "mosaic_calls": {k: text.count(f'kernel_name = "{k}"') for k in (
+                "hvd_cca_decode", "hvd_swa_decode", "hvd_moe_gmm",
+                "hvd_flash_fwd", "hvd_flash_swa_fwd", "hvd_flash_hg_fwd")},
+            "aliased_params": sorted(int(i) for i in re.findall(
+                r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)),
+            "argument_bytes": int(mem.argument_size_in_bytes),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "output_bytes": int(mem.output_size_in_bytes),
+            "alias_bytes": int(mem.alias_size_in_bytes)}
+
+    step = swa_moe.build_decode_step(cfg, mesh, slots=slots, page_size=page,
+                                     pages_per_slot=pps, dtype=bf)
+    report("decode", step._fn.lower(
+        params, pool, pool, whole((slots,), jnp.int32),
+        whole((slots,), jnp.int32), whole((slots, pps), jnp.int32),
+        whole((slots,), jnp.bool_), whole((slots, ring), jnp.int32),
+        wpool, wpool, whole((7, 128), jnp.int32),
+        whole(no_round(slots, 1).shape, jnp.int32)))
+    out["decode"]["pool_params"] = [
+        len(jax.tree.leaves(params)) + i for i in (0, 1, 7, 8)]
+
+    def prefill(p, toks):
+        return swa_moe.prefill_forward(p, cfg, toks, dtype=bf)
+
+    for t in prompts or (8192,):
+        report(f"prefill_{t}", jax.jit(prefill).lower(
+            params, whole((1, t), jnp.int32)))
+        # Beside the prefill: the weights and both groups of pools.
+        out[f"prefill_{t}"]["resident_with_cache"] = (
+            out["cache_bytes"] + out[f"prefill_{t}"]["argument_bytes"]
+            + out[f"prefill_{t}"]["temp_bytes"]
+            + out[f"prefill_{t}"]["output_bytes"])
+    print(json.dumps(out))
+    return 0
+
+
 def exchange(topology: str) -> int:
     import re
 
@@ -396,6 +518,9 @@ if __name__ == "__main__":
     if sys.argv[2:3] == ["dense_step"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(dense_step(topo, *(int(a) for a in sys.argv[3:5])))
+    if sys.argv[2:3] == ["swa_step"]:
+        os.environ["HOROVOD_PALLAS"] = "1"
+        sys.exit(swa_step(topo, *(int(a) for a in sys.argv[3:])))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

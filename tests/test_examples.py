@@ -87,11 +87,18 @@ def test_straggler_probe_example_cpu(tmp_path):
     """8-rank virtual-mesh drill: the chaos `slow` fault stalls one
     rank, the straggler monitor and the merged-trace report must both
     name it with a dispatch_gap-dominated step (the probe asserts this
-    internally; the entry it writes is checked here)."""
+    internally; the entry it writes is checked here).  The stall falls
+    before the LAST step: the monitor names the rank by an average that
+    forgets (0.3 s at step 4 of 10 is 11 ms of it by the end, less than a
+    busy host's jitter) and the span by the rank's last step, which then
+    is the stalled one.  It is 1.5 s long: the merged report calls a rank
+    host-bound by its gaps against its compute over the whole run, and
+    ten steps' compute is 0.15 s alone and 0.5 s beside five other test
+    workers."""
     bench = tmp_path / "straggler.json"
     out = _run([os.path.join(REPO, "examples", "straggler_probe.py"),
-                "--steps", "10", "--slow-rank", "3", "--slow-step", "4",
-                "--slow-secs", "0.3", "--bench-json", str(bench)])
+                "--steps", "10", "--slow-rank", "3", "--slow-step", "9",
+                "--slow-secs", "1.5", "--bench-json", str(bench)])
     assert "straggler probe OK" in out
     assert "straggler: rank 3" in out
     assert "dispatch_gap" in out
@@ -100,7 +107,7 @@ def test_straggler_probe_example_cpu(tmp_path):
     st = doc["parsed"]["straggler"]
     assert st["detected_rank"] == 3 and st["injected_rank"] == 3
     assert st["merged_ranks"] == st["world"] == 8
-    assert "slow@step=4" in st["spec"] and st["dominant_span"]
+    assert "slow@step=9" in st["spec"] and st["dominant_span"]
 
 
 @pytest.mark.integration

@@ -185,8 +185,13 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # with its plane a traced scalar inside a rolled loop (ONE call in
     # the loop's body), and that model's 128-token prefill; the same walk
     # over TWO pools of eight heads a row, the dense decode step's, at
-    # Mistral-7B's 32 slots (bfloat16) and LLAMA_1B's 8 (float32).
+    # Mistral-7B's 32 slots (bfloat16) and LLAMA_1B's 8 (float32); since
+    # PR 39 the walk over a ring of nine window pages a slot out of two
+    # pools (``hvd_swa_decode``) and the banded prefill kernel
+    # (``hvd_flash_swa_fwd``) over 8,192 and over 512 tokens.
     assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
+                   "swa_decode_b32": 1, "flash_swa_prefill_8k": 1,
+                   "flash_swa_prefill_512": 1,
                    "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
                    "head_group": ["flash_cca_prefill_512",
                                   "flash_loop_prefill_128",
@@ -221,6 +226,25 @@ def test_topology_aot_dense_decode_step_reads_the_pools_in_place():
     assert out["attention"] == "walk" and out["mosaic_calls"] == 1
     assert out["aliased_params"] == out["pool_params"]
     assert out["pool_writes"] == 2 * 4 and out["plane_sized"] == []
+
+
+def test_topology_aot_swa_step_fits_beside_its_cache_at_a_ragged_prompt():
+    """K-EXAONE's cut compiled for one v5e chip: the decode step aliases
+    all four pools to their successors, and a prompt of 6,000 tokens --
+    no multiple of the 2,048 a layer's per-token work runs at a time --
+    is chunked all the same (two chunks and a rest of 1,904: two
+    instances of the layers' 14 expert matmuls) and fits beside the
+    weights and both groups of pools.  Whole, the float32 intermediates
+    of an 8,192-token prompt were 4.6 GB of temporaries and did not."""
+    out = _topology_worker("v5e:2x2", "swa_step", "32", "6000")
+    step, pre = out["decode"], out["prefill_6000"]
+    assert set(step["pool_params"]) <= set(step["aliased_params"])
+    assert step["mosaic_calls"]["hvd_swa_decode"] == 1
+    assert step["mosaic_calls"]["hvd_cca_decode"] == 1
+    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 14
+    assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
+    assert pre["temp_bytes"] < 1.3e9
+    assert pre["resident_with_cache"] < 16 * 2 ** 30
 
 
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():
