@@ -24,7 +24,10 @@ Blocked kernels:
 * Softmax statistics cross the kernel boundary as ``(block, 128)``
   lane-broadcast tiles (the layout jax's own TPU flash attention uses for
   its l/m residuals); the persistent VJP residual is sliced to ``(b,h,t)``
-  so only transient kernel I/O pays the lane broadcast.
+  so only transient kernel I/O pays the lane broadcast.  The forward
+  keeps ``m`` and ``l`` that wide INSIDE its step too: a ``(block, 1)``
+  column costs a lane broadcast through the cross-lane unit at every use,
+  and the step waited for that unit, not for the MXU (PERF.md, PR 41).
 * Backward is the standard two-kernel FA2 split: ``dq`` accumulates over
   kv blocks, ``dk/dv`` accumulate over q blocks; ``delta = rowsum(dO*O)``
   is precomputed by XLA (a trivially fused elementwise reduce).  dk/dv
@@ -53,10 +56,13 @@ broadcast statistics a head in the blocked set; PERF.md, PR 29):
 Both sets:
 
 * Matmul operands: q.k^T and dO.v^T hand the MXU the operands in the type
-  they arrive in (head-group) or upcast to float32 (blocked); p and ds
-  are float32 values handed to ``dot`` at Mosaic's default precision,
-  which on the v5e is ONE bfloat16 pass whatever the operand type
-  (measured, PERF.md PR 29) -- so both sets compute the same products.
+  they arrive in (head-group; the blocked forward, which also rounds p
+  to v's type for p.v as the page walk does) or upcast to float32 (the
+  blocked backward pair); elsewhere p and ds are float32 values handed
+  to ``dot`` at Mosaic's default precision, which on the v5e is ONE
+  bfloat16 pass whatever the operand type (measured, PERF.md PR 29; the
+  operands' type saves vregs and conversions, not passes: PR 41) -- so
+  both sets compute the same products.
 * Causal masking is bottom-right aligned (query ``i`` sits at absolute
   position ``tk - tq + i``, the KV-cache/decode convention, matching
   ``attention_reference``); in the blocked set whole blocks above the
@@ -1008,34 +1014,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
 
     @pl.when(live)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        # Both products take their operands in the type the tiles arrive
+        # in (bfloat16 in every served cell) and accumulate in float32, as
+        # the page walk does; the statistics stay float32, ``l`` summed
+        # before ``p`` is rounded.  Float32 tiles multiply in float32.
+        s = jax.lax.dot_general(q_ref[0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
             s = _causal_mask(s, qi, ki, bq, bk, off, window)
         if has_seg:
             s = _seg_mask(s, qseg_ref, kseg_ref)
 
-        m_prev = m_scr[:, :1]                        # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)    # (bq, 1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                       # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)              # (bq, 1)
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-        v_blk = v_ref[0, 0].astype(jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        # m and l are (bq, 128) tiles whose lanes are alike in every row,
+        # and are read, combined and written that wide: a (bq, 1) column
+        # costs a lane broadcast through the cross-lane unit at EVERY use
+        # (192 a block of 512 x 512), and the step then waits for that
+        # unit, not for the MXU (PERF.md, PR 41: 22.3 against 12.2 ms a
+        # call over 8,192 tokens).  ``_lanes`` lays a statistic beside
+        # itself to meet the score tile and the accumulator: whole vregs.
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, bk))               # (bq, bk)
+        alpha = jnp.exp(m_prev - m_new)                  # (bq, 128)
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
+        v_blk = v_ref[0, 0]
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, acc_scr.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(step == nk - 1)
     def _finish():
-        l = l_scr[:, :1]
+        d = acc_scr.shape[1]
+        l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o = acc_scr[:] / l_safe
-        lse = m_scr[:, :1] + jnp.log(l_safe)
+        o = acc_scr[:] / _lanes(l_safe, d)
+        lse = m_scr[:] + jnp.log(l_safe)
         if has_seg:
             # DEAD rows (m never rose above the mask floor): zero the
             # output, and push lse to +BIG so both backward kernels'
@@ -1043,12 +1059,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, has_seg,
             # f32 absorbs log(l) into -1e30 and the backward sees
             # p = 1 PER KEY (a ~tk-fold gradient explosion on pad rows;
             # caught by review, regression-tested).
-            dead = m_scr[:, :1] <= _NEG_INF / 2
-            o = jnp.where(dead, 0.0, o)
-            lse = jnp.where(dead, -_NEG_INF, lse)
+            o = jnp.where(_lanes(m_scr[:], d) <= _NEG_INF / 2, 0.0, o)
+            lse = jnp.where(m_scr[:] <= _NEG_INF / 2, -_NEG_INF, lse)
         o_ref[0, 0] = o.astype(o_ref.dtype)
         if lse_ref is not None:
-            lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[-2:])
+            lse_ref[0, 0] = lse
+
+
+def _lanes(x, n: int):
+    """``x`` ``(rows, 128)`` whose lanes are alike in every row, as
+    ``(rows, n)``: copies of it side by side, cut to ``n`` columns.
+    Whole vregs, no lane moves."""
+    reps = -(-n // _LANES)
+    wide = x if reps == 1 else jnp.concatenate([x] * reps, axis=1)
+    return wide if wide.shape[1] == n else wide[:, :n]
 
 
 def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
@@ -1609,6 +1633,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     query at absolute position ``i`` sees the keys ``i - window < j <=
     i``.  The kernel (``hvd_flash_swa_fwd``) SKIPS the key blocks wholly
     outside that band: they are not in its grid.
+
+    Arithmetic of the blocked forward kernels (``hvd_flash_fwd``,
+    ``hvd_flash_swa_fwd``): both products take their operands in the
+    type they arrive in (bfloat16 q, k, v: ``q k^T`` exact in float32,
+    the weights rounded to bfloat16 for ``p v``, as the page walk of the
+    decode step does), float32 accumulation and float32 softmax
+    statistics; float32 operands multiply in float32.
 
     Dispatch: Pallas kernels when running on TPU (or ``HOROVOD_PALLAS=1``
     / ``HOROVOD_PALLAS_FLASH=1``, which use the interpreter off-TPU --

@@ -629,6 +629,17 @@ def _lowered_for_tpu(fn, *args):
                   text)
 
 
+def _bf16_prefill_gaps(prefill_forward, cfg, params, toks, want):
+    """A family's prefill computing in bfloat16, under whatever kernel
+    switch the environment has NOW: the jaxpr's text and every row's
+    distance from ``want``, the family's float32 reference logits."""
+    traced = jax.jit(lambda p, x: prefill_forward(
+        p, cfg, x, dtype=jnp.bfloat16, last_only=False)[0][0]).trace(
+            params, toks)
+    return str(traced.jaxpr), np.abs(np.asarray(
+        traced.lower().compile()(params, toks)) - want)
+
+
 @pytest.mark.parametrize("cell", list(_ONE_POOL_LOWERED))
 def test_one_pool_walk_lowers_to_what_it_was(monkeypatch, cell):
     import hashlib
@@ -954,3 +965,223 @@ def test_public_api_reaches_head_group_kernels(monkeypatch):
                                atol=3e-2, rtol=3e-2)
     said = [r.getMessage() for r in records]
     assert len(said) == 1 and "head_group kernels, 16 heads" in said[0]
+
+
+# ---------------------------------------------------------------------------
+# The blocked forward multiplies in its operands' type (PR 41).
+# ---------------------------------------------------------------------------
+
+def _blocked_by_hand(q, k, v, *, scale, block, window, round_p):
+    """``_fwd_kernel``'s arithmetic in ``jax.numpy``, a head and a query
+    block at a time: the products take q, k and v as they are and sum in
+    float32, the statistics are float32, ``l`` is summed from the float32
+    ``p``; with ``round_p`` the weights are rounded to the values' type
+    before ``p v``, without it they and the values multiply in float32
+    (what the kernel did before)."""
+    f32 = jnp.float32
+    b, h, t, d = q.shape
+    rep = h // k.shape[1]
+    out = np.zeros(q.shape, np.float32)
+    at = jnp.arange(block)
+    for bi, hi, i in np.ndindex(b, h, t // block):
+        rows = slice(i * block, (i + 1) * block)
+        m = jnp.full((block, 1), _attn._NEG_INF, f32)
+        l = jnp.zeros((block, 1), f32)
+        acc = jnp.zeros((block, d), f32)
+        first = 0 if window is None else _attn._band_first(
+            i, block, block, 0, window)
+        for j in range(first, i + 1):
+            cols = slice(j * block, (j + 1) * block)
+            kb, vb = k[bi, hi // rep, cols], v[bi, hi // rep, cols]
+            s = jax.lax.dot_general(q[bi, hi, rows], kb,
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=f32) * scale
+            gap = (i - j) * block + at[:, None] - at[None, :]
+            keep = gap >= 0 if window is None else (gap >= 0) & (gap < window)
+            s = jnp.where(keep, s, _attn._NEG_INF)
+            m_new = jnp.maximum(m, s.max(1, keepdims=True))
+            p, alpha = jnp.exp(s - m_new), jnp.exp(m - m_new)
+            l = alpha * l + p.sum(1, keepdims=True)
+            pv = (p.astype(vb.dtype), vb) if round_p else (p, vb.astype(f32))
+            acc = acc * alpha + jax.lax.dot_general(
+                *pv, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            m = m_new
+        out[bi, hi, rows] = np.asarray((acc / l).astype(q.dtype), np.float32)
+    return out
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("d", [128, 192])
+@pytest.mark.parametrize("kernel,window", [("hvd_flash_fwd", None),
+                                           ("hvd_flash_swa_fwd", 96)])
+def test_blocked_forward_multiplies_in_the_operands_type(monkeypatch, kernel,
+                                                         window, d, dtype):
+    """q, k and v over two blocks of 128 (two query heads a key head).
+    bfloat16: the kernel IS the online softmax with bfloat16 products and
+    float32 sums, ``p`` rounded to bfloat16 before ``p v`` (the result
+    leaves as bfloat16, so a float32 ulp of the accumulation either
+    vanishes or shows as ONE bfloat16 ulp of a rare element), which the
+    same arithmetic with a float32 ``p v`` is not (it agrees on 63% of
+    the elements); it stays within the head-group kernels' bfloat16
+    tolerance of the float32 reference; no q, k or v tile is converted to
+    float32 inside it.  float32: the same online softmax in float32,
+    element for element (the statistics' width is a layout, not an
+    arithmetic)."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    bf = jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(d), 3)
+    q = _rand((1, 2, 256, d), keys[0], dtype)
+    k = _rand((1, 1, 256, d), keys[1], dtype)
+    v = _rand((1, 1, 256, d), keys[2], dtype)
+
+    def run(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=128, block_kv=128)
+
+    got = run(q, k, v)
+    assert got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    hand = {r: _blocked_by_hand(q, k, v, scale=d ** -0.5, block=128,
+                                window=window, round_p=r)
+            for r in (True, False)}
+    if dtype == bf:
+        assert np.mean(got == hand[True]) > 0.999
+        np.testing.assert_allclose(got, hand[True], rtol=2.0 ** -7,
+                                   atol=1e-30)
+        assert np.mean(got == hand[False]) < 0.9
+    else:           # a few float32 ulps: the products' order of summation
+        np.testing.assert_allclose(got, hand[True], rtol=2e-6, atol=2e-6)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref = attention_reference(f32[0], jnp.repeat(f32[1], 2, axis=1),
+                              jnp.repeat(f32[2], 2, axis=1), causal=True,
+                              window=window)
+    np.testing.assert_allclose(got, np.asarray(ref), **_HG_TOL[dtype])
+
+    calls = [e for e in _equations(jax.make_jaxpr(run)(q, k, v).jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert [str(e.params["name"]) for e in calls] == [kernel]
+    body = list(_equations(calls[0].params["jaxpr"]))
+    assert sum(e.primitive.name == "dot_general" for e in body) == 2
+    for e in body:
+        if e.primitive.name == "dot_general":
+            assert [x.aval.dtype for x in e.invars] == [dtype, dtype]
+            assert e.outvars[0].aval.dtype == jnp.float32
+        if e.primitive.name == "convert_element_type":
+            assert e.invars[0].aval.dtype != bf, e
+    # The statistics are read and written a whole (bq, 128) tile, every
+    # lane of a row alike: the one (bq, 1) column a step makes is a row
+    # reduction's (the max and the sum of the step, and nothing in
+    # ``_init`` or ``_finish``), never a slice of a scratch tile, which
+    # the cross-lane unit would have to broadcast again.
+    columns = [e.primitive.name for e in body
+               if any(getattr(v.aval, "shape", None) == (128, 1)
+                      for v in e.outvars)]
+    assert columns == ["broadcast_in_dim"] * 2       # the two keepdims
+
+
+# sha256 of the TPU lowering (``_lowered_for_tpu``) of the blocked kernels.
+# The backward pair and the head-group pair were recorded on PR 40's tree,
+# the parent of the PR that rewrote ``_fwd_kernel``'s step: that PR did
+# not touch them, in either type.  The forward's were recorded on PR 41's
+# own tree (its products in the operands' type, its statistics a whole
+# (bq, 128) tile: float32 operands no longer lower to the parent's text,
+# their arithmetic is the parent's element for element): whoever changes
+# code these kernels share sees it here, re-records, and owes JoyAI's and
+# K-EXAONE's cells a measurement.  At Mistral's 1,024 tokens the forward
+# is also held by ``tests/test_serving_swa.py``.
+_BLOCKED_LOWERED = {
+    "flash_fwd_f32":
+        "80fa44c4e3b5a6aafea039ea0efdde62c3091e4882f335943c03378f5e5cbda1",
+    "flash_fwd_bf16":
+        "e2a01657fee3dc2eb3d46093c1d2effb74f05ce2b538c6fbc9420ab5cde33ddf",
+    "flash_fwd_d192_f32":
+        "8ac605e954a57c00bdee4c42ac2c89fa3f60ed42687518a0fc1e13a6c2bc94b5",
+    "flash_fwd_d192_bf16":
+        "5b21536348bce849e1fe85f09915bce1f805fb5d569cb1f8205c267a78545cb4",
+    "flash_swa_fwd_f32":
+        "897a4d89d88548c86032be3ca2205a9f2eea16b69178581193c32e5f213e0b95",
+    "flash_swa_fwd_bf16":
+        "e8ab31a5187eb1f72c1284aa77c73c805ccdc913535ba124dd203c947d4dd54b",
+    "flash_vjp_f32":
+        "f77ea63ee201d998614a2d2961013e3476826803b1b9e1f6210d7b476a186275",
+    "flash_seg_vjp_f32":
+        "79efd766cd6f92432fd36f69c4c6635e44219493acbc53c82cb539c9309bd031",
+    # Untouched by PR 41: the parent's text.
+    "flash_bwd_f32":
+        "252d05b653e8649fdfab9088e282f3d11d53c1271da4c04e2ac09ad4a5ed20c6",
+    "flash_bwd_bf16":
+        "43bda09f87090aa46f226790832657dea9e1fb22495f719b3d1ee717ef21fb26",
+    "hg_fwd_f32":
+        "0b1879797d7ca6a35c8e189faeed049d3999b244f6681554a011cdcc31f7b9b8",
+    "hg_fwd_bf16":
+        "007261c2981d62e1dd4ade28e71efd3efd62b2c0f5f9aacfd9de30fb54b5c320",
+    "hg_bwd_f32":
+        "3e6369a5642320360ec788dffcd9fb48177260d2cd8dfed4f1521abf9c0d27b4",
+    "hg_bwd_bf16":
+        "df712031cd7bd7d8c797e70e87b516a02bdde2adb6df0d3faafca81749c909f2",
+}
+
+
+def _blocked_lowered_case(case):
+    """``(fn, shapes, Mosaic calls)`` of a case: 1,024 tokens in blocks
+    of 512, eight query heads over two key heads of 128 (``d192``: four
+    over four of 192, JoyAI's width); the head-group pair at BERT-Large's
+    sixteen heads of 64 over 128 tokens, under a scale no other test
+    gives them (they are ``jax.jit`` functions: a trace another test made
+    with the interpreter on would be found again here)."""
+    S = jax.ShapeDtypeStruct
+    what, dt = case.rsplit("_", 1)
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dt]
+    q, kv = S((1, 8, 1024, 128), dt), S((1, 2, 1024, 128), dt)
+    seg, stat = S((1, 1024), jnp.int32), S((1, 8, 1024), jnp.float32)
+    hq, hstat = S((2, 16, 128, 64), dt), S((2, 16, 128), jnp.float32)
+    opts = dict(scale=0.1, causal=True, bq=512, bk=512)
+    total = lambda o: o.astype(jnp.float32).sum()  # noqa: E731
+    return {
+        "flash_fwd": (lambda q, k, v: _attn._flash_fwd(
+            q, k, v, None, None, **opts), (q, kv, kv), 1),
+        "flash_fwd_d192": (lambda q, k, v: _attn._flash_fwd(
+            q, k, v, None, None, **opts),
+            (S((1, 4, 1024, 192), dt),) * 3, 1),
+        "flash_swa_fwd": (lambda q, k, v: _attn._flash_swa_fwd(
+            q, k, v, scale=0.1, window=128, bq=512, bk=512), (q, kv, kv), 1),
+        # The forward inside the custom_vjp pair, beside its backward.
+        "flash_vjp": (jax.grad(lambda q, k, v: total(_flash(
+            q, k, v, 0.1, True, 512, 512)), (0, 1, 2)), (q, kv, kv), 3),
+        "flash_seg_vjp": (jax.grad(lambda q, k, v, a, b: total(
+            _attn._flash_seg(q, k, v, a, b, 0.1, True, 512, 512)),
+            (0, 1, 2)), (q, kv, kv, seg, seg), 3),
+        "flash_bwd": (lambda q, k, v, o, lse, g: _attn._flash_bwd(
+            (q, k, v, o, lse, None, None), g, **opts),
+            (q, kv, kv, q, stat, q), 2),
+        "hg_fwd": (lambda q, k, v: _attn._hg_fwd(
+            q, k, v, scale=0.12, causal=False, group=16), (hq, hq, hq), 1),
+        "hg_bwd": (lambda q, k, v, lse, g: _attn._hg_bwd(
+            q, k, v, lse, g, scale=0.12, causal=False, group=16),
+            (hq, hq, hq, hstat, hq), 1),
+    }[what]
+
+
+@pytest.mark.parametrize("case", list(_BLOCKED_LOWERED))
+def test_blocked_and_head_group_kernels_lower_to_what_was_recorded(
+        monkeypatch, case):
+    import hashlib
+
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(_attn._pallas, "interpret_mode", lambda: False)
+    fn, shapes, mosaic_calls = _blocked_lowered_case(case)
+    text = _lowered_for_tpu(fn, *shapes)
+    assert "loc(" not in text
+    assert text.count("stablehlo.custom_call @tpu_custom_call") \
+        == mosaic_calls
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _BLOCKED_LOWERED[case]
