@@ -7,11 +7,16 @@ left alone):
 
 * the ROUTER IS THE CALLER'S: ``moe_ffn`` takes a :class:`Routing` (each
   token's chosen experts and what each adds) and never scores anything.
-  Two routers call it: :func:`route` (one matrix, sigmoid scores in
+  Three routers call it: :func:`route` (one matrix, sigmoid scores in
   float32, ``top_k`` of ALL the experts by ``score + bias``, weights
-  renormalised and scaled) and :func:`route_top1` (scores the caller's
+  renormalised and scaled), :func:`route_top1` (scores the caller's
   own network made, softmax, the one expert ``score + bias`` puts first,
-  weighed by its score) -- in both the bias chooses and never weighs;
+  weighed by its score) -- in both the bias chooses and never weighs --
+  and :func:`route_topk_softmax` (one matrix, the ``top_k`` largest
+  LOGITS, a softmax over the chosen; no bias).  A caller that knows its
+  routing ahead of the rows it is applied to (a router that reads the
+  layer's input, before attention) also lays the pairs out ahead
+  (:func:`routed_layout`) and hands ``moe_ffn`` the :class:`Layout`;
 * the ``tokens * top_k`` pairs are sorted by expert and laid out in
   row tiles of ``tm`` that never straddle two experts (each expert's run
   is padded up to a tile), so the matmul kernel is a plain tiled product
@@ -26,7 +31,8 @@ left alone):
 * the layer is told which experts it HOLDS (``first``, and the leading
   dim of the stacked weights): it routes over all of them, computes its
   own experts' part and adds the shared expert only where
-  ``with_shared`` says so -- the cut expert parallelism asks for, with
+  ``with_shared`` says so (a model without one says no) -- the cut
+  expert parallelism asks for, with
   no exchange on one chip.  A share's rows follow the pairs routed to
   ITS experts, not all the pairs: the grouped matmul runs over
   :func:`pass_rows` padded rows a pass (twice what even routing brings
@@ -34,6 +40,8 @@ left alone):
   router sends beyond that, so nothing is dropped and nothing is sized
   for the pairs held elsewhere (8,192 prompt tokens, top 8 of 128, 16
   held: 18,432 rows a pass, where all the pairs are 67,584);
+* the gate's activation is the model's, by name (``gate_act``: ``"silu"``
+  | ``"relu"``): one kernel body, the epilogue chosen statically.
 
 The Mosaic call is named ``hvd_moe_gmm`` (the trace's name for it); off
 the TPU the same tiles go through a ``jax.numpy`` loop-free reference
@@ -60,6 +68,8 @@ _VMEM_LIMIT = 48 * 1024 * 1024
 # weight: a third of the limit, so that the fetch of the next block has
 # room beside the one in use, the row tiles and Mosaic's own scratch.
 _WEIGHT_BLOCK_BUDGET = 16 * 1024 * 1024
+# The gate's activation of an expert, by the name a model's config gives.
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 class Routing(NamedTuple):
@@ -87,6 +97,16 @@ def route_top1(logits, bias) -> Routing:
     idx = jnp.argmax(s + bias.astype(jnp.float32), axis=-1)[:, None]
     return Routing(idx.astype(jnp.int32),
                    jnp.take_along_axis(s, idx, axis=-1))
+
+
+def route_topk_softmax(x, w_router, *, top_k: int) -> Routing:
+    """The ``top_k`` largest of the float32 logits ``x @ w_router``, each
+    weighed by the softmax over the chosen logits (equal to a softmax
+    over all the experts renormalised over the chosen)."""
+    logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=_HI)
+    chosen, idx = jax.lax.top_k(logits, top_k)
+    return Routing(idx.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1))
 
 
 def row_tile(pairs: int, experts: int) -> int:
@@ -168,9 +188,10 @@ def pass_rows(pairs: int, held: int, num_experts: int, tm: int) -> int:
     return _padded_rows(pairs, held, tm)
 
 
-def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool, tile_axis: int):
+def _gmm_kernel(te_ref, na_ref, x_ref, *refs, gated: bool, tile_axis: int,
+                gate_act: str):
     """One row tile against its expert's weight block (or a column slice
-    of it).  ``swiglu``: ``silu(x @ w_gate) * (x @ w_up)`` in one pass
+    of it).  ``gated``: ``gate_act(x @ w_gate) * (x @ w_up)`` in one pass
     over ``x``."""
     del te_ref
     o_ref = refs[-1]
@@ -179,8 +200,8 @@ def _gmm_kernel(te_ref, na_ref, x_ref, *refs, swiglu: bool, tile_axis: int):
     def _tile():
         x = x_ref[...]
         y = jnp.dot(x, refs[0][...], preferred_element_type=jnp.float32)
-        if swiglu:
-            y = jax.nn.silu(y) * jnp.dot(
+        if gated:
+            y = GATE_ACTS[gate_act](y) * jnp.dot(
                 x, refs[1][...], preferred_element_type=jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
 
@@ -202,7 +223,7 @@ def _column_block(kdim: int, n: int, weights: int, itemsize: int) -> int:
     return bn
 
 
-def _gmm_pallas(x, ws, tile_expert, active, tm: int):
+def _gmm_pallas(x, ws, tile_expert, active, tm: int, gate_act: str):
     rows, kdim = x.shape
     n = ws[0].shape[2]
     bn = _column_block(kdim, n, len(ws), x.dtype.itemsize)
@@ -231,8 +252,8 @@ def _gmm_pallas(x, ws, tile_expert, active, tm: int):
             (tm, kdim), lambda j, i, te, na: (last(i, na), 0))
         o_spec = pl.BlockSpec(
             (tm, bn), lambda j, i, te, na: (last(i, na), j))
-    kernel = functools.partial(_gmm_kernel, swiglu=len(ws) == 2,
-                               tile_axis=len(grid) - 1)
+    kernel = functools.partial(_gmm_kernel, gated=len(ws) == 2,
+                               tile_axis=len(grid) - 1, gate_act=gate_act)
     with jax.named_scope("hvd_moe_gmm"):
         return pl.pallas_call(
             kernel,
@@ -251,7 +272,7 @@ def _gmm_pallas(x, ws, tile_expert, active, tm: int):
         )(tile_expert, active, x, *ws)
 
 
-def _gmm_reference(x, ws, tile_expert, active, tm: int):
+def _gmm_reference(x, ws, tile_expert, active, tm: int, gate_act: str):
     """The same tiles in ``jax.numpy``: each tile against the weights of
     its expert, gathered a tile (fine at test sizes, and the CPU path of
     a tiny engine)."""
@@ -264,26 +285,28 @@ def _gmm_reference(x, ws, tile_expert, active, tm: int):
 
     y = mm(ws[0])
     if len(ws) == 2:
-        y = jax.nn.silu(y) * mm(ws[1])
+        y = GATE_ACTS[gate_act](y) * mm(ws[1])
     live = jnp.arange(rows // tm) < active[0]
     return jnp.where(live[:, None, None], y, 0.0).astype(x.dtype).reshape(
         rows, -1)
 
 
 def grouped_matmul(x, ws, tile_expert, active, *, tm: int,
-                   force_reference: bool = False):
+                   gate_act: str = "silu", force_reference: bool = False):
     """``x`` ``[rows, k]`` in tiles of ``tm`` rows, tile ``i`` against
     ``w[tile_expert[i]]`` for each ``w`` ``[experts, k, n]`` of ``ws``:
-    one weight gives ``x @ w``; two give ``silu(x @ w0) * (x @ w1)``.
-    Only the first ``active[0]`` tiles are computed; the rows of the
-    others are undefined."""
+    one weight gives ``x @ w``; two give ``gate_act(x @ w0) * (x @ w1)``
+    (``gate_act``: a key of ``GATE_ACTS``).  Only the first ``active[0]``
+    tiles are computed; the rows of the others are undefined."""
     if x.shape[0] % tm:
         raise ValueError(f"{x.shape[0]} rows are not whole tiles of {tm}")
     if len(ws) not in (1, 2):
         raise ValueError(f"one or two weights, got {len(ws)}")
+    if gate_act not in GATE_ACTS:
+        raise ValueError(f"gate_act {gate_act!r}: one of {sorted(GATE_ACTS)}")
     if not force_reference and _pallas.pallas_enabled("moe_gmm"):
-        return _gmm_pallas(x, tuple(ws), tile_expert, active, tm)
-    return _gmm_reference(x, tuple(ws), tile_expert, active, tm)
+        return _gmm_pallas(x, tuple(ws), tile_expert, active, tm, gate_act)
+    return _gmm_reference(x, tuple(ws), tile_expert, active, tm, gate_act)
 
 
 def _share_passes(experts, lay: Layout, weights, rows: int, tm: int, shape):
@@ -322,12 +345,29 @@ def _share_passes(experts, lay: Layout, weights, rows: int, tm: int, shape):
                              jnp.zeros(shape, jnp.float32))
 
 
+def routed_layout(routing: Routing, *, num_experts: int, held: int,
+                  first: int = 0, live=None) -> Layout:
+    """The :class:`Layout` :func:`moe_ffn` computes under ``routing``, for
+    a caller that has the routing before it has the rows: the sort and
+    the scans need the chosen experts alone."""
+    t, top_k = routing.experts.shape
+    return layout(routing.experts, num_experts,
+                  row_tile(t * top_k, num_experts), first=first, held=held,
+                  live=live)
+
+
 def moe_ffn(h, params, routing: Routing, *, num_experts: int,
             first: int = 0, with_shared: bool = True, live=None,
+            gate_act: str = "silu", lay: Optional[Layout] = None,
             force_reference: bool = False):
     """The routed layer over ``h`` ``[tokens, d]`` under the caller's
     ``routing`` (``[tokens, top_k]`` experts of ``num_experts`` and
-    weights: :func:`route`, :func:`route_top1`, or any other router).
+    weights: :func:`route`, :func:`route_top1`,
+    :func:`route_topk_softmax`, or any other router).  ``gate_act``: the
+    gate's activation in the experts and in the shared expert.  ``lay``:
+    the pairs' layout where the caller made it ahead
+    (:func:`routed_layout` under the same ``first``, ``live`` and held
+    experts); None: made here.
 
     ``params``: ``experts`` (``w_gate``, ``w_up`` ``[held, d, f]``,
     ``w_down`` ``[held, f, d]``: the experts ``first .. first + held -
@@ -342,13 +382,14 @@ def moe_ffn(h, params, routing: Routing, *, num_experts: int,
     ex = params["experts"]
     held = ex["w_gate"].shape[0]
     tm = row_tile(t * top_k, num_experts)
-    lay = layout(routing.experts, num_experts, tm, first=first, held=held,
-                 live=live)
+    if lay is None:
+        lay = routed_layout(routing, num_experts=num_experts, held=held,
+                            first=first, live=live)
 
     def experts(src, tile_expert, active):
         act = grouped_matmul(h[src], (ex["w_gate"].astype(dtype),
                                       ex["w_up"].astype(dtype)),
-                             tile_expert, active, tm=tm,
+                             tile_expert, active, tm=tm, gate_act=gate_act,
                              force_reference=force_reference)
         return grouped_matmul(act, (ex["w_down"].astype(dtype),),
                               tile_expert, active, tm=tm,
@@ -367,7 +408,7 @@ def moe_ffn(h, params, routing: Routing, *, num_experts: int,
         sh = params["shared"]
         gate = h @ sh["w_gate"]["kernel"].astype(dtype)
         up = h @ sh["w_up"]["kernel"].astype(dtype)
-        y = y + jnp.dot((jax.nn.silu(gate) * up).astype(dtype),
+        y = y + jnp.dot((GATE_ACTS[gate_act](gate) * up).astype(dtype),
                         sh["w_down"]["kernel"].astype(dtype),
                         preferred_element_type=jnp.float32)
     return y, lay.counts

@@ -589,7 +589,12 @@ class ServingEngine:
         tokens rounded up to whole pages (with ``planes`` and a kernel's
         time, a trace gives the nanoseconds a page).  ``walk``: 1 where
         the step reads attention by that walk (its ``meta["attention"]``),
-        0 where it gathers slot views (verify, the fp8 path)."""
+        0 where it gathers slot views (verify, the fp8 path).  With a
+        window group: ``window_tokens`` and ``window_pages``, what a
+        window layer's walk reads in ONE of its planes, and
+        ``window_pages_held``, the group's pages that slots hold as the
+        round is dispatched (of ``window_num_pages``: a ring grows page
+        by page, so short requests never hold a whole one)."""
         live = [int(self.cache.lengths[s]) + 1 for s in slots]
         page = self.page_size
         # (A test's stand-in for the step may be a bare function.)
@@ -603,7 +608,8 @@ class ServingEngine:
                 window_tokens=sum(min(n, w) for n in live),
                 window_pages=sum((n - 1) // page - max(n - w, 0) // page + 1
                                  for n in live),
-                window_planes=self.spec.window_planes)
+                window_planes=self.spec.window_planes,
+                window_pages_held=self.cache.window_live_pages)
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=sum(live), ahead=int(ahead),

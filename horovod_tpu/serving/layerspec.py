@@ -83,7 +83,9 @@ class LayerSpec:
     # context: its planes grow with the sequence) or "window" (the last
     # ``window`` tokens, the current one among them: its planes keep
     # ``ceil(window / page_size) + 1`` pages a slot, written round and
-    # round).  None: every layer is "full", and the model describes
+    # round: 9 at a window of 128 over pages of 16, 257 at 4,096, where
+    # a slot's ring grows page by page and short requests never fill
+    # it).  None: every layer is "full", and the model describes
     # itself as before there were kinds.  The two kinds live in two
     # groups of planes (``PagedKVCache``), each under a page table of
     # its own: full layer number ``i`` reads plane ``i`` of the pools,

@@ -1,37 +1,66 @@
 """Window and full attention layers side by side over a routed
-feed-forward of which this chip holds a SHARE: the K-EXAONE (``exaone_moe``)
-block, served.
+feed-forward of which this chip may hold a SHARE: the K-EXAONE
+(``exaone_moe``) block and the SmallThinker block, served.
 
 The fifth instance of :class:`~horovod_tpu.serving.layerspec.LayerSpec`,
 and the first whose layers are of two attention kinds
-(``LayerSpec.attn_kinds``) and whose routed layers hold fewer experts
+(``LayerSpec.attn_kinds``) and whose routed layers may hold fewer experts
 than the router scores.  ``x`` is the residual stream (float32), RMSNorm
-(``rms_eps``) everywhere, no biases, ``silu``.  Layer ``l`` has an
-attention kind ``attn_kinds[l]`` (``"window"`` | ``"full"``) and a
-feed-forward kind ``ffn_kinds[l]`` (``"dense"`` | ``"moe"``).
+(``rms_eps``) everywhere, no biases.  Layer ``l`` has an attention kind
+``attn_kinds[l]`` (``"window"`` | ``"full"``) and a feed-forward kind
+``ffn_kinds[l]`` (``"dense"`` | ``"moe"``).
 
 Attention, token ``i``, ``h = norm_1(x)``: ``q = h W_q`` in ``num_heads``
 heads of ``head_dim``, ``k = h W_k`` and ``v = h W_v`` in ``num_kv_heads``;
-``q`` and ``k`` are RMS-normalised A HEAD over their ``head_dim`` columns
-(learned scales ``q_norm``, ``k_norm``), then rotated by RoPE
-(``rope_theta``, half against half) ON WINDOW LAYERS ONLY: a full layer
-rotates nothing.  Scores ``q k^T / sqrt(head_dim)``; query ``i`` sees keys
-``j <= i`` on a full layer and ``i - window < j <= i`` on a window layer
-(``window`` keys, itself among them); query head ``n`` reads key/value
-head ``n // (num_heads / num_kv_heads)``.  ``a = softmax(scores) v`` goes
-out through ``W_o``.
+where ``qk_norm``, ``q`` and ``k`` are RMS-normalised A HEAD over their
+``head_dim`` columns (learned scales ``q_norm``, ``k_norm``); then both
+are rotated by RoPE (``rope_theta``, half against half) ON WINDOW LAYERS
+ONLY: a full layer rotates nothing.  Scores ``q k^T / sqrt(head_dim)``;
+query ``i`` sees keys ``j <= i`` on a full layer and ``i - window < j <=
+i`` on a window layer (``window`` keys, itself among them); query head
+``n`` reads key/value head ``n // (num_heads / num_kv_heads)``.  ``a =
+softmax(scores) v`` goes out through ``W_o``.
 
 Feed-forward, ``h = norm_2(x)``.  Dense: a SwiGLU of ``ffn_hidden``.
-Routed: ``s = sigmoid(h W_r)`` in float32 over ALL ``num_experts``; the
-``experts_per_token`` with the highest ``s + e_score_correction_bias`` are
-chosen (one group: no group limit); ``g_i = routed_scale * s_i / sum of
-the chosen s``; ``y = shared(h) + sum over the chosen i of g_i E_i(h)``,
-each ``E_i`` and the shared expert a SwiGLU of ``moe_hidden``:
-:func:`horovod_tpu.ops.moe.route` and :func:`~horovod_tpu.ops.moe.moe_ffn`
-as they stand.
+Routed: the router reads ``z`` (``route_from``: ``h`` itself, or the
+layer's INPUT ``x`` as it stood before attention) and chooses
+``experts_per_token`` experts ``i`` with weights ``g_i`` (``router``);
+``y = [shared(h) +] sum over the chosen i of g_i E_i(h)``, each ``E_i``
+and the shared expert (where ``num_shared_experts``) ``W_down(gate_act(h
+W_gate) * (h W_up))`` of ``moe_hidden``: the routers of
+:mod:`horovod_tpu.ops.moe` and its ``moe_ffn`` as they stand.
 
 The block: ``x += attention(norm_1(x))``, ``x += feed_forward(norm_2(x))``;
 a final norm; an untied head.
+
+THE BLOCK'S TWO INSTANCES differ in equations, each a field of
+:class:`SwaMoeConfig` that a model's config decides (none is a knob):
+
+====================  ==========================  ==========================
+field                 K-EXAONE-236B-A23B          SmallThinker-21BA3B
+====================  ==========================  ==========================
+``qk_norm``           True: a norm a head on q,k  False: none
+``router``            ``"sigmoid_bias"``: sigmoid ``"topk_softmax"``: the
+                      over all, top k of ``s +    top k LOGITS, a softmax
+                      bias``, ``routed_scale *    over the chosen
+                      s_i / sum``
+``route_from``        ``"ffn_input"``:            ``"layer_input"``: ``x``
+                      ``norm_2(x')``              ahead of ``norm_1`` and of
+                                                  attention
+``gate_act``          ``"silu"``                  ``"relu"``
+``num_shared_experts``  1                         0
+period                ``L L L G``, a leading      ``G L L L``, every layer
+                      dense layer                 routed
+====================  ==========================  ==========================
+
+With ``route_from="layer_input"`` a layer's routing is KNOWN BEFORE ITS
+ATTENTION: the decode step makes it, and the pairs' layout (the sort and
+the scans of ``ops.moe.layout``), at the layer's top, so that only the
+gather, the two grouped matmuls and the weighted sum stand between the
+attention call and the residual (nothing is fetched under attention yet:
+ROADMAP, Speed); the prefill makes the routing with the other per-token
+work ahead of attention.  Router, top-k and layout lie under the named
+scope ``hvd_moe_route``.
 
 THE SHARE.  ``experts_held`` of the ``num_experts`` routed experts live
 here, ``first_expert ..``; the router keeps its full width.  A routed
@@ -95,8 +124,8 @@ class SwaMoeConfig:
     attn_kinds: tuple            # a layer: "window" | "full"
     ffn_kinds: tuple             # a layer: "dense" | "moe"
     window: int
-    num_shared_experts: int = 1
-    routed_scale: float = 1.0
+    num_shared_experts: int = 1  # 0: the routed layers have none
+    routed_scale: float = 1.0    # (the "sigmoid_bias" router's)
     rope_theta: float = 1e6
     rms_eps: float = 1e-5
     max_seq_len: int = 8192
@@ -104,6 +133,11 @@ class SwaMoeConfig:
     experts_held: Optional[int] = None
     first_expert: int = 0
     vocab_held: Optional[int] = None
+    # The block's variants (module docstring): equations, not knobs.
+    qk_norm: bool = True
+    router: str = "sigmoid_bias"       # | "topk_softmax"
+    route_from: str = "ffn_input"      # | "layer_input"
+    gate_act: str = "silu"             # | "relu"
 
     def __post_init__(self):
         for name, whole in (("experts_held", self.num_experts),
@@ -123,6 +157,15 @@ class SwaMoeConfig:
                 f"a share of {self.experts_held} experts from "
                 f"{self.first_expert} of {self.num_experts}, "
                 f"{self.vocab_held} of {self.vocab_size} rows")
+        if self.router not in ("sigmoid_bias", "topk_softmax") \
+                or self.route_from not in ("ffn_input", "layer_input") \
+                or self.gate_act not in _moe.GATE_ACTS \
+                or self.num_shared_experts < 0 \
+                or (self.gate_act != "silu" and "dense" in self.ffn_kinds):
+            raise ValueError(
+                f"router {self.router!r} from {self.route_from!r}, "
+                f"{self.gate_act!r} gates, {self.num_shared_experts} shared "
+                "experts (a dense layer's gate is silu)")
 
     @property
     def num_layers(self) -> int:
@@ -157,7 +200,8 @@ class SwaMoeConfig:
             attention="gqa",
             page=((cfg.kv_width,), (cfg.kv_width,)),
             page_holds=("the keys of every key/value head side by side, "
-                        "normalised a head and, on a window layer, rotated",
+                        + ("normalised a head and, " if cfg.qk_norm else "")
+                        + "on a window layer, rotated",
                         "the values"),
             ffn=cfg.ffn_kinds, tied_head=False,
             max_seq_len=cfg.max_seq_len, tp_page_dim=None,
@@ -217,18 +261,22 @@ def param_shapes(config: SwaMoeConfig, dtype=jnp.float32):
             "attn": {"wq": kernel(d, c.num_heads * dh),
                      "wk": kernel(d, c.kv_width),
                      "wv": kernel(d, c.kv_width),
-                     "wo": kernel(c.num_heads * dh, d),
-                     "q_norm": {"scale": leaf(dh)},
-                     "k_norm": {"scale": leaf(dh)}},
+                     "wo": kernel(c.num_heads * dh, d)},
             "mlp_norm": {"scale": leaf(d)}}
+        if c.qk_norm:
+            out["attn"].update(q_norm={"scale": leaf(dh)},
+                               k_norm={"scale": leaf(dh)})
         if c.ffn_kinds[i] == "moe":
             e, f = c.experts_held, c.moe_hidden
             out["moe"] = {
-                "router": {"kernel": leaf(d, c.num_experts),
-                           "e_score_correction_bias": leaf(c.num_experts)},
+                "router": {"kernel": leaf(d, c.num_experts)},
                 "experts": {"w_gate": leaf(e, d, f), "w_up": leaf(e, d, f),
-                            "w_down": leaf(e, f, d)},
-                "shared": swiglu(f * c.num_shared_experts)}
+                            "w_down": leaf(e, f, d)}}
+            if c.router == "sigmoid_bias":
+                out["moe"]["router"]["e_score_correction_bias"] = leaf(
+                    c.num_experts)
+            if c.num_shared_experts:
+                out["moe"]["shared"] = swiglu(f * c.num_shared_experts)
         else:
             out["mlp"] = swiglu(c.ffn_hidden)
         return out
@@ -275,42 +323,72 @@ def init_params(config: SwaMoeConfig, key, dtype=jnp.float32,
 
 def _qkv(h, attn, cfg, positions, dtype, *, rotate: bool):
     """``h`` ``[..., d]`` -> the queries ``[..., heads, head_dim]``, the
-    keys ``[..., kv_heads, head_dim]`` (both normalised a head and,
-    where ``rotate``, rotated; in ``dtype``) and the values' row ``[...,
-    kv_heads * head_dim]``."""
+    keys ``[..., kv_heads, head_dim]`` (both normalised a head where the
+    model does so and, where ``rotate``, rotated; in ``dtype``) and the
+    values' row ``[..., kv_heads * head_dim]``."""
     lead, dh = h.shape[:-1], cfg.head_dim
     f32 = jnp.float32
 
-    def heads(node, n, scale):
-        z = _rmsnorm(_dense(h, node, dtype).reshape(*lead, n, dh),
-                     scale["scale"], f32, cfg.rms_eps)
+    def heads(node, n, norm):
+        if cfg.qk_norm:
+            z = _rmsnorm(_dense(h, node, dtype).reshape(*lead, n, dh),
+                         attn[norm]["scale"], f32, cfg.rms_eps)
+        else:
+            # Float32 out of the product: rounded once, after the rotation.
+            z = _dense_out(h, node, dtype).reshape(*lead, n, dh)
         if rotate:
             z = _rope_partial(z, positions[..., None], cfg.rope_theta, dh)
         return z.astype(dtype)
 
-    return (heads(attn["wq"], cfg.num_heads, attn["q_norm"]),
-            heads(attn["wk"], cfg.num_kv_heads, attn["k_norm"]),
+    return (heads(attn["wq"], cfg.num_heads, "q_norm"),
+            heads(attn["wk"], cfg.num_kv_heads, "k_norm"),
             _dense(h, attn["wv"], dtype))
 
 
-def _ffn(x, blk, cfg, dtype, *, live=None):
+def _route(z, blk, cfg) -> _moe.Routing:
+    """A routed layer's routing from the rows ``z`` ``[tokens, d]``
+    (float32) that its router reads: ``norm_2(x')`` or the layer's input
+    (``cfg.route_from``)."""
+    router = blk["moe"]["router"]
+    with jax.named_scope("hvd_moe_route"):
+        if cfg.router == "topk_softmax":
+            return _moe.route_topk_softmax(z, router["kernel"],
+                                           top_k=cfg.experts_per_token)
+        return _moe.route(z, router["kernel"],
+                          router["e_score_correction_bias"],
+                          top_k=cfg.experts_per_token,
+                          scale=cfg.routed_scale)
+
+
+def _lay_out(routing, cfg, live=None) -> _moe.Layout:
+    """Where ``moe_ffn`` will find the pairs of ``routing``."""
+    with jax.named_scope("hvd_moe_route"):
+        return _moe.routed_layout(
+            routing, num_experts=cfg.num_experts, held=cfg.experts_held,
+            first=cfg.first_expert, live=live)
+
+
+def _ffn(x, blk, cfg, dtype, *, live=None, routing=None, lay=None):
     """The layer's feed-forward over ``x`` ``[tokens, d]`` (float32): the
     residual's float32 addend -- a routed layer's is THIS SHARE's part
-    (the held experts' and the shared expert's) -- and, for a routed
-    layer, the ``[num_experts]`` counts of the pairs its live rows
-    routed."""
+    (the held experts' and, where the model has one, the shared expert's)
+    -- and, for a routed layer, the ``[num_experts]`` counts of the pairs
+    its live rows routed.  ``routing`` (and ``lay``, its layout): what a
+    router that reads the layer's input made ahead of attention; None:
+    made here, from ``norm_2(x)``."""
     h32 = _rmsnorm(x, blk["mlp_norm"]["scale"], jnp.float32, cfg.rms_eps)
     h = h32.astype(dtype)
     if "moe" not in blk:
         return _swiglu(h, blk["mlp"], dtype), None
-    router = blk["moe"]["router"]
-    routing = _moe.route(h32, router["kernel"],
-                         router["e_score_correction_bias"],
-                         top_k=cfg.experts_per_token,
-                         scale=cfg.routed_scale)
+    if routing is None:
+        routing = _route(h32, blk, cfg)
+    if lay is None:
+        lay = _lay_out(routing, cfg, live)
     return _moe.moe_ffn(h, blk["moe"], routing,
                         num_experts=cfg.num_experts,
-                        first=cfg.first_expert, live=live)
+                        first=cfg.first_expert, live=live,
+                        with_shared=cfg.num_shared_experts > 0,
+                        gate_act=cfg.gate_act, lay=lay)
 
 
 # ---------------------------------------------------------------------------
@@ -378,21 +456,28 @@ def prefill_forward(params, config: SwaMoeConfig, tokens, positions=None,
     rows = {"full": ([], []), "window": ([], [])}
     for li, kind in enumerate(cfg.attn_kinds):
         blk = p[f"layer_{li}"]
-        attn = blk["attn"]
         banded = kind == "window"
+        early = cfg.route_from == "layer_input" and "moe" in blk
 
-        def before(x, positions, blk=blk, banded=banded):
+        def before(x, positions, blk=blk, banded=banded, early=early):
+            # A router that reads the layer's input: with the rest of the
+            # rows' own work ahead of attention, a chunk at a time.
+            routed = tuple(z.reshape(*x.shape[:2], -1) for z in _route(
+                x.reshape(-1, x.shape[-1]), blk, cfg)) if early else ()
             h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
             q, k, v = _qkv(h, blk["attn"], cfg, positions, dtype,
                            rotate=banded)
-            return q, k.reshape(*k.shape[:2], cfg.kv_width), v
+            return (q, k.reshape(*k.shape[:2], cfg.kv_width), v) + routed
 
-        def after(x, o, blk=blk):
+        def after(x, o, *routed, blk=blk):
             x = x + _dense_out(o, blk["attn"]["wo"], dtype)
-            y, _ = _ffn(x.reshape(-1, x.shape[-1]), blk, cfg, dtype)
+            routing = _moe.Routing(*(z.reshape(-1, z.shape[-1])
+                                     for z in routed)) if routed else None
+            y, _ = _ffn(x.reshape(-1, x.shape[-1]), blk, cfg, dtype,
+                        routing=routing)
             return x + y.reshape(x.shape)
 
-        q, k, v = _by_chunks(before, x, positions)
+        q, k, v, *routed = _by_chunks(before, x, positions)
         first = kept if banded else 0
         rows[kind][0].append(k[:, first:])
         rows[kind][1].append(v[:, first:])
@@ -405,7 +490,8 @@ def prefill_forward(params, config: SwaMoeConfig, tokens, positions=None,
             q.transpose(0, 2, 1, 3), heads(k), heads(v), causal=True,
             scale=cfg.head_dim ** -0.5,
             window=cfg.window if banded else None)
-        x = _by_chunks(after, x, o.transpose(0, 2, 1, 3).reshape(b, t, -1))
+        x = _by_chunks(after, x, o.transpose(0, 2, 1, 3).reshape(b, t, -1),
+                       *routed)
     if last_only:
         x = x[:, -1:]
     out = (stepparts.readout(x, p, cfg.rms_eps, dtype, tied=False),
@@ -463,6 +549,12 @@ def build_decode_step(config: SwaMoeConfig, mesh, *, slots: int,
         attn = blk["attn"]
         banded = cfg.attn_kinds[li] == "window"
         plane = cfg.plane(li)
+        routing = lay = None
+        if cfg.route_from == "layer_input" and "moe" in blk:
+            # The router reads the layer's input: routing and layout are
+            # made here, ahead of the attention call.
+            routing = _route(x, blk, cfg)
+            lay = _lay_out(routing, cfg, rnd.active)
         h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
         q, k, v = _qkv(h, attn, cfg, rnd.positions, dtype, rotate=banded)
         k = k.reshape(s, cfg.kv_width)
@@ -491,7 +583,8 @@ def build_decode_step(config: SwaMoeConfig, mesh, *, slots: int,
                 q, kp, rnd.page_table, layer=plane, lengths=rnd.lengths,
                 kv_heads=cfg.num_kv_heads, scale=dh ** -0.5, values=vp)
         x = x + _dense_out(o.reshape(s, heads * dh), attn["wo"], dtype)
-        y, counts = _ffn(x, blk, cfg, dtype, live=rnd.active)
+        y, counts = _ffn(x, blk, cfg, dtype, live=rnd.active,
+                         routing=routing, lay=lay)
         return x + y, pools, carried, local, routed_index.get(li), counts
 
     return stepparts.build_one_chip_step(
@@ -502,4 +595,6 @@ def build_decode_step(config: SwaMoeConfig, mesh, *, slots: int,
         held=slice(cfg.first_expert, cfg.first_expert + cfg.experts_held),
         meta={"arch": "swa_moe", "d_model": cfg.d_model,
               "slots": int(slots), "attn_kinds": tuple(cfg.attn_kinds),
-              "window": cfg.window, "experts_held": cfg.experts_held})
+              "window": cfg.window, "experts_held": cfg.experts_held,
+              "route_from": cfg.route_from, "gate_act": cfg.gate_act,
+              "heads": heads, "kv_heads": cfg.num_kv_heads})

@@ -45,6 +45,11 @@ by default, 16-token pages, contexts to 9,216, bfloat16) and its prefill
 at each ``prompt`` length (8,192 by default), and prints what each
 holds: Mosaic calls by name, the pools aliased in place, argument,
 temporary and total bytes against the chip's 16 GiB.
+``<topology> small_step [slots [prompt ...]]``: the same for the block's
+second instance at SmallThinker-21BA3B-Instruct's widths as
+``benchmarks/configs/smallthinker_21b_a3b.json`` cuts it (8 layers ``G L
+L L G L L L``, every layer routed over all 64 experts, the whole
+vocabulary, a window ring of 257 pages a slot, 64 slots by default).
 
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
@@ -150,6 +155,24 @@ def kernels(topology: str) -> int:
 
     def swa_prefill(q, k, v):
         return attention.flash_attention(q, k, v, causal=True, window=128)
+
+    def swa_decode_h28(q, keys, values, page_table, lengths):
+        return attention.cca_decode_attention(
+            q, keys, page_table, layer=3, lengths=lengths, kv_heads=4,
+            scale=128 ** -0.5, values=values, window=4096)
+
+    def full_decode_h28(q, keys, values, page_table, lengths):
+        return attention.cca_decode_attention(
+            q, keys, page_table, layer=1, lengths=lengths, kv_heads=4,
+            scale=128 ** -0.5, values=values)
+
+    def swa_prefill_w4096(q, k, v):
+        return attention.flash_attention(q, k, v, causal=True, window=4096)
+
+    def gmm_relu(x, w_gate, w_up, w_down, tile_expert, active):
+        act = moe.grouped_matmul(x, (w_gate, w_up), tile_expert, active,
+                                 tm=16, gate_act="relu")
+        return moe.grouped_matmul(act, (w_down,), tile_expert, active, tm=16)
 
     def loop_decode(q, pool, page_table, lengths):
         # The plane is a traced scalar: pass t of layer 5 of 48, inside a
@@ -272,6 +295,33 @@ def kernels(topology: str) -> int:
             spec((1, 64, 512, 128), jnp.bfloat16),
             spec((1, 8, 512, 128), jnp.bfloat16),
             spec((1, 8, 512, 128), jnp.bfloat16)]),
+        # SmallThinker's shapes (PR 42): SEVEN query heads a key/value
+        # head (28 over 4: no whole sublane tile), the walk over a ring of
+        # 257 window pages a slot and over 576 pages of a full layer out
+        # of two pools of 512-column rows, 64 slots; the banded prefill
+        # over 8,192 tokens under a window of 4,096 (nine key blocks a
+        # query block); the grouped matmul's ReLU epilogue at a decode
+        # round's 64 x 6 pairs over 64 experts of 2560 x 768.
+        "swa_decode_b64_h28": (swa_decode_h28, [
+            spec((64, 28, 128), jnp.bfloat16),
+            spec((6, 16449, 16, 512), jnp.bfloat16),
+            spec((6, 16449, 16, 512), jnp.bfloat16),
+            spec((64, 257), jnp.int32), spec((64,), jnp.int32)]),
+        "full_decode_b64_h28": (full_decode_h28, [
+            spec((64, 28, 128), jnp.bfloat16),
+            spec((2, 36865, 16, 512), jnp.bfloat16),
+            spec((2, 36865, 16, 512), jnp.bfloat16),
+            spec((64, 576), jnp.int32), spec((64,), jnp.int32)]),
+        "flash_swa_prefill_8k_w4096": (swa_prefill_w4096, [
+            spec((1, 28, 8192, 128), jnp.bfloat16),
+            spec((1, 4, 8192, 128), jnp.bfloat16),
+            spec((1, 4, 8192, 128), jnp.bfloat16)]),
+        "moe_gmm_relu_decode": (gmm_relu, [
+            spec((1344, 2560), jnp.bfloat16),
+            spec((64, 2560, 768), jnp.bfloat16),
+            spec((64, 2560, 768), jnp.bfloat16),
+            spec((64, 768, 2560), jnp.bfloat16),
+            spec((84,), jnp.int32), spec((1,), jnp.int32)]),
         # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
         "flash_decode_b8": (decode, [
             spec((8, 16, 1, 128), jnp.float32),
@@ -382,7 +432,8 @@ def dense_step(topology: str, layers: int = 4, tp: int = 1) -> int:
     return 0
 
 
-def swa_step(topology: str, slots: int = 32, *prompts: int) -> int:
+def swa_step(topology: str, slots: int = 32, *prompts: int,
+             small: bool = False) -> int:
     import re
 
     import jax
@@ -399,15 +450,23 @@ def swa_step(topology: str, slots: int = 32, *prompts: int) -> int:
     td = topologies.get_topology_desc(platform="tpu",
                                       topology_name=topology)
     mesh = Mesh(np.asarray(td.devices[:1]), ("tp",))
-    cfg = swa_moe.SwaMoeConfig(
-        vocab_size=153600, d_model=6144, num_heads=64, num_kv_heads=8,
-        head_dim=128, ffn_hidden=18432, moe_hidden=2048, num_experts=128,
-        experts_per_token=8, attn_kinds=("window",) * 3 + ("full",)
-        + ("window",) * 3 + ("full",), ffn_kinds=("dense",) + ("moe",) * 7,
-        window=128, routed_scale=2.5, max_seq_len=262144, experts_held=16,
-        vocab_held=19200)
+    if small:
+        from benchmarks.families import smallthinker_swa_moe as family
+        with open(os.path.join(dirname(dirname(abspath(__file__))),
+                               "benchmarks", "configs",
+                               "smallthinker_21b_a3b.json")) as f:
+            cfg = family.program_config(json.load(f))
+    else:
+        cfg = swa_moe.SwaMoeConfig(
+            vocab_size=153600, d_model=6144, num_heads=64, num_kv_heads=8,
+            head_dim=128, ffn_hidden=18432, moe_hidden=2048,
+            num_experts=128, experts_per_token=8,
+            attn_kinds=("window",) * 3 + ("full",) + ("window",) * 3
+            + ("full",), ffn_kinds=("dense",) + ("moe",) * 7, window=128,
+            routed_scale=2.5, max_seq_len=262144, experts_held=16,
+            vocab_held=19200)
     page, max_len, bf = 16, 9216, jnp.bfloat16
-    pps, ring = max_len // page, 128 // page + 1
+    pps, ring = max_len // page, -(-cfg.window // page) + 1
     on = NamedSharding(mesh, P())
 
     def whole(shape, dtype):
@@ -445,7 +504,8 @@ def swa_step(topology: str, slots: int = 32, *prompts: int) -> int:
         params, pool, pool, whole((slots,), jnp.int32),
         whole((slots,), jnp.int32), whole((slots, pps), jnp.int32),
         whole((slots,), jnp.bool_), whole((slots, ring), jnp.int32),
-        wpool, wpool, whole((7, 128), jnp.int32),
+        wpool, wpool,
+        whole((len(cfg.moe_layers), cfg.num_experts), jnp.int32),
         whole(no_round(slots, 1).shape, jnp.int32)))
     out["decode"]["pool_params"] = [
         len(jax.tree.leaves(params)) + i for i in (0, 1, 7, 8)]
@@ -518,9 +578,12 @@ if __name__ == "__main__":
     if sys.argv[2:3] == ["dense_step"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(dense_step(topo, *(int(a) for a in sys.argv[3:5])))
-    if sys.argv[2:3] == ["swa_step"]:
+    if sys.argv[2:3] in (["swa_step"], ["small_step"]):
         os.environ["HOROVOD_PALLAS"] = "1"
-        sys.exit(swa_step(topo, *(int(a) for a in sys.argv[3:])))
+        small = sys.argv[2] == "small_step"
+        sys.exit(swa_step(topo, *(int(a) for a in sys.argv[3:]
+                                  or (("64",) if small else ())),
+                          small=small))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

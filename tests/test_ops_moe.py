@@ -407,25 +407,58 @@ def test_cca_decode_interpreted_matches_jnp(monkeypatch, ppb, sub, lengths,
 
 # -- the chip's share adds up to the model ------------------------------------------
 
-def test_four_shares_of_held_experts_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("block", ["sigmoid_silu_shared",
+                                   "topk_softmax_relu_no_shared"])
+def test_four_shares_of_held_experts_add_up_to_the_whole_layer(block):
     """The expert layer run once for each of four ranges of held experts
     (each routes over all 16 and computes its own four), the shared
-    expert counted once: the parts add up to the reference's whole
-    layer."""
+    expert -- where the model has one -- counted once: the parts add up
+    to the reference's whole layer.  Both served blocks: JoyAI's and
+    K-EXAONE's (sigmoid scores, SiLU gates, a shared expert) against the
+    JoyAI family's reference, and SmallThinker's (a softmax over the
+    chosen logits, ReLU gates, no shared expert) against its family's."""
     p = _layer(seed=5)
     h = jax.random.normal(jax.random.PRNGKey(6), (24, 64))
-    whole = family.ref_moe(h, p, top_k=4, scale=2.5)
+    if block == "sigmoid_silu_shared":
+        def ffn(part, first, share):
+            return _joyai_ffn(h, part, first=first, with_shared=share == 0)
+
+        def ref(part, first=0, share=0):
+            return family.ref_moe(h, part, top_k=4, scale=2.5, first=first,
+                                  with_shared=share == 0)
+    else:
+        from benchmarks.families import smallthinker_swa_moe as small
+        logits = small.ref_logits(h, p["router"])
+        routing = moe.route_topk_softmax(h, p["router"]["kernel"], top_k=4)
+        dense = small._weights_of(logits, routing.experts)
+
+        def ffn(part, first, share):
+            return moe.moe_ffn(h, part, routing, num_experts=16, first=first,
+                               with_shared=False, gate_act="relu")
+
+        def ref(part, first=0, share=0):
+            # The family's reference holds every expert: a share's part is
+            # the whole layer under the held experts' weights alone.
+            held = part["experts"]["w_gate"].shape[0]
+            y = jnp.zeros_like(h)
+            for i in range(held):
+                y += dense[:, first + i, None] * small._expert_block(
+                    h, part["experts"], i, 1, lambda z: z)[0]
+            return y
+
+        np.testing.assert_allclose(
+            np.asarray(ref(p)), np.asarray(small.ref_moe(
+                h, logits, p, top_k=4)), rtol=2e-5, atol=2e-5)
+    whole = ref(p)
     total = jnp.zeros_like(h)
     routed = 0
     for share in range(4):
         first = 4 * share
         part = dict(p, experts={k: v[first:first + 4]
                                 for k, v in p["experts"].items()})
-        y, counts = _joyai_ffn(h, part, first=first,
-                               with_shared=share == 0)
-        ref_part = family.ref_moe(h, part, top_k=4, scale=2.5, first=first,
-                                  with_shared=share == 0)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref_part),
+        y, counts = ffn(part, first, share)
+        np.testing.assert_allclose(np.asarray(y),
+                                   np.asarray(ref(part, first, share)),
                                    rtol=2e-5, atol=2e-5)
         total = total + y
         routed += int(counts[first:first + 4].sum())
@@ -433,3 +466,63 @@ def test_four_shares_of_held_experts_add_up_to_the_whole_layer():
     assert routed == 24 * 4
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                rtol=5e-5, atol=5e-5)
+
+
+# -- the other blocks' expert layers lower to what they lowered to ---------------------
+
+# sha256 of the TPU lowering (Mosaic bodies printed without source
+# locations) of ``moe_ffn`` under ZAYA's router (top 1 of 16 experts of
+# 2,048 x 2,048, a decode round's 96 rows and a 512-token prompt) and
+# under JoyAI's (top 8 of 256 experts of 768 beside a shared one, a round's
+# 64 rows and an 8,192-token prompt), recorded on PR 41's tree, the parent
+# of the PR that gave the grouped matmul its gate by name, ``moe_ffn`` a
+# layout made ahead and the module a third router.
+_EXPERT_LAYER_LOWERED = {
+    ("zaya", 96):
+        "804fd12a8ae71243975cc615751fe542064573f2a4e78e00d038f1dfba47bd6d",
+    ("zaya", 512):
+        "aa39213f44edb49376fd11c4f7b6fea349fb1b839ce82664c2e557159bee4f51",
+    ("joyai", 64):
+        "bf5130931339457d54c0025a6c20c69e5729cdd759fcfa7524de40124895f785",
+    ("joyai", 8192):
+        "571ea45c8501c267f1882adec695496d78f9b49a57b0d4279a641d0d2b9eb93e",
+}
+
+
+@pytest.mark.parametrize("block,rows", list(_EXPERT_LAYER_LOWERED))
+def test_the_other_blocks_expert_layers_lower_to_what_they_lowered_to(
+        monkeypatch, block, rows):
+    import hashlib
+
+    from test_ops_attention import _lowered_for_tpu
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(attention._pallas, "interpret_mode", lambda: False)
+    S, bf, f32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.float32
+
+    def tree(e, d, f, shared):
+        p = {"experts": {"w_gate": S((e, d, f), bf), "w_up": S((e, d, f), bf),
+                         "w_down": S((e, f, d), bf)}}
+        if shared:
+            p["shared"] = {"w_gate": {"kernel": S((d, f), bf)},
+                           "w_up": {"kernel": S((d, f), bf)},
+                           "w_down": {"kernel": S((f, d), bf)}}
+        return p
+
+    if block == "zaya":
+        def zaya(h, p, logits, bias, live):
+            return moe.moe_ffn(h, p, moe.route_top1(logits, bias),
+                               num_experts=16, with_shared=False, live=live)
+        fn = zaya          # (the function's name is in the lowered text)
+        args = (S((rows, 2048), bf), tree(16, 2048, 2048, False),
+                S((rows, 16), f32), S((16,), f32), S((rows,), jnp.bool_))
+    else:
+        def joyai(h, p, wr, bias, live):
+            return moe.moe_ffn(
+                h.astype(bf), p, moe.route(h, wr, bias, top_k=8, scale=2.5),
+                num_experts=256, live=live)
+        fn = joyai
+        args = (S((rows, 2048), f32), tree(256, 2048, 768, True),
+                S((2048, 256), bf), S((256,), bf), S((rows,), jnp.bool_))
+    text = _lowered_for_tpu(fn, *args)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _EXPERT_LAYER_LOWERED[block, rows]

@@ -188,8 +188,13 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # Mistral-7B's 32 slots (bfloat16) and LLAMA_1B's 8 (float32); since
     # PR 39 the walk over a ring of nine window pages a slot out of two
     # pools (``hvd_swa_decode``) and the banded prefill kernel
-    # (``hvd_flash_swa_fwd``) over 8,192 and over 512 tokens.
+    # (``hvd_flash_swa_fwd``) over 8,192 and over 512 tokens; since PR 42
+    # both walks at 28 query heads over 4 (a ring of 257 pages, a full
+    # table of 576), the banded prefill under a window of 4,096 and the
+    # grouped matmul with ReLU gates.
     assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
+                   "swa_decode_b64_h28": 1, "full_decode_b64_h28": 1,
+                   "flash_swa_prefill_8k_w4096": 1, "moe_gmm_relu_decode": 2,
                    "swa_decode_b32": 1, "flash_swa_prefill_8k": 1,
                    "flash_swa_prefill_512": 1,
                    "flash_mistral_prefill_1024": 1, "flash_mla_prefill_8k": 1,
@@ -245,6 +250,29 @@ def test_topology_aot_swa_step_fits_beside_its_cache_at_a_ragged_prompt():
     assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
     assert pre["temp_bytes"] < 1.3e9
     assert pre["resident_with_cache"] < 16 * 2 ** 30
+
+
+def test_topology_aot_small_step_fits_beside_its_cache():
+    """SmallThinker's cut (the block's second instance, PR 42) compiled
+    for one v5e chip at the cell's 64 slots: the decode step aliases all
+    four pools (5.65 GB, the window group's 3.23 of it) to their
+    successors and holds ONE walk function of each name for its 28 query
+    heads over 4 and 16 expert matmuls with ReLU gates; an 8,192-token
+    prompt, in four chunks, fits beside 7.93 GB of weights and the
+    cache."""
+    out = _topology_worker("v5e:2x2", "small_step", "64", "8192")
+    assert out["weight_bytes"] == 7_933_875_200
+    assert out["cache_bytes"] == 5_649_989_632
+    step, pre = out["decode"], out["prefill_8192"]
+    assert set(step["pool_params"]) <= set(step["aliased_params"])
+    assert step["mosaic_calls"]["hvd_swa_decode"] == 1
+    assert step["mosaic_calls"]["hvd_cca_decode"] == 1
+    assert step["mosaic_calls"]["hvd_moe_gmm"] == 2 * 8
+    assert step["temp_bytes"] < 0.1e9
+    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 8
+    assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
+    assert pre["temp_bytes"] < 0.8e9
+    assert pre["resident_with_cache"] < 15.0e9
 
 
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():

@@ -548,15 +548,55 @@ _TWO_POOL_LOWERED = \
     "3add0ab8a2db3f3bc66808447725b15a162dad59ff4de019503b6c1e8af51176"
 _FLASH_1024_LOWERED = \
     "ec67ce3c2bea1f300cfec5800ef8de802a13fa03ff1ca33139363f9c8e1fc9ba"
+# K-EXAONE's own programs, recorded on PR 41's tree, the parent of the PR
+# that gave ``swa_moe.py`` its second instance (a router ahead of
+# attention, ReLU gates, no head norm, no shared expert: fields of
+# ``SwaMoeConfig`` whose defaults are K-EXAONE's): the decode step and a
+# 2,560-token prefill (a whole chunk and a shorter one) at its widths, a
+# window layer over a dense feed-forward and a full layer over a routed
+# one.
+_SWA_STEP_LOWERED = \
+    "ea717161cb7432cf7ec21eb8fcef90f8f069868f835d304f7ebe6852300fc090"
+_SWA_PREFILL_LOWERED = \
+    "e5c77db0a13251bd2d528f5afcfa500c78fd91f1d87511a31bdf76644e9127d8"
 
 
-@pytest.mark.parametrize("what", ["two_pool_walk", "blocked_flash"])
+def _k_exaone_two_layers():
+    return swa_moe.SwaMoeConfig(
+        vocab_size=153600, d_model=6144, num_heads=64, num_kv_heads=8,
+        head_dim=128, ffn_hidden=18432, moe_hidden=2048, num_experts=128,
+        experts_per_token=8, attn_kinds=("window", "full"),
+        ffn_kinds=("dense", "moe"), window=128, routed_scale=2.5,
+        max_seq_len=262144, experts_held=16, vocab_held=19200)
+
+
+@pytest.mark.parametrize("what", ["two_pool_walk", "blocked_flash",
+                                  "swa_step", "swa_prefill"])
 def test_the_window_left_the_other_cells_kernels_as_they_were(monkeypatch,
                                                               what):
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     monkeypatch.setattr(_attn._pallas, "interpret_mode", lambda: False)
     S, bf, i32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.int32
-    if what == "two_pool_walk":
+    if what == "swa_step":
+        from horovod_tpu.serving.decode import no_round
+        cfg = _k_exaone_two_layers()
+        slots, pps, ring = 32, 576, 9
+        fn = swa_moe.build_decode_step(cfg, None, slots=slots, page_size=16,
+                                       pages_per_slot=pps, dtype=bf)._fn
+        pool = S((1, slots * pps + 1, 16, 1024), bf)
+        wpool = S((1, slots * ring + 1, 16, 1024), bf)
+        args = (swa_moe.param_shapes(cfg, bf), pool, pool, S((slots,), i32),
+                S((slots,), i32), S((slots, pps), i32),
+                S((slots,), jnp.bool_), S((slots, ring), i32), wpool, wpool,
+                S((1, 128), i32), S(no_round(slots, 1).shape, i32))
+        want = _SWA_STEP_LOWERED
+    elif what == "swa_prefill":
+        cfg = _k_exaone_two_layers()
+        fn = lambda p, t: swa_moe.prefill_forward(  # noqa: E731
+            p, cfg, t, dtype=bf)
+        args = (swa_moe.param_shapes(cfg, bf), S((1, 2560), i32))
+        want = _SWA_PREFILL_LOWERED
+    elif what == "two_pool_walk":
         # Mistral's cells: 32 slots, 32 heads over 8, 96 pages a slot.
         fn = lambda q, k, v, t, n: _attn.cca_decode_attention(  # noqa: E731
             q, k, t, layer=1, lengths=n, kv_heads=8, scale=128 ** -0.5,
