@@ -39,6 +39,7 @@ fast-forwarded idle time).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -175,6 +176,32 @@ class _Flight:
     told: Any
     t0: float
     round: int
+
+
+@dataclasses.dataclass
+class _Join:
+    """A prefill the chip has been handed whose first token the host has
+    not read yet: its slot and request, and ``[token, finite]`` on the
+    device (:func:`_hand_over`), its copy to the host started."""
+
+    slot: int
+    req: Request
+    first: Any
+
+
+def _hand_over(told, row, slot, *, slots: int):
+    """A prefill's first token, left on the chip.  ``row``: the float32
+    logits ``[1, vocab]`` of the prompt's last position.  The greedy
+    token and the row's finite flag (as :func:`decode.tell_round` gives
+    them of a round) go into ``slot``'s two places of ``told``, the
+    vector the next round reads as its ``prev``: the host gives that
+    round ``-1`` for the slot, as for one that continues.  Returns the
+    patched vector and the pair alone, the first token's own way back to
+    the host (the next round's ``told`` holds the slot's SECOND token)."""
+    first = jnp.stack([
+        greedy_sample(row)[0],
+        jnp.isfinite(jnp.sum(row)).astype(jnp.int32)])
+    return told.at[slot].set(first[0]).at[slots + slot].set(first[1]), first
 
 
 @jax.jit
@@ -366,6 +393,12 @@ class ServingEngine:
         # before told.
         self._told = self._whole(
             no_round(self.slots, len(self.spec.step_tells)))
+        # A joining slot's first token goes into that vector on the
+        # chip; what comes out is committed as the step's own is.
+        whole = NamedSharding(self.mesh, PartitionSpec())
+        self._hand_over = jax.jit(
+            functools.partial(_hand_over, slots=self.slots),
+            out_shardings=(whole, whole))
         self.verify_step = None
         if self.spec_decode:
             self.verify_step = self.spec.build_step(
@@ -410,18 +443,36 @@ class ServingEngine:
                 "req": req, "dev": dev, "pos": matched,
                 "start": matched, "past": past}
         else:
+            ahead = "in_flight" in st
             flight = st.get("in_flight")
             first = self._do_prefill(
                 slot, req, dev, matched=matched, entries=entries,
-                behind=-1 if flight is None else flight.round)
-            self._join_decode(st, slot, req, first, now)
+                behind=-1 if flight is None else flight.round, defer=ahead)
+            if ahead:
+                # The look-ahead loop: the token stays on the chip and
+                # the slot is live for the next round by count (its
+                # first token is in flight); the host reads it where it
+                # next waits for the chip (:meth:`_retire`,
+                # :meth:`_settle_joins`).
+                req.state = "decode"
+                req.in_flight = 1
+                st["joins"].append(_Join(slot, req, first))
+                st["first_tokens_deferred"] += 1
+                self._note_resident(st, slot, req)
+            else:
+                self._join_decode(st, slot, req, first, now)
 
     def _do_prefill(self, slot: int, req: Request, prompt_dev,
                     matched: int = 0, entries: Sequence = (),
-                    behind: int = -1) -> int:
-        """``behind``: the number of the decode round in flight while
-        this prefill is dispatched (it queues behind it on the chip), -1
-        where there is none."""
+                    behind: int = -1, defer: bool = False):
+        """Dispatch one prompt's prefill, its pool write and its slot
+        state's, and sample its first token.  ``behind``: the number of
+        the decode round in flight while this prefill is dispatched (it
+        queues behind it on the chip), -1 where there is none.
+        ``defer``: leave the token on the chip, in the slot's place of
+        the vector the next round reads (:func:`_hand_over`), and return
+        its ``[token, finite]`` pair on the device without waiting for
+        anything; else fetch it and return it as an int."""
         rec = _spans.recorder()
         # With a window group: the rows a window plane is written, the
         # prompt's last ones.
@@ -432,7 +483,8 @@ class ServingEngine:
         with rec.span("dispatch", name="serve.prefill",
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
-                      planes=self.spec.planes, behind=behind, **windowed):
+                      planes=self.spec.planes, behind=behind,
+                      deferred=defer, **windowed):
             with rec.phase("prefill.dispatch", rid=req.rid):
                 state = []
                 if matched:
@@ -463,6 +515,12 @@ class ServingEngine:
                                state_bytes=rows.size
                                * self.cache.state.dtype.itemsize):
                     self.cache.write_state(slot, rows)
+            if defer:
+                with rec.phase("prefill.hand_over", rid=req.rid):
+                    self._told, first = self._hand_over(
+                        self._told, logits[:, -1, :], np.int32(slot))
+                    first.copy_to_host_async()
+                return first
             with rec.phase("prefill.sample_fetch", rid=req.rid):
                 first = int(greedy_sample(logits[:, -1, :])[0])
         return first
@@ -513,10 +571,14 @@ class ServingEngine:
                      first: int, now) -> None:
         """Prefill done (whole or final chunk): first token is sampled,
         the request enters the decode batch."""
-        sched = self.scheduler
-        req.tokens.append(first)
-        sched.note_prefill(req, now())
-        st["last_tokens"][slot] = first
+        self._note_resident(st, slot, req)
+        self._book_first(st, slot, req, first, now)
+
+    def _note_resident(self, st: Dict[str, Any], slot: int,
+                       req: Request) -> None:
+        """The prompt's rows are in the slot's pages (the write is
+        dispatched): what the next round and the next admission need of
+        that, before any token of the request is known."""
         st["adapter_ids"][slot] = req.adapter_id
         if self._prefix is not None:
             # Register the prompt's full pages in the radix tree (tree
@@ -525,6 +587,13 @@ class ServingEngine:
             self._prefix.insert(req.prompt, slot)
             if req.session_id is not None:
                 self._prefix.pin_session(req.session_id, req.prompt)
+
+    def _book_first(self, st: Dict[str, Any], slot: int, req: Request,
+                    first: int, now) -> None:
+        """The host has the request's first token: stamped now."""
+        req.tokens.append(first)
+        self.scheduler.note_prefill(req, now())
+        st["last_tokens"][slot] = first
         if self.drafter is not None:
             self.drafter.on_admit(slot, req)
         if req.finished:
@@ -549,7 +618,7 @@ class ServingEngine:
                 and int(self.cache.lengths[s]) < self.max_len]
 
     def _quarantine_logits(self, st: Dict[str, Any], slot: int,
-                           req: Request) -> None:
+                           req: Request, now) -> None:
         """A slot produced nonfinite logits: never stream a token
         sampled from a poisoned distribution.
 
@@ -559,14 +628,20 @@ class ServingEngine:
         the slot's length and page mapping from scratch, so the
         quarantine cannot leak pages -- and retry the same position on
         the next round.  The request keeps its slot and emitted prefix;
-        only the round is lost.
+        only the round is lost.  Where it was the prefill's own logits
+        (the request has no token yet) the prompt is prefilled again and
+        the token fetched at once.
         """
         from ..timeline import metrics as _metrics
         _metrics.registry().counter(
             "horovod_guard_serving_reprefills_total",
             "Decode rounds where a slot's nonfinite logits were "
             "quarantined by re-prefilling its context").inc()
-        st["last_tokens"][slot] = self.re_prefill(slot, req)
+        if req.tokens:
+            st["last_tokens"][slot] = self.re_prefill(slot, req)
+        else:
+            self._book_first(st, slot, req, self._do_prefill(
+                slot, req, jnp.asarray(req.prompt, jnp.int32)), now)
 
     # -- one decode round (shared with serving.controlplane) ---------------
     def _round_span(self, st: Dict[str, Any], slots: List[int],
@@ -632,7 +707,12 @@ class ServingEngine:
         (:meth:`_retire`), so the chip has the next round queued while
         the host reads the last one.  A continuing slot's token never
         leaves the chip: the host gives the step ``-1`` for it and the
-        step reads it from the ``told`` vector of the round before.
+        step reads it from the ``told`` vector of the round before.  So
+        with a JOINING slot's first token: its prefill left it in that
+        vector (:func:`_hand_over`), the round is dispatched behind the
+        prefill without a fetch between them, and the host reads the
+        token with the round it retires next (or alone, where none was
+        in flight: :meth:`_settle_joins`).
         Where ``st`` lacks the key (the control plane's drain loop and
         the fleet's decode worker, which rewrite slots and meshes
         between rounds) the round is dispatched AND retired by this
@@ -657,9 +737,12 @@ class ServingEngine:
                 tokens = np.array(st["last_tokens"])
                 if flying is not None:
                     # Their tokens are on the chip, in the round before's
-                    # ``told``; every other live slot joined since (from
-                    # a prefill or a re-prefill) and the host has its.
+                    # ``told``, and so is the first token of a slot
+                    # whose prefill was dispatched since; the host has
+                    # every other live slot's (a last chunk's, a
+                    # re-prefill's).
                     tokens[flying.slots] = -1
+                tokens[[j.slot for j in st.get("joins", ())]] = -1
                 args = [self._decode_params, cache.k, cache.v,
                         jnp.asarray(tokens),
                         cache.lengths_device(), cache.table_device(),
@@ -698,7 +781,12 @@ class ServingEngine:
                 return self._retire(st, this, now)
             st["in_flight"] = this
             st["rounds_ahead"] += flying is not None
-            return 0.0 if flying is None else self._retire(st, flying, now)
+            if flying is not None:
+                return self._retire(st, flying, now)
+            # No round to read: the first tokens of the prefills this
+            # round was dispatched behind, as soon as they end.
+            self._settle_joins(st, now)
+            return 0.0
 
     def _retire(self, st: Dict[str, Any], flight: _Flight, now,
                 dropped: Sequence[int] = ()) -> float:
@@ -712,13 +800,17 @@ class ServingEngine:
         phase = _spans.recorder().phase
         with phase("decode.sample_fetch", round=flight.round):
             sampled, finite, tells = read_told(flight.told, self.slots)
-        step_s = time.monotonic() - flight.t0
-        poisoned = []
+            step_s = time.monotonic() - flight.t0
+            # The prefills dispatched behind this round end after it:
+            # the host waits here for their first tokens too, while the
+            # chip has the round dispatched since.
+            joined = self._fetch_joins(st)
         # What the step told of its round (``LayerSpec.step_tells``:
         # a routed model's touched experts) goes on the bookkeep span.
         told = {name: int(x) for name, x in zip(self.spec.step_tells,
                                                 tells)}
         with phase("decode.bookkeep", round=flight.round, **told):
+            poisoned = self._book_joins(st, joined, now)
             t_tok = now()
             for slot in flight.slots:
                 req = sched.active[slot]
@@ -735,23 +827,69 @@ class ServingEngine:
                 if req.finished or (not req.in_flight and int(
                         self.cache.lengths[slot]) >= self.max_len):
                     self._release(st, slot, now)
+        self._quarantine(st, poisoned, now)
+        return step_s
+
+    def _quarantine(self, st: Dict[str, Any], poisoned: List[int],
+                    now) -> None:
+        """Slots whose token the host has just read was sampled from
+        logits that were not finite."""
         if poisoned:
-            # The round behind this one took these slots' tokens from
-            # the poisoned round: read it first, without them.
+            # The round behind took these slots' tokens from the
+            # poisoned one (and sat them out): read it first, without
+            # them.
             self._catch_up(st, now, dropped=poisoned)
             for slot in poisoned:
-                self._quarantine_logits(st, slot, sched.active[slot])
-        return step_s
+                self._quarantine_logits(st, slot,
+                                        self.scheduler.active[slot], now)
+
+    def _fetch_joins(self, st: Dict[str, Any]) -> list:
+        """``[(join, [token, finite])]`` of the prefills whose first
+        token was left on the chip, read now: the host waits for the
+        last of them to end."""
+        joins = st.get("joins")
+        if not joins:
+            return []
+        st["joins"] = []
+        return [(join, np.asarray(join.first)) for join in joins]
+
+    def _book_joins(self, st: Dict[str, Any], joined: list,
+                    now) -> List[int]:
+        """Book the first tokens just fetched (what :meth:`_join_decode`
+        does of a token the host had at once), ahead of anything the
+        round after them brought.  Returns the slots whose first token
+        came from logits that were not finite: nothing of them is
+        booked, and the round dispatched since sat them out."""
+        poisoned = []
+        for join, (first, finite) in joined:
+            join.req.in_flight -= 1
+            if finite:
+                self._book_first(st, join.slot, join.req, int(first), now)
+            else:
+                poisoned.append(join.slot)
+        return poisoned
+
+    def _settle_joins(self, st: Dict[str, Any], now) -> None:
+        """Read and book the first tokens left on the chip where there
+        is no round to read with them."""
+        if st.get("joins"):
+            with _spans.recorder().phase("prefill.sample_fetch",
+                                         joins=len(st["joins"])):
+                joined = self._fetch_joins(st)
+            self._quarantine(st, self._book_joins(st, joined, now), now)
 
     def _catch_up(self, st: Dict[str, Any], now,
                   dropped: Sequence[int] = ()) -> None:
-        """Retire the round in flight, if there is one: when the loop
-        has nothing to dispatch, and before anything that rewrites slot
-        or mesh state."""
+        """Retire the round in flight, if there is one, and read what
+        first tokens are still on the chip: when the loop has nothing to
+        dispatch, and before anything that rewrites slot or mesh
+        state."""
         flight = st.get("in_flight")
         if flight is not None:
             st["in_flight"] = None
             self._retire(st, flight, now, dropped)
+        else:
+            self._settle_joins(st, now)
 
     def spec_round(self, st: Dict[str, Any], now) -> float:
         """One speculative round: draft k, verify k+1 wide, accept the
@@ -810,7 +948,7 @@ class ServingEngine:
                 for s in slots:
                     req = reqs[s]
                     if not finite[s]:
-                        self._quarantine_logits(st, s, req)
+                        self._quarantine_logits(st, s, req, now)
                         continue
                     # Longest agreeing prefix: draft j survives iff every
                     # earlier draft did AND it equals the target's argmax
@@ -925,8 +1063,10 @@ class ServingEngine:
             "last_tokens": np.zeros((self.slots,), np.int32),
             "adapter_ids": np.zeros((self.slots,), np.int32),
             # This loop runs one round ahead (``decode_once``): the
-            # round the chip has and the host has not read.
-            "in_flight": None, "rounds_ahead": 0}
+            # round the chip has and the host has not read, and the
+            # prefills whose first token is still on the chip.
+            "in_flight": None, "rounds_ahead": 0,
+            "joins": [], "first_tokens_deferred": 0}
         completed: List[Request] = st["completed"]
         prompts_dev: Dict[int, Any] = {}
         self._chunking.clear()
@@ -987,7 +1127,9 @@ class ServingEngine:
 
             loop_s = _file_account(rec, root, before,
                                    rounds=int(st["decode_steps"]),
-                                   prefills=int(st["prefills"]))
+                                   prefills=int(st["prefills"]),
+                                   first_tokens_deferred=int(
+                                       st["first_tokens_deferred"]))
 
         wall_s = max(time.monotonic() - start, 1e-9)
         if self._step_state:

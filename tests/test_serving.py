@@ -1097,3 +1097,227 @@ def test_3d_checkpoint_roundtrip_into_serving(base_params, tmp_path):
                               pages_per_slot=ccfg.pages_per_slot)
     got = _decode_sequence(restored, dstep, cache, tokens, t0, T)
     np.testing.assert_allclose(got, full[0, t0:T], rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# A prefill's first token stays on the chip (the look-ahead loop's join)
+# ---------------------------------------------------------------------------
+
+from horovod_tpu.serving import engine as engine_mod  # noqa: E402
+from horovod_tpu.timeline import metrics  # noqa: E402
+from benchmarks.lib.tracing import CompileCounter  # noqa: E402
+from test_serve_lookahead import SLOTS as JOIN_SLOTS  # noqa: E402
+from test_serve_lookahead import _round_by_round  # noqa: E402
+from test_spans_clock import FAMILIES  # noqa: E402
+
+ONE_TOKEN = 3                  # the rid whose first token is its last
+
+
+def _join_engine(family):
+    cfg, params = FAMILIES[family]()
+    return cfg, ServingEngine(cfg, params, slots=JOIN_SLOTS, page_size=8,
+                              max_len=32, dtype=jnp.float32)
+
+
+def _join_requests(cfg):
+    """Seven requests over three slots, all there at t = 0: the first
+    three are prefilled with no round in flight, the rest join behind a
+    round as slots come free, and ``ONE_TOKEN`` is done with its first
+    token."""
+    rng = np.random.RandomState(5)
+    lens = [5, 9, 12, 9, 5, 12, 9]
+    outs = [4, 7, 3, 1, 6, 2, 5]
+    assert outs[ONE_TOKEN] == 1
+    return [Request(rid=i, prompt=rng.randint(0, min(90, cfg.vocab_size),
+                                              size=n).astype(np.int32),
+                    max_new_tokens=o, arrival_s=0.0)
+            for i, (n, o) in enumerate(zip(lens, outs))]
+
+
+def _synchronous_join(eng, reqs):
+    """The reference: every first token fetched by the host as its
+    prefill is dispatched, every round dispatched AND read by one call
+    (the loop as it was before a join's token stayed on the chip; the
+    control plane's form of it)."""
+    st = _round_by_round(eng, reqs)
+    assert len(st["completed"]) == len(reqs)
+    return [list(r.tokens) for r in reqs]
+
+
+def _poison_prefills(eng, rids):
+    """The prefill of each request of ``rids`` returns logits that are
+    not finite, the first time it runs.  Returns the rids still to go."""
+    real, do_prefill = eng._prefill, eng._do_prefill
+    todo, current = set(rids), []
+
+    def prefill(params, toks, *rest):
+        out = real(params, toks, *rest)
+        if current[-1] in todo:
+            todo.discard(current[-1])
+            return (out[0] * jnp.nan,) + tuple(out[1:])
+        return out
+
+    def naming(slot, req, *args, **kwargs):
+        current.append(req.rid)
+        return do_prefill(slot, req, *args, **kwargs)
+
+    eng._prefill, eng._do_prefill = prefill, naming
+    return todo
+
+
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def joined(request):
+    """One family's tiny engine serving :func:`_join_requests` through
+    the look-ahead loop, on the spans' clock, beside what the
+    synchronous join serves."""
+    import time
+    cfg, ref = _join_engine(request.param)
+    want = _synchronous_join(ref, _join_requests(cfg))
+    cfg, eng = _join_engine(request.param)
+    pages = eng.cache.free_pages
+    readings = []
+
+    class SpanClock:
+        @staticmethod
+        def monotonic():
+            readings.append(time.perf_counter_ns())
+            return readings[-1] / 1e9
+
+    rec = spans.recorder()
+    rec.reset()
+    reqs = _join_requests(cfg)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(engine_mod, "time", SpanClock)
+    try:
+        report = eng.serve(reqs)
+    finally:
+        mp.undo()
+    serve, = rec.records(name="serve")
+    records = rec.records(since_ns=serve.start_ns)
+    assert report.completed == len(reqs) and eng.cache.free_pages == pages
+    assert not any(r.in_flight for r in reqs)
+    # The same traffic again, as a benchmark's window follows its
+    # warm-up: what is lowered now would compile inside the window.
+    # (Lowerings, not backend compiles: a persistent cache would hide
+    # those.)
+    lowerings = CompileCounter()
+    lowerings.EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    again = _join_requests(cfg)
+    with lowerings.counting():
+        assert eng.serve(again).completed == len(reqs)
+    relowered = lowerings.count
+    assert [r.tokens for r in again] == [r.tokens for r in reqs]
+    return dict(reqs=reqs, want=want, report=report, serve=serve,
+                records=records, zero_ns=readings[0], relowered=relowered)
+
+
+def test_a_deferred_join_serves_what_the_synchronous_join_serves(joined):
+    reqs, want = joined["reqs"], joined["want"]
+    assert [r.tokens for r in reqs] == want
+    assert [len(r.tokens) for r in reqs] == [r.max_new_tokens for r in reqs]
+    assert len(reqs[ONE_TOKEN].tokens) == 1
+    prefills = [r for r in joined["records"] if r.name == "serve.prefill"]
+    # Some joined with no round in flight and some behind one.
+    behind = [p.attrs["behind"] for p in prefills]
+    assert -1 in behind and max(behind) >= 0
+    # One program a round whoever wrote ``told`` last, the step or a
+    # join, and one hand-over whatever the prompt's length: a second
+    # call of the same traffic lowered nothing.
+    assert joined["relowered"] == 0
+
+
+def test_a_join_waits_for_nothing_before_the_next_round(joined):
+    records, reqs = joined["records"], joined["reqs"]
+    by_id = {r.id: r for r in records}
+
+    def named(name):
+        return [r for r in records if r.name == name]
+
+    prefills = named("serve.prefill")
+    assert len(prefills) == len(reqs)
+    assert all(p.attrs["deferred"] is True for p in prefills)
+    ids = {p.id for p in prefills}
+    kids = {r.name for r in records if r.parent in ids}
+    assert "prefill.hand_over" in kids and "prefill.dispatch" in kids
+    assert "prefill.sample_fetch" not in kids
+    account, = named("serve.account")
+    assert account.attrs["first_tokens_deferred"] \
+        == account.attrs["prefills"] == len(reqs)
+    rounds = named("decode.round")
+    fetches = {r.attrs["round"]: r for r in named("decode.sample_fetch")}
+    dispatches = {by_id[r.parent].attrs["round"]: r
+                  for r in named("decode.dispatch")}
+    for p in prefills:
+        after = min((r for r in rounds if r.start_ns >= p.end_ns),
+                    key=lambda r: r.start_ns, default=None)
+        if p.attrs["behind"] < 0 or after is None:
+            continue
+        # The round dispatched after the join is on its way before the
+        # host reads the round that was in flight, whose fetch is the
+        # one that waits for the join's token.
+        fetch = fetches[p.attrs["behind"]]
+        assert fetch.parent == after.id
+        assert after.start_ns <= dispatches[after.attrs["round"]].end_ns \
+            <= fetch.start_ns
+    assert any(p.attrs["behind"] >= 0 for p in prefills)
+
+
+def test_a_first_token_is_stamped_when_the_host_has_it(joined):
+    records, reqs = joined["records"], joined["reqs"]
+    zero_ns = joined["zero_ns"]
+    prefills = {r.attrs["rid"]: r for r in records
+                if r.name == "serve.prefill"}
+    fetches = {r.attrs["round"]: r for r in records
+               if r.name == "decode.sample_fetch"}
+    books = {r.attrs["round"]: r for r in records
+             if r.name == "decode.bookkeep"}
+    alone = sorted((r for r in records if r.name == "prefill.sample_fetch"),
+                   key=lambda r: r.start_ns)
+    after_alone = 0
+    for req in reqs:
+        p = prefills[req.rid]
+        stamp_ns = zero_ns + req.first_token_s * 1e9
+        assert req.first_token_s == req.token_times[0]
+        if p.attrs["behind"] >= 0:
+            # Read at the retire that follows the join: not before that
+            # fetch has returned, and not a round later.
+            n = p.attrs["behind"]
+            assert fetches[n].end_ns - 1e3 <= stamp_ns \
+                <= books[n].end_ns + 1e3
+        else:
+            fetch = next(r for r in alone if r.start_ns >= p.end_ns)
+            later = [r.start_ns for r in records
+                     if r.name == "decode.round"
+                     and r.start_ns >= fetch.end_ns]
+            assert fetch.end_ns - 1e3 <= stamp_ns
+            assert not later or stamp_ns <= min(later) + 1e3
+            after_alone += 1
+    assert after_alone >= JOIN_SLOTS
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_first_token_that_is_not_finite_is_never_served(family):
+    """A prefill whose logits are not finite: the flag travels with the
+    token, the round dispatched behind it sits the slot out, and the
+    host prefills the prompt again before it books anything."""
+    cfg, ref = _join_engine(family)
+    want = _synchronous_join(ref, _join_requests(cfg))
+    cfg, eng = _join_engine(family)
+    pages = eng.cache.free_pages
+    # One of the first turn (none in flight) and one that joins behind a
+    # round.
+    todo = _poison_prefills(eng, {1, 4})
+    counter = metrics.registry().counter(
+        "horovod_guard_serving_reprefills_total")
+    before = counter.value
+    rec = spans.recorder()
+    rec.reset()
+    reqs = _join_requests(cfg)
+    report = eng.serve(reqs)
+    assert not todo and counter.value - before == 2
+    assert report.completed == len(reqs) and eng.cache.free_pages == pages
+    assert [r.tokens for r in reqs] == want
+    assert not any(r.in_flight for r in reqs)
+    again = [p for p in rec.records(name="serve.prefill")
+             if not p.attrs["deferred"]]
+    assert sorted(p.attrs["rid"] for p in again) == [1, 4]
