@@ -69,7 +69,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import cca_decode_attention, flash_attention
 from ..ops.moe import moe_ffn, route_top1
 from . import stepparts
-from .decode import ServingDecodeStep, _dense, _rmsnorm
+from .decode import ServingDecodeStep, _dense, _rmsnorm, one_trace
 from .layerspec import LayerSpec
 from .stepparts import dense_out as _dense_out
 
@@ -391,9 +391,8 @@ def prefill_forward(params, config: CcaMoeConfig, tokens, positions=None,
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
     x = stepparts.embed(p, tokens)
     r = jnp.zeros((b * t, cfg.router_hidden), jnp.float32)
-    rows, state = [], []
-    for li in range(cfg.num_layers):
-        blk = p[f"layer_{li}"]
+    @one_trace
+    def layer(x, r, blk, positions):
         attn = blk["attn"]
         h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
         u = jnp.concatenate([_dense(h, attn["wq"], dtype),
@@ -404,10 +403,8 @@ def prefill_forward(params, config: CcaMoeConfig, tokens, positions=None,
         v = jnp.concatenate([_dense(h, attn["wv1"], dtype), _shift(v2)],
                             axis=-1)
         k = k.astype(dtype)
-        rows.append(jnp.concatenate([k.reshape(b, t, cfg.kv_width), v],
-                                    axis=-1))
-        state.append(jnp.concatenate([u[:, -1], a[:, -1], v2[:, -1]],
-                                     axis=-1))
+        row = jnp.concatenate([k.reshape(b, t, cfg.kv_width), v], axis=-1)
+        last = jnp.concatenate([u[:, -1], a[:, -1], v2[:, -1]], axis=-1)
         o = flash_attention(
             q.astype(dtype).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim).transpose(
@@ -416,7 +413,14 @@ def prefill_forward(params, config: CcaMoeConfig, tokens, positions=None,
         o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_width)
         x = _scaled(x, blk["attn_alpha"]) + _dense_out(o, attn["wo"], dtype)
         y, r, _ = _experts(x.reshape(b * t, -1), r, blk, cfg, dtype)
-        x = _scaled(x, blk["moe_alpha"]) + y.reshape(b, t, -1)
+        return _scaled(x, blk["moe_alpha"]) + y.reshape(b, t, -1), r, row, \
+            last
+
+    rows, state = [], []
+    for li in range(cfg.num_layers):
+        x, r, row, last = layer(x, r, p[f"layer_{li}"], positions)
+        rows.append(row)
+        state.append(last)
     if last_only:
         x = x[:, -1:]
     return (stepparts.readout(x, p, cfg.rms_eps, dtype, tied=True),

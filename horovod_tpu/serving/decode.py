@@ -111,6 +111,19 @@ def _dense(x, node, dtype, *, lora_select=None, lora_alpha=16.0):
     return y
 
 
+def one_trace(layer):
+    """``layer``, the body of one layer of a prefill, as the Python loop
+    over the layers calls it: jitted, so that the layers of one shape
+    are traced ONCE and lowered to one function of the program they are
+    part of.  Unrolled, a body is traced and lowered again for every
+    layer, at every start-up, compile cache or not (three of the four
+    seconds a 48-layer prefill program of one more shape cost a warm
+    start); XLA inlines the calls, so the compiled program is the
+    unrolled one's.  What varies from layer to layer (its weights, a
+    past, its adapters) goes in as arguments."""
+    return jax.jit(layer)
+
+
 def _rmsnorm(x, scale, dtype, epsilon: float = 1e-5):
     x32 = x.astype(jnp.float32)
     norm = x32 * jax.lax.rsqrt(
@@ -176,9 +189,6 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
     emb = p["tok_embed"]
     x = emb[tokens].astype(dtype)
 
-    def select(a, bnk):
-        return a[adapter_id], bnk[adapter_id]
-
     ad = (adapters["params"] if adapters is not None and
           "params" in adapters else adapters)
     ks, vs = [], []
@@ -193,13 +203,14 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
         return heads.transpose(0, 2, 1, 3).reshape(
             heads.shape[0], heads.shape[2], -1)
 
-    for li in range(cfg.num_layers):
-        blk = p[f"layer_{li}"]
-        abk = None if ad is None else ad.get(f"layer_{li}")
+    @one_trace
+    def layer(x, blk, abk, past, positions, segment_ids, adapter_id):
+        def select(a, bnk):
+            return a[adapter_id], bnk[adapter_id]
 
-        def lora(group, name, _blk=blk, _abk=abk):
-            node = _blk[group][name]
-            anode = None if _abk is None else _abk.get(group, {}).get(name)
+        def lora(group, name):
+            node = blk[group][name]
+            anode = None if abk is None else abk.get(group, {}).get(name)
             return _node_lora(node, anode, select)
 
         h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype)
@@ -224,18 +235,16 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
             # within-chunk triangle.  past k/v arrive as cache rows
             # [b, t_past, H * D]: the FULL context (past ++ chunk) goes
             # back the same way, so chunk callers chain by replacement.
-            k_rows = jnp.concatenate(
-                [past[0][li].astype(k.dtype), k_rows], axis=1)
-            v_rows = jnp.concatenate(
-                [past[1][li].astype(v.dtype), v_rows], axis=1)
+            k_rows = jnp.concatenate([past[0].astype(k.dtype), k_rows],
+                                     axis=1)
+            v_rows = jnp.concatenate([past[1].astype(v.dtype), v_rows],
+                                     axis=1)
             k, v = heads_of(k_rows), heads_of(v_rows)
         o = flash_attention(q, k, v, causal=True,
                             segment_ids=segment_ids)
         o = o.transpose(0, 2, 1, 3).reshape(b, t, -1)
         x = x + _dense(o, attn["wo"], dtype, lora_select=lora("attn", "wo"),
                        lora_alpha=lora_alpha)
-        ks.append(k_rows)
-        vs.append(v_rows)
 
         h = _rmsnorm(x, blk["mlp_norm"]["scale"], dtype)
         mlp = blk["mlp"]
@@ -248,6 +257,15 @@ def prefill_forward(params, config: LlamaConfig, tokens, positions=None,
         x = x + _dense(jax.nn.silu(gate) * up, mlp["w_down"], dtype,
                        lora_select=lora("mlp", "w_down"),
                        lora_alpha=lora_alpha)
+        return x, k_rows, v_rows
+
+    for li in range(cfg.num_layers):
+        x, k_rows, v_rows = layer(
+            x, p[f"layer_{li}"], None if ad is None else ad.get(f"layer_{li}"),
+            None if past is None else (past[0][li], past[1][li]),
+            positions, segment_ids, adapter_id)
+        ks.append(k_rows)
+        vs.append(v_rows)
 
     x = _rmsnorm(x, p["final_norm"]["scale"], dtype)
     logits = x.astype(jnp.float32) @ emb.astype(jnp.float32).T

@@ -59,6 +59,7 @@ from .layerspec import layer_spec
 from .scheduler import (ContinuousBatchScheduler, Request,
                         parse_tenant_classes)
 from .spec import NgramDrafter
+from .swa_moe import PREFILL_TOKENS
 
 
 class _Stop:
@@ -181,27 +182,76 @@ class _Flight:
 @dataclasses.dataclass
 class _Join:
     """A prefill the chip has been handed whose first token the host has
-    not read yet: its slot and request, and ``[token, finite]`` on the
-    device (:func:`_hand_over`), its copy to the host started."""
+    not read yet: its slot and request, and the ``[token, finite]``
+    pairs of its prefill's group on the device (:func:`_hand_over`),
+    their copy to the host started; ``row``: which pair is its own."""
 
     slot: int
     req: Request
     first: Any
+    row: int = 0
 
 
-def _hand_over(told, row, slot, *, slots: int):
-    """A prefill's first token, left on the chip.  ``row``: the float32
-    logits ``[1, vocab]`` of the prompt's last position.  The greedy
-    token and the row's finite flag (as :func:`decode.tell_round` gives
-    them of a round) go into ``slot``'s two places of ``told``, the
-    vector the next round reads as its ``prev``: the host gives that
-    round ``-1`` for the slot, as for one that continues.  Returns the
-    patched vector and the pair alone, the first token's own way back to
-    the host (the next round's ``told`` holds the slot's SECOND token)."""
+# The joins of one length that go through ONE prefill program.
+GROUP = 4
+
+
+def group_size(prompt_len: int, max_rows: int) -> int:
+    """How many joins of ``prompt_len`` tokens go through one prefill
+    program: ``GROUP`` where its rows, ``GROUP * prompt_len``, are
+    within ``max_rows``, else 1.  One size, and not a pair's beside it:
+    every further size is a further program to trace, lower and load at
+    every start-up (two to four seconds each on the chip's host)."""
+    return GROUP if GROUP * prompt_len <= max_rows else 1
+
+
+def group_joins(keys: Sequence[Any], max_rows: int) -> List[List[int]]:
+    """The joins of ONE admission, grouped for their prefills: lists of
+    indices into ``keys``, each list one prefill program.  ``keys[i]``:
+    ``(prompt_len, ...)`` of a plain join, which may share a program
+    with the joins of the same key, or None for one that goes alone (a
+    prefix hit, a chunked prompt).  The joins of a key are taken
+    :func:`group_size` at a time; what is left over, fewer than that,
+    goes alone (a join alone is bound by nothing here).  The groups come
+    in the order in which their first members were admitted."""
+    buckets: Dict[Any, List[int]] = {}
+    groups = []
+    for i, key in enumerate(keys):
+        if key is None:
+            groups.append([i])
+        else:
+            buckets.setdefault(key, []).append(i)
+    for key, members in buckets.items():
+        size = group_size(key[0], max_rows)
+        whole = len(members) - len(members) % size
+        groups += [members[i:i + size] for i in range(0, whole, size)]
+        groups += [[i] for i in members[whole:]]
+    return sorted(groups)
+
+
+def _members(out):
+    """What a prefill of ``b`` prompts hands back beside its logits
+    (arrays ``[planes, b, ...]``), a member: ``b`` trees of ``[planes,
+    ...]``."""
+    b = jax.tree.leaves(out)[0].shape[1]
+    return [jax.tree.map(lambda x: x[:, i], out) for i in range(b)]
+
+
+def _hand_over(told, rows, slot, *, slots: int):
+    """A group's first tokens, left on the chip.  ``rows``: the float32
+    logits ``[b, vocab]`` of the prompts' last positions; ``slot``:
+    their ``b`` slots.  A row's greedy token and its finite flag (as
+    :func:`decode.tell_round` gives them of a round) go into its slot's
+    two places of ``told``, the vector the next round reads as its
+    ``prev``: the host gives that round ``-1`` for the slot, as for one
+    that continues.  Returns the patched vector and the pairs alone
+    (``[b, 2]``), the first tokens' own way back to the host (the next
+    round's ``told`` holds a slot's SECOND token)."""
     first = jnp.stack([
-        greedy_sample(row)[0],
-        jnp.isfinite(jnp.sum(row)).astype(jnp.int32)])
-    return told.at[slot].set(first[0]).at[slots + slot].set(first[1]), first
+        greedy_sample(rows),
+        jnp.isfinite(jnp.sum(rows, axis=-1)).astype(jnp.int32)], axis=-1)
+    return (told.at[slot].set(first[:, 0])
+            .at[slots + slot].set(first[:, 1])), first
 
 
 @jax.jit
@@ -364,8 +414,21 @@ class ServingEngine:
         def _prefill_chunk(p, toks, past):
             return spec.prefill(p, toks, dtype=dtype, past=past)
 
+        def _prefill_group(p, toks, ad, aid):
+            # What the prompts hand back leaves the program a member:
+            # nothing holds the rows of all of them a second time.
+            logits, *out = _prefill(p, toks, ad, aid)
+            return logits, _members(out)
+
+        # A trace names a program by its function: a group's is a
+        # prefill program like one prompt's (``jit__prefill``).
+        _prefill_group.__name__ = _prefill.__name__
         self._prefill = jax.jit(_prefill)
+        self._prefill_group = jax.jit(_prefill_group)
         self._prefill_chunked = jax.jit(_prefill_chunk)
+        # The prompt lengths whose group program is compiled
+        # (:meth:`_prepare_group`).
+        self._group_ready: set = set()
         # In-progress chunked prefills: slot -> dict(req, prompt, pos,
         # past).  Slots in here are state "prefill" and excluded from
         # the decode batch until their last chunk lands.
@@ -413,16 +476,59 @@ class ServingEngine:
     def _fresh_step_state(self) -> tuple:
         return tuple(self._whole(x) for x in self.spec.step_state())
 
-    # -- one-request helpers ----------------------------------------------
-    def _begin_prefill(self, st: Dict[str, Any], slot: int, req: Request,
-                       dev, now) -> None:
-        """Admit one request into its slot: radix-match the prompt
-        against the prefix cache (attach matched pages, no compute),
-        then prefill the remaining tail -- chunked when it is long.
+    # -- one admission's prefills ------------------------------------------
+    @property
+    def group_rows(self) -> int:
+        """The rows ``b * t`` a group of joins may have: no more than
+        one prompt the engine must be able to take (``max_len``), nor
+        than the rows a layer's per-token work was sized for
+        (``swa_moe.PREFILL_TOKENS``)."""
+        return min(self.max_len, PREFILL_TOKENS)
+
+    def _join_key(self, req: Request):
+        """What the joins that may share a prefill program with ``req``
+        have in common (:func:`group_joins`), None where it goes alone:
+        under a prefix cache (a join's pages enter the tree as it is
+        prefilled and the next join of the same admission may hit them)
+        and where its prompt is chunked."""
+        if self._prefix is not None \
+                or 0 < self.prefill_chunk < req.prompt_len:
+            return None
+        return (req.prompt_len,
+                req.adapter_id if self.adapters is not None else 0)
+
+    def _prepare_group(self, prompt_len: int) -> None:
+        """Compile what a group of prompts of ``prompt_len`` tokens runs
+        beyond what one prompt alone does, in one throwaway call: the
+        prefill over ``[b, prompt_len]`` and the hand-over of ``b``
+        first tokens (the pool writes are one prompt's, member by
+        member).  Done the first time the serve loop prefills the
+        length, so that a group never compiles when it forms: a warm-up
+        of one request a length prepares every length's group."""
+        self._group_ready.add(prompt_len)
+        b = group_size(prompt_len, self.group_rows)
+        if 1 < b <= self.slots:
+            logits, _ = self._prefill_group(
+                self.params, jnp.zeros((b, prompt_len), jnp.int32),
+                self.adapters,
+                None if self.adapters is None else jnp.int32(0))
+            self._hand_over(self._told, logits[:, -1, :],
+                            np.zeros((b,), np.int32))
+
+    def _begin_prefill(self, st: Dict[str, Any], members: Sequence[tuple],
+                       now) -> None:
+        """Take up one prefill program's joins, ``(slot, request,
+        prompt on the device)`` each: one join, or a group of plain
+        joins of one length (:func:`group_joins`).  One join alone:
+        radix-match the prompt against the prefix cache (attach matched
+        pages, no compute), then prefill the remaining tail -- chunked
+        when it is long.
         """
+        (slot, req, dev), *others = members
         matched, entries = 0, ()
-        req.prefill_start_s = now()
-        st["prefills"] += 1
+        for _, r, _ in members:
+            r.prefill_start_s = now()
+        st["prefills"] += len(members)
         if self._prefix is not None:
             matched, entries = self._prefix.match(req.prompt)
             st["prefix_queries"] += 1
@@ -442,38 +548,58 @@ class ServingEngine:
             self._chunking[slot] = {
                 "req": req, "dev": dev, "pos": matched,
                 "start": matched, "past": past}
-        else:
-            ahead = "in_flight" in st
-            flight = st.get("in_flight")
-            first = self._do_prefill(
-                slot, req, dev, matched=matched, entries=entries,
-                behind=-1 if flight is None else flight.round, defer=ahead)
-            if ahead:
-                # The look-ahead loop: the token stays on the chip and
-                # the slot is live for the next round by count (its
-                # first token is in flight); the host reads it where it
-                # next waits for the chip (:meth:`_retire`,
-                # :meth:`_settle_joins`).
+            return
+        ahead = "in_flight" in st
+        flight = st.get("in_flight")
+        if ahead and self._join_key(req) is not None \
+                and req.prompt_len not in self._group_ready:
+            self._prepare_group(req.prompt_len)
+        first = self._do_prefill(
+            slot, req, dev, matched=matched, entries=entries,
+            behind=-1 if flight is None else flight.round, defer=ahead,
+            others=others)
+        st["prefill_groups"] += 1
+        if others:
+            st["prefills_grouped"] += len(members)
+        if ahead:
+            # The look-ahead loop: the tokens stay on the chip and
+            # the slots are live for the next round by count (their
+            # first tokens are in flight); the host reads them where
+            # it next waits for the chip (:meth:`_retire`,
+            # :meth:`_settle_joins`).
+            for row, (slot, req, _) in enumerate(members):
                 req.state = "decode"
                 req.in_flight = 1
-                st["joins"].append(_Join(slot, req, first))
+                st["joins"].append(_Join(slot, req, first, row))
                 st["first_tokens_deferred"] += 1
                 self._note_resident(st, slot, req)
-            else:
-                self._join_decode(st, slot, req, first, now)
+        else:
+            self._join_decode(st, slot, req, first, now)
 
     def _do_prefill(self, slot: int, req: Request, prompt_dev,
                     matched: int = 0, entries: Sequence = (),
-                    behind: int = -1, defer: bool = False):
-        """Dispatch one prompt's prefill, its pool write and its slot
-        state's, and sample its first token.  ``behind``: the number of
-        the decode round in flight while this prefill is dispatched (it
-        queues behind it on the chip), -1 where there is none.
-        ``defer``: leave the token on the chip, in the slot's place of
-        the vector the next round reads (:func:`_hand_over`), and return
-        its ``[token, finite]`` pair on the device without waiting for
-        anything; else fetch it and return it as an int."""
+                    behind: int = -1, defer: bool = False,
+                    others: Sequence[tuple] = ()):
+        """Dispatch one prefill program, its pool writes and its slot
+        states', and sample its first tokens.  ``others``: the ``(slot,
+        request, prompt)`` of the further members of a GROUP, plain
+        joins of ``req``'s length that go through the program with it as
+        ``tokens[b, t]`` (the weights are read once a group); each
+        member's rows then go to its own slot as one prompt's do.
+        ``behind``: the number of the decode round in flight while this
+        prefill is dispatched (it queues behind it on the chip), -1
+        where there is none.  ``defer``: leave the tokens on the chip,
+        in the slots' places of the vector the next round reads
+        (:func:`_hand_over`), and return their ``[token, finite]``
+        pairs on the device, a row a member, without waiting for
+        anything; else fetch ``req``'s and return it as an int (one
+        prompt alone)."""
         rec = _spans.recorder()
+        members = [(slot, req, prompt_dev), *others]
+        slots = [m[0] for m in members]
+        if others and (matched or not defer):
+            raise ValueError(
+                "a group takes plain joins whose tokens stay on the chip")
         # With a window group: the rows a window plane is written, the
         # prompt's last ones.
         windowed = {} if self.spec.window is None else {
@@ -484,9 +610,10 @@ class ServingEngine:
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
                       planes=self.spec.planes, behind=behind,
-                      deferred=defer, **windowed):
+                      deferred=defer, group=len(members),
+                      rids=tuple(m[1].rid for m in members),
+                      slots=tuple(slots), **windowed):
             with rec.phase("prefill.dispatch", rid=req.rid):
-                state = []
                 if matched:
                     # Prefix hit: only the tail goes through the forward
                     # pass, conditioned on the cached pages as past K/V
@@ -494,31 +621,44 @@ class ServingEngine:
                     past = self.cache.gather_pages(entries)
                     logits, kl, vl = self._prefill_chunked(
                         self.params, prompt_dev[matched:][None], past)
-                    kl, vl = kl[:, 0, matched:], vl[:, 0, matched:]
+                    rows = [(kl[:, 0, matched:], vl[:, 0, matched:])]
                 else:
                     aid = jnp.int32(req.adapter_id) \
                         if self.adapters is not None else None
-                    logits, kl, vl, *state = self._prefill(
-                        self.params, prompt_dev[None], self.adapters, aid)
-                    kl = kl[:, 0]
-                    vl = None if vl is None else vl[:, 0]
-            with rec.phase("prefill.write_kv", rid=req.rid):
-                self.cache.write_prefill(
-                    slot, kl, vl, start=matched,
-                    window_rows=self._window_rows(state))
-            if self.spec.slot_state is not None:
-                # What the slot keeps beside its pages: the prompt's
-                # trailing rows (a hit or a chunk would need them of the
-                # prefix's last token, and is refused by the spec).
-                rows = state[0][:, 0]
-                with rec.phase("prefill.write_state", rid=req.rid,
-                               state_bytes=rows.size
-                               * self.cache.state.dtype.itemsize):
-                    self.cache.write_state(slot, rows)
+                    # The prompts of a group go up from the host as one
+                    # array (stacking them on the device would be a
+                    # program a size).
+                    if others:
+                        logits, rows = self._prefill_group(
+                            self.params, jnp.asarray(np.stack([
+                                np.asarray(m[1].prompt, np.int32)
+                                for m in members])), self.adapters, aid)
+                    else:
+                        # One prompt alone: the program and the slices
+                        # it always took.
+                        logits, *out = self._prefill(
+                            self.params, prompt_dev[None], self.adapters,
+                            aid)
+                        rows = _members(out)
+            for (slot_i, req_i, _), (kl, vl, *state) in zip(members, rows):
+                with rec.phase("prefill.write_kv", rid=req_i.rid):
+                    self.cache.write_prefill(
+                        slot_i, kl, vl, start=matched,
+                        window_rows=self._window_rows(state))
+                if self.spec.slot_state is not None:
+                    # What the slot keeps beside its pages: the prompt's
+                    # trailing rows (a hit or a chunk would need them of
+                    # the prefix's last token, and is refused by the
+                    # spec).
+                    with rec.phase("prefill.write_state", rid=req_i.rid,
+                                   state_bytes=state[0].size
+                                   * self.cache.state.dtype.itemsize):
+                        self.cache.write_state(slot_i, state[0])
             if defer:
                 with rec.phase("prefill.hand_over", rid=req.rid):
                     self._told, first = self._hand_over(
-                        self._told, logits[:, -1, :], np.int32(slot))
+                        self._told, logits[:, -1, :],
+                        np.asarray(slots, np.int32))
                     first.copy_to_host_async()
                 return first
             with rec.phase("prefill.sample_fetch", rid=req.rid):
@@ -526,12 +666,12 @@ class ServingEngine:
         return first
 
     def _window_rows(self, beyond: list):
-        """The window planes' rows of ONE prompt out of what a prefill
-        returned beyond its two planes (``LayerSpec.attn_kinds``: the
+        """The window planes' rows of ONE prompt out of what its prefill
+        handed back beyond its two planes (``LayerSpec.attn_kinds``: the
         last of them; popped), None for a model without window layers."""
         if self.spec.window is None:
             return None
-        return tuple(rows[:, 0] for rows in beyond.pop())
+        return tuple(beyond.pop())
 
     def _advance_chunks(self, st: Dict[str, Any], now) -> None:
         """Push each in-progress chunked prefill forward by ONE chunk.
@@ -851,7 +991,7 @@ class ServingEngine:
         if not joins:
             return []
         st["joins"] = []
-        return [(join, np.asarray(join.first)) for join in joins]
+        return [(join, np.asarray(join.first)[join.row]) for join in joins]
 
     def _book_joins(self, st: Dict[str, Any], joined: list,
                     now) -> List[int]:
@@ -1023,12 +1163,12 @@ class ServingEngine:
                                     leg="serving_reprefill"):
             aid = jnp.int32(req.adapter_id) if self.adapters is not None \
                 else None
-            _, kl, vl, *state = self._prefill(
+            _, *out = self._prefill(
                 self.params, jnp.asarray(full)[None], self.adapters, aid)
+            (kl, vl, *state), = _members(out)
             window_rows = self._window_rows(state)
             self.cache.write_prefill(
-                slot, kl[:, 0], None if vl is None else vl[:, 0],
-                state=state[0][:, 0] if state else None,
+                slot, kl, vl, state=state[0] if state else None,
                 window_rows=window_rows)
         if self.drafter is not None:
             self.drafter.re_prefill(slot, req)
@@ -1060,6 +1200,9 @@ class ServingEngine:
             "prefix_queries": 0, "prefix_hits": 0,
             "prefill_cached": 0, "prefill_computed": 0,
             "session_resumes": 0, "prefills": 0,
+            # ``serve.prefill`` programs dispatched for them, and the
+            # prompts among them that shared one.
+            "prefill_groups": 0, "prefills_grouped": 0,
             "last_tokens": np.zeros((self.slots,), np.int32),
             "adapter_ids": np.zeros((self.slots,), np.int32),
             # This loop runs one round ahead (``decode_once``): the
@@ -1104,9 +1247,14 @@ class ServingEngine:
 
                 with phase("serve.admit"):
                     admitted = sched.admit(now())
-                for slot, req in admitted:
-                    dev = prompts_dev.pop(req.rid)
-                    self._begin_prefill(st, slot, req, dev, now)
+                # The plain joins of one length that this turn admitted
+                # go through one prefill program, up to four of them.
+                joins = [(slot, req, prompts_dev.pop(req.rid))
+                         for slot, req in admitted]
+                for group in group_joins(
+                        [self._join_key(req) for _, req, _ in joins],
+                        self.group_rows):
+                    self._begin_prefill(st, [joins[i] for i in group], now)
 
                 if self._chunking:
                     with phase("serve.chunks"):
@@ -1128,6 +1276,9 @@ class ServingEngine:
             loop_s = _file_account(rec, root, before,
                                    rounds=int(st["decode_steps"]),
                                    prefills=int(st["prefills"]),
+                                   prefill_groups=int(st["prefill_groups"]),
+                                   prefills_grouped=int(
+                                       st["prefills_grouped"]),
                                    first_tokens_deferred=int(
                                        st["first_tokens_deferred"]))
 

@@ -60,7 +60,7 @@ from jax.sharding import PartitionSpec as P
 from ..models.transformer import rotary_embedding
 from ..ops.attention import cca_decode_attention, flash_attention
 from . import stepparts
-from .decode import ServingDecodeStep, _dense, _rmsnorm
+from .decode import ServingDecodeStep, _dense, _rmsnorm, one_trace
 from .layerspec import LayerSpec
 from .stepparts import dense_out as _dense_out
 
@@ -284,26 +284,30 @@ def prefill_forward(params, config: LoopDenseConfig, tokens, positions=None,
     def heads(z, n):
         return z.reshape(b, t, n, d).transpose(0, 2, 1, 3)
 
+    @one_trace
+    def layer(x, blk, positions):
+        attn = blk["attn"]
+        h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
+        q = rotary_embedding(heads(_dense(h, attn["wq"], dtype), h_q),
+                             positions, cfg.rope_theta)
+        k = rotary_embedding(heads(_dense(h, attn["wk"], dtype), h_kv),
+                             positions, cfg.rope_theta)
+        v = _dense(h, attn["wv"], dtype)
+        row = jnp.concatenate(
+            [k.transpose(0, 2, 1, 3).reshape(b, t, cfg.kv_width), v],
+            axis=-1)
+        o = flash_attention(q, k, heads(v, h_kv), causal=True,
+                            scale=d ** -0.5)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_width)
+        a = x + _post(_dense_out(o, attn["wo"], dtype), blk,
+                      "post_attn_norm", cfg)
+        return _mlp(a, blk, cfg, dtype), row
+
     def one_pass(x, _):
         rows = []
         for li in range(cfg.num_layers):
-            blk = p[f"layer_{li}"]
-            attn = blk["attn"]
-            h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
-            q = rotary_embedding(heads(_dense(h, attn["wq"], dtype), h_q),
-                                 positions, cfg.rope_theta)
-            k = rotary_embedding(heads(_dense(h, attn["wk"], dtype), h_kv),
-                                 positions, cfg.rope_theta)
-            v = _dense(h, attn["wv"], dtype)
-            rows.append(jnp.concatenate(
-                [k.transpose(0, 2, 1, 3).reshape(b, t, cfg.kv_width), v],
-                axis=-1))
-            o = flash_attention(q, k, heads(v, h_kv), causal=True,
-                                scale=d ** -0.5)
-            o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.q_width)
-            a = x + _post(_dense_out(o, attn["wo"], dtype), blk,
-                          "post_attn_norm", cfg)
-            x = _mlp(a, blk, cfg, dtype)
+            x, row = layer(x, p[f"layer_{li}"], positions)
+            rows.append(row)
         x, leave = after_pass(x, p, cfg)
         return x, (jnp.stack(rows), leave)
 

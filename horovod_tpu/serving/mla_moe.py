@@ -50,7 +50,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import flash_attention, mla_decode_attention
 from ..ops import moe as _moe
 from . import stepparts
-from .decode import ServingDecodeStep, _dense, _rmsnorm
+from .decode import ServingDecodeStep, _dense, _rmsnorm, one_trace
 from .layerspec import LayerSpec
 from .stepparts import dense_out as _dense_out, lane_pad as _lane_pad
 
@@ -307,14 +307,15 @@ def prefill_forward(params, config: MlaMoeConfig, tokens, positions=None,
     dn, dv, r = cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     dr, heads = cfg.qk_rope_head_dim, cfg.num_heads
     x = p["tok_embed"][tokens].astype(jnp.float32)
-    rows = []
-    for li in range(cfg.num_layers):
-        blk = p[f"layer_{li}"]
+    # A layer of each kind of feed-forward, to ask ``cfg`` about the kind.
+    of_kind = {cfg.is_moe(li): li for li in reversed(range(cfg.num_layers))}
+
+    @one_trace
+    def layer(x, blk, positions):
         attn = blk["attn"]
         h = _rmsnorm(x, blk["attn_norm"]["scale"], dtype, cfg.rms_eps)
         q = _queries(h, attn, cfg, dtype)
         row = _latents(h, attn, cfg, positions, dtype)
-        rows.append(row)
         c, k_pe = row[..., :r], row[..., r:r + dr]
         q = jnp.concatenate(
             [q[..., :dn], _rope_interleaved(q[..., dn:],
@@ -334,8 +335,14 @@ def prefill_forward(params, config: MlaMoeConfig, tokens, positions=None,
             v.transpose(0, 2, 1, 3), causal=True, scale=cfg.softmax_scale)
         o = o[..., :dv].transpose(0, 2, 1, 3).reshape(b, t, heads * dv)
         x = x + _dense_out(o, attn["wo"], dtype)
-        y, _ = _ffn(x.reshape(b * t, -1), blk, cfg, li, dtype)
-        x = x + y.reshape(b, t, -1)
+        y, _ = _ffn(x.reshape(b * t, -1), blk, cfg, of_kind["moe" in blk],
+                    dtype)
+        return x + y.reshape(b, t, -1), row
+
+    rows = []
+    for li in range(cfg.num_layers):
+        x, row = layer(x, p[f"layer_{li}"], positions)
+        rows.append(row)
     if last_only:
         x = x[:, -1:]
     return (stepparts.readout(x, p, cfg.rms_eps, dtype, tied=False),

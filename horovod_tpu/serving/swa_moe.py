@@ -92,6 +92,7 @@ multi-token-prediction block is not loaded and not served.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -103,7 +104,7 @@ from ..ops import moe as _moe
 from ..ops.attention import cca_decode_attention, flash_attention
 from . import stepparts
 from .cca_moe import _rope_partial
-from .decode import ServingDecodeStep, _dense, _rmsnorm
+from .decode import ServingDecodeStep, _dense, _rmsnorm, one_trace
 from .kvcache import window_rows_from
 from .layerspec import LayerSpec
 from .mla_moe import _swiglu
@@ -453,13 +454,14 @@ def prefill_forward(params, config: SwaMoeConfig, tokens, positions=None,
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
     kept = window_rows_from(t, cfg.window)
     x = stepparts.embed(p, tokens)
-    rows = {"full": ([], []), "window": ([], [])}
-    for li, kind in enumerate(cfg.attn_kinds):
-        blk = p[f"layer_{li}"]
-        banded = kind == "window"
+    def heads(z):
+        return z.reshape(b, t, cfg.num_kv_heads,
+                         cfg.head_dim).transpose(0, 2, 1, 3)
+
+    def layer(x, blk, positions, *, banded):
         early = cfg.route_from == "layer_input" and "moe" in blk
 
-        def before(x, positions, blk=blk, banded=banded, early=early):
+        def before(x, positions):
             # A router that reads the layer's input: with the rest of the
             # rows' own work ahead of attention, a chunk at a time.
             routed = tuple(z.reshape(*x.shape[:2], -1) for z in _route(
@@ -469,7 +471,7 @@ def prefill_forward(params, config: SwaMoeConfig, tokens, positions=None,
                            rotate=banded)
             return (q, k.reshape(*k.shape[:2], cfg.kv_width), v) + routed
 
-        def after(x, o, *routed, blk=blk):
+        def after(x, o, *routed):
             x = x + _dense_out(o, blk["attn"]["wo"], dtype)
             routing = _moe.Routing(*(z.reshape(-1, z.shape[-1])
                                      for z in routed)) if routed else None
@@ -478,20 +480,23 @@ def prefill_forward(params, config: SwaMoeConfig, tokens, positions=None,
             return x + y.reshape(x.shape)
 
         q, k, v, *routed = _by_chunks(before, x, positions)
-        first = kept if banded else 0
-        rows[kind][0].append(k[:, first:])
-        rows[kind][1].append(v[:, first:])
-
-        def heads(z):
-            return z.reshape(b, t, cfg.num_kv_heads,
-                             cfg.head_dim).transpose(0, 2, 1, 3)
-
         o = flash_attention(
             q.transpose(0, 2, 1, 3), heads(k), heads(v), causal=True,
             scale=cfg.head_dim ** -0.5,
             window=cfg.window if banded else None)
         x = _by_chunks(after, x, o.transpose(0, 2, 1, 3).reshape(b, t, -1),
                        *routed)
+        first = kept if banded else 0
+        return x, k[:, first:], v[:, first:]
+
+    layers = {kind: one_trace(functools.partial(layer,
+                                                banded=kind == "window"))
+              for kind in ("full", "window")}
+    rows = {"full": ([], []), "window": ([], [])}
+    for li, kind in enumerate(cfg.attn_kinds):
+        x, k, v = layers[kind](x, p[f"layer_{li}"], positions)
+        rows[kind][0].append(k)
+        rows[kind][1].append(v)
     if last_only:
         x = x[:, -1:]
     out = (stepparts.readout(x, p, cfg.rms_eps, dtype, tied=False),

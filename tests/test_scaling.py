@@ -238,15 +238,18 @@ def test_topology_aot_swa_step_fits_beside_its_cache_at_a_ragged_prompt():
     all four pools to their successors, and a prompt of 6,000 tokens --
     no multiple of the 2,048 a layer's per-token work runs at a time --
     is chunked all the same (two chunks and a rest of 1,904: two
-    instances of the layers' 14 expert matmuls) and fits beside the
-    weights and both groups of pools.  Whole, the float32 intermediates
-    of an 8,192-token prompt were 4.6 GB of temporaries and did not."""
+    instances of a routed layer's two expert matmuls, in the ONE lowered
+    function each of the two kinds of routed layer has since PR 45,
+    window and full: the seven routed layers call them) and fits beside
+    the weights and both groups of pools.  Whole, the float32
+    intermediates of an 8,192-token prompt were 4.6 GB of temporaries and
+    did not."""
     out = _topology_worker("v5e:2x2", "swa_step", "32", "6000")
     step, pre = out["decode"], out["prefill_6000"]
     assert set(step["pool_params"]) <= set(step["aliased_params"])
     assert step["mosaic_calls"]["hvd_swa_decode"] == 1
     assert step["mosaic_calls"]["hvd_cca_decode"] == 1
-    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 14
+    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 2 * 2
     assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
     assert pre["temp_bytes"] < 1.3e9
     assert pre["resident_with_cache"] < 16 * 2 ** 30
@@ -258,7 +261,9 @@ def test_topology_aot_small_step_fits_beside_its_cache():
     four pools (5.65 GB, the window group's 3.23 of it) to their
     successors and holds ONE walk function of each name for its 28 query
     heads over 4 and 16 expert matmuls with ReLU gates; an 8,192-token
-    prompt, in four chunks, fits beside 7.93 GB of weights and the
+    prompt, in four chunks (one instance of the two expert matmuls in
+    the lowered function of each kind of layer, window and full, which
+    the eight layers call), fits beside 7.93 GB of weights and the
     cache."""
     out = _topology_worker("v5e:2x2", "small_step", "64", "8192")
     assert out["weight_bytes"] == 7_933_875_200
@@ -269,7 +274,7 @@ def test_topology_aot_small_step_fits_beside_its_cache():
     assert step["mosaic_calls"]["hvd_cca_decode"] == 1
     assert step["mosaic_calls"]["hvd_moe_gmm"] == 2 * 8
     assert step["temp_bytes"] < 0.1e9
-    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 8
+    assert pre["mosaic_calls"]["hvd_moe_gmm"] == 2 * 2
     assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
     assert pre["temp_bytes"] < 0.8e9
     assert pre["resident_with_cache"] < 15.0e9

@@ -1152,14 +1152,19 @@ def _poison_prefills(eng, rids):
 
     def prefill(params, toks, *rest):
         out = real(params, toks, *rest)
-        if current[-1] in todo:
+        # (Outside a ``_do_prefill`` the engine is preparing a length's
+        # group programs: no request's prefill.)
+        if current and current[-1] in todo:
             todo.discard(current[-1])
             return (out[0] * jnp.nan,) + tuple(out[1:])
         return out
 
     def naming(slot, req, *args, **kwargs):
         current.append(req.rid)
-        return do_prefill(slot, req, *args, **kwargs)
+        try:
+            return do_prefill(slot, req, *args, **kwargs)
+        finally:
+            current.clear()
 
     eng._prefill, eng._do_prefill = prefill, naming
     return todo

@@ -1,0 +1,364 @@
+"""A band's short prompts are prefilled together: the plain joins of one
+length that ONE admission brought go through one prefill program, up to
+four of them (``serving/engine.py``: ``group_joins``, ``_begin_prefill``,
+``_do_prefill``).  Held here, on tiny engines of every served family: a
+grouped queue is served what the same queue is served a prompt at a time
+(tokens, lengths, the rows the cache holds of every prompt); the rule as
+a pure function, with the sizes the served cells get; what is never
+grouped; that a group compiles nothing when it forms; and what the
+``serve.account`` record and a ``serve.prefill`` span say of a group."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib.tracing import CompileCounter
+from horovod_tpu.models.transformer import LLAMA_SERVE, LlamaLM
+from horovod_tpu.serving import Request, ServingEngine, stack_adapters
+from horovod_tpu.serving import engine as engine_mod
+from horovod_tpu.serving.engine import group_joins, group_size
+from horovod_tpu.timeline import spans
+from test_serving_early_route import _tiny as _early_route_family
+from test_spans_clock import FAMILIES as _FIVE
+
+# The five served families, the window-and-full routed block in both its
+# instances: K-EXAONE's and the one with a router that reads the layer's
+# input ahead of attention (SmallThinker's).
+FAMILIES = dict(_FIVE, swa_moe_early_route=_early_route_family)
+SLOTS, PAGE, MAX_LEN = 8, 4, 32
+
+
+# -- the rule -----------------------------------------------------------------
+
+def _sizes(lengths, max_len):
+    groups = group_joins([(n, 0) for n in lengths], min(max_len, 2048))
+    assert sorted(i for g in groups for i in g) == list(range(len(lengths)))
+    assert all(len({lengths[i] for i in g}) == 1 for g in groups)
+    return [(lengths[g[0]], len(g)) for g in groups]
+
+
+@pytest.mark.parametrize("lengths, max_len, want", [
+    # ouro_2_6b (max_len 256): 64 x 4; four of 128 would be 512 rows.
+    ([64] * 4, 256, [(64, 4)]),
+    ([128] * 4, 256, [(128, 1)] * 4),
+    # zaya1_8b and mistral_7b (1,536): 128 x 4 and 256 x 4.
+    ([128] * 4, 1536, [(128, 4)]),
+    ([256] * 8, 1536, [(256, 4), (256, 4)]),
+    ([512] * 4, 1536, [(512, 1)] * 4),
+    ([1024] * 2, 1536, [(1024, 1), (1024, 1)]),
+    # k_exaone (9,216): 512 x 4, every longer prompt alone.
+    ([512] * 4, 9216, [(512, 4)]),
+    ([1024] * 4, 9216, [(1024, 1)] * 4),
+    ([2048] * 2, 9216, [(2048, 1), (2048, 1)]),
+    ([8192] * 2, 9216, [(8192, 1), (8192, 1)]),
+    # Fewer than four go alone, and so does what four leave over.
+    ([64] * 3, 256, [(64, 1)] * 3),
+    ([64] * 7, 256, [(64, 4)] + [(64, 1)] * 3),
+    ([128] * 9, 1536, [(128, 4), (128, 4), (128, 1)]),
+    # One join alone, whatever its length against the rows.
+    ([300], 256, [(300, 1)]),
+    ([], 256, []),
+])
+def test_the_sizes_of_the_groups(lengths, max_len, want):
+    assert _sizes(lengths, max_len) == want
+
+
+@pytest.mark.parametrize("prompt_len, max_len, want", [
+    (64, 256, 4), (128, 256, 1), (128, 1536, 4), (256, 1536, 4),
+    (512, 1536, 1), (512, 9216, 4), (513, 9216, 1), (1024, 8704, 1),
+    (2048, 9216, 1), (3584, 9216, 1)])
+def test_a_length_goes_four_at_a_time_or_alone(prompt_len, max_len, want):
+    assert group_size(prompt_len, min(max_len, 2048)) == want
+
+
+def test_groups_go_in_the_order_their_first_members_were_admitted():
+    lengths = [128, 256, 128, 128, 256, 128, 128, 256, 256]
+    groups = group_joins([(n, 0) for n in lengths], 1536)
+    assert groups == [[0, 2, 3, 5], [1, 4, 7, 8], [6]]
+    # A join that may not share a program (None) goes alone, in its
+    # place; two adapter ids of one length are two groups.
+    keys = [(64, 0), None, (64, 1), (64, 0), (64, 0), (64, 1), (64, 0),
+            (64, 1), None, (64, 1)]
+    assert group_joins(keys, 256) == [[0, 3, 4, 6], [1], [2, 5, 7, 9], [8]]
+
+
+# -- a grouped queue against the same queue a prompt at a time ---------------
+
+def _band_requests(cfg):
+    """Sixteen requests, all there at t = 0, over eight slots.  The first
+    admission brings five prompts of 6 tokens and three of 10: four of
+    the five in one program, everything else alone (four of 10 would be
+    40 rows, over ``MAX_LEN``).  Their outputs end in bands, so a later
+    admission brings four of 6 tokens at once too."""
+    rng = np.random.RandomState(7)
+    lens = [6, 10, 6, 6, 10, 6, 6, 10] + [6, 6, 6, 6, 10, 10, 10, 6]
+    outs = [3, 3, 3, 3, 5, 5, 5, 5] + [4, 4, 4, 4, 2, 2, 3, 3]
+    return [Request(rid=i, prompt=rng.randint(0, min(60, cfg.vocab_size),
+                                              size=n).astype(np.int32),
+                    max_new_tokens=o, arrival_s=0.0)
+            for i, (n, o) in enumerate(zip(lens, outs))]
+
+
+def _held(eng, slot):
+    """What the cache holds of ``slot``'s sequence: its length, its rows
+    in every plane of each pool, its ring's rows in the window planes,
+    its row of the slot state."""
+    cache, c = eng.cache, eng.cache_config
+    n = int(cache.lengths[slot])
+    pos = np.arange(n)
+    pages = cache.page_table[slot][pos // c.page_size]
+    out = {"length": n, "k": np.asarray(cache.k)[:, pages, pos % c.page_size]}
+    if cache.v is not None:
+        out["v"] = np.asarray(cache.v)[:, pages, pos % c.page_size]
+    if cache.window_table is not None:
+        first = max(n + 1 - c.window, 0)
+        pos = np.arange(first, n)
+        pages = cache.window_table[slot][
+            pos // c.page_size % c.window_pages_per_slot]
+        out["wk"] = np.asarray(cache.wk)[:, pages, pos % c.page_size]
+        out["wv"] = np.asarray(cache.wv)[:, pages, pos % c.page_size]
+    if cache.state is not None:
+        out["state"] = np.asarray(cache.state)[:, slot]
+    return out
+
+
+def ALONE(keys, rows):
+    """``group_joins`` as it would be without groups."""
+    return [[i] for i in range(len(keys))]
+
+
+@pytest.fixture(scope="module", params=list(FAMILIES))
+def served(request):
+    """One family's tiny engine after a benchmark's warm-up (ONE request
+    a prompt length), serving :func:`_band_requests` twice: every join
+    through a program of its own, as before there were groups, and then
+    grouped.  Of each call: the requests, what the cache held of each as
+    its prefill had been written, the ``serve.prefill`` spans, the
+    account and what the call lowered."""
+    cfg, params = FAMILIES[request.param]()
+    eng = ServingEngine(cfg, params, slots=SLOTS, page_size=PAGE,
+                        max_len=MAX_LEN, dtype=jnp.float32)
+    pages = eng.cache.free_pages
+    warm = [Request(rid=i, prompt=np.arange(n, dtype=np.int32) % 7,
+                    max_new_tokens=4, arrival_s=0.0)
+            for i, n in enumerate((6, 10))]
+    assert eng.serve(warm).completed == len(warm)
+    held = {}
+    do_prefill = eng._do_prefill
+
+    def watching(slot, req, dev, *args, others=(), **kwargs):
+        out = do_prefill(slot, req, dev, *args, others=others, **kwargs)
+        for s, r, _ in [(slot, req, dev), *others]:
+            held[r.rid] = _held(eng, s)
+        return out
+
+    eng._do_prefill = watching
+    rec = spans.recorder()
+    # (Lowerings, not backend compiles: a persistent cache would hide
+    # those.)
+    lowerings = CompileCounter()
+    lowerings.EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    out = {}
+    for how in ("alone", "grouped"):
+        mp = pytest.MonkeyPatch()
+        if how == "alone":
+            mp.setattr(engine_mod, "group_joins", ALONE)
+        rec.reset()
+        held.clear()
+        lowerings.count = 0
+        reqs = _band_requests(cfg)
+        try:
+            with lowerings.counting():
+                report = eng.serve(reqs)
+        finally:
+            mp.undo()
+        assert report.completed == len(reqs)
+        assert eng.cache.free_pages == pages and not eng.cache.lengths.any()
+        assert not any(r.in_flight for r in reqs)
+        account, = rec.records(name="serve.account")
+        out[how] = dict(reqs=reqs, held=dict(held), account=account.attrs,
+                        prefills=rec.records(name="serve.prefill"),
+                        lowered=lowerings.count)
+    return out
+
+
+def test_a_group_is_served_what_its_prompts_are_served_alone(served):
+    want, got = served["alone"], served["grouped"]
+    assert [r.tokens for r in got["reqs"]] == [r.tokens for r in want["reqs"]]
+    for r in got["reqs"]:
+        assert len(r.tokens) == r.max_new_tokens
+        assert r.admit_s <= r.prefill_start_s <= r.first_token_s
+        mine, theirs = got["held"][r.rid], want["held"][r.rid]
+        assert mine["length"] == theirs["length"] == r.prompt_len
+        assert mine.keys() == theirs.keys()
+        for name in set(mine) - {"length"}:
+            assert mine[name].shape == theirs[name].shape
+            np.testing.assert_allclose(mine[name], theirs[name],
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+def test_a_prefill_span_covers_its_group(served):
+    reqs, prefills = served["grouped"]["reqs"], served["grouped"]["prefills"]
+    # The first admission: five of 6 tokens as 4 + 1 and three of 10,
+    # each alone, in the order of their first members.
+    first = [(p.attrs["prompt_len"], p.attrs["rids"]) for p in prefills[:5]]
+    assert first == [(6, (0, 2, 3, 5)), (10, (1,)), (10, (4,)), (6, (6,)),
+                     (10, (7,))]
+    for p in prefills:
+        a = p.attrs
+        assert a["group"] == len(a["rids"]) == len(a["slots"])
+        assert len(set(a["slots"])) == a["group"]
+        assert (a["rid"], a["slot"]) == (a["rids"][0], a["slots"][0])
+        assert {reqs[rid].prompt_len for rid in a["rids"]} \
+            == {a["prompt_len"]}
+        assert a["group"] * a["prompt_len"] <= MAX_LEN or a["group"] == 1
+        assert a["deferred"] is True
+    assert sorted(rid for p in prefills for rid in p.attrs["rids"]) \
+        == list(range(len(reqs)))
+    assert {p.attrs["group"] for p in prefills} == {1, 4}
+    assert all(p.attrs["group"] == 1 and p.attrs["rids"] == (p.attrs["rid"],)
+               for p in served["alone"]["prefills"])
+
+
+def test_the_account_counts_prompts_programs_and_shared_programs(served):
+    got, alone = served["grouped"], served["alone"]
+    account, prefills, n = got["account"], got["prefills"], len(got["reqs"])
+    assert account["prefills"] == alone["account"]["prefills"] == n
+    assert account["prefill_groups"] == len(prefills) < n
+    assert account["prefills_grouped"] == sum(
+        p.attrs["group"] for p in prefills if p.attrs["group"] > 1) >= 8
+    assert account["first_tokens_deferred"] == n
+    assert alone["account"]["prefill_groups"] == len(alone["prefills"]) == n
+    assert alone["account"]["prefills_grouped"] == 0
+
+
+def test_a_group_compiles_nothing_when_it_forms(served):
+    """The warm-up met each length ONCE, a prompt alone: the engine
+    prepared that length's group programs then, and the calls that
+    follow, where groups of four form, lower nothing."""
+    assert served["alone"]["lowered"] == 0
+    assert served["grouped"]["lowered"] == 0
+
+
+# -- what is never grouped -----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def dense():
+    return LLAMA_SERVE, LlamaLM(LLAMA_SERVE, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+
+def _same_length(n, length, outs=3, vocab=60, seed=3, **kw):
+    rng = np.random.RandomState(seed)
+    return [Request(rid=i, prompt=rng.randint(0, vocab, size=length)
+                    .astype(np.int32), max_new_tokens=outs, arrival_s=0.0,
+                    **kw) for i in range(n)]
+
+
+def _served_groups(eng, reqs):
+    rec = spans.recorder()
+    rec.reset()
+    report = eng.serve(reqs)
+    assert report.completed == len(reqs)
+    account, = rec.records(name="serve.account")
+    return ([p.attrs for p in rec.records(name="serve.prefill")],
+            account.attrs, report)
+
+
+def test_a_lone_join_is_a_group_of_one(dense):
+    cfg, params = dense
+    eng = ServingEngine(cfg, params, slots=4, page_size=PAGE,
+                        max_len=MAX_LEN)
+    # Distinct lengths at t = 0, and two of one length that arrive one
+    # after the other is done: no admission brings two of a length.
+    reqs = _same_length(2, 6, outs=2)
+    reqs[1].arrival_s = 1e3
+    reqs += [Request(rid=2 + i, prompt=np.arange(n, dtype=np.int32),
+                     max_new_tokens=2, arrival_s=0.0)
+             for i, n in enumerate((5, 7))]
+    prefills, account, _ = _served_groups(eng, reqs)
+    assert [a["group"] for a in prefills] == [1] * 4
+    assert all(a["rids"] == (a["rid"],) and a["slots"] == (a["slot"],)
+               for a in prefills)
+    assert (account["prefills"], account["prefill_groups"],
+            account["prefills_grouped"]) == (4, 4, 0)
+
+
+def test_a_prefix_hit_is_never_grouped(dense):
+    cfg, params = dense
+    eng = ServingEngine(cfg, params, slots=4, page_size=PAGE,
+                        max_len=MAX_LEN, prefix_cache=True)
+    # Four prompts of one length and one first page in one admission:
+    # each enters the tree as it is prefilled and the next one hits it.
+    reqs = _same_length(4, 10)
+    for r in reqs[1:]:
+        r.prompt[:2 * PAGE] = reqs[0].prompt[:2 * PAGE]
+    prefills, account, report = _served_groups(eng, reqs)
+    assert [a["group"] for a in prefills] == [1] * 4
+    assert report.prefix_hits == 3
+    assert (account["prefill_groups"], account["prefills_grouped"]) == (4, 0)
+
+
+def test_a_chunked_prompt_is_never_grouped(dense):
+    cfg, params = dense
+    eng = ServingEngine(cfg, params, slots=6, page_size=PAGE,
+                        max_len=MAX_LEN, prefill_chunk=4)
+    # Four prompts of 3 tokens (under the chunk: one program for them)
+    # and two of 10, which go chunk by chunk, each alone.
+    reqs = _same_length(4, 3) + _same_length(2, 10, seed=4)
+    for i, r in enumerate(reqs):
+        r.rid = i
+    rec = spans.recorder()
+    prefills, account, _ = _served_groups(eng, reqs)
+    assert [(a["prompt_len"], a["rids"]) for a in prefills] \
+        == [(3, (0, 1, 2, 3))]
+    chunks = rec.records(name="prefill_chunk")
+    assert len(chunks) == 2 * 3
+    assert (account["prefills"], account["prefill_groups"],
+            account["prefills_grouped"]) == (6, 1, 4)
+
+
+def test_two_adapter_ids_are_never_one_group():
+    cfg = LLAMA_SERVE
+    model = LlamaLM(cfg, dtype=jnp.float32, lora_rank=2)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+    def bank(key):
+        leaves, treedef = jax.tree.flatten(params["params"])
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree.unflatten(treedef, [
+            0.05 * jax.random.normal(k, x.shape, x.dtype)
+            for k, x in zip(keys, leaves)])
+
+    banks = stack_adapters([bank(jax.random.PRNGKey(1)),
+                            bank(jax.random.PRNGKey(2))])
+    ids = [0, 1, 0, 1, 0, 0, 1, 0]
+
+    def serve(alone):
+        eng = ServingEngine(cfg, params, slots=8, page_size=PAGE,
+                            max_len=MAX_LEN, adapters={"params": banks})
+        reqs = _same_length(len(ids), 6, outs=6)
+        reqs[1].prompt = reqs[0].prompt.copy()
+        for r, aid in zip(reqs, ids):
+            r.adapter_id = aid
+        mp = pytest.MonkeyPatch()
+        if alone:
+            mp.setattr(engine_mod, "group_joins",
+                       lambda keys, rows: [[i] for i in range(len(keys))])
+        try:
+            prefills, _, _ = _served_groups(eng, reqs)
+        finally:
+            mp.undo()
+        return reqs, prefills
+
+    want, _ = serve(alone=True)
+    got, prefills = serve(alone=False)
+    # Five of adapter 0 as 4 + 1; three of adapter 1, fewer than the
+    # four a group of 6 tokens takes, each alone.
+    assert [a["rids"] for a in prefills] \
+        == [(0, 2, 4, 5), (1,), (3,), (6,), (7,)]
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+    # The adapters differ: requests 0 and 1 have ONE prompt and are
+    # served other tokens, so a group that mixed the ids would show.
+    assert want[0].tokens != want[1].tokens

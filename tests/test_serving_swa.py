@@ -557,8 +557,14 @@ _FLASH_1024_LOWERED = \
 # one.
 _SWA_STEP_LOWERED = \
     "ea717161cb7432cf7ec21eb8fcef90f8f069868f835d304f7ebe6852300fc090"
+# The prefill was recorded again on PR 45's tree, whose change it is
+# (e5c77db0.. before): a layer's body is lowered to ONE function a kind
+# of layer and called (``decode.one_trace``), where it was unrolled; the
+# kernels in it are the recorded ones, and XLA inlines the calls (the
+# optimised program of a deviceless v5e compile has the instructions,
+# temporaries and estimated cycles it had).
 _SWA_PREFILL_LOWERED = \
-    "e5c77db0a13251bd2d528f5afcfa500c78fd91f1d87511a31bdf76644e9127d8"
+    "5071b5ec9058a4ca6a73ffa892cea0ed88fe1e727f0bca17c727741f389d4369"
 
 
 def _k_exaone_two_layers():
