@@ -259,12 +259,14 @@ class ServingControlPlane:
 
     # -- decode step (shared by the main loop and the drain) ---------------
     def _decode_once(self, now) -> float:
-        # Delegates to the engine's shared round so occupancy/TTFT
-        # bookkeeping stays truthful whatever the engine's decode mode
-        # is (plain or speculative).  The DRAIN path always runs plain
-        # decode: a draining mesh is about to lose ranks and the verify
-        # step's wider dispatch buys nothing on the way down.
-        return self.engine.decode_once(self._stats, now)
+        # The engine's one round and the catch-up behind it: this loop
+        # may rewrite slots and the mesh before the next round (the
+        # monitor gets the round's own dispatch-to-fetch seconds).  The
+        # DRAIN path always runs plain decode: a draining mesh is about
+        # to lose ranks and the verify step's wider dispatch buys
+        # nothing on the way down.
+        self.engine.decode_once(self._stats, now)
+        return self.engine.catch_up(self._stats, now)
 
     # -- controller tick ---------------------------------------------------
     def _sample(self, now_s: float) -> SLOSample:
@@ -359,6 +361,11 @@ class ServingControlPlane:
         sched.pause_admission()
         for slot in list(sched.active):
             sched.mark_draining(slot)
+        # A prompt still going in chunk by chunk has no token to be
+        # suspended by: it goes in whole first.
+        while eng._chunking:
+            eng.join(st, (), now)
+        eng.catch_up(st, now)
         done_before = len(st["completed"])
         steps = 0
         while sched.active and decode_ok and steps < drain_budget:
@@ -444,16 +451,11 @@ class ServingControlPlane:
 
         snap_fn = getattr(sched._m_ttft, "snapshot", None)
         self.decisions = []
-        self._stats = {
-            "completed": [], "occ_samples": [], "decode_steps": 0,
-            "last_tokens": np.zeros((eng.slots,), np.int32),
-            "adapter_ids": np.zeros((eng.slots,), np.int32),
-            "last_tick": 0.0, "slo_violation_s": 0.0,
-            "drained_completed": 0, "drained_reprefilled": 0,
-            "drain_leaked_pages": 0, "resizes": 0,
-            "ttft_base": snap_fn() if snap_fn is not None else None,
-        }
-        st = self._stats
+        st = self._stats = dict(
+            eng.run_state(), last_tick=0.0, slo_violation_s=0.0,
+            drained_completed=0, drained_reprefilled=0,
+            drain_leaked_pages=0, resizes=0,
+            ttft_base=snap_fn() if snap_fn is not None else None)
         i = 0
 
         while True:
@@ -469,21 +471,17 @@ class ServingControlPlane:
                 self._tick(now)
                 continue
 
-            for slot, req in sched.admit(now()):
-                first = eng._do_prefill(
-                    slot, req, jnp.asarray(req.prompt, jnp.int32))
-                req.tokens.append(first)
-                sched.note_prefill(req, now())
-                st["last_tokens"][slot] = first
-                st["adapter_ids"][slot] = req.adapter_id
-                if req.finished:
-                    st["completed"].append(sched.release(slot, now()))
-
-            if sched.active:
+            eng.join(st, [(slot, req, jnp.asarray(req.prompt, jnp.int32))
+                          for slot, req in sched.admit(now())], now)
+            if eng._decode_slots():
                 step = st["decode_steps"] + 1
                 self._fire_faults(step, now())
                 step_s = self._decode_once(now)
                 self._feed_monitor(step, step_s)
+            else:
+                # Nothing to dispatch: what joined is done with its
+                # first token, or is still going in chunk by chunk.
+                eng.catch_up(st, now)
             self._tick(now)
 
         wall_s = max(time.monotonic() - start, 1e-9)

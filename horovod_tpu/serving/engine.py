@@ -8,6 +8,11 @@ to requests: a producer thread stages each upcoming prompt onto device
 while the engine is still decoding, so admission never stalls on a
 host-to-device copy.
 
+Whichever loop drives the engine (``serve``, the control plane's, the
+fleet's decode worker): ONE run state (``run_state``), ONE join
+(``join``: a first token stays on the chip) and ONE round
+(``decode_once``, one ahead; ``catch_up`` behind it where a loop must).
+
 Knobs (all overridable per-constructor-arg, documented in docs/api.md):
 
 * ``HOROVOD_SERVING_SLOTS`` -- decode batch slots (default 8)
@@ -476,7 +481,25 @@ class ServingEngine:
     def _fresh_step_state(self) -> tuple:
         return tuple(self._whole(x) for x in self.spec.step_state())
 
-    # -- one admission's prefills ------------------------------------------
+    def run_state(self) -> Dict[str, Any]:
+        """One run's mutable state, every key there from the start."""
+        return {
+            "completed": [], "occ_samples": [], "decode_steps": 0,
+            "spec_rounds": 0, "proposed": 0, "accepted": 0,
+            "prefix_queries": 0, "prefix_hits": 0,
+            "prefill_cached": 0, "prefill_computed": 0,
+            "session_resumes": 0, "prefills": 0,
+            # ``serve.prefill`` programs dispatched for them, and the
+            # prompts among them that shared one.
+            "prefill_groups": 0, "prefills_grouped": 0,
+            "last_tokens": np.zeros((self.slots,), np.int32),
+            "adapter_ids": np.zeros((self.slots,), np.int32),
+            # The round the chip has and the host has not read, and the
+            # prefills whose first token is still on the chip.
+            "in_flight": None, "rounds_ahead": 0,
+            "joins": [], "first_tokens_deferred": 0}
+
+    # -- one turn's joins --------------------------------------------------
     @property
     def group_rows(self) -> int:
         """The rows ``b * t`` a group of joins may have: no more than
@@ -515,16 +538,48 @@ class ServingEngine:
             self._hand_over(self._told, logits[:, -1, :],
                             np.zeros((b,), np.int32))
 
+    def join(self, st: Dict[str, Any], joins: Sequence[tuple], now) -> None:
+        """One turn of a loop, and the one way a prompt joins: the
+        ``(slot, request, prompt on the device)`` it admitted, plain
+        joins of one length through one prefill program
+        (:func:`group_joins`) behind whatever round is in flight, and a
+        chunk more of every chunked prefill.  (Dispatched from this body:
+        a frame more above a prefill's first trace costs seconds of
+        set-up, PERF.md section 6, PR 47.)"""
+        for group in group_joins([self._join_key(req) for _, req, _ in joins],
+                                 self.group_rows):
+            members = [joins[i] for i in group]
+            hit = self._begin_prefill(st, members, now)
+            if hit is None:
+                continue
+            (slot, req, dev), *others = members
+            if self._join_key(req) is not None \
+                    and req.prompt_len not in self._group_ready:
+                self._prepare_group(req.prompt_len)
+            flight = st["in_flight"]
+            first = self._do_prefill(
+                slot, req, dev, *hit, others=others,
+                behind=-1 if flight is None else flight.round)
+            st["prefill_groups"] += 1
+            if others:
+                st["prefills_grouped"] += len(members)
+            for row, (slot, req, _) in enumerate(members):
+                self._await_first(st, slot, req, first, row)
+                self._note_resident(st, slot, req)
+        if self._chunking:
+            with _spans.recorder().phase("serve.chunks"):
+                self._advance_chunks(st, now)
+
     def _begin_prefill(self, st: Dict[str, Any], members: Sequence[tuple],
-                       now) -> None:
+                       now) -> Optional[tuple]:
         """Take up one prefill program's joins, ``(slot, request,
         prompt on the device)`` each: one join, or a group of plain
         joins of one length (:func:`group_joins`).  One join alone:
         radix-match the prompt against the prefix cache (attach matched
-        pages, no compute), then prefill the remaining tail -- chunked
-        when it is long.
-        """
-        (slot, req, dev), *others = members
+        pages, no compute).  Returns ``(matched, entries)``, what the
+        prefill program is spared, or None where the tail is long and
+        goes in chunk by chunk (:meth:`_advance_chunks`)."""
+        slot, req, dev = members[0]
         matched, entries = 0, ()
         for _, r, _ in members:
             r.prefill_start_s = now()
@@ -548,58 +603,38 @@ class ServingEngine:
             self._chunking[slot] = {
                 "req": req, "dev": dev, "pos": matched,
                 "start": matched, "past": past}
-            return
-        ahead = "in_flight" in st
-        flight = st.get("in_flight")
-        if ahead and self._join_key(req) is not None \
-                and req.prompt_len not in self._group_ready:
-            self._prepare_group(req.prompt_len)
-        first = self._do_prefill(
-            slot, req, dev, matched=matched, entries=entries,
-            behind=-1 if flight is None else flight.round, defer=ahead,
-            others=others)
-        st["prefill_groups"] += 1
-        if others:
-            st["prefills_grouped"] += len(members)
-        if ahead:
-            # The look-ahead loop: the tokens stay on the chip and
-            # the slots are live for the next round by count (their
-            # first tokens are in flight); the host reads them where
-            # it next waits for the chip (:meth:`_retire`,
-            # :meth:`_settle_joins`).
-            for row, (slot, req, _) in enumerate(members):
-                req.state = "decode"
-                req.in_flight = 1
-                st["joins"].append(_Join(slot, req, first, row))
-                st["first_tokens_deferred"] += 1
-                self._note_resident(st, slot, req)
-        else:
-            self._join_decode(st, slot, req, first, now)
+            return None
+        return matched, entries
+
+    def _await_first(self, st: Dict[str, Any], slot: int, req: Request,
+                     first, row: int = 0) -> None:
+        """The request's first token is on the chip (row ``row`` of
+        ``first``): the host reads it where it next waits for the chip."""
+        req.state = "decode"
+        req.in_flight = 1
+        st["joins"].append(_Join(slot, req, first, row))
+        st["first_tokens_deferred"] += 1
 
     def _do_prefill(self, slot: int, req: Request, prompt_dev,
                     matched: int = 0, entries: Sequence = (),
-                    behind: int = -1, defer: bool = False,
-                    others: Sequence[tuple] = ()):
+                    behind: int = -1, others: Sequence[tuple] = ()):
         """Dispatch one prefill program, its pool writes and its slot
-        states', and sample its first tokens.  ``others``: the ``(slot,
-        request, prompt)`` of the further members of a GROUP, plain
-        joins of ``req``'s length that go through the program with it as
-        ``tokens[b, t]`` (the weights are read once a group); each
-        member's rows then go to its own slot as one prompt's do.
-        ``behind``: the number of the decode round in flight while this
-        prefill is dispatched (it queues behind it on the chip), -1
-        where there is none.  ``defer``: leave the tokens on the chip,
-        in the slots' places of the vector the next round reads
-        (:func:`_hand_over`), and return their ``[token, finite]``
-        pairs on the device, a row a member, without waiting for
-        anything; else fetch ``req``'s and return it as an int (one
-        prompt alone)."""
+        states', and leave its first tokens on the chip, in the slots'
+        places of the vector the next round reads (:func:`_hand_over`).
+        ``others``: the ``(slot, request, prompt)`` of the further
+        members of a GROUP, plain joins of ``req``'s length that go
+        through the program with it as ``tokens[b, t]`` (the weights are
+        read once a group); each member's rows then go to its own slot
+        as one prompt's do.  ``behind``: the number of the decode round
+        in flight while this prefill is dispatched (it queues behind it
+        on the chip), -1 where there is none.  Returns the ``[token,
+        finite]`` pairs on the device, a row a member, without waiting
+        for anything."""
         rec = _spans.recorder()
         members = [(slot, req, prompt_dev), *others]
         slots = [m[0] for m in members]
-        if others and (matched or not defer):
-            raise ValueError(
-                "a group takes plain joins whose tokens stay on the chip")
+        if others and matched:
+            raise ValueError("a group takes plain joins")
         # With a window group: the rows a window plane is written, the
         # prompt's last ones.
         windowed = {} if self.spec.window is None else {
@@ -610,7 +645,7 @@ class ServingEngine:
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
                       planes=self.spec.planes, behind=behind,
-                      deferred=defer, group=len(members),
+                      deferred=True, group=len(members),
                       rids=tuple(m[1].rid for m in members),
                       slots=tuple(slots), **windowed):
             with rec.phase("prefill.dispatch", rid=req.rid):
@@ -640,29 +675,34 @@ class ServingEngine:
                             self.params, prompt_dev[None], self.adapters,
                             aid)
                         rows = _members(out)
-            for (slot_i, req_i, _), (kl, vl, *state) in zip(members, rows):
-                with rec.phase("prefill.write_kv", rid=req_i.rid):
-                    self.cache.write_prefill(
-                        slot_i, kl, vl, start=matched,
-                        window_rows=self._window_rows(state))
-                if self.spec.slot_state is not None:
-                    # What the slot keeps beside its pages: the prompt's
-                    # trailing rows (a hit or a chunk would need them of
-                    # the prefix's last token, and is refused by the
-                    # spec).
-                    with rec.phase("prefill.write_state", rid=req_i.rid,
-                                   state_bytes=state[0].size
-                                   * self.cache.state.dtype.itemsize):
-                        self.cache.write_state(slot_i, state[0])
-            if defer:
-                with rec.phase("prefill.hand_over", rid=req.rid):
-                    self._told, first = self._hand_over(
-                        self._told, logits[:, -1, :],
-                        np.asarray(slots, np.int32))
-                    first.copy_to_host_async()
-                return first
-            with rec.phase("prefill.sample_fetch", rid=req.rid):
-                first = int(greedy_sample(logits[:, -1, :])[0])
+            self._write_rows(members, rows, matched)
+            return self._leave_first(logits, slots, req.rid)
+
+    def _write_rows(self, members: Sequence[tuple], rows: Sequence,
+                    matched: int = 0) -> None:
+        """Write what ONE prefill program handed back (``rows``, a
+        member each) into the members' slots: pool rows from ``matched``
+        on, window rows, slot state.  A join's and a re-prefill's."""
+        phase = _spans.recorder().phase
+        for (slot, req, _), (kl, vl, *state) in zip(members, rows):
+            with phase("prefill.write_kv", rid=req.rid):
+                self.cache.write_prefill(
+                    slot, kl, vl, start=matched,
+                    window_rows=self._window_rows(state))
+            if self.spec.slot_state is not None:
+                # What the slot keeps beside its pages: the prompt's
+                # trailing rows (the spec refuses a hit or a chunk).
+                with phase("prefill.write_state", rid=req.rid,
+                           state_bytes=state[0].size
+                           * self.cache.state.dtype.itemsize):
+                    self.cache.write_state(slot, state[0])
+
+    def _leave_first(self, logits, slots: Sequence[int], rid: int):
+        """Hand ``logits``' last rows over to ``slots`` (``_hand_over``)."""
+        with _spans.recorder().phase("prefill.hand_over", rid=rid):
+            self._told, first = self._hand_over(
+                self._told, logits[:, -1, :], np.asarray(slots, np.int32))
+            first.copy_to_host_async()
         return first
 
     def _window_rows(self, beyond: list):
@@ -676,7 +716,7 @@ class ServingEngine:
     def _advance_chunks(self, st: Dict[str, Any], now) -> None:
         """Push each in-progress chunked prefill forward by ONE chunk.
 
-        One chunk per slot per serve-loop iteration: a kilotoken
+        One chunk per slot per loop iteration: a kilotoken
         admission is sliced into ``prefill_chunk``-token forwards
         interleaved with decode steps, so the live decode batch keeps
         emitting while the long prompt fills in (the TTFT-p99 gate).
@@ -697,20 +737,20 @@ class ServingEngine:
                 if c["pos"] < req.prompt_len:
                     continue
                 # The last chunk's span holds the pool write and the
-                # first token's fetch, as ``serve.prefill`` does.
+                # first token's hand-over, as ``serve.prefill`` does.
                 del self._chunking[slot]
-                start = int(c.get("start", 0))
+                start = int(c["start"])
                 with rec.phase("prefill.write_kv", rid=req.rid):
                     self.cache.write_prefill(slot, kl[:, 0, start:],
                                              vl[:, 0, start:], start=start)
-                with rec.phase("prefill.sample_fetch", rid=req.rid):
-                    first = int(greedy_sample(logits[:, -1, :])[0])
-            self._join_decode(st, slot, req, first, now)
+                first = self._leave_first(logits, [slot], req.rid)
+            self._await_first(st, slot, req, first)
+            self._note_resident(st, slot, req)
 
     def _join_decode(self, st: Dict[str, Any], slot: int, req: Request,
                      first: int, now) -> None:
-        """Prefill done (whole or final chunk): first token is sampled,
-        the request enters the decode batch."""
+        """A request whose pages are resident and whose first token the
+        HOST has (the fleet's imported ticket) enters the decode batch."""
         self._note_resident(st, slot, req)
         self._book_first(st, slot, req, first, now)
 
@@ -757,8 +797,7 @@ class ServingEngine:
                 and len(r.tokens) + r.in_flight < r.max_new_tokens
                 and int(self.cache.lengths[s]) < self.max_len]
 
-    def _quarantine_logits(self, st: Dict[str, Any], slot: int,
-                           req: Request, now) -> None:
+    def _quarantine_logits(self, st: Dict[str, Any], slot: int) -> None:
         """A slot produced nonfinite logits: never stream a token
         sampled from a poisoned distribution.
 
@@ -767,23 +806,25 @@ class ServingEngine:
         history via :meth:`re_prefill` -- ``write_prefill`` re-derives
         the slot's length and page mapping from scratch, so the
         quarantine cannot leak pages -- and retry the same position on
-        the next round.  The request keeps its slot and emitted prefix;
-        only the round is lost.  Where it was the prefill's own logits
-        (the request has no token yet) the prompt is prefilled again and
-        the token fetched at once.
+        the next round: only the round is lost.  Where it was the
+        prefill's own logits (the request has no token yet) the prompt
+        is handed in again, whole, as a join is.
         """
         from ..timeline import metrics as _metrics
         _metrics.registry().counter(
             "horovod_guard_serving_reprefills_total",
             "Decode rounds where a slot's nonfinite logits were "
             "quarantined by re-prefilling its context").inc()
+        req = self.scheduler.active[slot]
         if req.tokens:
             st["last_tokens"][slot] = self.re_prefill(slot, req)
         else:
-            self._book_first(st, slot, req, self._do_prefill(
-                slot, req, jnp.asarray(req.prompt, jnp.int32)), now)
+            st["prefills"] += 1
+            st["prefill_groups"] += 1
+            self._await_first(st, slot, req, self._do_prefill(
+                slot, req, jnp.asarray(req.prompt, jnp.int32)))
 
-    # -- one decode round (shared with serving.controlplane) ---------------
+    # -- one decode round --------------------------------------------------
     def _round_span(self, st: Dict[str, Any], slots: List[int],
                     ahead: bool = False, step=None):
         """The ``decode.round`` span of one round over ``slots``,
@@ -834,14 +875,11 @@ class ServingEngine:
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """Dispatch one plain continuous-batching decode round over the
-        live slots, and read and book a round.
+        live slots, and read and book the round before it.
 
-        ``st`` is the mutable per-run state dict (``last_tokens``,
-        ``adapter_ids``, ``completed``, ``occ_samples``,
-        ``decode_steps``).  Where it has the key ``in_flight`` (the
-        ``st`` that :meth:`serve` builds) the loop runs ONE ROUND AHEAD:
-        this call reserves pages for, builds the operands of and
-        dispatches the round for the slots that are live by count
+        ``st``: the run's state (:meth:`run_state`).  The loop runs ONE
+        ROUND AHEAD: this call reserves pages for, builds the operands
+        of and dispatches the round for the slots that are live by count
         (:meth:`_decode_slots`), leaves it in ``st["in_flight"]``, and
         only then fetches and books the round that was in flight
         (:meth:`_retire`), so the chip has the next round queued while
@@ -849,22 +887,20 @@ class ServingEngine:
         leaves the chip: the host gives the step ``-1`` for it and the
         step reads it from the ``told`` vector of the round before.  So
         with a JOINING slot's first token: its prefill left it in that
-        vector (:func:`_hand_over`), the round is dispatched behind the
-        prefill without a fetch between them, and the host reads the
-        token with the round it retires next (or alone, where none was
-        in flight: :meth:`_settle_joins`).
-        Where ``st`` lacks the key (the control plane's drain loop and
-        the fleet's decode worker, which rewrite slots and meshes
-        between rounds) the round is dispatched AND retired by this
-        call.  On entry ``_decode_slots()`` and ``cache.lengths``
-        describe the round this call dispatches: lengths advance at
-        dispatch.  Returns the seconds from the retired round's dispatch
-        to its fetch (0.0 where nothing was retired).
+        vector (:func:`_hand_over`), and the host reads it with the
+        round it retires next (or alone, where none was in flight:
+        :meth:`_settle_joins`).  A loop that must have a round's tokens
+        before it goes on (it rewrites slots or the mesh between rounds)
+        calls :meth:`catch_up` behind this.  On entry
+        ``_decode_slots()`` and ``cache.lengths`` describe the round
+        this call dispatches: lengths advance at dispatch.  Returns the
+        seconds from the retired round's dispatch to its fetch (0.0
+        where nothing was retired).
         """
         cache = self.cache
         phase = _spans.recorder().phase
         slots = self._decode_slots()
-        flying: Optional[_Flight] = st.get("in_flight")
+        flying: Optional[_Flight] = st["in_flight"]
         n = int(st["decode_steps"])
         with self._round_span(st, slots, ahead=flying is not None):
             with phase("decode.reserve"):
@@ -879,10 +915,10 @@ class ServingEngine:
                     # Their tokens are on the chip, in the round before's
                     # ``told``, and so is the first token of a slot
                     # whose prefill was dispatched since; the host has
-                    # every other live slot's (a last chunk's, a
-                    # re-prefill's).
+                    # every other live slot's (a re-prefill's, an
+                    # imported ticket's).
                     tokens[flying.slots] = -1
-                tokens[[j.slot for j in st.get("joins", ())]] = -1
+                tokens[[j.slot for j in st["joins"]]] = -1
                 args = [self._decode_params, cache.k, cache.v,
                         jnp.asarray(tokens),
                         cache.lengths_device(), cache.table_device(),
@@ -916,10 +952,7 @@ class ServingEngine:
                 self.scheduler.active[slot].in_flight += 1
             st["decode_steps"] += 1
             st["occ_samples"].append(self.scheduler.occupancy)
-            this = _Flight(slots, self._told, t0, n)
-            if "in_flight" not in st:
-                return self._retire(st, this, now)
-            st["in_flight"] = this
+            st["in_flight"] = _Flight(slots, self._told, t0, n)
             st["rounds_ahead"] += flying is not None
             if flying is not None:
                 return self._retire(st, flying, now)
@@ -978,19 +1011,15 @@ class ServingEngine:
             # The round behind took these slots' tokens from the
             # poisoned one (and sat them out): read it first, without
             # them.
-            self._catch_up(st, now, dropped=poisoned)
+            self.catch_up(st, now, dropped=poisoned)
             for slot in poisoned:
-                self._quarantine_logits(st, slot,
-                                        self.scheduler.active[slot], now)
+                self._quarantine_logits(st, slot)
 
     def _fetch_joins(self, st: Dict[str, Any]) -> list:
         """``[(join, [token, finite])]`` of the prefills whose first
         token was left on the chip, read now: the host waits for the
         last of them to end."""
-        joins = st.get("joins")
-        if not joins:
-            return []
-        st["joins"] = []
+        joins, st["joins"] = st["joins"], []
         return [(join, np.asarray(join.first)[join.row]) for join in joins]
 
     def _book_joins(self, st: Dict[str, Any], joined: list,
@@ -1011,25 +1040,24 @@ class ServingEngine:
 
     def _settle_joins(self, st: Dict[str, Any], now) -> None:
         """Read and book the first tokens left on the chip where there
-        is no round to read with them."""
-        if st.get("joins"):
+        is no round to read with them: the ONE ``prefill.sample_fetch``."""
+        while st["joins"]:
             with _spans.recorder().phase("prefill.sample_fetch",
                                          joins=len(st["joins"])):
                 joined = self._fetch_joins(st)
             self._quarantine(st, self._book_joins(st, joined, now), now)
 
-    def _catch_up(self, st: Dict[str, Any], now,
-                  dropped: Sequence[int] = ()) -> None:
+    def catch_up(self, st: Dict[str, Any], now,
+                 dropped: Sequence[int] = ()) -> float:
         """Retire the round in flight, if there is one, and read what
         first tokens are still on the chip: when the loop has nothing to
-        dispatch, and before anything that rewrites slot or mesh
-        state."""
-        flight = st.get("in_flight")
-        if flight is not None:
-            st["in_flight"] = None
-            self._retire(st, flight, now, dropped)
-        else:
-            self._settle_joins(st, now)
+        dispatch, and before anything that rewrites slot or mesh state.
+        Returns the retired round's seconds from dispatch to fetch."""
+        flight, st["in_flight"] = st["in_flight"], None
+        step_s = 0.0 if flight is None \
+            else self._retire(st, flight, now, dropped)
+        self._settle_joins(st, now)
+        return step_s
 
     def spec_round(self, st: Dict[str, Any], now) -> float:
         """One speculative round: draft k, verify k+1 wide, accept the
@@ -1047,7 +1075,7 @@ class ServingEngine:
         phase = _spans.recorder().phase
         k = self.spec_k
         width = k + 1
-        self._catch_up(st, now)
+        self.catch_up(st, now)
         slots = self._decode_slots()
         n = int(st["decode_steps"])
         reqs = {s: sched.active[s] for s in slots}
@@ -1082,13 +1110,13 @@ class ServingEngine:
             step_s = time.monotonic() - t0
             with phase("decode.bookkeep", round=n):
                 st["decode_steps"] += 1
-                st["spec_rounds"] = st.get("spec_rounds", 0) + 1
+                st["spec_rounds"] += 1
                 st["occ_samples"].append(sched.occupancy)
                 t_tok = now()
                 for s in slots:
                     req = reqs[s]
                     if not finite[s]:
-                        self._quarantine_logits(st, s, req, now)
+                        self._quarantine_logits(st, s)
                         continue
                     # Longest agreeing prefix: draft j survives iff every
                     # earlier draft did AND it equals the target's argmax
@@ -1100,8 +1128,8 @@ class ServingEngine:
                                req.max_new_tokens - len(req.tokens),
                                self.max_len - base[s])
                     accepted = max(emit - 1, 0)
-                    st["proposed"] = st.get("proposed", 0) + k
-                    st["accepted"] = st.get("accepted", 0) + accepted
+                    st["proposed"] += k
+                    st["accepted"] += accepted
                     sched.note_spec(k, accepted)
                     for j in range(emit):
                         req.tokens.append(int(sampled[s, j]))
@@ -1165,11 +1193,7 @@ class ServingEngine:
                 else None
             _, *out = self._prefill(
                 self.params, jnp.asarray(full)[None], self.adapters, aid)
-            (kl, vl, *state), = _members(out)
-            window_rows = self._window_rows(state)
-            self.cache.write_prefill(
-                slot, kl, vl, state=state[0] if state else None,
-                window_rows=window_rows)
+            self._write_rows([(slot, req, None)], _members(out))
         if self.drafter is not None:
             self.drafter.re_prefill(slot, req)
         return int(req.tokens[-1])
@@ -1194,22 +1218,7 @@ class ServingEngine:
         def now() -> float:
             return time.monotonic() - start + skip
 
-        st: Dict[str, Any] = {
-            "completed": [], "occ_samples": [], "decode_steps": 0,
-            "spec_rounds": 0, "proposed": 0, "accepted": 0,
-            "prefix_queries": 0, "prefix_hits": 0,
-            "prefill_cached": 0, "prefill_computed": 0,
-            "session_resumes": 0, "prefills": 0,
-            # ``serve.prefill`` programs dispatched for them, and the
-            # prompts among them that shared one.
-            "prefill_groups": 0, "prefills_grouped": 0,
-            "last_tokens": np.zeros((self.slots,), np.int32),
-            "adapter_ids": np.zeros((self.slots,), np.int32),
-            # This loop runs one round ahead (``decode_once``): the
-            # round the chip has and the host has not read, and the
-            # prefills whose first token is still on the chip.
-            "in_flight": None, "rounds_ahead": 0,
-            "joins": [], "first_tokens_deferred": 0}
+        st = self.run_state()
         completed: List[Request] = st["completed"]
         prompts_dev: Dict[int, Any] = {}
         self._chunking.clear()
@@ -1247,22 +1256,12 @@ class ServingEngine:
 
                 with phase("serve.admit"):
                     admitted = sched.admit(now())
-                # The plain joins of one length that this turn admitted
-                # go through one prefill program, up to four of them.
-                joins = [(slot, req, prompts_dev.pop(req.rid))
-                         for slot, req in admitted]
-                for group in group_joins(
-                        [self._join_key(req) for _, req, _ in joins],
-                        self.group_rows):
-                    self._begin_prefill(st, [joins[i] for i in group], now)
-
-                if self._chunking:
-                    with phase("serve.chunks"):
-                        self._advance_chunks(st, now)
+                self.join(st, [(slot, req, prompts_dev.pop(req.rid))
+                               for slot, req in admitted], now)
                 if not self._decode_slots():
                     # Nothing to dispatch: what is live has its last
                     # token in flight, or is still being prefilled.
-                    self._catch_up(st, now)
+                    self.catch_up(st, now)
                     continue
 
                 # One continuous-batching round over the decode batch:

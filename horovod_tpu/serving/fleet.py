@@ -161,14 +161,7 @@ class DecodeWorker:
         # role so a fleet trace distinguishes decode meshes from the
         # colocated baseline.
         engine.step._meta["fleet_role"] = "decode"
-        self.st: Dict[str, Any] = {
-            "completed": [], "occ_samples": [], "decode_steps": 0,
-            "spec_rounds": 0, "proposed": 0, "accepted": 0,
-            "prefix_queries": 0, "prefix_hits": 0,
-            "prefill_cached": 0, "prefill_computed": 0,
-            "session_resumes": 0,
-            "last_tokens": np.zeros((engine.slots,), np.int32),
-            "adapter_ids": np.zeros((engine.slots,), np.int32)}
+        self.st: Dict[str, Any] = engine.run_state()
 
     @property
     def scheduler(self):
@@ -195,18 +188,26 @@ class DecodeWorker:
         self.busy_s += time.monotonic() - t0
         return len(buf)
 
-    def local_prefill(self, slot: int, req: Request, prompt_dev,
-                      now) -> None:
-        """Fallback: compute the prompt here (colocated-style) when no
-        prefill worker can serve it."""
+    def local_prefill(self, joins: Sequence[tuple], now) -> float:
+        """Fallback: compute here (colocated-style, the engine's one
+        join) the prompts no prefill worker can serve, ``(slot, request,
+        prompt on the device)`` each, and push this engine's chunked
+        prefills forward by a chunk.  Returns the seconds it took."""
         t0 = time.monotonic()
-        first = self.engine._do_prefill(slot, req, prompt_dev)
-        self.engine._join_decode(self.st, slot, req, first, now)
-        self.busy_s += time.monotonic() - t0
+        self.engine.join(self.st, joins, now)
+        dt = time.monotonic() - t0
+        self.busy_s += dt
+        return dt
 
     def decode_step(self, now) -> float:
+        """The engine's one round and the catch-up behind it: the fleet
+        imports pages into slots between rounds, so nothing stays in
+        flight.  Without a slot to decode, the catch-up alone (a join
+        that is done with its first token)."""
         t0 = time.monotonic()
-        self.engine.decode_once(self.st, now)
+        if self.engine._decode_slots():
+            self.engine.decode_once(self.st, now)
+        self.engine.catch_up(self.st, now)
         dt = time.monotonic() - t0
         self.busy_s += dt
         return dt
@@ -403,7 +404,9 @@ class ServingFleet:
                           or self.prefill_workers[0].name)
                 self.kill_prefill(victim)
 
-            # 3. Import last iteration's in-flight pages.
+            # 3. Import last iteration's in-flight pages.  ``local``: the
+            # joins each decode engine computes itself this iteration.
+            local: Dict[str, List[tuple]] = {n: [] for n in self.decode}
             for h in self._in_flight:
                 w = self.decode[h["engine"]]
                 t0 = time.monotonic()
@@ -413,8 +416,8 @@ class ServingFleet:
                     # Publisher died and its object was reaped: the
                     # prompt is re-computed locally; the stream stays
                     # correct, only the offload is lost.
-                    w.local_prefill(h["slot"], h["req"],
-                                    prompts_dev[h["req"].rid], now)
+                    local[h["engine"]].append(
+                        (h["slot"], h["req"], prompts_dev[h["req"].rid]))
                     handoffs_local += 1
                     self._m_handoffs.labels(outcome="local").inc()
                 else:
@@ -440,13 +443,12 @@ class ServingFleet:
                         dispatch.append({"engine": name, "slot": slot,
                                          "req": req})
                     else:
-                        t0 = time.monotonic()
-                        w.local_prefill(slot, req,
-                                        prompts_dev.pop(req.rid), now)
+                        local[name].append(
+                            (slot, req, prompts_dev.pop(req.rid)))
                         handoffs_local += 1
                         self._m_handoffs.labels(outcome="local").inc()
-                        busy[name] = busy.get(name, 0.0) \
-                            + (time.monotonic() - t0)
+                busy[name] = busy.get(name, 0.0) \
+                    + w.local_prefill(local[name], now)
 
             # 5. Dispatch prefills round-robin over alive workers.
             for d in dispatch:
@@ -468,9 +470,7 @@ class ServingFleet:
 
             # 6. One decode round per engine with live decode slots.
             for name, w in self.decode.items():
-                if w.engine._decode_slots():
-                    dt = w.decode_step(now)
-                    busy[name] = busy.get(name, 0.0) + dt
+                busy[name] = busy.get(name, 0.0) + w.decode_step(now)
 
             # 7. Fleet controller.
             if self.scaler is not None:

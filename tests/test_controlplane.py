@@ -414,6 +414,60 @@ def test_drain_completion_path_bitwise(base_params, baseline_tokens):
     assert plane.engine.cache.allocated_pages == 0
 
 
+@pytest.mark.parametrize("how", ["undisturbed", "same_mesh_reprefill",
+                                 "chunked_across_a_drain"])
+def test_the_planes_loop_serves_what_serve_serves(base_params, how):
+    """The control plane's loop goes through the engine's one join and
+    one round: ten requests over six slots (the first admission brings
+    four of one length, one prefill program) are served the tokens
+    ``serve`` serves them, bitwise, every first token left on the chip
+    -- undisturbed, across a suspend-and-re-prefill onto the same mesh,
+    and where the drain comes while prompts still go in chunk by chunk
+    (they go in whole first: a request is suspended by its tokens)."""
+    _, params = base_params
+    chunked = how == "chunked_across_a_drain"
+    kw = dict(slots=6, page_size=8, max_len=32,
+              prefill_chunk=4 if chunked else 0)
+
+    def requests():
+        rng = np.random.RandomState(2)
+        return [Request(rid=i, prompt=rng.randint(0, 60, size=n)
+                        .astype(np.int32), max_new_tokens=o, arrival_s=0.0)
+                for i, (n, o) in enumerate(zip(
+                    [6, 6, 9, 6, 6, 5, 6, 9, 6, 6],
+                    [5, 3, 4, 6, 1, 4, 3, 5, 2, 4]))]
+
+    want = requests()
+    ServingEngine(CFG, params, **kw).serve(want)
+    swap = Decision("shrink", "scripted-swap", target_size=1)
+    script = {"undisturbed": {}, "same_mesh_reprefill": {2: swap},
+              "chunked_across_a_drain": {0: swap}}[how]
+    plane = ServingControlPlane(
+        CFG, params, devices=jax.devices()[:1], initial_tp=1,
+        policy=ScriptedPolicy(script),
+        policy_config=PolicyConfig(interval_s=0.0, drain_steps=0), **kw)
+    spans.recorder().reset()
+    got = requests()
+    rep = plane.serve(got)
+    assert rep.lost_requests == 0 and rep.drain_leaked_pages == 0
+    assert rep.resizes == (how != "undisturbed")
+    assert [r.tokens for r in got] == [r.tokens for r in want]
+    st = plane._stats
+    assert st["first_tokens_deferred"] == st["prefills"] == len(got)
+    assert st["in_flight"] is None and not st["joins"]
+    assert st["prefills_grouped"] >= (0 if chunked else 4)
+    if chunked:
+        # The six of the first admission were one chunk in when the
+        # drain came: one was done with its first token, five were
+        # suspended with theirs.
+        assert rep.drained_reprefilled == 5
+    prefills = spans.recorder().records(name="serve.prefill")
+    assert {p.attrs["deferred"] for p in prefills} <= {True}
+    assert {p.attrs["behind"] for p in prefills} <= {-1}
+    assert bool(prefills) == (not chunked)
+    assert plane.engine.cache.allocated_pages == 0
+
+
 def test_drain_reprefill_path_across_shrink(base_params, baseline_tokens):
     _, params = base_params
     # Zero drain budget: the mid-decode request is suspended and

@@ -273,26 +273,39 @@ def test_fleet_scaler_grows_under_surge(base_params, kv_plane):
     assert "horovod_fleet_engines 2" in text
 
 
+@pytest.mark.parametrize("prefill_worker", ["killed", "none"])
 def test_dead_prefill_worker_falls_back_local_zero_leaks(base_params,
-                                                         kv_plane):
+                                                         kv_plane,
+                                                         prefill_worker):
     """Killing the only prefill worker mid-run reaps its un-imported
     KV objects; affected requests re-prefill LOCALLY on the decode
-    engine and the run completes with zero leaked pages."""
+    engine (all of them, where the fleet never had a worker) and the run
+    completes with zero leaked pages.  A local prefill is the engine's
+    one join: the streams are ``serve``'s, bitwise, and every first
+    token it computed was left on the chip."""
     spec = LoadSpec(num_requests=16, rate_rps=60.0, prompt_lens=(8, 16),
                     output_lens=(6, 10), seed=5)
+    want = generate(spec)
+    assert _engine(base_params).serve(want).completed == 16
     reqs = generate(spec)
+    killed = prefill_worker == "killed"
+    worker = DecodeWorker("decode0", _engine(base_params), kv_plane)
     fleet = ServingFleet(
-        [PrefillWorker("p0", CFG, base_params, kv_plane, page_size=8)],
-        [DecodeWorker("decode0", _engine(base_params), kv_plane)],
-        kv_plane)
-    frep = fleet.serve(reqs, kill_prefill_at_step=2)
+        [PrefillWorker("p0", CFG, base_params, kv_plane, page_size=8)]
+        if killed else [], [worker], kv_plane)
+    frep = fleet.serve(reqs, kill_prefill_at_step=2 if killed else None)
     assert frep.completed == 16
     # The kill forced at least one local fallback; nothing was lost.
-    assert frep.handoffs_local >= 1
+    assert frep.handoffs_local >= (1 if killed else 16)
     assert frep.handoffs_streamed + frep.handoffs_local == 16
     assert frep.leaked_pages == {"decode0": 0}
     assert frep.refcounts_balanced
-    assert not fleet.prefill_workers[0].alive
+    assert not killed or not fleet.prefill_workers[0].alive
+    assert {r.rid: list(r.tokens) for r in reqs} \
+        == {r.rid: list(r.tokens) for r in want}
+    assert worker.st["first_tokens_deferred"] == worker.st["prefills"] \
+        == frep.handoffs_local
+    assert worker.st["in_flight"] is None and not worker.st["joins"]
 
 
 # ---------------------------------------------------------------------------

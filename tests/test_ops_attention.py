@@ -474,6 +474,7 @@ def test_decode_attention_validation():
 # ---------------------------------------------------------------------------
 
 from horovod_tpu.ops.attention import cca_decode_attention
+from serving_families import lowered_for_tpu as _lowered_for_tpu
 
 _PAGE, _PPS, _PPB = 8, 12, 4        # a block of 32 keys, three a slot
 
@@ -607,37 +608,6 @@ _ONE_POOL_LOWERED = {
     "ouro": "1612732b6a3731f777c426eb6f26b813cc2180d6f1c47ce1949e9444be4721ca",
     "joyai": "435906f506d3563cc70ad4590b1df2d3ab8371ba85e4d833dc22f4c91d73bf09",
 }
-
-
-def _lowered_for_tpu(fn, *args):
-    import base64
-    import re
-
-    from jax._src.interpreters import mlir as jmlir
-    from jaxlib.mlir import ir
-
-    def body(match):
-        with jmlir.make_ir_context() as ctx:
-            ctx.allow_unregistered_dialects = True
-            return ir.Module.parse(base64.b64decode(
-                match.group(1))).operation.get_asm(enable_debug_info=False)
-
-    text = jax.jit(fn).trace(*args).lower(
-        lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
-    return re.sub(r'(?<=body\\22: \\22)([A-Za-z0-9+/=]+)(?=\\22)', body,
-                  text)
-
-
-def _bf16_prefill_gaps(prefill_forward, cfg, params, toks, want):
-    """A family's prefill computing in bfloat16, under whatever kernel
-    switch the environment has NOW: the jaxpr's text and every row's
-    distance from ``want``, the family's float32 reference logits."""
-    traced = jax.jit(lambda p, x: prefill_forward(
-        p, cfg, x, dtype=jnp.bfloat16, last_only=False)[0][0]).trace(
-            params, toks)
-    return str(traced.jaxpr), np.abs(np.asarray(
-        traced.lower().compile()(params, toks)) - want)
 
 
 @pytest.mark.parametrize("cell", list(_ONE_POOL_LOWERED))
@@ -1088,7 +1058,8 @@ def test_blocked_forward_multiplies_in_the_operands_type(monkeypatch, kernel,
     assert columns == ["broadcast_in_dim"] * 2       # the two keepdims
 
 
-# sha256 of the TPU lowering (``_lowered_for_tpu``) of the blocked kernels.
+# sha256 of the TPU lowering (``serving_families.lowered_for_tpu``) of the
+# blocked kernels.
 # The backward pair and the head-group pair were recorded on PR 40's tree,
 # the parent of the PR that rewrote ``_fwd_kernel``'s step: that PR did
 # not touch them, in either type.  The forward's were recorded on PR 41's

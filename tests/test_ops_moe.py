@@ -494,7 +494,7 @@ def test_the_other_blocks_expert_layers_lower_to_what_they_lowered_to(
         monkeypatch, block, rows):
     import hashlib
 
-    from test_ops_attention import _lowered_for_tpu
+    from serving_families import lowered_for_tpu as _lowered_for_tpu
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     monkeypatch.setattr(attention._pallas, "interpret_mode", lambda: False)
     S, bf, f32 = jax.ShapeDtypeStruct, jnp.bfloat16, jnp.float32

@@ -1,52 +1,39 @@
 """``ServingEngine.serve`` runs one decode round ahead: the step samples
 and screens on the chip, and the host reads round n while round n + 1 is
-queued.  What it serves is, token for token, what the round-by-round form
-of ``decode_once`` serves (the control plane's: dispatch and read in one
-call), for each of the three families; the time stamps are taken when the
-host has the token.
+queued.  What it serves is, token for token, what a loop serves that
+catches up behind every round (the control plane's and the fleet's: the
+same ``decode_once``, then ``catch_up``), for each of the five families;
+the time stamps are taken when the host has the token.
 """
 
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.families import joyai_mla_moe, zaya_cca_moe
-from horovod_tpu.models.transformer import LLAMA_SERVE, LlamaLM
-from horovod_tpu.serving import Request, ServingEngine, cca_moe, mla_moe
+from horovod_tpu.serving import Request, ServingEngine
 from horovod_tpu.serving import engine as engine_mod
 from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.timeline import metrics, spans
-from test_serving_cca_moe import TINY as TINY_CCA
-from test_serving_mla_moe import TINY as TINY_MLA
+from serving_families import FAMILIES, round_by_round
 
-SLOTS, PAGE, MAX_LEN, VOCAB = 3, 8, 32, 256
+SLOTS, PAGE, MAX_LEN = 3, 8, 32
 CAPPED, POISONED = 2, 4          # the rids of two requests, see _requests
-
-
-def _gqa():
-    return LLAMA_SERVE, LlamaLM(LLAMA_SERVE, dtype=jnp.float32).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
-
-
-def _mla():
-    cfg = joyai_mla_moe.program_config(TINY_MLA)
-    return cfg, mla_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-def _cca():
-    cfg = zaya_cca_moe.program_config(TINY_CCA)
-    return cfg, cca_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-FAMILIES = {"dense_gqa": _gqa, "mla_moe": _mla, "cca_moe": _cca}
 
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
 def family(request):
     return FAMILIES[request.param]()
+
+
+@pytest.fixture(autouse=True)
+def _no_poison_left_in_the_registry():
+    """A poisoned round reaches what a step publishes of itself (the
+    looped block's ``loop.exit_mass`` is NaN after one), and the
+    registry is the process's: no later file's rendering may find it."""
+    yield
+    metrics.reset_metrics()
 
 
 def _engine(family):
@@ -55,7 +42,7 @@ def _engine(family):
                          max_len=MAX_LEN, dtype=jnp.float32)
 
 
-def _requests():
+def _requests(cfg):
     """Seven requests over three slots, all there at t = 0: four join
     mid-stream as slots come free, they finish at different rounds, one
     is done with its prefill's token, and ``CAPPED`` fills its slot to
@@ -64,7 +51,8 @@ def _requests():
     lens = [5, 9, 12, 4, 7, 6, 10]
     outs = [6, 3, 20, 1, 9, 4, 5]
     assert lens[CAPPED] + outs[CAPPED] == MAX_LEN
-    return [Request(rid=i, prompt=rng.randint(0, VOCAB, size=n)
+    return [Request(rid=i, prompt=rng.randint(
+                        0, min(256, cfg.vocab_size), size=n)
                     .astype(np.int32), max_new_tokens=o, arrival_s=0.0)
             for i, (n, o) in enumerate(zip(lens, outs))]
 
@@ -110,34 +98,6 @@ def _reprefills() -> float:
         "horovod_guard_serving_reprefills_total").value
 
 
-def _plain_st():
-    """The control plane's ``st``: it carries no look-ahead."""
-    return {"completed": [], "occ_samples": [], "decode_steps": 0,
-            "last_tokens": np.zeros((SLOTS,), np.int32),
-            "adapter_ids": np.zeros((SLOTS,), np.int32)}
-
-
-def _round_by_round(eng, reqs):
-    """The control plane's form: one round dispatched AND read a call of
-    ``decode_once``."""
-    sched, st = eng.scheduler, _plain_st()
-    t0 = time.monotonic()
-
-    def now():
-        return time.monotonic() - t0
-
-    for req in reqs:
-        sched.submit(req)
-    while sched.has_work():
-        for slot, req in sched.admit(now()):
-            first = eng._do_prefill(slot, req,
-                                    jnp.asarray(req.prompt, jnp.int32))
-            eng._join_decode(st, slot, req, first, now)
-        if eng._decode_slots():
-            eng.decode_once(st, now)
-    return st
-
-
 def _drained(eng, reqs, total_pages):
     assert eng.cache.free_pages == total_pages
     assert not eng.cache.lengths.any()
@@ -157,15 +117,16 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
             return readings[-1] / 1e9
 
     # -- round by round: the tokens to match --------------------------------
+    cfg = family[0]
     eng = _engine(family)
     total_pages = eng.cache.free_pages
     _lift_count(eng)
     fired = _poison_once(eng)
     rec = spans.recorder()
     rec.reset()
-    want = _requests()
+    want = _requests(cfg)
     before = _reprefills()
-    st = _round_by_round(eng, want)
+    st = round_by_round(eng, want)
     assert len(st["completed"]) == len(want) and len(fired) == 1
     assert _reprefills() - before == 1
     _drained(eng, want, total_pages)
@@ -181,7 +142,7 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
     _lift_count(eng)
     fired = _poison_once(eng)
     counted, paged, caught_up, booked = [], [], [], []
-    decode_once, catch_up = eng.decode_once, eng._catch_up
+    decode_once, catch_up = eng.decode_once, eng.catch_up
     note = eng.scheduler.note_decode_token
 
     def counting(st, now):
@@ -195,7 +156,7 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
         return decode_once(st, now)
 
     def catching_up(st, now, dropped=()):
-        if st.get("in_flight") is not None:
+        if st["in_flight"] is not None:
             caught_up.append(st["decode_steps"])
         return catch_up(st, now, dropped)
 
@@ -204,11 +165,11 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
             r.name == "decode.sample_fetch" for r in rec.records())))
         return note(req, now_s)
 
-    eng.decode_once, eng._catch_up = counting, catching_up
+    eng.decode_once, eng.catch_up = counting, catching_up
     eng.scheduler.note_decode_token = noting
     monkeypatch.setattr(engine_mod, "time", SpanClock)
     rec.reset()
-    got = _requests()
+    got = _requests(cfg)
     before = _reprefills()
     report = eng.serve(got)
     monkeypatch.undo()
@@ -265,26 +226,29 @@ def test_the_step_samples_and_screens_its_own_logits(family):
     returns; a slot given ``-1`` takes its token from ``prev``, and sits
     the round out where ``prev`` screened it as not finite."""
     eng = _engine(family)
-    reqs, st = _requests()[:2], _plain_st()
+    reqs, st = _requests(family[0])[:2], eng.run_state()
     for req in reqs:
         eng.scheduler.submit(req)
-    for slot, req in eng.scheduler.admit(0.0):
-        first = eng._do_prefill(slot, req, jnp.asarray(req.prompt))
-        eng._join_decode(st, slot, req, first, lambda: 0.0)
+    eng.join(st, [(slot, req, jnp.asarray(req.prompt))
+                  for slot, req in eng.scheduler.admit(0.0)], lambda: 0.0)
+    eng.catch_up(st, lambda: 0.0)
     cache, tells = eng.cache, len(eng.spec.step_tells)
 
     def run(tokens, prev):
         for slot in (0, 1):
             n = int(cache.lengths[slot])
             cache.reserve(slot, n + 1, writable_from=n)
-        own = () if cache.state is None else (jnp.copy(cache.state),)
-        state = tuple(jnp.copy(x) for x in eng._step_state)
+        # (The step donates its pools and its state: copies.)
+        window = () if cache.window_table is None \
+            else (cache.window_table_device(),)
+        state = tuple(jnp.copy(x)
+                      for x in (*cache.carried, *eng._step_state))
         out = eng.step(
             eng._decode_params, jnp.copy(cache.k),
             None if cache.v is None else jnp.copy(cache.v),
             jnp.asarray(tokens, jnp.int32), cache.lengths_device(),
             cache.table_device(), jnp.asarray([True, True, False]),
-            *own, *state, prev)
+            *window, *state, prev)
         return np.asarray(out[0]), out[1], read_told(out[-1], SLOTS)
 
     held = st["last_tokens"].copy()

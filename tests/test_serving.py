@@ -1106,10 +1106,9 @@ def test_3d_checkpoint_roundtrip_into_serving(base_params, tmp_path):
 from horovod_tpu.serving import engine as engine_mod  # noqa: E402
 from horovod_tpu.timeline import metrics  # noqa: E402
 from benchmarks.lib.tracing import CompileCounter  # noqa: E402
-from test_serve_lookahead import SLOTS as JOIN_SLOTS  # noqa: E402
-from test_serve_lookahead import _round_by_round  # noqa: E402
-from test_spans_clock import FAMILIES  # noqa: E402
+from serving_families import FAMILIES, round_by_round  # noqa: E402
 
+JOIN_SLOTS = 3
 ONE_TOKEN = 3                  # the rid whose first token is its last
 
 
@@ -1135,12 +1134,12 @@ def _join_requests(cfg):
 
 
 def _synchronous_join(eng, reqs):
-    """The reference: every first token fetched by the host as its
-    prefill is dispatched, every round dispatched AND read by one call
-    (the loop as it was before a join's token stayed on the chip; the
-    control plane's form of it)."""
-    st = _round_by_round(eng, reqs)
+    """The reference: a loop that catches up behind every join's round,
+    so that no round is dispatched before the host has every token of
+    the one before (the control plane's form of the loop)."""
+    st = round_by_round(eng, reqs)
     assert len(st["completed"]) == len(reqs)
+    assert st["first_tokens_deferred"] == st["prefills"] == len(reqs)
     return [list(r.tokens) for r in reqs]
 
 
@@ -1323,6 +1322,14 @@ def test_a_first_token_that_is_not_finite_is_never_served(family):
     assert report.completed == len(reqs) and eng.cache.free_pages == pages
     assert [r.tokens for r in reqs] == want
     assert not any(r.in_flight for r in reqs)
-    again = [p for p in rec.records(name="serve.prefill")
-             if not p.attrs["deferred"]]
-    assert sorted(p.attrs["rid"] for p in again) == [1, 4]
+    # Each of the two was handed in a second time, like any join: its
+    # token left on the chip again, and read with what the host read
+    # next.
+    prefills = rec.records(name="serve.prefill")
+    assert all(p.attrs["deferred"] is True for p in prefills)
+    rids = [p.attrs["rid"] for p in prefills]
+    assert sorted(rid for rid in set(rids) if rids.count(rid) == 2) == [1, 4]
+    assert len(rids) == len(reqs) + 2
+    account, = rec.records(name="serve.account")
+    assert account.attrs["first_tokens_deferred"] \
+        == account.attrs["prefills"] == len(reqs) + 2

@@ -18,15 +18,8 @@ from horovod_tpu.serving import cca_moe
 from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.serving.layerspec import LayerSpec, layer_spec
 from horovod_tpu.timeline import metrics, spans
+from serving_families import TINY_CCA as TINY
 
-TINY = {
-    "vocab_size": 256, "hidden_size": 64, "moe_intermediate_size": 32,
-    "num_hidden_layers": 3, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
-    "num_experts_per_tok": 1, "router_hidden_size": 16, "cca_time0": 2,
-    "cca_time1": 2, "partial_rotary_factor": 0.5,
-    "rope_parameters": {"hybrid": {"rope_theta": 10000.0}},
-    "rms_norm_eps": 1e-5, "max_position_embeddings": 128}
 CFG = family.program_config(TINY)
 STATE = 2 * (4 + 2) * 16 + 16          # u, a, W_v2 h: 208 values a layer
 

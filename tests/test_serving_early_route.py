@@ -21,21 +21,15 @@ from horovod_tpu import serving
 from horovod_tpu.ops import moe
 from horovod_tpu.serving import swa_moe
 from horovod_tpu.timeline import metrics as _metrics
+from serving_families import EARLY_EPS as EPS
+from serving_families import EARLY_KINDS as KINDS
+from serving_families import EARLY_THETA as THETA
+from serving_families import EARLY_TOP_K as TOP_K
+from serving_families import EARLY_WINDOW as WINDOW
+from serving_families import early_route as _tiny
 
 HI = jax.lax.Precision.HIGHEST
-KINDS = ("full", "window", "window", "window")
-WINDOW, PAGE, THETA, EPS, TOP_K = 8, 4, 10000.0, 1e-6, 3
-
-
-def _tiny(**over):
-    cfg = swa_moe.SwaMoeConfig(**dict(dict(
-        vocab_size=64, d_model=32, num_heads=14, num_kv_heads=2, head_dim=16,
-        ffn_hidden=0, moe_hidden=16, num_experts=8, experts_per_token=TOP_K,
-        attn_kinds=KINDS, ffn_kinds=("moe",) * 4, window=WINDOW,
-        num_shared_experts=0, rope_theta=THETA, rms_eps=EPS, max_seq_len=64,
-        qk_norm=False, router="topk_softmax", route_from="layer_input",
-        gate_act="relu"), **over))
-    return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
+PAGE = 4
 
 
 # -- the plain forward ------------------------------------------------------------

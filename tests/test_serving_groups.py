@@ -19,13 +19,12 @@ from horovod_tpu.serving import Request, ServingEngine, stack_adapters
 from horovod_tpu.serving import engine as engine_mod
 from horovod_tpu.serving.engine import group_joins, group_size
 from horovod_tpu.timeline import spans
-from test_serving_early_route import _tiny as _early_route_family
-from test_spans_clock import FAMILIES as _FIVE
-
 # The five served families, the window-and-full routed block in both its
 # instances: K-EXAONE's and the one with a router that reads the layer's
 # input ahead of attention (SmallThinker's).
-FAMILIES = dict(_FIVE, swa_moe_early_route=_early_route_family)
+from serving_families import FAMILIES_AND_EARLY_ROUTE as FAMILIES
+from serving_families import host_first_tokens
+
 SLOTS, PAGE, MAX_LEN = 8, 4, 32
 
 
@@ -180,12 +179,16 @@ def served(request):
         out[how] = dict(reqs=reqs, held=dict(held), account=account.attrs,
                         prefills=rec.records(name="serve.prefill"),
                         lowered=lowerings.count)
-    return out
+    return dict(out, eng=eng, cfg=cfg)
 
 
 def test_a_group_is_served_what_its_prompts_are_served_alone(served):
     want, got = served["alone"], served["grouped"]
     assert [r.tokens for r in got["reqs"]] == [r.tokens for r in want["reqs"]]
+    # ... and each first token, a member of four's too, is the one the
+    # host reads off the prompt's own prefill (no hand-over on the way).
+    assert [r.tokens[0] for r in got["reqs"]] \
+        == host_first_tokens(served["eng"], got["reqs"])
     for r in got["reqs"]:
         assert len(r.tokens) == r.max_new_tokens
         assert r.admit_s <= r.prefill_start_s <= r.first_token_s
@@ -239,6 +242,37 @@ def test_a_group_compiles_nothing_when_it_forms(served):
     follow, where groups of four form, lower nothing."""
     assert served["alone"]["lowered"] == 0
     assert served["grouped"]["lowered"] == 0
+
+
+def test_a_re_prefill_restores_what_the_join_wrote(served):
+    """A request that has its first token and nothing more is rebuilt
+    from its prompt alone: ``re_prefill`` runs the join's own program
+    and writes, so the pools' rows, the window planes' rows and the
+    slot state come back as the join left them, bit for bit."""
+    eng, cfg = served["eng"], served["cfg"]
+    req = _band_requests(cfg)[1]
+    assert req.prompt_len == 10
+    st = eng.run_state()
+    eng.scheduler.submit(req)
+    (slot, _), = eng.scheduler.admit(0.0)
+    eng.join(st, [(slot, req, jnp.asarray(req.prompt))], lambda: 0.0)
+    eng.catch_up(st, lambda: 0.0)
+    assert len(req.tokens) == 1 and not st["joins"]
+    want = _held(eng, slot)
+    # What the cache holds goes bad, every row of it.
+    c = eng.cache
+    c.k = c.k + 1e3
+    for name in ("v", "wk", "wv", "state"):
+        if getattr(c, name) is not None:
+            setattr(c, name, getattr(c, name) + 1e3)
+    assert not np.array_equal(_held(eng, slot)["k"], want["k"])
+    assert eng.re_prefill(slot, req) == req.tokens[-1]
+    got = _held(eng, slot)
+    assert got.keys() == want.keys() and got["length"] == 10
+    for name in set(want) - {"length"}:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+    eng.scheduler.release(slot, 0.0)
+    assert not eng.cache.lengths.any()
 
 
 # -- what is never grouped -----------------------------------------------------
@@ -317,6 +351,8 @@ def test_a_chunked_prompt_is_never_grouped(dense):
     assert len(chunks) == 2 * 3
     assert (account["prefills"], account["prefill_groups"],
             account["prefills_grouped"]) == (6, 1, 4)
+    # The chunked ones' tokens stay on the chip as the group's do.
+    assert account["first_tokens_deferred"] == 6
 
 
 def test_two_adapter_ids_are_never_one_group():

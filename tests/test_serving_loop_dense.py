@@ -21,13 +21,8 @@ from horovod_tpu.serving import kvwire, loop_dense, stepparts
 from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.serving.layerspec import layer_spec
 from horovod_tpu.timeline import metrics, spans
+from serving_families import TINY_LOOP as TINY
 
-TINY = {
-    "vocab_size": 97, "hidden_size": 32, "intermediate_size": 48,
-    "num_hidden_layers": 3, "num_attention_heads": 4,
-    "num_key_value_heads": 4, "head_dim": 8, "total_ut_steps": 3,
-    "early_exit_threshold": 1, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
-    "max_position_embeddings": 128}
 CFG = family.program_config(TINY)
 LAYERS, PASSES, PLANES, ROW = 3, 3, 9, 2 * 4 * 8
 

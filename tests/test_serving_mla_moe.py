@@ -18,17 +18,9 @@ from horovod_tpu.serving import mla_moe
 from horovod_tpu.serving.decode import no_round, read_told
 from horovod_tpu.serving.layerspec import layer_spec
 from horovod_tpu.timeline import metrics, spans
-from test_ops_attention import _bf16_prefill_gaps
+from serving_families import TINY_MLA as TINY
+from serving_families import bf16_prefill_gaps as _bf16_prefill_gaps
 
-TINY = {
-    "vocab_size": 256, "hidden_size": 64, "intermediate_size": 128,
-    "moe_intermediate_size": 32, "num_hidden_layers": 3,
-    "first_k_dense_replace": 1, "num_attention_heads": 4,
-    "q_lora_rank": 32, "kv_lora_rank": 32, "qk_nope_head_dim": 16,
-    "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 16,
-    "num_experts_per_tok": 4, "n_shared_experts": 1,
-    "routed_scaling_factor": 2.5, "rope_theta": 10000.0,
-    "rms_norm_eps": 1e-6, "max_position_embeddings": 128}
 CFG = family.program_config(TINY)
 
 # float32 against float32: what is left is the order of summation (the

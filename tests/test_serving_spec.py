@@ -126,6 +126,39 @@ def test_spec_round_accounting_and_metric_family(base_params):
     assert 'horovod_serving_spec_tokens_total{outcome="accepted"}' in text
 
 
+@pytest.mark.parametrize("drafter", ["ngram", "model"])
+def test_a_speculative_round_catches_up_first(base_params, drafter):
+    """``spec_round`` takes the engine's one join and its one catch-up:
+    a joining prompt's first token stays on the chip like any, and the
+    host reads it (alone: no round is ever in flight here) before the
+    verify round that drafts from it is built."""
+    from horovod_tpu.timeline import spans
+    _, params = base_params
+    drafter = NgramDrafter() if drafter == "ngram" else ModelDrafter(
+        CFG, params, slots=4, page_size=8, max_len=64, dtype=jnp.float32)
+    rec = spans.recorder()
+    rec.reset()
+    _, rep = _serve_streams(params, ndev=1, spec_decode=True, spec_k=3,
+                            drafter=drafter)
+    account, = rec.records(name="serve.account")
+    assert account.attrs["first_tokens_deferred"] \
+        == account.attrs["prefills"] == 8
+    prefills = rec.records(name="serve.prefill")
+    assert {p.attrs["deferred"] for p in prefills} == {True}
+    assert {p.attrs["behind"] for p in prefills} == {-1}
+    fetches = rec.records(name="prefill.sample_fetch")
+    ids = {p.id for p in prefills}
+    assert fetches and not any(f.parent in ids for f in fetches)
+    assert sum(f.attrs["joins"] for f in fetches) == 8
+    rounds = rec.records(name="decode.round")
+    assert rep.rounds_ahead == 0 and not any(r.attrs["ahead"]
+                                             for r in rounds)
+    # Each fetch lies before the round that follows it, under none.
+    for f in fetches:
+        assert all(r.end_ns <= f.start_ns or f.end_ns <= r.start_ns
+                   for r in rounds)
+
+
 def test_spec_fields_zero_when_disabled(base_params):
     _, params = base_params
     _, rep = _serve_streams(params, ndev=1)

@@ -22,32 +22,12 @@ from horovod_tpu.ops import attention as _attn
 from horovod_tpu.ops import moe
 from horovod_tpu.serving import kvcache, layerspec, stepparts, swa_moe
 from horovod_tpu.timeline import metrics as _metrics
-from test_ops_attention import _bf16_prefill_gaps, _lowered_for_tpu
+from serving_families import TINY_SWA as TINY
+from serving_families import bf16_prefill_gaps as _bf16_prefill_gaps
+from serving_families import lowered_for_tpu as _lowered_for_tpu
+from serving_families import swa_moe as _tiny
 
 KINDS = ("window", "window", "full", "window")
-TINY = {
-    "kind": "serve", "family": "exaone_swa_moe", "vocab_size": 32,
-    "hidden_size": 32, "intermediate_size": 48, "moe_intermediate_size": 16,
-    "num_hidden_layers": 4, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 16, "num_experts": 4,
-    "num_experts_per_tok": 4, "num_shared_experts": 1,
-    "routed_scaling_factor": 2.5, "sliding_window": 8,
-    "layer_types": ["sliding_attention", "sliding_attention",
-                    "full_attention", "sliding_attention"],
-    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse"],
-    "rope_parameters": {"rope_theta": 10000.0, "rope_type": "default"},
-    "rms_norm_eps": 1e-5, "max_position_embeddings": 64,
-    "published": {"vocab_size": 64, "num_experts": 16},
-    "share": {"first_expert": 4, "experts_held": 4},
-    "compute_dtype": "float32",
-    "serving": {"slots": 3, "page_size": 4, "max_len": 64},
-    "limits": {"served_logit_gap_max": 1e-3, "routing_margin_min": 0.0,
-               "routing_branches_max": 1}}
-
-
-def _tiny(**over):
-    cfg = family.program_config(dict(TINY, **over))
-    return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
 
 
 def _requests(lens, vocab=32, seed=0):
@@ -536,7 +516,7 @@ def test_what_a_window_group_cannot_do_is_refused_by_name(feature):
 # -- (5) the other served cells' walks lower to what they lowered to ---------------
 
 # sha256 of the TPU lowering (Mosaic bodies printed without source
-# locations: ``tests/test_ops_attention.py:_lowered_for_tpu``) recorded
+# locations: ``tests/serving_families.py:lowered_for_tpu``) recorded
 # on PR 38's tree, the parent of the PR that gave the walk its window:
 # Mistral's two-pool walk.  The three one-pool walks are held by
 # ``test_one_pool_walk_lowers_to_what_it_was``.  The blocked flash

@@ -18,6 +18,7 @@ import horovod_tpu as hv
 from horovod_tpu.models.transformer import LLAMA_SERVE, LlamaLM
 from horovod_tpu.serving import Request, ServingEngine
 from horovod_tpu.timeline import spans
+from serving_families import FAMILIES
 
 CFG = LLAMA_SERVE
 ROUND_CHILDREN = {"decode.reserve", "decode.args", "decode.dispatch",
@@ -447,48 +448,6 @@ def test_token_latency_sees_a_prefill_that_stalls_the_batch(params,
 
 # -- the serve loop's account of itself, on every served family ---------------
 
-def _dense_family():
-    return CFG, LlamaLM(CFG, dtype=jnp.float32).init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
-
-
-def _mla_family():
-    from benchmarks.families import joyai_mla_moe
-    from horovod_tpu.serving import mla_moe
-    from test_serving_mla_moe import TINY
-    cfg = joyai_mla_moe.program_config(TINY)
-    return cfg, mla_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-def _cca_family():
-    from benchmarks.families import zaya_cca_moe
-    from horovod_tpu.serving import cca_moe
-    from test_serving_cca_moe import TINY
-    cfg = zaya_cca_moe.program_config(TINY)
-    return cfg, cca_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-def _loop_family():
-    from benchmarks.families import ouro_loop
-    from horovod_tpu.serving import loop_dense
-    from test_serving_loop_dense import TINY
-    cfg = ouro_loop.program_config(TINY)
-    return cfg, loop_dense.init_params(cfg, jax.random.PRNGKey(0))
-
-
-def _swa_family():
-    from benchmarks.families import exaone_swa_moe
-    from horovod_tpu.serving import swa_moe
-    from test_serving_swa import TINY
-    cfg = exaone_swa_moe.program_config(TINY)
-    return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
-
-
-FAMILIES = {"dense": _dense_family, "mla_moe": _mla_family,
-            "cca_moe": _cca_family, "loop_dense": _loop_family,
-            "swa_moe": _swa_family}
-
-
 @pytest.fixture(scope="module", params=list(FAMILIES))
 def served(request):
     """One ``serve`` call of a tiny engine of the family, the look-ahead
@@ -619,25 +578,50 @@ def test_a_request_says_when_its_prefill_began(served):
         assert a["admit_s"] <= a["prefill_start_s"] <= a["first_token_s"]
 
 
-def test_the_last_chunk_of_a_chunked_prefill_holds_its_write_and_fetch(
-        params):
+@pytest.mark.parametrize("how", ["chunked", "prefix_hit"])
+def test_a_last_chunk_and_a_prefix_hit_hand_their_token_over(params, how):
     """A chunked prefill's stall is one ``prefill_chunk`` tree: the pool
-    write and the first token's fetch lie under the last chunk's span,
-    so the account has no hole there."""
+    write and the first token's HAND-OVER lie under the last chunk's
+    span, so the account has no hole there; a prefix hit's tail is a
+    ``serve.prefill`` like any.  Neither waits for its token: no
+    ``prefill.sample_fetch`` lies under either span, and every first
+    token of the call was left on the chip."""
+    kw = dict(prefill_chunk=8) if how == "chunked" else dict(
+        prefix_cache=True)
     eng = ServingEngine(CFG, params, mesh=_mesh1(), slots=2, page_size=8,
-                        max_len=64, prefill_chunk=8)
+                        max_len=64, **kw)
     rec = spans.recorder()
     rec.reset()
     reqs = _requests([20, 5], [3, 3])
+    if how == "prefix_hit":
+        # The second prompt is the first one's first two pages and a
+        # tail of its own, and comes when the first is in the tree.
+        reqs[1].prompt = np.concatenate([reqs[0].prompt[:16],
+                                         reqs[1].prompt])
+        reqs[1].arrival_s = 1e3
     report = eng.serve(reqs)
     assert report.completed == 2
-    chunks = rec.records(name="prefill_chunk")
-    assert len(chunks) == 3
-    kids = [r for r in rec.records() if r.parent in {c.id for c in chunks}]
-    assert [(k.name, k.parent) for k in kids] == [
-        ("prefill.write_kv", chunks[-1].id),
-        ("prefill.sample_fetch", chunks[-1].id)]
+    if how == "chunked":
+        under = rec.records(name="prefill_chunk")
+        assert len(under) == 3
+        kids = [r for r in rec.records()
+                if r.parent in {c.id for c in under}]
+        assert [(k.name, k.parent) for k in kids] == [
+            ("prefill.write_kv", under[-1].id),
+            ("prefill.hand_over", under[-1].id)]
+    else:
+        assert report.prefix_hits == 1
+        under = rec.records(name="serve.prefill")
+        assert [p.attrs["deferred"] for p in under] == [True, True]
+        kids = [r for r in rec.records() if r.parent == under[-1].id]
+        assert [k.name for k in kids] == [
+            "prefill.dispatch", "prefill.write_kv", "prefill.hand_over"]
+    ids = {r.id for r in under}
+    fetches = rec.records(name="prefill.sample_fetch")
+    assert fetches and not any(f.parent in ids for f in fetches)
     account, = rec.records(name="serve.account")
+    assert account.attrs["first_tokens_deferred"] \
+        == account.attrs["prefills"] == 2
     own = sum(t["self_ns"] for t in account.attrs["spans"].values())
     assert abs(own - account.attrs["wall_ns"]) \
         <= 1e-3 * account.attrs["wall_ns"]
