@@ -5,9 +5,9 @@ every Pallas kernel family in the package:
 
 - ``auto`` (default): on TPU the families Mosaic has compiled at their
   callers' full-width shapes run as kernels (``flash``,
-  ``flash_decode``, ``mla_decode``, ``moe_gmm``); ``fused_update`` and
-  ``bn_bwd`` resolve to the XLA path (see ``_AUTO_XLA``).  Off TPU every
-  family takes the XLA reference;
+  ``flash_decode``, ``mla_decode``, ``moe_gmm``, ``ssm_decode``);
+  ``fused_update`` and ``bn_bwd`` resolve to the XLA path (see
+  ``_AUTO_XLA``).  Off TPU every family takes the XLA reference;
 - ``1``: force the kernels everywhere (off-TPU they run in the Pallas
   interpreter -- slow, but numerically the kernel path; this is what the
   CPU parity tests and the CI step audit use);
@@ -98,6 +98,16 @@ KERNEL_CONTRACTS = {
         "note": "grouped matmul over the experts a chip holds; the layer "
                 "runs without its exchange on one chip",
     },
+    "ssm_decode": {
+        "collectives": (),
+        "wire_delta_bytes": 0,
+        "site": "ops.ssm.ssm_decode_update",
+        "note": "one step of a state-space recurrence for every live slot "
+                "of a decode round, over the cache's float32 slot-state "
+                "array in place (hvd_ssm_decode: the array aliased input "
+                "to output, the groups of eight slots that hold a live one "
+                "visited, the ids prefetched); no exchange of its own",
+    },
     "fused_update": {
         "collectives": (),
         "wire_delta_bytes": 0,
@@ -118,6 +128,7 @@ _FAMILY_ENV = {
     "flash": "PALLAS_FLASH",
     "flash_decode": "PALLAS_DECODE",
     "mla_decode": "PALLAS_DECODE",     # the decode kernels share a switch
+    "ssm_decode": "PALLAS_DECODE",
     "fused_update": "PALLAS_FUSED_UPDATE",
     "bn_bwd": "PALLAS_BN",
 }

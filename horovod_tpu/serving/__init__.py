@@ -30,6 +30,7 @@ from .loadgen import (LoadSpec, fleet_spec, generate,  # noqa: F401
 from .cca_moe import CcaMoeConfig  # noqa: F401
 from .loop_dense import LoopDenseConfig  # noqa: F401
 from .mla_moe import MlaMoeConfig  # noqa: F401
+from .ssm_hybrid import SsmHybridConfig  # noqa: F401
 from .swa_moe import SwaMoeConfig  # noqa: F401
 from .policy import (Decision, FleetPolicy,  # noqa: F401
                      FleetPolicyConfig, FleetSample, PolicyConfig,

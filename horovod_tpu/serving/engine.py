@@ -389,6 +389,7 @@ class ServingEngine:
             page_size=self.page_size, max_len=self.max_len,
             dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
             page=spec.page, slot_state=spec.slot_state,
+            slot_state_dtype=spec.slot_state_dtype,
             window_layers=spec.window_planes, window=spec.window)
         self.cache = PagedKVCache(self.cache_config,
                                   spec.pool_sharding(mesh))
@@ -641,6 +642,10 @@ class ServingEngine:
             "window_rows": req.prompt_len - window_rows_from(
                 req.prompt_len, self.spec.window),
             "window_planes": self.spec.window_planes}
+        if self.spec.scan_chunk:
+            # A prefill that is a scan: the chunks its members run.
+            windowed["scan_chunks"] = len(members) * -(
+                -req.prompt_len // self.spec.scan_chunk)
         with rec.span("dispatch", name="serve.prefill",
                       leg="serving_prefill", rid=req.rid, slot=slot,
                       prompt_len=req.prompt_len, passes=self.spec.passes,
@@ -850,7 +855,10 @@ class ServingEngine:
         window layer's walk reads in ONE of its planes, and
         ``window_pages_held``, the group's pages that slots hold as the
         round is dispatched (of ``window_num_pages``: a ring grows page
-        by page, so short requests never hold a whole one)."""
+        by page, so short requests never hold a whole one).  With a slot
+        state: ``state_planes`` and ``state_bytes``, the live slots' rows
+        (``LayerSpec.slot_state_step`` values of each, a plane) that the
+        round's update reads and writes again."""
         live = [int(self.cache.lengths[s]) + 1 for s in slots]
         page = self.page_size
         # (A test's stand-in for the step may be a bare function.)
@@ -866,6 +874,15 @@ class ServingEngine:
                                  for n in live),
                 window_planes=self.spec.window_planes,
                 window_pages_held=self.cache.window_live_pages)
+        if self.spec.slot_state is not None:
+            # What the round's state update must read, and write again:
+            # each live slot's row (the part a round rewrites) a plane.
+            spec = self.spec
+            windowed.update(
+                state_planes=spec.planes,
+                state_bytes=len(slots) * spec.planes
+                * (spec.slot_state_step or spec.slot_state)
+                * self.cache.state.dtype.itemsize)
         return _spans.recorder().phase(
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=sum(live), ahead=int(ahead),

@@ -117,8 +117,10 @@ class CacheConfig:
     # ``head_dim`` (which then stay None).
     page: Optional[Tuple[Tuple[int, ...], Optional[Tuple[int, ...]]]] = None
     # Values a slot keeps a layer beside its pages (``LayerSpec.
-    # slot_state``); None: no such state.
+    # slot_state``); None: no such state.  ``slot_state_dtype``: their
+    # type (None: ``dtype``, the pools').
     slot_state: Optional[int] = None
+    slot_state_dtype: Optional[str] = None
     # Planes of the WINDOW GROUP (``LayerSpec.window_planes``; 0: there
     # is none) and the window's length in tokens.
     window_layers: int = 0
@@ -263,11 +265,16 @@ class PagedKVCache:
     holds, ``[layers, pages, page_size, *entry]`` a pool, mapped through
     the page table.  SLOT STATE (``CacheConfig.slot_state``; None for a
     model whose pages are all it has): what the sequence itself holds
-    beside them, ``state`` ``[layers, slots, width]``, one row a slot --
+    beside them, ``state`` ``[layers, slots, width]``, one row a slot,
+    in a type of its own where the model says so
+    (``CacheConfig.slot_state_dtype``: a recurrence's float32 state
+    beside bfloat16 pools) --
     written by :meth:`write_state` from the prefill's trailing rows,
-    advanced by the decode step, cleared by :meth:`free_slot`.  It is
-    owned like a pool: every program that writes it is handed the array,
-    donated, and returns its successor.
+    advanced by the decode step, cleared by :meth:`free_slot` (from one
+    row of zeros kept on the device).  It is owned like a pool: every
+    program that writes it is handed the array, donated, and returns
+    its successor; it may outweigh the pools, and nothing ever holds a
+    second copy of it.
 
     ``k`` and ``v`` have ONE owner at a time: every program that writes
     a pool (the decode/verify step, :meth:`write_prefill`, the
@@ -293,8 +300,20 @@ class PagedKVCache:
         self.v = v
         self.state = None
         if c.slot_state is not None:
+            kept = jnp.dtype(c.slot_state_dtype or c.dtype)
             self.state = jnp.zeros(
-                (c.num_layers, c.slots, c.slot_state), jnp.dtype(c.dtype))
+                (c.num_layers, c.slots, c.slot_state), kept)
+            # A released slot's row is cleared from this ONE row of
+            # zeros, made once: a release builds nothing.
+            self._cleared = jnp.zeros((c.num_layers, c.slot_state), kept)
+            self._m_state_written = _registry().counter(
+                "kv.state_bytes_written",
+                "bytes of slot state that joins wrote (a prefill's "
+                "trailing rows, a plane a row)")
+            self._m_state_cleared = _registry().counter(
+                "kv.state_rows_cleared",
+                "slot-state rows (a slot's, over every plane) that "
+                "releases cleared")
             if sharding is not None:
                 # Whole on every chip, and committed like the pools: a
                 # step compiles once, whoever wrote the array last.
@@ -672,9 +691,9 @@ class PagedKVCache:
         if self.state is not None and self.lengths[slot]:
             # Unlike a page's contents the slot's row is not behind a
             # length mask: the next sequence here starts from zeros.
-            c = self.config
-            self.write_state(slot, jnp.zeros(
-                (c.num_layers, c.slot_state), jnp.dtype(c.dtype)))
+            self.state = _state_set(self.state, self._cleared,
+                                    jnp.int32(slot))
+            self._m_state_cleared.inc()
         self.lengths[slot] = 0
 
     def release_all(self) -> int:
@@ -859,6 +878,8 @@ class PagedKVCache:
         what the sequence holds beside its pages once its last
         prefilled token is in."""
         self.state = _state_set(self.state, rows, jnp.int32(slot))
+        self._m_state_written.inc(
+            int(rows.size) * self.state.dtype.itemsize)
 
     def write_prefill(self, slot: int, k_layers, v_layers,
                       start: int = 0, state=None, window_rows=None) -> None:

@@ -14,7 +14,8 @@ A config describes itself through a ``layer_spec()`` method
 (:class:`~horovod_tpu.serving.mla_moe.MlaMoeConfig`,
 :class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`,
 :class:`~horovod_tpu.serving.loop_dense.LoopDenseConfig`,
-:class:`~horovod_tpu.serving.swa_moe.SwaMoeConfig`); a
+:class:`~horovod_tpu.serving.swa_moe.SwaMoeConfig`,
+:class:`~horovod_tpu.serving.ssm_hybrid.SsmHybridConfig`); a
 ``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
 described here, by the functions of ``serving/decode.py``.
 """
@@ -73,6 +74,17 @@ class LayerSpec:
     # the rows of its live slots, release clears a row.
     slot_state: Optional[int] = None
     slot_state_holds: Optional[str] = None
+    # The slot state's own type (None: the pools').  A recurrent state
+    # that every round rounds again is kept wider than the rows of a
+    # page, which are written once.
+    slot_state_dtype: Optional[str] = None
+    # Of a slot's row, the leading values a decode round's state update
+    # must read and write again WHOLE, a plane (None: all of them): what
+    # ``decode.round`` counts as ``state_bytes``.
+    slot_state_step: Optional[int] = None
+    # Tokens a chunk of the prefill's scan, where the prefill is one
+    # (``serve.prefill`` then says its ``scan_chunks``).
+    scan_chunk: Optional[int] = None
     # Times a token runs through the layers, over the SAME weights (a
     # looped model; one for every other).  Each pass keeps keys and
     # values of its own: the pools (and the slot state) have ``planes``
@@ -134,6 +146,14 @@ class LayerSpec:
             raise ValueError(
                 f"slot state {self.slot_state} and what it holds "
                 f"{self.slot_state_holds!r}")
+        step = self.slot_state_step
+        if (self.slot_state_dtype is not None or step is not None) \
+                and self.slot_state is None \
+                or step is not None and not 0 < step <= self.slot_state:
+            raise ValueError(
+                f"a slot state of {self.slot_state} values, its type "
+                f"{self.slot_state_dtype!r}, {self.slot_state_step} of them "
+                "rewritten a round")
 
     @property
     def num_layers(self) -> int:

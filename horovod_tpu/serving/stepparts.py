@@ -1,8 +1,9 @@
 """What the one-chip decode steps share, written once.
 
-``mla_moe.py``, ``cca_moe.py`` and ``loop_dense.py`` build their decode
-programs from the same parts: the embedding read into a float32
-residual stream, where a round's token lands in the page pool, a loop
+``mla_moe.py``, ``cca_moe.py``, ``loop_dense.py``, ``swa_moe.py`` and
+``ssm_hybrid.py`` build their decode programs from the same parts: the
+embedding read into a float32 residual stream (times a constant, where
+the model has one), where a round's token lands in the page pool, a loop
 over the layers that hands each one the pool (and whatever else the
 model carries), around it -- for a model whose tokens make several
 passes over the same layers -- ONE rolled loop over the passes with the
@@ -102,7 +103,8 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                         routed: bool = True, passes: int = 1,
                         after_pass: Optional[Callable] = None,
                         window_group: bool = False,
-                        held: Optional[slice] = None
+                        held: Optional[slice] = None,
+                        embed_scale: float = 1.0, logit_scale: float = 1.0
                         ) -> ServingDecodeStep:
     """The jitted step ``name``::
 
@@ -155,6 +157,9 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     which lead the ``carried`` arrays.  ``held`` (a chip that holds a
     share of the experts): the slice of a routed layer's counts that the
     ``tells`` are made of; the histogram keeps the router's whole width.
+    ``embed_scale`` and ``logit_scale`` (a model that multiplies its
+    embedding and its logits by constants): applied where they are not
+    1, so that a model without them lowers to what it lowered to.
     """
     looped = after_pass is not None
     if passes > 1 and not looped:
@@ -173,6 +178,8 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
         p = params["params"] if "params" in params else params
         tokens, active = round_inputs(tokens, active, prev)
         x = embed(p, tokens)                                     # [S, d]
+        if embed_scale != 1.0:
+            x = x * embed_scale
         rnd = round_of(positions, page_table, active, page_size=page_size,
                        scratch=scratch)._replace(window_table=window_table)
         told = [jnp.zeros((), jnp.int32) for _ in tells]
@@ -211,6 +218,8 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                 0, passes, body, (x, pool, list(carry), hist, told, mass,
                                   jnp.ones(x.shape[:1], jnp.float32)))
         logits = readout(x, p, eps, dtype, tied=tied, normed=looped)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
         own = ([hist] if routed else []) + ([mass] if looped else [])
         if two_pools:
             pool, no_pool = pool

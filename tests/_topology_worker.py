@@ -51,6 +51,16 @@ second instance at SmallThinker-21BA3B-Instruct's widths as
 L L G L L L``, every layer routed over all 64 experts, the whole
 vocabulary, a window ring of 257 pages a slot, 64 slots by default).
 
+``<topology> ssm_step [slots [prompt[xgroup] ...]]``: compiles, for ONE
+chip, the decode step of ``serving/ssm_hybrid.py`` at
+Falcon-H1-34B-Instruct's widths as ``benchmarks/configs/falcon_h1_34b
+.json`` cuts it (6 layers, a Mamba-2 mixer beside attention in each, the
+whole vocabulary, 80 slots by default, contexts to 1,024) and its
+prefill at each ``prompt`` length (``256x4``: a group of four), and
+prints what each holds, as ``swa_step`` does; the slot state ``[6,
+slots, 1,063,936]`` float32 must be aliased in place and nothing as
+large as one of its planes may be a temporary.
+
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
@@ -109,7 +119,7 @@ def kernels(topology: str) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops import attention, moe, pallas
+    from horovod_tpu.ops import attention, moe, pallas, ssm
 
     # This process's default backend is the CPU (no chip attached), so
     # the package would pick the XLA reference and, forced on, the
@@ -322,6 +332,17 @@ def kernels(topology: str) -> int:
             spec((64, 2560, 768), jnp.bfloat16),
             spec((64, 768, 2560), jnp.bfloat16),
             spec((84,), jnp.int32), spec((1,), jnp.int32)]),
+        # PR 48: one step of the state-space recurrence for 80 slots, 32
+        # heads of 128 columns over a state of 256 in 2 groups, in place
+        # over plane 3 of six planes of 1,063,936-value float32 rows.
+        "ssm_decode_b80": (
+            lambda st, x, dt, a, b, c, d, live: ssm.ssm_decode_update(
+                st, x, dt, a, b, c, d, live, plane=3), [
+            spec((6, 80, 1063936), jnp.float32),
+            spec((80, 32, 128), jnp.float32), spec((80, 32), jnp.float32),
+            spec((32,), jnp.float32), spec((80, 2, 256), jnp.float32),
+            spec((80, 2, 256), jnp.float32), spec((32,), jnp.float32),
+            spec((80,), jnp.bool_)]),
         # LLAMA_1B decode, 8 slots, GQA 16/8, S 1024, D 128.
         "flash_decode_b8": (decode, [
             spec((8, 16, 1, 128), jnp.float32),
@@ -525,6 +546,91 @@ def swa_step(topology: str, slots: int = 32, *prompts: int,
     return 0
 
 
+def ssm_step(topology: str, slots: int = 80, *prompts: str) -> int:
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import falcon_h1_hybrid as family
+    from horovod_tpu.ops import pallas
+    from horovod_tpu.serving import ssm_hybrid
+    from horovod_tpu.serving.decode import no_round
+
+    pallas.interpret_mode = lambda: False
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    mesh = Mesh(np.asarray(td.devices[:1]), ("tp",))
+    with open(os.path.join(dirname(dirname(abspath(__file__))),
+                           "benchmarks", "configs",
+                           "falcon_h1_34b.json")) as f:
+        config = json.load(f)
+    cfg = family.program_config(config)
+    serving = config["serving"]
+    page, max_len, bf = serving["page_size"], serving["max_len"], jnp.bfloat16
+    pps = max_len // page
+    on = NamedSharding(mesh, P())
+
+    def whole(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    params = jax.tree.map(lambda z: whole(z.shape, bf),
+                          ssm_hybrid.param_shapes(cfg, bf))
+    weights = sum(int(np.prod(z.shape)) * 2 for z in jax.tree.leaves(params))
+    pool = whole((cfg.num_layers, slots * pps + 1, page, cfg.kv_width), bf)
+    state = whole((cfg.num_layers, slots, cfg.slot_state_width), jnp.float32)
+    out = {"weight_bytes": weights,
+           "cache_bytes": 2 * 2 * int(np.prod(pool.shape))
+           + 4 * int(np.prod(state.shape)),
+           "state_plane_bytes": 4 * slots * cfg.slot_state_width}
+
+    def report(name, lowered):
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        text = lowered.as_text()
+        header = compiled.as_text()
+        header = header[:header.index("\n")]
+        out[name] = {
+            "mosaic_calls": {k: text.count(f'kernel_name = "{k}"') for k in (
+                "hvd_cca_decode", "hvd_ssm_decode", "hvd_flash_fwd",
+                "hvd_flash_hg_fwd")},
+            "aliased_params": sorted(int(i) for i in re.findall(
+                r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)),
+            "argument_bytes": int(mem.argument_size_in_bytes),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "output_bytes": int(mem.output_size_in_bytes),
+            "alias_bytes": int(mem.alias_size_in_bytes)}
+
+    step = ssm_hybrid.build_decode_step(cfg, mesh, slots=slots,
+                                        page_size=page, pages_per_slot=pps,
+                                        dtype=bf)
+    report("decode", step._fn.lower(
+        params, pool, pool, whole((slots,), jnp.int32),
+        whole((slots,), jnp.int32), whole((slots, pps), jnp.int32),
+        whole((slots,), jnp.bool_), state,
+        whole(no_round(slots).shape, jnp.int32)))
+    out["decode"]["pool_params"] = [
+        len(jax.tree.leaves(params)) + i for i in (0, 1, 6)]
+
+    def prefill(p, toks):
+        return ssm_hybrid.prefill_forward(p, cfg, toks, dtype=bf)
+
+    for what in prompts or ("512", "256x4"):
+        t, _, b = what.partition("x")
+        report(f"prefill_{what}", jax.jit(prefill).lower(
+            params, whole((int(b or 1), int(t)), jnp.int32)))
+        # Beside the prefill: the weights, the pools and the slot state.
+        out[f"prefill_{what}"]["resident_with_cache"] = (
+            out["cache_bytes"] + out[f"prefill_{what}"]["argument_bytes"]
+            + out[f"prefill_{what}"]["temp_bytes"]
+            + out[f"prefill_{what}"]["output_bytes"])
+    print(json.dumps(out))
+    return 0
+
+
 def exchange(topology: str) -> int:
     import re
 
@@ -584,6 +690,10 @@ if __name__ == "__main__":
         sys.exit(swa_step(topo, *(int(a) for a in sys.argv[3:]
                                   or (("64",) if small else ())),
                           small=small))
+    if sys.argv[2:3] == ["ssm_step"]:
+        os.environ["HOROVOD_PALLAS"] = "1"
+        sys.exit(ssm_step(topo, *(int(a) for a in sys.argv[3:4]),
+                          *sys.argv[4:]))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

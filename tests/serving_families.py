@@ -1,5 +1,5 @@
 """What the serving tests share, in a module that is no test file: the
-tiny configurations of the five served families (and the second instance
+tiny configurations of the six served families (and the second instance
 of the window-and-full block), ONE table of them, the loop that drives an
 engine a round at a time, and the two lowering helpers.  No test file
 imports another; each takes these from here."""
@@ -59,6 +59,31 @@ TINY_SWA = {
     "limits": {"served_logit_gap_max": 1e-3, "routing_margin_min": 0.0,
                "routing_branches_max": 1}}
 
+# A state-space mixer beside attention in every layer (Falcon-H1's
+# block): every multiplier off one, a chunk that divides no prompt of
+# the tests' lengths.
+TINY_SSM = {
+    "kind": "serve", "family": "falcon_h1_hybrid", "vocab_size": 64,
+    "hidden_size": 64, "intermediate_size": 96, "num_hidden_layers": 2,
+    "num_attention_heads": 10, "num_key_value_heads": 2, "head_dim": 8,
+    "mamba_n_heads": 4, "mamba_d_head": 8, "mamba_d_ssm": 32,
+    "mamba_d_state": 16, "mamba_n_groups": 2, "mamba_d_conv": 4,
+    "mamba_chunk_size": 4, "mamba_expand": 0.5, "mamba_conv_bias": True,
+    "mamba_proj_bias": False, "mamba_rms_norm": True,
+    "mamba_norm_before_gate": False, "mamba_use_mlp": True,
+    "attention_bias": False, "mlp_bias": False, "projectors_bias": False,
+    "hidden_act": "silu", "attn_layer_indices": None, "rope_scaling": None,
+    "tie_word_embeddings": False, "rope_theta": 10000.0,
+    "rms_norm_eps": 1e-5, "max_position_embeddings": 128,
+    "embedding_multiplier": 5.0, "lm_head_multiplier": 0.125,
+    "attention_in_multiplier": 1.5, "attention_out_multiplier": 0.25,
+    "key_multiplier": 0.5, "ssm_in_multiplier": 0.75,
+    "ssm_out_multiplier": 0.5, "mlp_multipliers": [0.75, 0.25],
+    "ssm_multipliers": [0.5, 1.5, 0.75, 1.25, 0.6],
+    "compute_dtype": "float32",
+    "serving": {"slots": 3, "page_size": 4, "max_len": 64},
+    "limits": {"served_logit_gap_max": 1e-3}}
+
 # The window-and-full block's second instance (SmallThinker's variant).
 EARLY_KINDS = ("full", "window", "window", "window")
 EARLY_WINDOW, EARLY_THETA, EARLY_EPS, EARLY_TOP_K = 8, 10000.0, 1e-6, 3
@@ -99,6 +124,13 @@ def swa_moe(**over):
     return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
 
 
+def ssm_hybrid(**over):
+    from benchmarks.families import falcon_h1_hybrid
+    from horovod_tpu.serving import ssm_hybrid
+    cfg = falcon_h1_hybrid.program_config(dict(TINY_SSM, **over))
+    return cfg, ssm_hybrid.init_params(cfg, jax.random.PRNGKey(0))
+
+
 def early_route(**over):
     from horovod_tpu.serving import swa_moe
     cfg = swa_moe.SwaMoeConfig(**dict(dict(
@@ -112,9 +144,10 @@ def early_route(**over):
     return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
 
 
-# The five served families, a tiny engine's worth each.
+# The six served families, a tiny engine's worth each.
 FAMILIES = {"dense": dense, "mla_moe": mla_moe, "cca_moe": cca_moe,
-            "loop_dense": loop_dense, "swa_moe": swa_moe}
+            "loop_dense": loop_dense, "swa_moe": swa_moe,
+            "ssm_hybrid": ssm_hybrid}
 # ... and the window-and-full routed block in both its instances.
 FAMILIES_AND_EARLY_ROUTE = dict(FAMILIES, swa_moe_early_route=early_route)
 
@@ -168,10 +201,11 @@ def round_by_round(eng, reqs):
 
 # -- lowerings ----------------------------------------------------------------
 
-def lowered_for_tpu(fn, *args):
+def lowered_for_tpu(fn, *args, kernels: bool = True):
     """``fn`` lowered for the TPU, each Mosaic body printed as MLIR
     without source locations (the recipe of
-    ``.claude/skills/verify/SKILL.md``)."""
+    ``.claude/skills/verify/SKILL.md``).  ``kernels``: the text must
+    hold a Mosaic call (a tiny dense prefill holds none)."""
     import base64
     import re
 
@@ -186,7 +220,7 @@ def lowered_for_tpu(fn, *args):
 
     text = jax.jit(fn).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in text or not kernels
     return re.sub(r'(?<=body\\22: \\22)([A-Za-z0-9+/=]+)(?=\\22)', body,
                   text)
 

@@ -388,7 +388,7 @@ def test_expected_exchange_kernel_aware(hvd, monkeypatch):
     assert report.ok(), report.render()
     assert report.expected.kernels == ("bn_bwd", "flash", "flash_decode",
                                        "fused_update", "mla_decode",
-                                       "moe_gmm")
+                                       "moe_gmm", "ssm_decode")
     assert not report.expected.notes
     assert report.summary["unaccounted_ops"] == 0
 

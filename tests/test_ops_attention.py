@@ -656,7 +656,8 @@ def test_pallas_switch_resolution(monkeypatch):
     with monkeypatch.context() as m:
         m.setattr(_pallas.jax, "default_backend", lambda: "tpu")
         assert _pallas.active_kernels() == ("flash", "flash_decode",
-                                            "mla_decode", "moe_gmm")
+                                            "mla_decode", "moe_gmm",
+                                            "ssm_decode")
     # global switch gates every family...
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     assert _pallas.active_kernels() == _pallas.registered_kernels()
@@ -664,6 +665,7 @@ def test_pallas_switch_resolution(monkeypatch):
     monkeypatch.setenv("HOROVOD_PALLAS_DECODE", "0")
     assert not _pallas.pallas_enabled("flash_decode")
     assert not _pallas.pallas_enabled("mla_decode")   # the same switch
+    assert not _pallas.pallas_enabled("ssm_decode")   # a decode kernel too
     assert _pallas.pallas_enabled("flash")
     assert _pallas.pallas_enabled("moe_gmm")          # the global one only
     with pytest.raises(ValueError, match="unknown pallas kernel family"):

@@ -170,7 +170,7 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     case here first."""
     from horovod_tpu.ops import pallas
     assert set(pallas.registered_kernels()) - pallas._AUTO_XLA == {
-        "flash", "flash_decode", "mla_decode", "moe_gmm"}
+        "flash", "flash_decode", "mla_decode", "moe_gmm", "ssm_decode"}
     out = _topology_worker("v5e:2x2", "kernels")
     # BERT-Large at T = 128 is one block: the head-group forward and ONE
     # backward (dq, dk, dv together), as is Mistral's 512-token prefill;
@@ -191,8 +191,10 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     # (``hvd_flash_swa_fwd``) over 8,192 and over 512 tokens; since PR 42
     # both walks at 28 query heads over 4 (a ring of 257 pages, a full
     # table of 576), the banded prefill under a window of 4,096 and the
-    # grouped matmul with ReLU gates.
-    assert out == {"flash_bert_large": 2, "flash_mistral_prefill_512": 1,
+    # grouped matmul with ReLU gates; since PR 48 one step of a
+    # state-space recurrence for 80 slots in place over one plane of six
+    # planes of 1,063,936-value float32 rows (``hvd_ssm_decode``).
+    assert out == {"ssm_decode_b80": 1, "flash_bert_large": 2, "flash_mistral_prefill_512": 1,
                    "swa_decode_b64_h28": 1, "full_decode_b64_h28": 1,
                    "flash_swa_prefill_8k_w4096": 1, "moe_gmm_relu_decode": 2,
                    "swa_decode_b32": 1, "flash_swa_prefill_8k": 1,
@@ -278,6 +280,32 @@ def test_topology_aot_small_step_fits_beside_its_cache():
     assert pre["mosaic_calls"]["hvd_flash_swa_fwd"] == 1
     assert pre["temp_bytes"] < 0.8e9
     assert pre["resident_with_cache"] < 15.0e9
+
+
+def test_topology_aot_ssm_step_updates_its_state_in_place():
+    """Falcon-H1-34B's cut (PR 48) compiled for one v5e chip at the
+    cell's 80 slots: the decode step aliases both pools AND the 2.04 GB
+    float32 slot state to their successors, holds one walk function and
+    one state update a layer, and NOTHING as large as one plane of the
+    state (340 MB) is a temporary: a second copy of the state does not
+    fit, and ``state[plane]`` materialised was a plane copied a layer
+    (seen here first: 352 MB of temporaries, 11 MB since).  A group of
+    four 256-token prompts and a 512-token prompt alone, each a chunked
+    scan, fit beside 10.51 GB of weights and the 3.05 GB cache."""
+    out = _topology_worker("v5e:2x2", "ssm_step", "80", "512", "256x4")
+    assert out["weight_bytes"] == 10_509_188_224
+    assert out["cache_bytes"] == 3_049_586_688
+    step = out["decode"]
+    assert step["aliased_params"] == step["pool_params"]
+    assert step["alias_bytes"] == out["cache_bytes"]
+    assert step["mosaic_calls"]["hvd_cca_decode"] == 1
+    assert step["mosaic_calls"]["hvd_ssm_decode"] == 6
+    assert step["temp_bytes"] < out["state_plane_bytes"] / 10
+    for name in ("prefill_512", "prefill_256x4"):
+        pre = out[name]
+        assert pre["mosaic_calls"]["hvd_flash_hg_fwd"] == 1
+        assert pre["temp_bytes"] < 0.3e9
+        assert pre["resident_with_cache"] < 14.2e9
 
 
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():

@@ -484,7 +484,7 @@ def test_auditor_names_the_attention_kernel_the_step_calls(base_params,
         assert meta_from_step(steps[name])["attention"] == "view"
         assert "flash_decode" in names[name]
         assert "mla_decode" not in names[name]
-    assert {len(v) for v in names.values()} == {5}   # the other families
+    assert {len(v) for v in names.values()} == {6}   # the other families
     monkeypatch.setenv("HOROVOD_PALLAS", "0")
     assert expected_exchange(
         params, meta_from_step(steps["walk"])).kernels == ()

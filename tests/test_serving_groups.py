@@ -398,3 +398,103 @@ def test_two_adapter_ids_are_never_one_group():
     # The adapters differ: requests 0 and 1 have ONE prompt and are
     # served other tokens, so a group that mixed the ids would show.
     assert want[0].tokens != want[1].tokens
+
+
+# -- the slot state's own type left the other blocks' programs alone -----------
+
+# The decode step, one prompt's prefill (10 tokens) and a group's (four of
+# 6) of each of the five other served blocks (the window-and-full block
+# in both its instances), a tiny engine each (8 slots, pages of 4, max_len
+# 32, float32), lowered for the TPU with the kernels on: recorded on PR
+# 47's tree, the parent of the PR that gave the slot state a type of its
+# own (``LayerSpec.slot_state_dtype``, ``CacheConfig.slot_state_dtype``),
+# ``stepparts.build_one_chip_step`` its ``embed_scale`` and
+# ``logit_scale``, ``decode.round`` its ``state_bytes`` and
+# ``PagedKVCache.free_slot`` its one row of zeros.
+_OTHER_BLOCKS_LOWERED = {
+    "dense.step":
+        "fe7f83bb467ddaffccb03b85f1167988f4e8012139e93898acb40276aa28ef57",
+    "dense.prefill":
+        "00b87a8f124ce1aece08c141b52d54d833b35bd38c75afa5749be1c58653ab60",
+    "dense.group":
+        "1275953e8aacd6b837923fddda1e29ffbd5a4e0afdc6570b88c3d56d70021e40",
+    "mla_moe.step":
+        "7e2564801b069ccddc3855cf1159dd602e61397259f56b307bfd1ccc501f88d6",
+    "mla_moe.prefill":
+        "f8fe2593c900d10900f5942214ea117cf06b5571bf03c829237d834c5e667d05",
+    "mla_moe.group":
+        "1aa5a0ed65e91edaf849064b4166521b4fa038d2282f14f830a4140eb1b0a663",
+    "cca_moe.step":
+        "14fdd64f502e2d712bf7cf54775cf398bcbf869c70dd52be97e4c0588064a790",
+    "cca_moe.prefill":
+        "644cbba322aa162a7743362f8d361323ef81f4c59348a4deb272d19a24fa2341",
+    "cca_moe.group":
+        "96902cae85fbea5d3b8d578f786737a2c1e3620c2c9b82350ed3e6cbcb5ce50d",
+    "loop_dense.step":
+        "966b483edabfcb588e355130d6a217fb7dd36eb73e7323a78588bced31fb6c4b",
+    "loop_dense.prefill":
+        "c42848df04321299c17783c55a58ef09da323e063cf893004f3ee89afb562b62",
+    "loop_dense.group":
+        "c17c0ac61f9ad9ef41a38e94a53266506eb835906b7719e944a8591d0978f089",
+    "swa_moe.step":
+        "79b08d93224e0eb051ddd5c92b0e3412701bce40488c58edbbf680e6e715d37c",
+    "swa_moe.prefill":
+        "06e36c77d091015be00d84cc7f58b2735d7ff14fd297dfeca52ec655fb6b7de3",
+    "swa_moe.group":
+        "750ef11490a7e723a98fd2925bbbfbb650e7f7d0de1e02a23b0b828fe2fa2dfb",
+    "swa_moe_early_route.step":
+        "84a376c4e35cc2e9c9186f208f4841587b3f5ca62f900d0b185c0f3df9a5560b",
+    "swa_moe_early_route.prefill":
+        "18ef9d952af93b08234ba9e7cd2e72001b31f3a2c0828a9e0fe0edef1b90dae1",
+    "swa_moe_early_route.group":
+        "9ef30123ed09dad6e656fed69efb1453f34a63ec9503ec95b283f27f0001e81d"}
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    """A family's tiny engine, built once for its three lowerings."""
+    built = {}
+
+    def engine(family):
+        if family not in built:
+            cfg, params = FAMILIES[family]()
+            built[family] = (params, ServingEngine(
+                cfg, params, slots=8, page_size=4, max_len=32,
+                dtype=jnp.float32))
+        return built[family]
+
+    return engine
+
+
+@pytest.mark.parametrize("case", list(_OTHER_BLOCKS_LOWERED))
+def test_the_state_s_own_type_left_the_other_blocks_programs_as_they_were(
+        monkeypatch, tiny_engine, case):
+    import hashlib
+
+    from horovod_tpu.ops import pallas
+    from serving_families import lowered_for_tpu
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    monkeypatch.setattr(pallas, "interpret_mode", lambda: False)
+    family, what = case.rsplit(".", 1)
+    params, eng = tiny_engine(family)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+    if what == "step":
+        c = eng.cache
+        args = [eng._decode_params, c.k, c.v, jnp.zeros((8,), jnp.int32),
+                c.lengths_device(), c.table_device(), jnp.zeros((8,), bool)]
+        if c.window_table is not None:
+            args.append(c.window_table_device())
+        args += [*c.carried, *eng._step_state, eng._told]
+        text = lowered_for_tpu(eng.step._fn, *shapes(args), kernels=False)
+    else:
+        fn, rows = ((eng._prefill, (1, 10)) if what == "prefill"
+                    else (eng._prefill_group, (4, 6)))
+        text = lowered_for_tpu(
+            lambda p, t: fn(p, t, None, None), shapes(params),
+            jax.ShapeDtypeStruct(rows, jnp.int32), kernels=False)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _OTHER_BLOCKS_LOWERED[case]
