@@ -29,15 +29,18 @@ import json
 import os
 import tempfile
 
-# name: (query heads, key heads, tokens, head width, window).  The first
-# three are what an 8,192-token prompt hands the kernel in
-# ``k_exaone_236b_mixed_offline`` (a full layer, a window layer) and
-# ``joyai_llm_flash_offline_docs`` (values padded to the keys' 192); the
-# last is Mistral's longest prompt.
+# name: (query heads, key heads, tokens, head width, window[, value
+# width]).  The first three are what an 8,192-token prompt hands the
+# kernel in ``k_exaone_236b_mixed_offline`` (a full layer, a window layer)
+# and ``joyai_llm_flash_offline_docs`` (values 128 wide beside keys of
+# 192); ``joyai_padded`` is what that cell handed it before PR 49 (the
+# values padded with zeros to the keys' 192: the one of the two a tree
+# older than PR 49 can run); the last is Mistral's longest prompt.
 SHAPES = {
     "exaone_full": (64, 8, 8192, 128, None),
     "exaone_window": (64, 8, 8192, 128, 128),
-    "joyai": (32, 32, 8192, 192, None),
+    "joyai": (32, 32, 8192, 192, None, 128),
+    "joyai_padded": (32, 32, 8192, 192, None),
     "mistral_1024": (32, 8, 1024, 128, None),
 }
 
@@ -74,11 +77,12 @@ def main():
     report = {"device_kind": device.device_kind, "dtype": str(dtype),
               "tree": _dir(_dir(_abs(__file__))), "shapes": {}}
     for name in args.shapes:
-        heads, kv_heads, t, d, window = SHAPES[name]
+        heads, kv_heads, t, d, window, *dv = SHAPES[name]
+        dv = dv[0] if dv else d
         keys = jax.random.split(jax.random.PRNGKey(len(name) + t), 3)
         q = jax.random.normal(keys[0], (1, heads, t, d), dtype)
         k = jax.random.normal(keys[1], (1, kv_heads, t, d), dtype)
-        v = jax.random.normal(keys[2], (1, kv_heads, t, d), dtype)
+        v = jax.random.normal(keys[2], (1, kv_heads, t, dv), dtype)
         fn = jax.jit(lambda q, k, v, w=window: attn.flash_attention(
             q, k, v, causal=True, window=w))
         out = jax.block_until_ready(fn(q, k, v))
@@ -96,6 +100,10 @@ def main():
                 causal=True, window=window)
         err = float(jnp.max(jnp.abs(
             out[:, :hq, -1024:].astype(jnp.float32) - ref)))
+        # Values narrower than the keys: bit for bit the kept columns of
+        # the call with the values padded to the keys' width.
+        same = None if dv == d else bool(jnp.array_equal(out, fn(
+            q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, d - dv),)))[..., :dv]))
         kernel = "hvd_flash_fwd" if window is None else "hvd_flash_swa_fwd"
         with tempfile.TemporaryDirectory() as logdir:
             jax.profiler.start_trace(logdir)
@@ -108,15 +116,21 @@ def main():
         if len(events) != args.calls:
             raise SystemExit(f"{name}: {len(events)} {kernel} events on the "
                              f"ops line, {args.calls} calls made")
+        # XLA's own ops beside the kernel: the copies of operands into
+        # the call's layout.
+        beside = sum(e.dur_ns for e in ops
+                     if not e.name.startswith("%" + kernel)) / args.calls
         block = attn._block(t, attn.DEFAULT_BLOCK_Q)
         blocks = heads * live_blocks(t, block, window)
         ms = float(np.median(events)) / 1e6
-        flop = 4.0 * block * block * d
+        flop = 2.0 * block * block * (d + dv)
         report["shapes"][name] = {
             "kernel": kernel, "q": [1, heads, t, d], "kv_heads": kv_heads,
+            "value_width": dv, "equal_to_padded_call": same,
             "window": window, "calls": len(events),
             "ms_median": ms, "ms_min": min(events) / 1e6,
-            "ms_max": max(events) / 1e6, "live_blocks": blocks,
+            "ms_max": max(events) / 1e6,
+            "ms_other_ops_a_call": beside / 1e6, "live_blocks": blocks,
             "us_per_block": ms * 1e3 / blocks,
             "block_mxu_peak_pct": 100 * flop * blocks / (ms / 1e3) / peak,
             "max_abs_err_vs_f32_reference": err}
@@ -125,7 +139,11 @@ def main():
               f"{blocks} live blocks of {block}, "
               f"{ms * 1e3 / blocks:.3f} us a block, "
               f"{report['shapes'][name]['block_mxu_peak_pct']:.1f}% of the "
-              f"bfloat16 peak, |err| {err:.4f}", flush=True)
+              f"bfloat16 peak, {beside / 1e6:.3f} ms of other ops a call, "
+              f"|err| {err:.4f}"
+              + ("" if same is None else
+                 f", equal to the padded call bit for bit: {same}"),
+              flush=True)
     if args.out:
         os.makedirs(_dir(_abs(args.out)), exist_ok=True)
         with open(args.out, "w") as f:

@@ -13,7 +13,8 @@ has two kernel sets and picks one from the shapes it is given
 ``hvd_flash_bwd_dq``, ``hvd_flash_bwd_dkv``) whenever the queries or the
 keys take more than one block or segment ids ride along, the HEAD-GROUP
 kernels (``hvd_flash_hg_fwd``, ``hvd_flash_hg_bwd``) when one block holds
-the sequence.  The names are what the ops line of a device trace shows.
+the sequence and the values are as wide as the keys.  The names are what
+the ops line of a device trace shows.
 
 Blocked kernels:
 
@@ -28,6 +29,12 @@ Blocked kernels:
   keeps ``m`` and ``l`` that wide INSIDE its step too: a ``(block, 1)``
   column costs a lane broadcast through the cross-lane unit at every use,
   and the step waited for that unit, not for the MXU (PERF.md, PR 41).
+* The forward's values, accumulator and result are as wide as the VALUES
+  are (``v.shape[3]``), which need not be the keys' width: latent
+  attention's prefill hands keys of 192 columns beside values of 128,
+  and a ``p v`` over 192 padded columns is twice the MXU passes of one
+  over 128 (PERF.md, PR 49).  The backward pair knows one width and
+  pads to it (``_flash_bwd``).
 * Backward is the standard two-kernel FA2 split: ``dq`` accumulates over
   kv blocks, ``dk/dv`` accumulate over q blocks; ``delta = rowsum(dO*O)``
   is precomputed by XLA (a trivially fused elementwise reduce).  dk/dv
@@ -151,7 +158,9 @@ def attention_reference(q, k, v, *, causal: bool = False,
                         scale: Optional[float] = None,
                         segment_ids=None, kv_segment_ids=None,
                         window: Optional[int] = None):
-    """Plain XLA attention. q,k,v: (batch, heads, seq, head_dim).
+    """Plain XLA attention. q,k,v: (batch, heads, seq, head_dim); the
+    values' ``head_dim`` may be another than the keys', and is the
+    result's.
 
     Causal masking is bottom-right aligned: with ``tq < tk`` (decode with a
     KV cache), query ``i`` attends keys ``0 .. tk - tq + i``.
@@ -1077,7 +1086,7 @@ def _lanes(x, n: int):
 
 def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
     batch, heads, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     rep = heads // k.shape[1]
     bq = _block(tq, bq)
     bk = _block(tk, bk)
@@ -1085,7 +1094,7 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
     off = tk - tq
     grid = (batch, heads, nq, nk)
     has_seg = qseg is not None
-    path, group = _flash_path(q, k, has_seg=has_seg, bq=bq, bk=bk)
+    path, group = _flash_path(q, k, v, has_seg=has_seg, bq=bq, bk=bk)
     if path == "head_group":
         return _hg_fwd(q, k, v, scale=scale, causal=causal, group=group)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -1095,7 +1104,7 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
         pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
         pl.BlockSpec((1, 1, bk, d),
                      lambda b, h, i, j: (b, h // rep, j, 0)),
-        pl.BlockSpec((1, 1, bk, d),
+        pl.BlockSpec((1, 1, bk, dv),
                      lambda b, h, i, j: (b, h // rep, j, 0)),
     ]
     operands = [q, k, v]
@@ -1114,18 +1123,18 @@ def _flash_fwd(q, k, v, qseg, kseg, *, scale, causal, bq, bk):
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bq, dv), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bq, _LANES),
                              lambda b, h, i, j: (b, h, i, 0)),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((batch, heads, tq, dv), q.dtype),
                 jax.ShapeDtypeStruct((batch, heads, tq, _LANES), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((bq, _LANES), jnp.float32),
                 pltpu.VMEM((bq, _LANES), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
             ],
             name="hvd_flash_fwd",
             interpret=_pallas.interpret_mode(),
@@ -1141,7 +1150,7 @@ def _flash_swa_fwd(q, k, v, *, scale, window, bq, bk):
     a window under a block), so a long prompt does the work of its band's
     blocks and not of the triangle's.  Forward only: a served prefill."""
     batch, heads, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]
     rep = heads // k.shape[1]
     nq, nkb = tq // bq, tk // bk
     off = tk - tq
@@ -1162,14 +1171,14 @@ def _flash_swa_fwd(q, k, v, *, scale, window, bq, bk):
             in_specs=[
                 pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
                 pl.BlockSpec((1, 1, bk, d), kv_block),
-                pl.BlockSpec((1, 1, bk, d), kv_block)],
-            out_specs=pl.BlockSpec((1, 1, bq, d),
+                pl.BlockSpec((1, 1, bk, dv), kv_block)],
+            out_specs=pl.BlockSpec((1, 1, bq, dv),
                                    lambda b, h, i, j: (b, h, i, 0)),
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            out_shape=jax.ShapeDtypeStruct((batch, heads, tq, dv), q.dtype),
             scratch_shapes=[
                 pltpu.VMEM((bq, _LANES), jnp.float32),
                 pltpu.VMEM((bq, _LANES), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32)],
+                pltpu.VMEM((bq, dv), jnp.float32)],
             name="hvd_flash_swa_fwd",
             interpret=_pallas.interpret_mode(),
         )(q, k, v)
@@ -1273,6 +1282,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _flash_bwd(res, g, *, scale, causal, bq, bk):
     q, k, v, o, lse, qseg, kseg = res
+    if v.shape[3] != q.shape[3]:
+        # The backward kernels know one width.  Zero columns beside the
+        # narrower of (q, k) and (v, o, dO) change no product and no
+        # ``delta``, and take zero gradients: pad, run, cut.
+        d, dv = q.shape[3], v.shape[3]
+        wide = max(d, dv)
+
+        def pad(x):
+            return jnp.pad(x, ((0, 0),) * 3 + ((0, wide - x.shape[3]),))
+
+        dq, dk, dv_ = _flash_bwd(
+            (pad(q), pad(k), pad(v), pad(o), lse, qseg, kseg), pad(g),
+            scale=scale, causal=causal, bq=bq, bk=bk)
+        return dq[..., :d], dk[..., :d], dv_[..., :dv]
     batch, heads, tq, d = q.shape
     h_kv, tk = k.shape[1], k.shape[2]
     rep = heads // h_kv
@@ -1414,19 +1437,23 @@ def _head_group(heads: int, kv_heads: int, tq: int, tk: int, d: int,
     return 0
 
 
-def _flash_path(q, k, *, has_seg: bool, bq: int, bk: int):
+def _flash_path(q, k, v=None, *, has_seg: bool, bq: int, bk: int):
     """``("head_group", G)`` when one block holds the query sequence and
-    one the keys, no segment ids ride along and a group fits VMEM;
+    one the keys, no segment ids ride along, the values (``v``; None: as
+    wide as the keys) are as wide as the keys and a group fits VMEM;
     ``("blocked", 0)`` (the online-softmax kernels) otherwise.  Decided
     at trace time from shapes; logged once a lowering."""
     tq, tk = q.shape[2], k.shape[2]
+    dv = q.shape[3] if v is None else v.shape[3]
     group = 0
-    if not has_seg and _block(tq, bq) == tq and _block(tk, bk) == tk:
+    if not has_seg and dv == q.shape[3] \
+            and _block(tq, bq) == tq and _block(tk, bk) == tk:
         group = _head_group(q.shape[1], k.shape[1], tq, tk, q.shape[3],
                             q.dtype.itemsize)
     path = ("head_group", group) if group else ("blocked", 0)
-    logger.debug("flash attention q%s k%s blocks (%d, %d): %s kernels, "
-                 "%d heads a grid step", q.shape, k.shape, bq, bk, *path)
+    logger.debug("flash attention q%s k%s values %d wide blocks (%d, %d): "
+                 "%s kernels, %d heads a grid step", q.shape, k.shape, dv,
+                 bq, bk, *path)
     return path
 
 
@@ -1616,11 +1643,23 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_kv: int = DEFAULT_BLOCK_KV,
                     window: Optional[int] = None,
                     force_reference: bool = False):
-    """Fused attention. q: (b, h, t, d); k, v: (b, h_kv, s, d).
+    """Fused attention. q: (b, h, t, d); k: (b, h_kv, s, d); v: (b, h_kv,
+    s, dv); the result is (b, h, t, dv).
 
     ``h_kv`` may divide ``h`` (grouped-query attention); kv heads are
     broadcast to query heads via the kernel block index map (no HBM copy).
     ``causal=True`` requires ``t <= s`` and masks bottom-right aligned.
+
+    ``dv`` may differ from ``d`` (latent attention's expanded prefill:
+    keys of 128 + 64 rotated columns beside values of 128).  The blocked
+    forward kernels, ``window`` and segment ids included, then carry the
+    values, the accumulator and the result ``dv`` wide and nothing else
+    changes: a column of the result is its own dot product over the same
+    weights, so the call gives bit for bit the kept columns of the same
+    call with zero-padded values.  Such a call runs the blocked kernels
+    whatever its length; its backward pads the narrower operands with
+    zeros to the wider width, runs the blocked backward pair and cuts
+    the gradients.  ``scale`` defaults from ``d``.
 
     ``segment_ids`` (``(b, t)`` int) restricts each query to keys with an
     EQUAL id -- packed-sequence training and padding isolation (give pad
@@ -1658,6 +1697,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if block_q < _MIN_BLOCK or block_kv < _MIN_BLOCK:
         raise ValueError(f"block_q/block_kv must be >= {_MIN_BLOCK}, got "
                          f"{block_q}/{block_kv}")
+    if k.shape[3] != q.shape[3] or v.shape[:3] != k.shape[:3]:
+        raise ValueError(
+            f"q {q.shape}, k {k.shape} and v {v.shape} do not fit together: "
+            "keys as wide as the queries, a value a key")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     tq, tk = q.shape[2], k.shape[2]

@@ -18,7 +18,7 @@ residual stream, RMSNorm everywhere, no biases):
   case), so that a page is one aligned slab a kernel can copy.  No
   per-head K or V is ever written.
 * prefill takes the EXPANDED path (per-head keys and values through
-  ``flash_attention``, values padded to the keys' width and cut again);
+  ``flash_attention``, the values at their own narrower width);
   decode takes the ABSORBED path (``q_nope`` carried into the latent
   space, ``hvd_mla_decode`` over the latents, the result carried out
   through ``W_kvb``'s value half) -- the same mathematics.
@@ -326,14 +326,12 @@ def prefill_forward(params, config: MlaMoeConfig, tokens, positions=None,
             [kv[..., :dn], jnp.broadcast_to(
                 k_pe[:, :, None, :], (b, t, heads, k_pe.shape[-1]))],
             axis=-1)
-        # One kernel for both widths: values padded to the keys' width
-        # and cut again.
-        v = jnp.pad(kv[..., dn:],
-                    ((0, 0),) * 3 + ((0, k.shape[-1] - dv),))
+        # The values go in at their own width, narrower than the keys'.
         o = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, scale=cfg.softmax_scale)
-        o = o[..., :dv].transpose(0, 2, 1, 3).reshape(b, t, heads * dv)
+            kv[..., dn:].transpose(0, 2, 1, 3), causal=True,
+            scale=cfg.softmax_scale)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, heads * dv)
         x = x + _dense_out(o, attn["wo"], dtype)
         y, _ = _ffn(x.reshape(b * t, -1), blk, cfg, of_kind["moe" in blk],
                     dtype)

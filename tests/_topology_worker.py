@@ -221,9 +221,10 @@ def kernels(topology: str) -> int:
             spec((64, 32, 640), jnp.bfloat16),
             spec((5, 34817, 16, 640), jnp.bfloat16),
             spec((64, 544), jnp.int32), spec((64,), jnp.int32)]),
-        # Its expanded prefill: keys 192 wide, values padded to 192.
+        # Its expanded prefill: queries and keys 192 wide, values 128.
         "flash_mla_prefill_8k": (
-            prefill, [spec((1, 32, 8192, 192), jnp.bfloat16)] * 3),
+            prefill, [spec((1, 32, 8192, 192), jnp.bfloat16)] * 2
+            + [spec((1, 32, 8192, 128), jnp.bfloat16)]),
         # 256 experts of 2048 x 768: a decode round's 64 x 8 pairs in
         # tiles of 16 rows, an 8,192-token prefill's in tiles of 128.
         "moe_gmm_decode": (gmm(16), gmm_args(4352, 16)),

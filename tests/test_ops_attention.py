@@ -1080,6 +1080,12 @@ _BLOCKED_LOWERED = {
         "8ac605e954a57c00bdee4c42ac2c89fa3f60ed42687518a0fc1e13a6c2bc94b5",
     "flash_fwd_d192_bf16":
         "5b21536348bce849e1fe85f09915bce1f805fb5d569cb1f8205c267a78545cb4",
+    # Values 128 wide beside keys of 192 (JoyAI's prefill since PR 49):
+    # recorded on PR 49's tree; every other entry is as PR 49 found it.
+    "flash_fwd_d192_v128_f32":
+        "54a0c15715864392e17888a8d18a63ecd4c0c19751b872871cfc46d9e817be00",
+    "flash_fwd_d192_v128_bf16":
+        "c89e076e33e463a778827e186ecb243066b8c5fc42b28a912450950902669c84",
     "flash_swa_fwd_f32":
         "897a4d89d88548c86032be3ca2205a9f2eea16b69178581193c32e5f213e0b95",
     "flash_swa_fwd_bf16":
@@ -1107,7 +1113,8 @@ _BLOCKED_LOWERED = {
 def _blocked_lowered_case(case):
     """``(fn, shapes, Mosaic calls)`` of a case: 1,024 tokens in blocks
     of 512, eight query heads over two key heads of 128 (``d192``: four
-    over four of 192, JoyAI's width); the head-group pair at BERT-Large's
+    over four of 192, JoyAI's width; ``v128``: its values at their own
+    128); the head-group pair at BERT-Large's
     sixteen heads of 64 over 128 tokens, under a scale no other test
     gives them (they are ``jax.jit`` functions: a trace another test made
     with the interpreter on would be found again here)."""
@@ -1125,6 +1132,10 @@ def _blocked_lowered_case(case):
         "flash_fwd_d192": (lambda q, k, v: _attn._flash_fwd(
             q, k, v, None, None, **opts),
             (S((1, 4, 1024, 192), dt),) * 3, 1),
+        "flash_fwd_d192_v128": (lambda q, k, v: _attn._flash_fwd(
+            q, k, v, None, None, **opts),
+            (S((1, 4, 1024, 192), dt),) * 2 + (S((1, 4, 1024, 128), dt),),
+            1),
         "flash_swa_fwd": (lambda q, k, v: _attn._flash_swa_fwd(
             q, k, v, scale=0.1, window=128, bq=512, bk=512), (q, kv, kv), 1),
         # The forward inside the custom_vjp pair, beside its backward.
@@ -1158,3 +1169,186 @@ def test_blocked_and_head_group_kernels_lower_to_what_was_recorded(
         == mosaic_calls
     assert hashlib.sha256(text.encode()).hexdigest() \
         == _BLOCKED_LOWERED[case]
+
+
+# ---------------------------------------------------------------------------
+# Values of a width of their own (PR 49).
+# ---------------------------------------------------------------------------
+
+def _own_width_case(shape, dv, dtype, kv_heads=None, seed=49):
+    """q and k of ``shape``, v ``dv`` wide, and v padded with zeros to the
+    keys' width: what latent attention's prefill handed the kernel
+    before."""
+    b, h, t, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(seed + t + dv), 3)
+    q = _rand(shape, keys[0], dtype)
+    k = _rand((b, kv_heads or h, t, d), keys[1], dtype)
+    v = _rand((b, kv_heads or h, t, dv), keys[2], dtype)
+    padded = jnp.pad(v, ((0, 0),) * 3 + ((0, max(d - dv, 0)),))
+    return q, k, v, padded
+
+
+def _own_width_reference(q, k, v, **kw):
+    rep = q.shape[1] // k.shape[1]
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    return attention_reference(f32[0], jnp.repeat(f32[1], rep, axis=1),
+                               jnp.repeat(f32[2], rep, axis=1), **kw)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_blocked_forward_takes_values_narrower_than_the_keys(monkeypatch,
+                                                             dtype):
+    """JoyAI's prefill call at four heads: 1,024 tokens in blocks of 512,
+    queries and keys 192 wide, values 128.  The result is 128 wide and
+    BIT FOR BIT the kept columns of the call with the values padded to
+    192 (a column of ``p v`` is its own dot product over the same ``p``);
+    the one kernel reads ``v`` and writes ``o`` 128 wide and its body is
+    the padded call's but for the widths."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    q, k, v, padded = _own_width_case((1, 4, 1024, 192), 128, dtype)
+
+    def run(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    got = run(q, k, v)
+    assert got.shape == (1, 4, 1024, 128) and got.dtype == dtype
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(run(q, k, padded)[..., :128], np.float32))
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_own_width_reference(q, k, v, causal=True)),
+        **_HG_TOL[dtype])
+
+    def call(v):
+        calls = [e for e in _equations(jax.make_jaxpr(run)(q, k, v).jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert [str(e.params["name"]) for e in calls] == ["hvd_flash_fwd"]
+        return calls[0]
+
+    own, wide = call(v), call(padded)
+    assert [x.aval.shape[-1] for x in own.invars] == [192, 192, 128]
+    assert [x.aval.shape[-1] for x in own.outvars] == [128, 128]
+    assert [x.aval.shape[-1] for x in wide.outvars] == [192, 128]
+    # No branch on the width: the same equations, less what ``_lanes``
+    # needs to lay a statistic 192 columns wide
+    # (``alpha`` beside the accumulator, ``l`` at the close).
+    import collections
+    names = lambda call: collections.Counter(  # noqa: E731
+        e.primitive.name for e in _equations(call.params["jaxpr"]))
+    assert names(wide) - names(own) == {"concatenate": 2, "slice": 2}
+    assert not names(own) - names(wide)
+
+
+@pytest.mark.parametrize("d,dv", [(24, 16), (16, 24)],
+                         ids=["narrower", "wider"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("block", [32, 512], ids=["blocks", "one_block"])
+def test_blocked_grads_with_values_of_their_own_width(d, dv, causal, block):
+    """The backward kernels know one width: ``_flash_bwd`` pads the
+    narrower of (q, k) and (v, o, dO) with zeros, runs and cuts.  dq, dk
+    and dv equal the reference's, each in its operand's shape; four query
+    heads over two key heads.  ``one_block``: the forward still runs
+    blocked, and the padded backward, which one block holds, takes the
+    head-group kernel over the blocked forward's logsumexp."""
+    q, k, v, _ = _own_width_case((1, 4, 64, d), dv, jnp.float32, kv_heads=2)
+
+    def flash(q, k, v):
+        return _flash(q, k, v, d ** -0.5, causal, block, block)
+
+    names = str(jax.make_jaxpr(jax.grad(
+        lambda *a: flash(*a).sum(), argnums=(0, 1, 2)))(q, k, v))
+    assert "hvd_flash_fwd" in names and "hvd_flash_hg_fwd" not in names
+    assert ("hvd_flash_hg_bwd" in names) == (block == 512)
+
+    def ref(q, k, v):
+        return _own_width_reference(q, k, v, causal=causal)
+
+    assert flash(q, k, v).shape == (1, 4, 64, dv)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))  # noqa: E731
+    for x, a, b in zip((q, k, v),
+                       jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                       jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        assert a.shape == x.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+
+
+def test_window_takes_values_of_their_own_width(monkeypatch):
+    """``hvd_flash_swa_fwd`` as the docstring says: the band's kernel
+    carries the values at their width too, bit for bit the padded
+    call's kept columns."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    q, k, v, padded = _own_width_case((1, 2, 256, 192), 128, jnp.float32,
+                                      kv_heads=1)
+
+    def run(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=96,
+                               block_q=128, block_kv=128)
+
+    got = run(q, k, v)
+    assert got.shape == (1, 2, 256, 128)
+    assert "hvd_flash_swa_fwd" in str(jax.make_jaxpr(run)(q, k, v))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(run(q, k, padded)[..., :128]))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_own_width_reference(
+            q, k, v, causal=True, window=96)), **_HG_TOL[jnp.float32])
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_segment_ids_take_values_of_their_own_width(monkeypatch, causal):
+    """A packed batch with values narrower than its keys: the blocked
+    kernels with segment ids, forward, gradients and dead rows as the
+    reference has them."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    q, k, v, _ = _own_width_case((2, 2, 128, 24), 16, jnp.float32)
+    seg = _packed_segments(jax.random.PRNGKey(3), 2, 128)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg)
+
+    def ref(q, k, v):
+        return attention_reference(q, k, v, causal=causal, segment_ids=seg)
+
+    assert "hvd_flash_fwd" in str(jax.make_jaxpr(flash)(q, k, v))
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))  # noqa: E731
+    for a, b in zip(jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("t,want", [(128, "blocked"), (1024, "blocked")])
+def test_values_of_their_own_width_take_the_blocked_path(caplog, t, want):
+    """One block or many: the head-group kernels know one width, so a
+    call whose values differ from its keys runs the blocked set, and the
+    debug line says how wide the values are."""
+    import logging
+    q, k = _shapes((1, 32, t, 192), (1, 32, t, 192))
+    v = jax.ShapeDtypeStruct((1, 32, t, 128), jnp.bfloat16)
+    with caplog.at_level(logging.DEBUG, logger="horovod_tpu.ops"):
+        assert _attn._flash_path(q, k, v, has_seg=False, bq=512, bk=512) \
+            == (want, 0)
+        as_wide = _attn._flash_path(q, k, k, has_seg=False, bq=512, bk=512)
+    assert as_wide == _attn._flash_path(q, k, has_seg=False, bq=512, bk=512)
+    assert (as_wide[0] == "head_group") == (t == 128)
+    assert "values 128 wide" in caplog.messages[0]
+    assert "values 192 wide" in caplog.messages[1]
+
+
+@pytest.mark.parametrize("k_shape,v_shape", [
+    ((1, 2, 64, 16), (1, 2, 64, 24)),       # keys narrower than queries
+    ((1, 2, 64, 24), (1, 2, 32, 24)),       # a value a key
+    ((1, 2, 64, 24), (1, 1, 64, 24))])      # ... and a head
+def test_operands_that_do_not_fit_together_are_refused(k_shape, v_shape):
+    x = jnp.zeros((1, 2, 64, 24))
+    with pytest.raises(ValueError, match="do not fit together"):
+        flash_attention(x, jnp.zeros(k_shape), jnp.zeros(v_shape))

@@ -174,9 +174,10 @@ def test_topology_aot_mosaic_compiles_auto_kernels():
     out = _topology_worker("v5e:2x2", "kernels")
     # BERT-Large at T = 128 is one block: the head-group forward and ONE
     # backward (dq, dk, dv together), as is Mistral's 512-token prefill;
-    # its 1,024-token prefill and the 8k prefill (keys 192 wide) keep the
-    # blocked forward.  One split-KV call; the latent-attention decode
-    # out of a 64-slot page pool; the grouped expert matmul (gate and up
+    # its 1,024-token prefill and the 8k prefill (keys 192 wide, values
+    # 128 since PR 49) keep the blocked forward.  One split-KV call; the
+    # latent-attention decode out of a 64-slot page pool; the grouped
+    # expert matmul (gate and up
     # fused, then down) at a decode round's and at an 8k prefill's row
     # tiles, and at 16 experts of 2048 x 2048 (gate and up in column
     # slices); the page walk over rows of two key and two value heads (96

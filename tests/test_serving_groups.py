@@ -420,10 +420,14 @@ _OTHER_BLOCKS_LOWERED = {
         "1275953e8aacd6b837923fddda1e29ffbd5a4e0afdc6570b88c3d56d70021e40",
     "mla_moe.step":
         "7e2564801b069ccddc3855cf1159dd602e61397259f56b307bfd1ccc501f88d6",
+    # Re-recorded on PR 49's tree, these two alone: the latent-attention
+    # prefill hands ``flash_attention`` its values at their own width,
+    # so the ``pad`` before the call and the cut after it are gone (the
+    # two texts differ in nothing else but the numbers of the values).
     "mla_moe.prefill":
-        "f8fe2593c900d10900f5942214ea117cf06b5571bf03c829237d834c5e667d05",
+        "935126be570cb0a0793f6aa3516a062138da0fa343c6364f0ac9fc6332e359da",
     "mla_moe.group":
-        "1aa5a0ed65e91edaf849064b4166521b4fa038d2282f14f830a4140eb1b0a663",
+        "f60c8c60351dd166e0569479323920c80295bdbe17cdf847f4cc24a33b496985",
     "cca_moe.step":
         "14fdd64f502e2d712bf7cf54775cf398bcbf869c70dd52be97e4c0588064a790",
     "cca_moe.prefill":

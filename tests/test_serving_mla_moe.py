@@ -57,14 +57,33 @@ def test_prefill_logits_match_the_reference(params):
                                atol=TOL)
 
 
+def test_prefill_with_interpreted_kernels_matches_the_reference(
+        params, monkeypatch):
+    """The tiny model's 40 tokens, keys 24 wide and values 16, with the
+    kernels on: one block, and still the blocked forward (the head-group
+    kernels know one width), within the file's tolerance of the
+    reference like the prefill that runs no kernel."""
+    monkeypatch.setenv("HOROVOD_PALLAS", "1")
+    ctx = np.random.RandomState(1).randint(0, 256, size=40)
+    toks = jnp.asarray(ctx, jnp.int32)[None]
+    traced = jax.jit(lambda p, x: mla_moe.prefill_forward(
+        p, CFG, x, last_only=False)[0]).trace(params, toks)
+    text = str(traced.jaxpr)
+    assert "name=hvd_flash_fwd" in text and "hvd_flash_hg" not in text
+    got = traced.lower().compile()(params, toks)
+    np.testing.assert_allclose(np.asarray(got[0]),
+                               _reference_logits(params, ctx, 0, 40),
+                               rtol=0, atol=TOL)
+
+
 def test_a_bfloat16_prefill_at_the_served_head_width_over_two_blocks(
         monkeypatch):
     """1,024 tokens, two blocks of 512, at JoyAI's head widths (128 + 64
-    query and key columns, 128 value columns padded to 192) computing in
-    bfloat16: ``hvd_flash_fwd`` with bfloat16 products (interpreted)
-    leaves the logits of every row as near the float32 reference as
-    XLA's attention does in the same type.  Every expert is chosen (top 4
-    of 4), so no rounding flips a routing (with 16 experts bfloat16 reads
+    query and key columns, 128 value columns that go in as they are)
+    computing in bfloat16: ``hvd_flash_fwd`` with bfloat16 products
+    (interpreted) leaves the logits of every row as near the float32
+    reference as XLA's attention does in the same type.  Every expert is
+    chosen (top 4 of 4), so no rounding flips a routing (16 experts read
     1.66, ``test_bfloat16_fails_the_float32_tolerance``); float32 against
     float32 reads 1e-5 here, bfloat16 0.011 in the mean and 0.10-0.11 at
     the worst element either way, logits of deviation 1."""
@@ -85,7 +104,14 @@ def test_a_bfloat16_prefill_at_the_served_head_width_over_two_blocks(
     monkeypatch.setenv("HOROVOD_PALLAS", "1")
     text, kernels = run()
     assert text.count("name=hvd_flash_fwd") == 2          # a layer each
-    assert "bf16[1,2,1024,192]" in text
+    # Queries and keys 192 wide, values and the kernel's result 128: no
+    # pad of the values before the call, no cut of its result after it.
+    assert text.count("bf16[1,2,1024,192] = transpose") == 4
+    assert text.count("bf16[1,2,1024,128] = transpose") == 2
+    assert text.count(
+        "out_avals=(ShapedArray(bfloat16[1,2,1024,128]), ") == 2
+    assert "bf16[1,1024,2,192] = jit[name=_pad" not in text
+    assert "bf16[1,2,1024,128] = slice" not in text
     assert kernels.mean() < 1.05 * xla.mean() < 0.02
     assert kernels.max() < 1.5 * xla.max() < 0.5
 
