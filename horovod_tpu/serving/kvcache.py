@@ -230,23 +230,48 @@ def window_rows_from(length: int, window: int) -> int:
     return max(length + 1 - window, 0)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _pool_set(pool, values, pages, offs=None):
-    """``pool.at[:, pages(, offs)].set(values)`` in place: the pool is
-    donated, so the scatter writes the rows it names and copies nothing
-    else.  Compiles once per ``values`` shape."""
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("head",))
+def _pool_set(pool, values, pages=None, rows=None, head=None):
+    """Write ``values`` into the pool in place: the pool is donated, so
+    the scatters write what they name and copy nothing else.  Compiles
+    once per ``values`` shape (``head`` follows from it in every prompt a
+    cell sends).
+
+    ``head`` None: ``values`` ARE pages, ``[layers, (n,) page_size,
+    *entry]`` for ``pool[:, pages]`` (a streamed page, a clone).
+
+    Else ``values`` are a prompt's rows ``[layers, t, *entry]``: rows
+    ``head .. head + n * page_size`` are the ``n`` WHOLE pages ``pages``
+    names (None: the rows cover no page whole), one scatter update a
+    page a layer; what lies before and after them (a first page entered
+    past its start, a last page left before its end) goes to the
+    ``(page, offset)`` pairs ``rows`` ``[2, r]`` names (None: there is
+    nothing ragged), one update a row a layer.  Why pages: a pool is
+    laid out in tiles of 16 bfloat16 rows by 128 columns (8 float32
+    rows), so a 16-row page is one row of tiles (two), and an update of
+    ONE row rewrites a tile under each 128 of its columns and costs an
+    update, some 100-130 ns on a v5e whatever the row holds -- sixteen
+    rows cost sixteen updates, their page one of 125-210 ns (``PERF.md``
+    section 6, PR 50).  The layers are indexed like the pages: under a
+    leading slice XLA re-lays a pool of rows with no head dim out to
+    scatter (two pool-sized copies) and copies the pages' values
+    transposed first, both seen in a deviceless compile; indexed, every
+    form scatters in place."""
     with jax.named_scope("hvd_kv_write_prefill"):
-        if offs is None:
+        if head is None:
             return pool.at[:, pages].set(values)
-        if pool.ndim == 4:
-            # Rows with no head dim: under a leading slice XLA re-lays
-            # the WHOLE pool out to scatter ``[layers, t, w]`` rows (two
-            # pool-sized copies, seen in a deviceless compile); with the
-            # layers indexed like the pages it scatters in place.
-            layers = jnp.arange(pool.shape[0])[:, None]
-            return pool.at[layers, pages[None, :], offs[None, :]].set(
-                values)
-        return pool.at[:, pages, offs].set(values)
+        layers = jnp.arange(pool.shape[0])[:, None]
+        span = 0 if pages is None else pages.shape[0] * pool.shape[2]
+        if span:
+            whole = values[:, head:head + span].reshape(
+                (pool.shape[0], -1) + pool.shape[2:])
+            pool = pool.at[layers, pages[None, :]].set(whole)
+        if rows is not None:
+            ragged = jnp.concatenate(
+                [values[:, :head], values[:, head + span:]], axis=1)
+            pool = pool.at[layers, rows[0][None, :], rows[1][None, :]].set(
+                ragged)
+        return pool
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -341,6 +366,17 @@ class PagedKVCache:
                 "kv.window_pages_reused",
                 "pages a window plane's slot wrote again once their "
                 "tokens had left the window")
+        # What prefills wrote, by scatter updates (one a page or a row,
+        # a plane, a pool): the rows' share that took the page path is
+        # ``page_size * pages / (page_size * pages + rows)``.
+        self._m_pages_written = _registry().counter(
+            "kv.prefill_pages_written",
+            "whole pages that prefills wrote, a plane a pool: one "
+            "scatter update each")
+        self._m_rows_written = _registry().counter(
+            "kv.prefill_rows_written",
+            "rows of a page entered past its start or left before its "
+            "end that prefills wrote singly, a plane a pool")
         # Host-side logical view.  Unallocated table entries point at
         # page 0 -- harmless, reads beyond ``lengths`` are masked.
         self.page_table = np.zeros((c.slots, c.pages_per_slot), np.int32)
@@ -883,7 +919,14 @@ class PagedKVCache:
 
     def write_prefill(self, slot: int, k_layers, v_layers,
                       start: int = 0, state=None, window_rows=None) -> None:
-        """Scatter a prefilled prompt's K/V into the slot's pages.
+        """Scatter a prefilled prompt's K/V into the slot's pages: the
+        pages it covers whole (all of them, where ``start`` and ``start
+        + t`` are multiples of the page size) a PAGE an update, one tile
+        row written once; the rows of a first page entered past its
+        start and of a last page left before its end a ROW an update,
+        each reading and writing the tiles it lies in (:func:`_pool_set`
+        has the arithmetic).  The counters ``kv.prefill_pages_written``
+        and ``kv.prefill_rows_written`` say how much went which way.
 
         ``k_layers``/``v_layers``: ``[num_layers, t, *entry]`` of each
         pool (``num_kv_heads * head_dim`` columns, post-RoPE, as the
@@ -911,14 +954,9 @@ class PagedKVCache:
         self.reserve(slot, start + t, writable_from=start)
         if window_rows is not None:
             self._write_window(slot, t, *window_rows)
-        pos = np.arange(start, start + t)
-        pages = jnp.asarray(self.page_table[slot][pos // c.page_size])
-        offs = jnp.asarray(pos % c.page_size)
-        dt = jnp.dtype(c.dtype)
-        # One scatter per pool: [L, t, *entry] lands at (page, off) pairs.
-        self.k = _pool_set(self.k, k_layers.astype(dt), pages, offs)
-        if self.v is not None:
-            self.v = _pool_set(self.v, v_layers.astype(dt), pages, offs)
+        self.k, self.v = self._scatter_rows(
+            (self.k, self.v), (k_layers, v_layers), self.page_table[slot],
+            start, start + t)
         if state is not None:
             self.write_state(slot, state)
         self.lengths[slot] = start + t
@@ -931,13 +969,40 @@ class PagedKVCache:
             raise ValueError(
                 f"a window plane keeps rows {first}-{length - 1} of a "
                 f"prompt of {length}, got {int(wk_rows.shape[1])} rows")
-        pos = np.arange(first, length)
-        pages = jnp.asarray(self.window_table[slot][
-            pos // c.page_size % c.window_pages_per_slot])
-        offs = jnp.asarray(pos % c.page_size)
+        self.wk, self.wv = self._scatter_rows(
+            (self.wk, self.wv), (wk_rows, wv_rows), self.window_table[slot],
+            first, length)
+
+    def _scatter_rows(self, pools, values, table, first: int, last: int
+                    ) -> list:
+        """Rows ``first .. last - 1`` of a slot into each of ``pools``
+        (None: there is no such pool) through the slot's ``table`` (a
+        ring where it is shorter than the rows reach: the window
+        group's); the pools' successors.  The pages that the rows cover
+        whole are written as pages, the ragged ends as rows
+        (:func:`_pool_set`); one program a pool either way."""
+        c = self.config
+        ps = c.page_size
+        lo, hi = -(-first // ps) * ps, last // ps * ps
+        if hi <= lo:                    # no page is covered whole
+            lo = hi = first
+        # (An index array is built, and sent, only where it names
+        # something: the aligned prompts of every cell send the pages'
+        # alone, a sixteenth of what the rows' two were.)
+        pages = jnp.asarray(table[
+            np.arange(lo, hi, ps) // ps % len(table)]) if hi > lo else None
+        pos = np.concatenate([np.arange(first, lo), np.arange(hi, last)])
+        rows = jnp.asarray(np.stack(
+            [table[pos // ps % len(table)], pos % ps]).astype(np.int32)
+            ) if len(pos) else None
         dt = jnp.dtype(c.dtype)
-        self.wk = _pool_set(self.wk, wk_rows.astype(dt), pages, offs)
-        self.wv = _pool_set(self.wv, wv_rows.astype(dt), pages, offs)
+        out = [None if pool is None else _pool_set(
+            pool, rows_of.astype(dt), pages, rows, head=lo - first)
+            for pool, rows_of in zip(pools, values)]
+        planes = sum(int(pool.shape[0]) for pool in out if pool is not None)
+        self._m_pages_written.inc(planes * ((hi - lo) // ps))
+        self._m_rows_written.inc(planes * len(pos))
+        return out
 
     def grow(self, slot: int) -> None:
         """Account one decoded token (the decode step already wrote its

@@ -61,6 +61,18 @@ prints what each holds, as ``swa_step`` does; the slot state ``[6,
 slots, 1,063,936]`` float32 must be aliased in place and nothing as
 large as one of its planes may be a temporary.
 
+``<topology> pool_write``: compiles, for ONE chip, the program that
+follows every prefill (``serving/kvcache.py:_pool_set``) at Mistral's
+pool ``[16, 3073, 16, 1024]`` with a 512-token prompt (32 whole pages)
+and at SmallThinker's window pool ``[6, 16449, 16, 512]`` with the 4,095
+rows a window plane keeps of an 8,192-token prompt (15 rows, then 255
+pages), and prints what each became: its scatters (updates and the
+window one update writes, from the lowered text; how many the optimized
+module holds), whether the pool is aliased input to output, the
+temporary bytes, and the copies whose result is at least a pool plane in
+size (there must be none: a page write that re-lays the pool out is
+worse than the rows it replaces).
+
 Must run in its own process: the TPU compiler takes a host-wide libtpu
 lock, and the test process itself is pinned to the CPU backend.
 """
@@ -632,6 +644,64 @@ def ssm_step(topology: str, slots: int = 80, *prompts: str) -> int:
     return 0
 
 
+def pool_write(topology: str) -> int:
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.serving import kvcache
+
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    one = SingleDeviceSharding(td.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    out = {}
+    # name: (pool, rows of the prompt, whole pages, single rows before).
+    for name, (pool, t, pages, head) in {
+            "mistral_512": ((16, 3073, 16, 1024), 512, 32, 0),
+            "smallthinker_window_8192": ((6, 16449, 16, 512), 4095, 255,
+                                         15)}.items():
+        lowered = kvcache._pool_set.lower(
+            shape(pool, jnp.bfloat16),
+            shape((pool[0], t, pool[3]), jnp.bfloat16),
+            shape((pages,), jnp.int32),
+            shape((2, t - 16 * pages), jnp.int32) if t > 16 * pages
+            else None, head=head)
+        scatters = []
+        for dims, updates in re.findall(
+                r'"stablehlo\.scatter".*?update_window_dims = \[([\d, ]*)\]'
+                r".*?\}\) : \(tensor<[^>]*>, tensor<[^>]*>, "
+                r"tensor<([^>]*)>\)", lowered.as_text(), re.S):
+            window = [int(d) for d in dims.split(",")]
+            sizes = [int(n) for n in updates.split("x")[:-1]]
+            scatters.append({
+                "updates": math.prod(n for i, n in enumerate(sizes)
+                                     if i not in window),
+                "window": [sizes[i] for i in window]})
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        plane = math.prod(pool[1:])
+        out[name] = {
+            "scatters": scatters,
+            "compiled_scatters": len(re.findall(r"= \S+ scatter\(", text)),
+            "aliased": "input_output_alias={ {}: (0, {}" in text,
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
+            "plane_sized_copies": [
+                dims for dims in re.findall(
+                    r"= \w+\[([\d,]+)\]\S* copy\(", text)
+                if math.prod(int(n) for n in dims.split(",")) >= plane],
+        }
+    print(json.dumps(out))
+    return 0
+
+
 def exchange(topology: str) -> int:
     import re
 
@@ -682,6 +752,8 @@ if __name__ == "__main__":
     topo = sys.argv[1] if len(sys.argv) > 1 else "v5e:2x4"
     if sys.argv[2:] == ["exchange"]:
         sys.exit(exchange(topo))
+    if sys.argv[2:] == ["pool_write"]:
+        sys.exit(pool_write(topo))
     if sys.argv[2:3] == ["dense_step"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(dense_step(topo, *(int(a) for a in sys.argv[3:5])))

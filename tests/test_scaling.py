@@ -309,6 +309,31 @@ def test_topology_aot_ssm_step_updates_its_state_in_place():
         assert pre["resident_with_cache"] < 14.2e9
 
 
+def test_topology_aot_prefill_write_scatters_whole_pages_in_place():
+    """The program that follows every prefill (``kvcache._pool_set``)
+    compiled for one v5e chip.  At Mistral's pool and a 512-token prompt:
+    ONE scatter of 16 layers x 32 pages = 512 updates (8,192 when a row
+    was an update), the page and the entry in an update's window.  At
+    SmallThinker's window pool and the 4,095 rows a window plane keeps
+    of an 8,192-token prompt: ONE program holds both the 255 pages and
+    the 15 rows that enter the ring's first page past its start.  Either
+    way the pool is aliased input to output, nothing is a temporary and
+    no copy is as large as a plane of the pool: a bfloat16 page is one
+    row of tiles, and a write that re-laid the pool out to get at it
+    would cost more than the sixteen rows it replaces."""
+    out = _topology_worker("v5e:2x2", "pool_write")
+    assert out["mistral_512"]["scatters"] == [
+        {"updates": 16 * 512 // 16, "window": [16, 1024]}]
+    assert out["smallthinker_window_8192"]["scatters"] == [
+        {"updates": 6 * 255, "window": [16, 512]},
+        {"updates": 6 * 15, "window": [512]}]
+    for case in out.values():
+        assert case["compiled_scatters"] == len(case["scatters"])
+        assert case["aliased"] is True
+        assert case["temp_bytes"] < 2 ** 20
+        assert case["plane_sized_copies"] == []
+
+
 def test_topology_aot_exchange_is_one_many_operand_all_reduce():
     """The leaf-wise gradient exchange over 32 leaves (50 MB of fp16),
     compiled for the v5e: the step holds one all-reduce a leaf and XLA's
