@@ -936,6 +936,101 @@ def cca_decode_attention(q, pool, page_table, *, layer, lengths,
                       preferred_element_type=jnp.float32).reshape(b, h, d)
 
 
+def eva_decode_attention(q, pool, page_table, window_table, *, layer,
+                         lengths, window: int, row_tokens: int,
+                         kv_heads: int, scale: float, values,
+                         force_reference: bool = False):
+    """Single-token decode attention of a CHUNKED layer: ONE softmax over
+    the exact rows of the aligned window the current token lies in and
+    the pooled rows of every chunk of the windows before it, both read
+    straight out of one pair of pools.
+
+    ``q``: ``(b, h, d)``; ``pool`` and ``values``: ``(layers, pages,
+    page_size, kv_heads * d)``, keys and values under the same page ids,
+    a row either a token's own (a ring page) or a chunk's pooled one (a
+    growing page).  ``page_table``: ``(b, pages_per_slot)``, row ``i``'s
+    growing pages in order, pooled row ``c`` (the chunk of tokens ``c *
+    row_tokens ..``) at row ``c % page_size`` of entry ``c //
+    page_size``.  ``window_table``: ``(b, ring)`` with ``ring >= window /
+    page_size + 1``, the ring of exact rows: the page of tokens ``n *
+    page_size ..`` is entry ``n % ring``.  ``lengths``: ``(b,)``, a
+    row's live tokens, the current one among them (0: an idle row, which
+    gives exactly zero).  The token at position ``i = lengths - 1`` lies
+    in window ``w = i // window`` and sees the pooled rows ``c < w *
+    window / row_tokens`` and the exact rows ``w * window <= j <= i``;
+    the statistics are float32 and there is one normalisation over both
+    sets.  Query head ``n`` reads key/value head ``n // (h /
+    kv_heads)``.  The result is ``(b, h, d)`` float32.
+
+    The kernel is ``hvd_mla_decode``'s walk of a page table under the
+    name ``hvd_eva_decode`` (same family switch), handed a table COMPOSED
+    by index arithmetic alone: the growing pages that the visible pooled
+    rows fill, then the ring turned so that the window's first page comes
+    first.  That asks that every boundary fall on a page's edge
+    (``window`` a multiple of ``row_tokens * page_size``: a window's
+    pooled rows fill whole pages; the window starts on a page's first
+    row): where it does not, and where the family is off, the same
+    softmax runs in ``jax.numpy`` over gathered views."""
+    b, h, d = q.shape
+    page, pps, ring = pool.shape[2], page_table.shape[1], window_table.shape[1]
+    if pool.ndim != 4 or pool.shape[3] != kv_heads * d or h % kv_heads \
+            or values.shape != pool.shape or values.dtype != pool.dtype \
+            or page_table.shape[0] != b or window_table.shape[0] != b \
+            or lengths.shape != (b,):
+        raise ValueError(
+            f"eva_decode_attention: q {q.shape}, pool {pool.shape}, values "
+            f"{values.shape}, tables {page_table.shape} and "
+            f"{window_table.shape}, lengths {lengths.shape} and kv_heads "
+            f"{kv_heads} do not fit together")
+    if window < 1 or window % page or window % row_tokens \
+            or ring < window // page + 1:
+        raise ValueError(
+            f"eva_decode_attention: an aligned window of {window} over "
+            f"pages of {page} and chunks of {row_tokens} needs whole pages, "
+            f"whole chunks and {window // page + 1} ring entries a row, "
+            f"got {ring}")
+    lengths = lengths.astype(jnp.int32)
+    w = jnp.maximum(lengths - 1, 0) // window
+    pooled = w * (window // row_tokens)             # visible pooled rows
+    exact = jnp.where(lengths > 0, lengths - w * window, 0)
+    # The ring turned: the window's first page first.
+    turned = jnp.take_along_axis(
+        window_table, ((w * (window // page))[:, None]
+                       + jnp.arange(ring)) % ring, axis=1)
+    if not force_reference and _pallas.pallas_enabled("mla_decode") \
+            and window % (row_tokens * page) == 0:
+        ahead = pooled // page                      # growing pages walked
+        at = jnp.arange(pps + ring)[None, :]
+        table = jnp.where(
+            at < ahead[:, None],
+            jnp.take_along_axis(page_table, jnp.minimum(at, pps - 1), axis=1),
+            jnp.take_along_axis(
+                turned, jnp.clip(at - ahead[:, None], 0, ring - 1), axis=1))
+        return _mla_decode(q, pool, table, jnp.where(
+                               lengths > 0, pooled + exact, 0),
+                           jnp.asarray(layer, jnp.int32), values,
+                           value_dim=d, scale=float(scale),
+                           kv_heads=kv_heads, value_off=0,
+                           name="hvd_eva_decode", **_walk_sizes())
+
+    def view(z, table):
+        return z[layer, table].reshape(b, -1, kv_heads, d).astype(q.dtype)
+
+    keys = jnp.concatenate([view(pool, page_table), view(pool, turned)], 1)
+    vals = jnp.concatenate([view(values, page_table),
+                            view(values, turned)], 1)
+    rows = jnp.concatenate([jnp.arange(pps * page)[None] < pooled[:, None],
+                            jnp.arange(ring * page)[None] < exact[:, None]],
+                           axis=1)[:, None, None, :]
+    qg = q.reshape(b, kv_heads, h // kv_heads, d)
+    logits = jnp.einsum("bgrd,bsgd->bgrs", qg, keys,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(rows, logits, _NEG_INF)
+    probs = jnp.where(rows, jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("bgrs,bsgd->bgrd", probs.astype(vals.dtype), vals,
+                      preferred_element_type=jnp.float32).reshape(b, h, d)
+
+
 def _causal_mask(s, qi, ki, bq, bk, off, window=None):
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + off
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)

@@ -28,6 +28,7 @@ from .layerspec import LayerSpec, layer_spec  # noqa: F401
 from .loadgen import (LoadSpec, fleet_spec, generate,  # noqa: F401
                       long_prompt_spec, prefix_spec)
 from .cca_moe import CcaMoeConfig  # noqa: F401
+from .eva_dense import EvaDenseConfig  # noqa: F401
 from .loop_dense import LoopDenseConfig  # noqa: F401
 from .mla_moe import MlaMoeConfig  # noqa: F401
 from .ssm_hybrid import SsmHybridConfig  # noqa: F401

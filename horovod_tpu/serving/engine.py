@@ -390,7 +390,8 @@ class ServingEngine:
             dtype=str(jnp.dtype(dtype)), compress=self.kv_compress,
             page=spec.page, slot_state=spec.slot_state,
             slot_state_dtype=spec.slot_state_dtype,
-            window_layers=spec.window_planes, window=spec.window)
+            window_layers=spec.window_planes, window=spec.window,
+            row_tokens=spec.row_tokens)
         self.cache = PagedKVCache(self.cache_config,
                                   spec.pool_sharding(mesh))
         # Admission must price the widest step a slot can take: k drafts
@@ -640,8 +641,16 @@ class ServingEngine:
         # prompt's last ones.
         windowed = {} if self.spec.window is None else {
             "window_rows": req.prompt_len - window_rows_from(
-                req.prompt_len, self.spec.window),
+                req.prompt_len, self.spec.window, self.spec.window_aligned),
             "window_planes": self.spec.window_planes}
+        if self.spec.row_tokens > 1:
+            # Pooled rows: the windows a member's prefill attends in, the
+            # whole chunks it pools and the rows of its ragged last chunk,
+            # which wait in the ring for the round that fills it.
+            windowed.update(
+                windows=-(-req.prompt_len // self.spec.window),
+                chunks_pooled=req.prompt_len // self.spec.row_tokens,
+                pending_rows=req.prompt_len % self.spec.row_tokens)
         if self.spec.scan_chunk:
             # A prefill that is a scan: the chunks its members run.
             windowed["scan_chunks"] = len(members) * -(
@@ -858,13 +867,39 @@ class ServingEngine:
         by page, so short requests never hold a whole one).  With a slot
         state: ``state_planes`` and ``state_bytes``, the live slots' rows
         (``LayerSpec.slot_state_step`` values of each, a plane) that the
-        round's update reads and writes again."""
+        round's update reads and writes again.  With pooled rows
+        (``LayerSpec.row_tokens`` > 1): ``attended_rows``, the exact rows
+        of its aligned window and the pooled rows of the windows before
+        that each live slot attends, in ONE layer, ``pooled_rows`` the
+        pooled part, ``chunks_pooled`` the chunks this round's tokens
+        fill (pooled inside the round) and ``window_crossings`` the slots
+        whose token is a window's first; ``pages`` are then the pages of
+        those rows, and ``window_tokens`` and ``window_pages`` the exact
+        part's."""
         live = [int(self.cache.lengths[s]) + 1 for s in slots]
         page = self.page_size
         # (A test's stand-in for the step may be a bare function.)
         meta = getattr(step or self.step, "meta", {})
         windowed = {}
-        if self.spec.window is not None:
+        pages = sum(-(-n // page) for n in live)
+        if self.spec.row_tokens > 1:
+            # Pooled rows: a slot attends the exact rows of the aligned
+            # window its token lies in and one pooled row a chunk of the
+            # windows before, not its live tokens.
+            w, rows = self.spec.window, self.spec.row_tokens
+            exact = [(n - 1) % w + 1 for n in live]
+            pooled = [(n - 1) // w * (w // rows) for n in live]
+            pages = sum(-(-n // page) for n in exact + pooled)
+            windowed = dict(
+                attended_rows=sum(exact) + sum(pooled),
+                pooled_rows=sum(pooled),
+                chunks_pooled=self._chunks_filled(slots),
+                window_crossings=sum(n % w == 1 for n in live),
+                window_tokens=sum(exact),
+                window_pages=sum(-(-n // page) for n in exact),
+                window_planes=self.spec.window_planes,
+                window_pages_held=self.cache.window_live_pages)
+        elif self.spec.window is not None:
             # What a window layer's walk reads in ONE of its planes: a
             # slot's last ``window`` tokens, and the pages they lie in.
             w = self.spec.window
@@ -887,8 +922,14 @@ class ServingEngine:
             "decode.round", round=int(st["decode_steps"]), slots=len(slots),
             live_tokens=sum(live), ahead=int(ahead),
             passes=self.spec.passes, planes=self.spec.planes,
-            pages=sum(-(-n // page) for n in live),
+            pages=pages,
             walk=int(meta.get("attention") == "walk"), **windowed)
+
+    def _chunks_filled(self, slots: List[int]) -> int:
+        """Slots whose token of the round about to be dispatched is its
+        chunk's last (pooled rows): the round pools that chunk."""
+        return sum((int(self.cache.lengths[s]) + 1) % self.spec.row_tokens
+                   == 0 for s in slots)
 
     def decode_once(self, st: Dict[str, Any], now) -> float:
         """Dispatch one plain continuous-batching decode round over the
@@ -964,6 +1005,8 @@ class ServingEngine:
             # Sent to the host as soon as the round ends, whenever the
             # host comes to read it.
             self._told.copy_to_host_async()
+            if self.spec.row_tokens > 1:
+                cache.count_pooled(self._chunks_filled(slots))
             for slot in slots:
                 cache.lengths[slot] += 1
                 self.scheduler.active[slot].in_flight += 1

@@ -42,6 +42,19 @@ counter ``kv.window_pages_reused``).  A read masks what is older than
 the window and what is past the length, so a reused page's stale rows
 are as unreachable as a recycled full page's.
 
+POOLED ROWS (``CacheConfig.row_tokens`` > 1; a model whose layers read
+an ALIGNED window exactly and every chunk of ``row_tokens`` tokens behind
+it through one pooled row): a row of the growing planes then stands for
+a chunk, so a sequence of ``n`` tokens holds ``ceil(ceil(n / row_tokens)
+/ page_size)`` growing pages beside its ring, and admission, reservation
+and release price it so.  Every such layer has a plane in both groups,
+and the window group's pages lie IN the growing planes' pools, behind
+their scratch page (``window_in_pool``), so that one page walk reaches a
+slot's pooled rows and its ring::
+
+    k, v: [num_layers, num_pages + 1 + slots * window_pages_per_slot,
+           page_size, row]
+
 Pages are allocated lazily from a free list as a slot's sequence grows
 and returned wholesale on eviction -- continuous batching recycles slots
 mid-flight, so the pool, not the slot count, bounds resident KV bytes.
@@ -125,6 +138,13 @@ class CacheConfig:
     # is none) and the window's length in tokens.
     window_layers: int = 0
     window: Optional[int] = None
+    # Tokens a row of the growing planes stands for
+    # (``LayerSpec.row_tokens``: a pooled row a chunk).  More than one:
+    # every layer has a plane in both groups (``LayerSpec.attn_kinds``:
+    # "chunked"), the window is an ALIGNED one (``window_aligned``) and
+    # the window group's pages lie in the growing planes' own pools
+    # (``window_in_pool``).
+    row_tokens: int = 1
 
     def __post_init__(self):
         heads = (self.num_kv_heads, self.head_dim)
@@ -143,10 +163,12 @@ class CacheConfig:
             raise NotImplementedError(
                 "the fp8 cold pool mirrors TWO pools (six step operands), "
                 f"not {self.page}")
-        if self.max_len % self.page_size:
+        if self.max_len % self.page_size or self.row_tokens < 1:
             raise ValueError(
                 f"max_len {self.max_len} not a multiple of page_size "
-                f"{self.page_size}")
+                f"{self.page_size}" + (
+                    f"; {self.row_tokens} tokens a row"
+                    if self.row_tokens != 1 else ""))
         if self.hot_pages < 0:
             raise ValueError(f"hot_pages must be >= 0: {self.hot_pages}")
         if (self.window_layers > 0) != (self.window is not None) or (
@@ -158,6 +180,28 @@ class CacheConfig:
             raise NotImplementedError(
                 "a window group goes with two pools and no fp8 cold "
                 f"pool: compress {self.compress}, pools {self.page}")
+        if self.row_tokens > 1 and (
+                self.window_layers != self.num_layers
+                or self.window % self.page_size
+                or self.window % self.row_tokens):
+            raise ValueError(
+                f"rows of {self.row_tokens} tokens go with a window plane "
+                f"a plane ({self.window_layers} of {self.num_layers}) and "
+                f"an aligned window of whole pages and rows: {self.window} "
+                f"over pages of {self.page_size}")
+
+    @property
+    def window_in_pool(self) -> bool:
+        """The window group's pages lie in the growing planes' own pools,
+        behind the scratch page, under the ids ``window_first_page ..``:
+        a layer reads both groups through one walk."""
+        return self.row_tokens > 1
+
+    @property
+    def window_aligned(self) -> bool:
+        """Token ``i`` sees the window it lies in, from ``window * (i //
+        window)``, not its last ``window`` tokens."""
+        return self.row_tokens > 1
 
     @property
     def window_pages_per_slot(self) -> int:
@@ -179,11 +223,34 @@ class CacheConfig:
 
     @property
     def pages_per_slot(self) -> int:
-        return self.max_len // self.page_size
+        return -(-self.max_len // (self.page_size * self.row_tokens))
 
     @property
     def num_pages(self) -> int:
         return self.slots * self.pages_per_slot
+
+    @property
+    def pool_pages(self) -> int:
+        """Pages of a plane of the pools: the growing group's, the
+        scratch page and, where they lie there, the window group's."""
+        return self.num_pages + 1 + (
+            self.window_num_pages if self.window_in_pool else 0)
+
+    @property
+    def window_first_page(self) -> int:
+        """The id of the window group's first page: 0 in pools of its
+        own, behind the scratch page in the growing planes'."""
+        return self.num_pages + 1 if self.window_in_pool else 0
+
+    def pages_for(self, length: int) -> int:
+        """Growing pages a sequence of ``length`` tokens holds."""
+        return -(-(-(-int(length) // self.row_tokens)) // self.page_size)
+
+    def ring_pages_for(self, length: int) -> int:
+        """Window pages a sequence of ``length`` tokens holds: its
+        tokens' pages, as far as the ring goes."""
+        return min(-(-int(length) // self.page_size),
+                   self.window_pages_per_slot)
 
     @property
     def scratch_page(self) -> int:
@@ -199,7 +266,7 @@ class CacheConfig:
         how many ranks the kv-head dim is split over (asserted by
         tests/test_serving.py across 1- and 8-device meshes)."""
         out = {
-            "kv_shape": [self.num_layers, self.num_pages + 1,
+            "kv_shape": [self.num_layers, self.pool_pages,
                          self.page_size, *self.entries[0]],
             "page_table_shape": [self.slots, self.pages_per_slot],
             "page_size": self.page_size,
@@ -213,20 +280,31 @@ class CacheConfig:
             # did: no key is added to its layout.)
             out.update(
                 window=self.window,
-                window_kv_shape=[self.window_layers,
-                                 self.window_num_pages + 1,
-                                 self.page_size, *self.entries[0]],
                 window_table_shape=[self.slots,
                                     self.window_pages_per_slot],
                 window_pages_per_slot=self.window_pages_per_slot,
-                window_num_pages=self.window_num_pages,
-                window_scratch_page=self.window_num_pages)
+                window_num_pages=self.window_num_pages)
+            if self.window_in_pool:
+                # (Nor to a window group's in pools of its own.)
+                out.update(row_tokens=self.row_tokens,
+                           window_first_page=self.window_first_page)
+            else:
+                out.update(
+                    window_kv_shape=[self.window_layers,
+                                     self.window_num_pages + 1,
+                                     self.page_size, *self.entries[0]],
+                    window_scratch_page=self.window_num_pages)
         return out
 
 
-def window_rows_from(length: int, window: int) -> int:
+def window_rows_from(length: int, window: int,
+                     aligned: bool = False) -> int:
     """The first row a window plane keeps of a prompt of ``length``
-    tokens: the oldest token that the NEXT token's window still sees."""
+    tokens: the oldest token that the NEXT token's window still sees
+    (``aligned``: the first token of the window the next token lies
+    in)."""
+    if aligned:
+        return length // window * window
     return max(length + 1 - window, 0)
 
 
@@ -313,7 +391,7 @@ class PagedKVCache:
         self.config = config
         c = config
         # +1: trailing scratch page, the write sink for idle slots.
-        lead = (c.num_layers, c.num_pages + 1, c.page_size)
+        lead = (c.num_layers, c.pool_pages, c.page_size)
         k = jnp.zeros(lead + c.entries[0], jnp.dtype(c.dtype))
         v = None if c.entries[1] is None else jnp.zeros(
             lead + c.entries[1], jnp.dtype(c.dtype))
@@ -351,17 +429,27 @@ class PagedKVCache:
         self.wk = self.wv = None
         self.window_table = None
         self._wallocated = np.zeros((c.slots,), np.int32)
+        # Pages of tokens a slot's sequence has reached (its growing
+        # pages, where a row is a token).
+        self._wreached = np.zeros((c.slots,), np.int32)
         self._wfree: List[int] = []
         if c.window_layers:
-            wlead = (c.window_layers, c.window_num_pages + 1, c.page_size)
-            self.wk = jnp.zeros(wlead + c.entries[0], jnp.dtype(c.dtype))
-            self.wv = jnp.zeros(wlead + c.entries[1], jnp.dtype(c.dtype))
-            if sharding is not None:
-                self.wk = jax.device_put(self.wk, sharding)
-                self.wv = jax.device_put(self.wv, sharding)
-            self.window_table = np.zeros(
-                (c.slots, c.window_pages_per_slot), np.int32)
-            self._wfree = list(range(c.window_num_pages - 1, -1, -1))
+            if not c.window_in_pool:
+                wlead = (c.window_layers, c.window_num_pages + 1,
+                         c.page_size)
+                self.wk = jnp.zeros(wlead + c.entries[0],
+                                    jnp.dtype(c.dtype))
+                self.wv = jnp.zeros(wlead + c.entries[1],
+                                    jnp.dtype(c.dtype))
+                if sharding is not None:
+                    self.wk = jax.device_put(self.wk, sharding)
+                    self.wv = jax.device_put(self.wv, sharding)
+            self.window_table = np.full(
+                (c.slots, c.window_pages_per_slot), c.window_first_page,
+                np.int32)
+            self._wfree = list(range(
+                c.window_first_page + c.window_num_pages - 1,
+                c.window_first_page - 1, -1))
             self._m_reused = _registry().counter(
                 "kv.window_pages_reused",
                 "pages a window plane's slot wrote again once their "
@@ -377,6 +465,12 @@ class PagedKVCache:
             "kv.prefill_rows_written",
             "rows of a page entered past its start or left before its "
             "end that prefills wrote singly, a plane a pool")
+        if c.row_tokens > 1:
+            self._m_pooled = _registry().counter(
+                "kv.pooled_rows_written",
+                "pooled rows (a chunk of a slot's sequence each, one row "
+                "in every growing plane of each pool) that prefills and "
+                "decode rounds wrote")
         # Host-side logical view.  Unallocated table entries point at
         # page 0 -- harmless, reads beyond ``lengths`` are masked.
         self.page_table = np.zeros((c.slots, c.pages_per_slot), np.int32)
@@ -555,12 +649,13 @@ class PagedKVCache:
                 a += min(cold, len(self._cfree))
             return a
 
-        need = -(-max(int(length), 1) // self.config.page_size)
+        c = self.config
+        need = c.pages_for(max(int(length), 1))
         if need > avail() and self.reclaim_cb is not None:
             self.reclaim_cb(need - avail())
         # The window group: a slot's ring at its fullest.
-        return need <= avail() and min(
-            need, self.config.window_pages_per_slot) <= len(self._wfree)
+        return need <= avail() and c.ring_pages_for(
+            max(int(length), 1)) <= len(self._wfree)
 
     def reserve(self, slot: int, length: int,
                 writable_from: Optional[int] = None) -> None:
@@ -575,9 +670,9 @@ class PagedKVCache:
         c = self.config
         if length > c.max_len:
             raise ValueError(f"length {length} exceeds max_len {c.max_len}")
-        need = -(-int(length) // c.page_size)
+        need = c.pages_for(length)
         have = int(self._allocated[slot])
-        ring_short = min(need, c.window_pages_per_slot) \
+        ring_short = c.ring_pages_for(length) \
             - int(self._wallocated[slot]) - len(self._wfree)
         if ring_short > 0:
             # Before a page of either group is taken.
@@ -601,23 +696,26 @@ class PagedKVCache:
                 self.page_table[slot, i] = pid
             self._allocated[slot] = need
         if c.window_layers:
-            self._reserve_window(slot, need, have)
+            self._reserve_window(slot, -(-int(length) // c.page_size))
         if writable_from is not None:
             self._make_writable(slot, writable_from)
 
-    def _reserve_window(self, slot: int, need: int, have: int) -> None:
-        """The window group's side of :meth:`reserve`: the slot's ring
-        grows to ``min(need, window_pages_per_slot)`` pages; a page
-        beyond that is one of the ring's own, taken back from tokens the
-        window has left (``have``: the pages the slot's sequence had
+    def _reserve_window(self, slot: int, need: int) -> None:
+        """The window group's side of :meth:`reserve`, ``need`` the pages
+        of TOKENS the sequence now reaches: the slot's ring grows to
+        ``min(need, window_pages_per_slot)`` pages; a page beyond that
+        is one of the ring's own, taken back from tokens the window has
+        left (counted against the pages the slot's sequence had reached
         before this call; a prompt's prefill, which starts from none,
         writes its last pages only and takes nothing back)."""
         ring = self.config.window_pages_per_slot
         held = int(self._wallocated[slot])
+        have = int(self._wreached[slot])
         want = min(need, ring)
         for i in range(held, want):
             self.window_table[slot, i] = self._wfree.pop()
         self._wallocated[slot] = max(held, want)
+        self._wreached[slot] = max(have, need)
         if have and need > max(have, ring):
             self._m_reused.inc(need - max(have, ring))
 
@@ -629,7 +727,7 @@ class PagedKVCache:
         exact bytes they attached (bitwise, by construction: the write
         lands in the clone)."""
         c = self.config
-        for i in range(int(from_pos) // c.page_size,
+        for i in range(int(from_pos) // (c.page_size * c.row_tokens),
                        int(self._allocated[slot])):
             if self.compress and self.comp_mask[slot, i]:
                 raise RuntimeError(
@@ -721,7 +819,7 @@ class PagedKVCache:
         self._allocated[slot] = 0
         for i in range(int(self._wallocated[slot]) - 1, -1, -1):
             self._wfree.append(int(self.window_table[slot, i]))
-        self._wallocated[slot] = 0
+        self._wallocated[slot] = self._wreached[slot] = 0
         if self.compress:
             self._cheld[slot] = 0
         if self.state is not None and self.lengths[slot]:
@@ -942,7 +1040,15 @@ class PagedKVCache:
         window group, the pair ``(first, second)`` of its planes' rows
         ``[window_layers, rows, *entry]``: the prompt's LAST rows, from
         :func:`window_rows_from` on -- a window plane is written those
-        only, into the slot's ring."""
+        only, into the slot's ring.
+
+        With pooled rows (``row_tokens`` > 1) ``k_layers``/``v_layers``
+        are the POOLED rows of the prompt's whole chunks, ``[num_layers,
+        t // row_tokens, *entry]``, and ``window_rows`` the exact rows of
+        its last (aligned) window: the prompt's length is what the two
+        say together.  The rows of the ragged last chunk are in the ring
+        and nowhere else: the decode round that fills the chunk pools
+        it."""
         c = self.config
         t = int(k_layers.shape[1])
         if (window_rows is not None) != bool(c.window_layers) or (
@@ -951,12 +1057,24 @@ class PagedKVCache:
                 f"{c.window_layers} window planes, window rows "
                 f"{'given' if window_rows is not None else 'missing'}, "
                 f"start {start}")
+        rows = t
+        if c.row_tokens > 1:
+            # ``rows`` whole chunks; the windows they fill, and the last
+            # window's own rows behind them.
+            t = rows * c.row_tokens // c.window * c.window \
+                + int(window_rows[0].shape[1])
+            if t // c.row_tokens != rows:
+                raise ValueError(
+                    f"{rows} pooled rows of {c.row_tokens} tokens and "
+                    f"{int(window_rows[0].shape[1])} rows of the last "
+                    f"window of {c.window} are no prompt's")
+            self._m_pooled.inc(rows)
         self.reserve(slot, start + t, writable_from=start)
         if window_rows is not None:
             self._write_window(slot, t, *window_rows)
         self.k, self.v = self._scatter_rows(
             (self.k, self.v), (k_layers, v_layers), self.page_table[slot],
-            start, start + t)
+            start, start + rows)
         if state is not None:
             self.write_state(slot, state)
         self.lengths[slot] = start + t
@@ -964,11 +1082,16 @@ class PagedKVCache:
     def _write_window(self, slot: int, length: int, wk_rows, wv_rows
                       ) -> None:
         c = self.config
-        first = window_rows_from(length, c.window)
+        first = window_rows_from(length, c.window, c.window_aligned)
         if int(wk_rows.shape[1]) != length - first:
             raise ValueError(
                 f"a window plane keeps rows {first}-{length - 1} of a "
                 f"prompt of {length}, got {int(wk_rows.shape[1])} rows")
+        if c.window_in_pool:
+            self.k, self.v = self._scatter_rows(
+                (self.k, self.v), (wk_rows, wv_rows),
+                self.window_table[slot], first, length)
+            return
         self.wk, self.wv = self._scatter_rows(
             (self.wk, self.wv), (wk_rows, wv_rows), self.window_table[slot],
             first, length)
@@ -1003,6 +1126,11 @@ class PagedKVCache:
         self._m_pages_written.inc(planes * ((hi - lo) // ps))
         self._m_rows_written.inc(planes * len(pos))
         return out
+
+    def count_pooled(self, rows: int) -> None:
+        """Pooled rows the decode round just dispatched writes: a live
+        slot's each, where the round's token is its chunk's last."""
+        self._m_pooled.inc(int(rows))
 
     def grow(self, slot: int) -> None:
         """Account one decoded token (the decode step already wrote its

@@ -15,7 +15,8 @@ A config describes itself through a ``layer_spec()`` method
 :class:`~horovod_tpu.serving.cca_moe.CcaMoeConfig`,
 :class:`~horovod_tpu.serving.loop_dense.LoopDenseConfig`,
 :class:`~horovod_tpu.serving.swa_moe.SwaMoeConfig`,
-:class:`~horovod_tpu.serving.ssm_hybrid.SsmHybridConfig`); a
+:class:`~horovod_tpu.serving.ssm_hybrid.SsmHybridConfig`,
+:class:`~horovod_tpu.serving.eva_dense.EvaDenseConfig`); a
 ``LlamaConfig`` (a plain dataclass of ``models/transformer.py``) is
 described here, by the functions of ``serving/decode.py``.
 """
@@ -109,8 +110,26 @@ class LayerSpec:
     # planes are the full layers' alone.  The decode step takes the
     # window group's table after ``active`` and the window pools, donated,
     # before its own state.
+    #
+    # A third kind, "chunked": the layer reads the window the current
+    # token lies in EXACTLY and every ``row_tokens`` tokens of the
+    # windows before it through ONE pooled row.  Such a layer has a plane
+    # in BOTH groups, its number among the chunked layers in each: its
+    # window plane is the ring of exact rows, its growing plane holds a
+    # row a chunk.  So that one walk reaches both, the window group's
+    # pages then lie in the growing planes' own pools, behind their
+    # scratch page (``CacheConfig.window_in_pool``): the step takes the
+    # window group's table and no further pool.  The prefill hands back
+    # the POOLED rows of the prompt's whole chunks as its first and
+    # second planes (``[planes, batch, t // row_tokens, *page entry]``)
+    # and the exact rows of its last window as the window pair.
     attn_kinds: Optional[Tuple[str, ...]] = None
     window: Optional[int] = None
+    # Tokens ONE row of the growing planes stands for (a chunked layer's
+    # pooled row): what prices, reserves and frees the cache reckons a
+    # sequence of ``n`` tokens as ``ceil(ceil(n / row_tokens) /
+    # page_size)`` growing pages.
+    row_tokens: int = 1
 
     def __post_init__(self):
         if self.attention not in ("gqa", "mla", "cca"):
@@ -119,17 +138,28 @@ class LayerSpec:
             raise ValueError(f"feed-forward kinds {sorted(set(self.ffn))}")
         kinds = self.attn_kinds
         if kinds is not None and (
-                set(kinds) - {"full", "window"} or len(kinds) != len(self.ffn)
-                or "full" not in kinds):
+                set(kinds) - {"full", "window", "chunked"}
+                or len(kinds) != len(self.ffn)
+                or not {"full", "chunked"} & set(kinds)):
             raise ValueError(
                 f"attention kinds {kinds} of {len(self.ffn)} layers: "
-                '"full" or "window" a layer, one of them at least "full"')
+                '"full", "window" or "chunked" a layer, one of them at '
+                'least "full" or "chunked"')
         if (self.window is not None) != (
-                kinds is not None and "window" in kinds) \
+                kinds is not None and bool({"window", "chunked"}
+                                           & set(kinds))) \
                 or (self.window is not None and self.window < 1):
             raise ValueError(
                 f"window {self.window} and attention kinds {kinds}: a "
                 "window's length goes with window layers")
+        chunked = kinds is not None and "chunked" in kinds
+        if chunked != (self.row_tokens > 1) or self.row_tokens < 1 \
+                or (chunked and (set(kinds) != {"chunked"}
+                                 or self.window % self.row_tokens)):
+            raise ValueError(
+                f"attention kinds {kinds}, {self.row_tokens} tokens a "
+                f"growing row, window {self.window}: chunked layers, all "
+                "or none, read a window of whole chunks")
         if self.window is not None and (self.passes != 1
                                         or self.page[1] is None):
             raise NotImplementedError(
@@ -164,13 +194,23 @@ class LayerSpec:
     def planes(self) -> int:
         """Leading entries of the pools: what sizes, writes, reads,
         frees, re-prefills or ships the cache counts these, never the
-        layers.  The full layers' alone, where some are window layers."""
-        return self.passes * (len(self.ffn) - self.window_planes)
+        layers.  The full layers' alone, where some are window layers; a
+        chunked layer has one here and one in the window group."""
+        return self.passes * (len(self.ffn)
+                              - (self.attn_kinds or ()).count("window"))
 
     @property
     def window_planes(self) -> int:
         """Leading entries of the window group's pools."""
-        return (self.attn_kinds or ()).count("window")
+        kinds = self.attn_kinds or ()
+        return kinds.count("window") + kinds.count("chunked")
+
+    @property
+    def window_aligned(self) -> bool:
+        """The window is ALIGNED (chunked layers): token ``i`` sees the
+        window it lies in, from ``window * (i // window)``, not its last
+        ``window`` tokens."""
+        return "chunked" in (self.attn_kinds or ())
 
     def require(self, **wanted: bool) -> None:
         """Raise ``NotImplementedError``, by name, for each feature that

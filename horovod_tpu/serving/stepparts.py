@@ -1,9 +1,10 @@
 """What the one-chip decode steps share, written once.
 
-``mla_moe.py``, ``cca_moe.py``, ``loop_dense.py``, ``swa_moe.py`` and
-``ssm_hybrid.py`` build their decode programs from the same parts: the
-embedding read into a float32 residual stream (times a constant, where
-the model has one), where a round's token lands in the page pool, a loop
+``mla_moe.py``, ``cca_moe.py``, ``loop_dense.py``, ``swa_moe.py``,
+``ssm_hybrid.py`` and ``eva_dense.py`` build their decode programs from
+the same parts: the embedding read into a float32 residual stream (times
+a constant, where the model has one), where a round's token lands in the
+page pool, a loop
 over the layers that hands each one the pool (and whatever else the
 model carries), around it -- for a model whose tokens make several
 passes over the same layers -- ONE rolled loop over the passes with the
@@ -62,12 +63,16 @@ def embed(p, tokens):
     return p["tok_embed"][tokens].astype(jnp.float32)
 
 
-def readout(x, p, eps: float, dtype, *, tied: bool, normed: bool = False):
+def readout(x, p, eps: float, dtype, *, tied: bool, normed: bool = False,
+            unit_offset: bool = False):
     """Float32 logits over the final norm (``normed``: ``x`` has been
-    through it already): against ``lm_head`` or, tied, against the
-    embedding's own rows."""
+    through it already; ``unit_offset``: the norm multiplies by ``1 +
+    scale``): against ``lm_head`` or, tied, against the embedding's own
+    rows."""
     if not normed:
-        x = _rmsnorm(x, p["final_norm"]["scale"], dtype, eps)
+        scale = p["final_norm"]["scale"]
+        x = _rmsnorm(x, 1.0 + scale.astype(jnp.float32) if unit_offset
+                     else scale, dtype, eps)
     if not tied:
         return dense_out(x, p["lm_head"], dtype)
     return jax.lax.dot_general(
@@ -104,7 +109,10 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
                         after_pass: Optional[Callable] = None,
                         window_group: bool = False,
                         held: Optional[slice] = None,
-                        embed_scale: float = 1.0, logit_scale: float = 1.0
+                        embed_scale: float = 1.0, logit_scale: float = 1.0,
+                        window_pools: bool = True,
+                        unit_offset: bool = False,
+                        sample_columns: Optional[int] = None
                         ) -> ServingDecodeStep:
     """The jitted step ``name``::
 
@@ -160,6 +168,13 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
     ``embed_scale`` and ``logit_scale`` (a model that multiplies its
     embedding and its logits by constants): applied where they are not
     1, so that a model without them lowers to what it lowered to.
+    ``window_pools=False`` (a model whose window group's pages lie in the
+    pools themselves, ``CacheConfig.window_in_pool``): the step takes the
+    window group's table and no further pool.  ``unit_offset``: the
+    final norm multiplies by ``1 + scale``.  ``sample_columns`` (a head
+    of several predictions side by side): the logits' leading columns
+    that the round's token is the greedy one of; the step still returns
+    every column.
     """
     looped = after_pass is not None
     if passes > 1 and not looped:
@@ -217,19 +232,22 @@ def build_one_chip_step(name: str, layer: Callable, *, num_layers: int,
             x, pool, carry, hist, told, mass, _ = jax.lax.fori_loop(
                 0, passes, body, (x, pool, list(carry), hist, told, mass,
                                   jnp.ones(x.shape[:1], jnp.float32)))
-        logits = readout(x, p, eps, dtype, tied=tied, normed=looped)
+        logits = readout(x, p, eps, dtype, tied=tied, normed=looped,
+                         **({"unit_offset": True} if unit_offset else {}))
         if logit_scale != 1.0:
             logits = logits * logit_scale
         own = ([hist] if routed else []) + ([mass] if looped else [])
         if two_pools:
             pool, no_pool = pool
         return (logits, pool, no_pool, *carry, *own,
-                tell_round(logits, told))
+                tell_round(logits if sample_columns is None
+                           else logits[:, :sample_columns], told))
 
     step.__name__ = step.__qualname__ = name
     first = 7 + window_group
     fn = jax.jit(step, donate_argnums=(1, 2) + tuple(range(
-        first, first + 2 * window_group + carried + routed + looped)))
+        first, first + 2 * (window_group and window_pools) + carried
+        + routed + looped)))
     return ServingDecodeStep(fn, dict(
         meta, kind="serving_decode", world=1, tp=1, num_layers=num_layers,
         passes=passes, dtype=str(jnp.dtype(dtype)), lora=False,

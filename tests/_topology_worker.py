@@ -644,6 +644,101 @@ def ssm_step(topology: str, slots: int = 80, *prompts: str) -> int:
     return 0
 
 
+def eva_step(topology: str, slots: int = 0, *prompts: int) -> int:
+    """``serving/eva_dense.py``'s decode step and prefills at EvaByte's
+    widths from ``benchmarks/configs/evabyte_6_5b.json``: both groups'
+    pages in ONE pair of pools, aliased in place; the walk under its own
+    name; what a prefill holds beside weights and cache."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import eva_dense as family
+    from horovod_tpu.ops import pallas
+    from horovod_tpu.serving import eva_dense
+    from horovod_tpu.serving.decode import no_round
+    from horovod_tpu.serving.kvcache import CacheConfig
+
+    pallas.interpret_mode = lambda: False
+    td = topologies.get_topology_desc(platform="tpu",
+                                      topology_name=topology)
+    mesh = Mesh(np.asarray(td.devices[:1]), ("tp",))
+    with open(os.path.join(dirname(dirname(abspath(__file__))),
+                           "benchmarks", "configs",
+                           "evabyte_6_5b.json")) as f:
+        config = json.load(f)
+    cfg = family.program_config(config)
+    serving = config["serving"]
+    slots = slots or serving["slots"]
+    bf = jnp.bfloat16
+    spec = cfg.layer_spec()
+    cc = CacheConfig(
+        num_layers=spec.planes, slots=slots, page_size=serving["page_size"],
+        max_len=serving["max_len"], dtype="bfloat16", page=spec.page,
+        window_layers=spec.window_planes, window=spec.window,
+        row_tokens=spec.row_tokens)
+    on = NamedSharding(mesh, P())
+
+    def whole(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=on)
+
+    params = jax.tree.map(lambda z: whole(z.shape, bf),
+                          eva_dense.param_shapes(cfg, bf))
+    weights = sum(int(np.prod(z.shape)) * 2 for z in jax.tree.leaves(params))
+    pool = whole(cc.layout()["kv_shape"], bf)
+    out = {"weight_bytes": weights,
+           "cache_bytes": 2 * 2 * int(np.prod(pool.shape)),
+           "plane_bytes": 2 * int(np.prod(pool.shape[1:])),
+           "pool_shape": list(pool.shape)}
+
+    def report(name, lowered):
+        compiled = lowered.compile()
+        mem = compiled.memory_analysis()
+        text = lowered.as_text()
+        header = compiled.as_text()
+        header = header[:header.index("\n")]
+        out[name] = {
+            "mosaic_calls": {k: text.count(f'kernel_name = "{k}"') for k in (
+                "hvd_eva_decode", "hvd_cca_decode", "hvd_flash_fwd",
+                "hvd_flash_hg_fwd")},
+            "aliased_params": sorted(int(i) for i in re.findall(
+                r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)),
+            "argument_bytes": int(mem.argument_size_in_bytes),
+            "temp_bytes": int(mem.temp_size_in_bytes),
+            "output_bytes": int(mem.output_size_in_bytes),
+            "alias_bytes": int(mem.alias_size_in_bytes)}
+
+    step = eva_dense.build_decode_step(
+        cfg, mesh, slots=slots, page_size=cc.page_size,
+        pages_per_slot=cc.pages_per_slot, dtype=bf)
+    report("decode", step._fn.lower(
+        params, pool, pool, whole((slots,), jnp.int32),
+        whole((slots,), jnp.int32), whole((slots, cc.pages_per_slot),
+                                          jnp.int32),
+        whole((slots,), jnp.bool_),
+        whole((slots, cc.window_pages_per_slot), jnp.int32),
+        whole(no_round(slots).shape, jnp.int32)))
+    out["decode"]["pool_params"] = [
+        len(jax.tree.leaves(params)) + i for i in (0, 1)]
+
+    def prefill(p, toks):
+        return spec.prefill(p, toks, dtype=bf)
+
+    for t in prompts:
+        report(f"prefill_{t}", jax.jit(prefill).lower(
+            params, whole((1, int(t)), jnp.int32)))
+        out[f"prefill_{t}"]["resident_with_cache"] = (
+            out["cache_bytes"] + out[f"prefill_{t}"]["argument_bytes"]
+            + out[f"prefill_{t}"]["temp_bytes"]
+            + out[f"prefill_{t}"]["output_bytes"])
+    print(json.dumps(out))
+    return 0
+
+
 def pool_write(topology: str) -> int:
     import math
     import re
@@ -767,6 +862,9 @@ if __name__ == "__main__":
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(ssm_step(topo, *(int(a) for a in sys.argv[3:4]),
                           *sys.argv[4:]))
+    if sys.argv[2:3] == ["eva_step"]:
+        os.environ["HOROVOD_PALLAS"] = "1"
+        sys.exit(eva_step(topo, *(int(a) for a in sys.argv[3:])))
     if sys.argv[2:] == ["kernels"]:
         os.environ["HOROVOD_PALLAS"] = "1"
         sys.exit(kernels(topo))

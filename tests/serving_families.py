@@ -1,9 +1,10 @@
 """What the serving tests share, in a module that is no test file: the
-tiny configurations of the six served families (and the second instance
-of the window-and-full block), ONE table of them, the loop that drives an
+tiny configurations of the seven served families (and the second
+instance of the window-and-full block), ONE table of them, the loop that drives an
 engine a round at a time, and the two lowering helpers.  No test file
 imports another; each takes these from here."""
 
+import functools
 import time
 
 import jax
@@ -84,6 +85,23 @@ TINY_SSM = {
     "serving": {"slots": 3, "page_size": 4, "max_len": 64},
     "limits": {"served_logit_gap_max": 1e-3}}
 
+# EVA attention (EvaByte's block) at a size that keeps its structure: a
+# window of 64 bytes in chunks and pages of 16 (four pooled rows a window:
+# a quarter of a growing page), 4 heads of 32, 8 x 320 head columns.
+TINY_EVA = {
+    "kind": "serve", "family": "eva_dense", "vocab_size": 320,
+    "hidden_size": 128, "intermediate_size": 192, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "window_size": 64,
+    "chunk_size": 16, "num_chunks": None, "num_pred_heads": 8,
+    "attention_bias": False, "attention_class": "eva",
+    "hidden_act": "silu", "norm_add_unit_offset": True,
+    "fp32_logits": True, "fp32_skip_add": True, "mixedp_attn": True,
+    "rope_scaling": None, "tie_word_embeddings": False,
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+    "max_position_embeddings": 512, "compute_dtype": "float32",
+    "serving": {"slots": 3, "page_size": 16, "max_len": 256},
+    "limits": {"served_logit_gap_max": 1e-3}}
+
 # The window-and-full block's second instance (SmallThinker's variant).
 EARLY_KINDS = ("full", "window", "window", "window")
 EARLY_WINDOW, EARLY_THETA, EARLY_EPS, EARLY_TOP_K = 8, 10000.0, 1e-6, 3
@@ -131,6 +149,13 @@ def ssm_hybrid(**over):
     return cfg, ssm_hybrid.init_params(cfg, jax.random.PRNGKey(0))
 
 
+def eva_dense(**over):
+    from benchmarks.families import eva_dense as family
+    from horovod_tpu.serving import eva_dense
+    cfg = family.program_config(dict(TINY_EVA, **over))
+    return cfg, eva_dense.init_params(cfg, jax.random.PRNGKey(0))
+
+
 def early_route(**over):
     from horovod_tpu.serving import swa_moe
     cfg = swa_moe.SwaMoeConfig(**dict(dict(
@@ -144,10 +169,15 @@ def early_route(**over):
     return cfg, swa_moe.init_params(cfg, jax.random.PRNGKey(0))
 
 
-# The six served families, a tiny engine's worth each.
+# The seven served families, a tiny engine's worth each.
+# (EVA attention with a window of 16 in chunks of 8 here: the engines
+# these tests share have pages of 8 and of 4 and contexts of 32, which
+# then span two windows, and a chunk of one page and of two.)
 FAMILIES = {"dense": dense, "mla_moe": mla_moe, "cca_moe": cca_moe,
             "loop_dense": loop_dense, "swa_moe": swa_moe,
-            "ssm_hybrid": ssm_hybrid}
+            "ssm_hybrid": ssm_hybrid,
+            "eva_dense": functools.partial(eva_dense, window_size=16,
+                                           chunk_size=8)}
 # ... and the window-and-full routed block in both its instances.
 FAMILIES_AND_EARLY_ROUTE = dict(FAMILIES, swa_moe_early_route=early_route)
 

@@ -1352,3 +1352,176 @@ def test_operands_that_do_not_fit_together_are_refused(k_shape, v_shape):
     x = jnp.zeros((1, 2, 64, 24))
     with pytest.raises(ValueError, match="do not fit together"):
         flash_attention(x, jnp.zeros(k_shape), jnp.zeros(v_shape))
+
+
+# ---------------------------------------------------------------------------
+# ``hvd_eva_decode``: one softmax over a ring of exact rows and the pooled
+# rows of the windows before, both in ONE pair of pools.
+# ---------------------------------------------------------------------------
+
+from horovod_tpu.ops.attention import eva_decode_attention
+
+# Pages and chunks of 8 rows; a window of 64 tokens is 8 pages of the ring
+# (of 9) and 8 pooled rows, ONE growing page: every boundary on an edge.
+_EVA_PAGE, _EVA_WINDOW, _EVA_RING, _EVA_PPS = 8, 64, 9, 3
+# Live tokens, the current one among them: inside the first window (no
+# pooled row), a window's last token, a window's FIRST token (one exact row
+# beside 8 and 16 pooled ones), mid-window and mid-page, an idle row.
+_EVA_LENGTHS = [1, 5, 64, 65, 129, 100, 191, 0, 150]
+
+
+def _eva_case(seed, lengths, h, kvh, d=128, page=_EVA_PAGE,
+              window=_EVA_WINDOW, ring=_EVA_RING, pps=_EVA_PPS):
+    """One pair of pools holding both groups' pages (two planes), shuffled
+    tables of each group, and what a slot's rows ARE, in order: its pooled
+    rows ``[b, pps * page, kvh * d]`` and the exact rows of its sequence
+    ``[b, tokens, kvh * d]`` (token ``n`` in ring entry ``n // page %
+    ring``; a page that a later token has overwritten holds the later
+    one)."""
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    pages = b * (pps + ring) + 1
+    shape = (2, pages, page, kvh * d)
+    keys = rng.normal(size=shape).astype(np.float32)
+    values = rng.normal(size=shape).astype(np.float32)
+    order = rng.permutation(pages - 1)
+    table = order[:b * pps].reshape(b, pps).astype(np.int32)
+    wtable = order[b * pps:].reshape(b, ring).astype(np.int32)
+    q = rng.normal(size=(b, h, d)).astype(np.float32)
+    return (jnp.asarray(q), jnp.asarray(keys), jnp.asarray(values),
+            jnp.asarray(table), jnp.asarray(wtable),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _eva_by_hand(q, keys, values, table, wtable, lengths, kvh, *, plane,
+                 page=_EVA_PAGE, window=_EVA_WINDOW, chunk=_EVA_PAGE):
+    """The equations a row at a time in numpy: the exact rows ``window *
+    w .. i`` out of the ring and the pooled rows ``c < w * window /
+    chunk`` out of the growing pages, ONE softmax."""
+    q, keys, values = (np.asarray(z, np.float64) for z in (q, keys, values))
+    b, h, d = q.shape
+    ring = wtable.shape[1]
+    out = np.zeros((b, h, d))
+    for s, n in enumerate(np.asarray(lengths)):
+        if n == 0:
+            continue
+        i = n - 1
+        w = i // window
+        ks, vs = [], []
+        for c in range(w * window // chunk):
+            pid, off = int(table[s, c // page]), c % page
+            ks.append(keys[plane, pid, off])
+            vs.append(values[plane, pid, off])
+        for j in range(w * window, i + 1):
+            pid, off = int(wtable[s, j // page % ring]), j % page
+            ks.append(keys[plane, pid, off])
+            vs.append(values[plane, pid, off])
+        ks, vs = (np.stack(z).reshape(len(ks), kvh, d) for z in (ks, vs))
+        for head in range(h):
+            g = head // (h // kvh)
+            z = ks[:, g] @ q[s, head] * d ** -0.5
+            p = np.exp(z - z.max())
+            out[s, head] = (p / p.sum()) @ vs[:, g]
+    return out
+
+
+@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("kernel", [True, False])
+def test_eva_decode_is_one_softmax_over_ring_and_pooled_rows(
+        monkeypatch, h, kvh, kernel):
+    q, keys, values, table, wtable, lens = _eva_case(
+        h + kernel, _EVA_LENGTHS, h, kvh)
+    want = _eva_by_hand(q, keys, values, table, wtable, lens, kvh, plane=1)
+    if kernel:
+        _walk_on(monkeypatch)
+    got = eva_decode_attention(
+        q, keys, table, wtable, layer=1, lengths=lens, window=_EVA_WINDOW,
+        row_tokens=_EVA_PAGE, kv_heads=kvh, scale=128 ** -0.5,
+        values=values, force_reference=not kernel)
+    assert got.shape == (len(_EVA_LENGTHS), h, 128)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    assert not np.any(np.asarray(got[_EVA_LENGTHS.index(0)]))
+
+
+def test_eva_decode_kernel_is_the_walk_under_its_own_name(monkeypatch):
+    q, keys, values, table, wtable, lens = _eva_case(2, [70, 0, 130], 4, 4)
+    _walk_on(monkeypatch)
+    text = str(jax.make_jaxpr(lambda *a: eva_decode_attention(
+        *a, layer=0, lengths=lens, window=_EVA_WINDOW, row_tokens=_EVA_PAGE,
+        kv_heads=4, scale=0.1, values=values))(q, keys, table, wtable))
+    assert "hvd_eva_decode" in text and "hvd_cca_decode" not in text
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_eva_decode_reads_nothing_it_does_not_see(monkeypatch, kernel):
+    """Huge, finite garbage in every row a slot does NOT attend: the ring's
+    rows past the current token and before the window (pages the window
+    has left behind and not yet overwritten), the pooled rows of the
+    window in progress and after, an idle slot's everything.  Nothing
+    moves by a bit."""
+    lengths = [5, 65, 0, 150, 129]
+    q, keys, values, table, wtable, lens = _eva_case(9, lengths, 4, 4)
+    if kernel:
+        _walk_on(monkeypatch)
+    kw = dict(layer=1, lengths=lens, window=_EVA_WINDOW,
+              row_tokens=_EVA_PAGE, kv_heads=4, scale=128 ** -0.5,
+              force_reference=not kernel)
+    clean = eva_decode_attention(q, keys, table, wtable, values=values, **kw)
+
+    def poisoned(pool):
+        pool = np.array(pool)
+        for s, n in enumerate(lengths):
+            i, w = n - 1, max(n - 1, 0) // _EVA_WINDOW
+            seen = w * _EVA_WINDOW // _EVA_PAGE if n else 0
+            for c in range(seen, _EVA_PPS * _EVA_PAGE):
+                pool[1, table[s, c // _EVA_PAGE], c % _EVA_PAGE] = 1e30
+            held = {j // _EVA_PAGE % _EVA_RING: j // _EVA_PAGE
+                    for j in range(w * _EVA_WINDOW, i + 1)} if n else {}
+            for entry in range(_EVA_RING):
+                for off in range(_EVA_PAGE):
+                    j = held.get(entry, -1) * _EVA_PAGE + off
+                    if entry not in held or j > i:
+                        pool[1, wtable[s, entry], off] = 1e30
+        return jnp.asarray(pool)
+
+    got = eva_decode_attention(q, poisoned(keys), table, wtable,
+                               values=poisoned(values), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+    assert not np.any(np.asarray(got[2]))
+
+
+def test_eva_decode_off_a_page_s_edge_takes_the_gathered_form(monkeypatch):
+    """A window whose pooled rows fill no whole page (64 tokens in chunks
+    of 16 over pages of 16: 4 pooled rows a window) cannot be walked as
+    one composed table: the same softmax over gathered views, kernels on
+    or off."""
+    lengths = [70, 130, 0, 64]
+    q, keys, values, table, wtable, lens = _eva_case(
+        4, lengths, 4, 4, page=16, ring=5, pps=2)
+    want = _eva_by_hand(q, keys, values, table, wtable, lens, 4, plane=0,
+                        page=16, chunk=16)
+    _walk_on(monkeypatch)
+    fn = lambda *a: eva_decode_attention(                        # noqa: E731
+        *a, layer=0, lengths=lens, window=64, row_tokens=16, kv_heads=4,
+        scale=128 ** -0.5, values=values)
+    assert "hvd_eva_decode" not in str(jax.make_jaxpr(fn)(
+        q, keys, table, wtable))
+    np.testing.assert_allclose(np.asarray(fn(q, keys, table, wtable)), want,
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_eva_decode_refuses_what_does_not_fit():
+    q, keys, values, table, wtable, lens = _eva_case(0, [4, 4], 4, 4)
+    kw = dict(layer=0, lengths=lens, row_tokens=_EVA_PAGE, kv_heads=4,
+              scale=1.0)
+    with pytest.raises(ValueError, match="do not fit together"):
+        eva_decode_attention(q, keys, table, wtable, window=_EVA_WINDOW,
+                             values=values[:, :-1], **kw)
+    with pytest.raises(ValueError, match="ring entries"):
+        # A window of 128 tokens wants 17 ring entries of 8 rows.
+        eva_decode_attention(q, keys, table, wtable, window=128,
+                             values=values, **kw)
+    with pytest.raises(ValueError, match="whole pages"):
+        eva_decode_attention(q, keys, table, wtable, window=60,
+                             values=values, **kw)

@@ -309,6 +309,32 @@ def test_topology_aot_ssm_step_updates_its_state_in_place():
         assert pre["resident_with_cache"] < 14.2e9
 
 
+def test_topology_aot_eva_step_walks_both_groups_out_of_one_pool():
+    """EvaByte's cut (PR 51) compiled for one v5e chip at the cell's 24
+    slots: ONE pair of pools of 4,441 pages a plane (24 x (56 growing +
+    129 ring) + the scratch page: 9.31 GB) holds both groups, the decode
+    step aliases both to their successors, holds one lowered walk under
+    its own name (``hvd_eva_decode``: the composed table is index
+    arithmetic, no gathered copy of a slot's rows) and nothing as large
+    as a twentieth of a plane as a temporary (the in-round pooling reads
+    24 ring pages a layer); a 7,500-byte prompt attends in four windows,
+    four shapes of the blocked flash forward, and fits beside 3.26 GB of
+    weights and the cache."""
+    out = _topology_worker("v5e:2x2", "eva_step", "24", "7500")
+    assert out["weight_bytes"] == 3_261_865_984
+    assert out["cache_bytes"] == 9_313_452_032
+    assert out["pool_shape"] == [8, 4441, 16, 4096]
+    step, pre = out["decode"], out["prefill_7500"]
+    assert step["aliased_params"] == step["pool_params"]
+    assert step["alias_bytes"] == out["cache_bytes"]
+    assert step["mosaic_calls"] == {"hvd_eva_decode": 1, "hvd_cca_decode": 0,
+                                    "hvd_flash_fwd": 0, "hvd_flash_hg_fwd": 0}
+    assert step["temp_bytes"] < out["plane_bytes"] / 20
+    assert pre["mosaic_calls"]["hvd_flash_fwd"] == 4
+    assert pre["temp_bytes"] < 1.4e9
+    assert pre["resident_with_cache"] < 14.4e9
+
+
 def test_topology_aot_prefill_write_scatters_whole_pages_in_place():
     """The program that follows every prefill (``kvcache._pool_set``)
     compiled for one v5e chip.  At Mistral's pool and a 512-token prompt:

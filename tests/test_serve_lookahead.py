@@ -149,10 +149,17 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
         # As the benchmark's wrapper counts what the round reads.
         counted.append(sum(int(eng.cache.lengths[s]) + 1
                            for s in eng._decode_slots()))
-        # Whole pages of one plane that the round's page walk copies.
-        paged.append(sum(-(-(int(eng.cache.lengths[s]) + 1)
-                           // eng.page_size)
-                         for s in eng._decode_slots()))
+        # Whole pages of one plane that the round's page walk copies: a
+        # slot's live tokens', or (pooled rows) its window's exact rows'
+        # and those of the pooled rows of the windows before.
+        spec, size = eng.spec, eng.page_size
+        paged.append(sum(
+            -(-n // size) if spec.row_tokens == 1 else
+            -(-((n - 1) % spec.window + 1) // size)
+            + -(-((n - 1) // spec.window * (spec.window // spec.row_tokens))
+                // size)
+            for n in (int(eng.cache.lengths[s]) + 1
+                      for s in eng._decode_slots())))
         return decode_once(st, now)
 
     def catching_up(st, now, dropped=()):
@@ -193,8 +200,9 @@ def test_one_round_ahead_serves_what_round_by_round_serves(family,
     assert [r.attrs["live_tokens"] for r in rounds] == counted
     assert [r.attrs["pages"] for r in rounds] == paged
     size = eng.page_size
-    assert all(p * size >= n > (p - r.attrs["slots"]) * size
-               for r, p, n in zip(rounds, paged, counted))
+    if eng.spec.row_tokens == 1:
+        assert all(p * size >= n > (p - r.attrs["slots"]) * size
+                   for r, p, n in zip(rounds, paged, counted))
     assert any(p * size > n for p, n in zip(paged, counted))
 
     # One program and one fetch a round.
@@ -253,7 +261,10 @@ def test_the_step_samples_and_screens_its_own_logits(family):
 
     held = st["last_tokens"].copy()
     logits, _, (sampled, finite, told) = run(held, no_round(SLOTS, tells))
-    assert list(sampled[:2]) == list(np.argmax(logits[:2], axis=-1))
+    # (A head of several predictions side by side: the next token's
+    # columns lead.)
+    assert list(sampled[:2]) == list(np.argmax(
+        logits[:2, :eng.config.vocab_size], axis=-1))
     assert finite[:2].all() and told.shape == (tells,)
 
     # The same tokens, left on the chip.
@@ -269,15 +280,25 @@ def test_the_step_samples_and_screens_its_own_logits(family):
     third, pool2, _ = run([-1, -1, 0], jnp.asarray(prev))
     np.testing.assert_array_equal(third[0], logits[0])
     row = int(cache.lengths[1])
-    page = int(cache.page_table[1, row // PAGE])
+
+    def page_of(row):
+        # (Pooled rows: a token's own row lies in the slot's ring.)
+        if eng.spec.row_tokens > 1:
+            return int(cache.window_table[1, row // PAGE
+                                          % cache.window_table.shape[1]])
+        return int(cache.page_table[1, row // PAGE])
+
+    page = page_of(row)
     assert np.asarray(pool[:, page, row % PAGE]).any()
     assert not np.asarray(pool2[:, page, row % PAGE]).any()
 
-    # A row gone bad shows in that slot's flag alone.
-    bad = int(cache.page_table[1, 0])
-    cache.k = cache.k.at[:, bad, 0].set(jnp.nan)
+    # A row gone bad shows in that slot's flag alone (a row the round
+    # attends: the first of all, or the newest of the window).
+    gone = 0 if eng.spec.row_tokens == 1 else row - 1
+    bad = page_of(gone)
+    cache.k = cache.k.at[:, bad, gone % PAGE].set(jnp.nan)
     if cache.v is not None:
-        cache.v = cache.v.at[:, bad, 0].set(jnp.nan)
+        cache.v = cache.v.at[:, bad, gone % PAGE].set(jnp.nan)
     logits, _, (_, finite, _) = run(held, no_round(SLOTS, tells))
     assert list(finite[:2]) == [True, False]
     assert np.isfinite(logits[0]).all() and not np.isfinite(logits[1]).all()

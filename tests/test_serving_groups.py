@@ -101,22 +101,26 @@ def _band_requests(cfg):
 
 def _held(eng, slot):
     """What the cache holds of ``slot``'s sequence: its length, its rows
-    in every plane of each pool, its ring's rows in the window planes,
-    its row of the slot state."""
+    in every plane of each pool (a row a token, or a pooled row a whole
+    chunk of ``row_tokens``), its ring's rows in the window planes (which
+    may lie in the pools themselves), its row of the slot state."""
+    from horovod_tpu.serving.kvcache import window_rows_from
     cache, c = eng.cache, eng.cache_config
     n = int(cache.lengths[slot])
-    pos = np.arange(n)
+    pos = np.arange(n // c.row_tokens)
     pages = cache.page_table[slot][pos // c.page_size]
     out = {"length": n, "k": np.asarray(cache.k)[:, pages, pos % c.page_size]}
     if cache.v is not None:
         out["v"] = np.asarray(cache.v)[:, pages, pos % c.page_size]
     if cache.window_table is not None:
-        first = max(n + 1 - c.window, 0)
+        first = window_rows_from(n, c.window, c.window_aligned)
         pos = np.arange(first, n)
         pages = cache.window_table[slot][
             pos // c.page_size % c.window_pages_per_slot]
-        out["wk"] = np.asarray(cache.wk)[:, pages, pos % c.page_size]
-        out["wv"] = np.asarray(cache.wv)[:, pages, pos % c.page_size]
+        wk, wv = (cache.k, cache.v) if c.window_in_pool \
+            else (cache.wk, cache.wv)
+        out["wk"] = np.asarray(wk)[:, pages, pos % c.page_size]
+        out["wv"] = np.asarray(wv)[:, pages, pos % c.page_size]
     if cache.state is not None:
         out["state"] = np.asarray(cache.state)[:, slot]
     return out
